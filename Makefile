@@ -101,12 +101,15 @@ scrape-cluster:
 scrape-devices:
 	env JAX_PLATFORMS=cpu $(PY) exp/scrape_devices.py
 
-# staged-pipeline smoke (exp/pipeline_smoke.py): boot the broker with
-# compaction + the 3-deep pipeline on, 1k-publish burst vs wildcard
-# subs, zero host-trie-oracle mismatches and a nonzero device duty
-# cycle; writes pipeline-smoke.json (uploaded as a CI artifact)
+# staged-pipeline smoke: the tiny-size CPU mode of chip_smoke.py (the
+# script the chip itself is proven with, ISSUE 21) — served path end to
+# end, deliveries checked against the filters, zero breaker/staging
+# fallbacks, device-vs-host parity, every kernel vs its host oracle;
+# writes pipeline-smoke.json (uploaded as a CI artifact)
 pipeline-smoke:
-	env JAX_PLATFORMS=cpu $(PY) exp/pipeline_smoke.py
+	set -o pipefail; env JAX_PLATFORMS=cpu $(PY) chip_smoke.py \
+	  --expect-platform cpu --subs 20000 --publishes 6000 \
+	  | tee pipeline-smoke.json
 
 # connection-scale smoke (exp/conn_smoke.py): boot the event-loop shard
 # fabric (loop_shards>1), ramp thousands of mostly-idle connections +

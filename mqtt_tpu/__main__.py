@@ -314,6 +314,15 @@ def cmd_serve(args, argv: list) -> int:
     if workers == 0:
         workers = os.cpu_count() or 1
     if workers > 1 and os.environ.get("MQTT_TPU_WORKER") is None:
+        from .cluster import ChipConflictError, require_one_process_per_chip
+
+        opts = config_mod.from_file(args.config) if args.config else None
+        try:
+            require_one_process_per_chip(
+                workers, opts is not None and opts.device_matcher
+            )
+        except ChipConflictError as e:
+            raise SystemExit(f"--workers {workers}: {e}")
         return _spawn_workers(argv, workers)
     if args.admin_user is not None:
         user, sep, pwd = args.admin_user.partition(":")

@@ -3186,6 +3186,43 @@ class Cluster:
         s._finish_remote_clock(pk)
 
 
+class ChipConflictError(RuntimeError):
+    """More than one process was asked to serve from the default JAX
+    device."""
+
+
+def require_one_process_per_chip(n_workers: int, device_matcher: bool) -> None:
+    """Refuse, AT LAUNCH, a worker fleet whose every process would
+    initialize the default JAX device. An accelerator chip belongs to
+    one process: on a one-chip host the second worker dies in backend
+    init after the first took the chip (seen on the v5e, PR 21: the
+    stress launcher fell over an AssertionError, the CLI launcher's
+    3-second readiness window can pass before the loser dies), and the
+    mesh would serve short a worker without saying so.
+
+    The launcher must not touch JAX itself (it would take the chip from
+    its own workers), so the one thing it can know is what the
+    environment asks for: ``JAX_PLATFORMS`` starting with ``cpu`` pins
+    every worker's matcher to the host platform, which any number of
+    processes share. Anything else may be an accelerator. Mapping
+    workers onto chips is ROADMAP D5."""
+    if n_workers <= 1 or not device_matcher:
+        return
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms.split(",")[0].strip().lower() == "cpu":
+        return
+    raise ChipConflictError(
+        f"{n_workers} workers with the device matcher: every worker "
+        "would initialize the default JAX device, and an accelerator "
+        "chip belongs to one process (JAX_PLATFORMS="
+        f"{platforms or '<unset>'!r}). Run one worker with "
+        "device_matcher (loop_shards spreads connections over cores), "
+        "turn device_matcher off for a host-only mesh, or set "
+        "JAX_PLATFORMS=cpu to run every worker's matcher on the host "
+        "platform. Workers are not mapped onto chips yet (ROADMAP D5)."
+    )
+
+
 def worker_env(
     worker_id: int,
     n_workers: int,

@@ -1,7 +1,8 @@
 """ctypes bindings for the native host data-plane core (mqtt_native.c).
 
 The shared library is compiled on demand from the checked-in C source
-(cached next to it, keyed on source mtime) and loaded via ctypes; every
+(cached next to it under a name keyed on a digest of that source plus
+the build flags, so a stale binary cannot load) and loaded via ctypes; every
 entry point has a pure-Python fallback, so the package works — just
 slower — when no C toolchain is present. ``lib()`` returns the loaded
 library or ``None``.
@@ -22,6 +23,7 @@ there is a single Python source of truth for those rules.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -41,6 +43,9 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 _ACCEL = None
 _ACCEL_TRIED = False
+# did THIS process compile the artifact (vs load one already on disk)
+_LIB_BUILT = False
+_ACCEL_BUILT = False
 
 # Per-scan frame cap: bounds the output arrays while the read loop keeps
 # rescanning until the buffer is drained, so it is not a throughput cap.
@@ -56,28 +61,34 @@ def _extra_cflags() -> list[str]:
     return flags.split() if flags else []
 
 
-def _so_tag() -> str:
+def source_digest(src: str) -> str:
+    """Digest of one C source PLUS the extra build flags — the part of
+    an artifact's name that makes a stale or differently-built binary
+    unloadable: the loader only ever opens the name computed from the
+    source on disk, so a ``.so`` built from other bytes (an old
+    checkout's, a sanitized build's) is simply never looked at. File
+    mtimes decide nothing: a copy of the tree does not preserve them."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(b"\0" + " ".join(_extra_cflags()).encode())
+    return h.hexdigest()[:12]
+
+
+def _so_path(stem: str, src: str) -> str:
     tag = f"{sys.implementation.cache_tag}-{os.uname().machine}"
-    flags = _extra_cflags()
-    if flags:
-        # a sanitized (or otherwise flag-modified) build must never
-        # poison the plain build's mtime cache — distinct artifact
-        # name, DETERMINISTIC across processes (hash() is seeded per
-        # process; a random tag would recompile on every run and leak
-        # uniquely-named .so files)
-        import hashlib
-
-        digest = hashlib.sha1(" ".join(flags).encode()).hexdigest()[:8]
-        tag += "-x" + digest
-    return tag
+    # flag-modified (sanitized) builds are throwaway: the ``x`` marks
+    # them for tools/c_gate.sh to sweep
+    mark = "x" if _extra_cflags() else ""
+    return os.path.join(
+        _HERE, f"{stem}-{tag}-{mark}{source_digest(src)}.so"
+    )
 
 
-def _so_path() -> str:
-    return os.path.join(_HERE, f"libmqtt_native-{_so_tag()}.so")
-
-
-def _build(so: str) -> bool:
-    """Compile mqtt_native.c → so. Returns False (and logs) on failure."""
+def _compile(src: str, so: str, extra: list[str]) -> bool:
+    """Compile ``src`` → ``so``. Returns False (and logs, loudly: the
+    Python fallbacks are correct but an order of magnitude slower, so a
+    broker that lost its C core must say so) on failure."""
     for cc in (os.environ.get("CC"), "cc", "gcc", "clang"):
         if not cc:
             continue
@@ -87,17 +98,23 @@ def _build(so: str) -> bool:
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
         os.close(fd)
         try:
-            cmd = [cc, "-O3", "-shared", "-fPIC", *_extra_cflags(),
-                   "-o", tmp, _SRC]
+            cmd = [cc, "-O3", "-shared", "-fPIC", *_extra_cflags(), *extra,
+                   "-o", tmp, src]
             # brokerlint: ok=R14 the compile is the whole point of the lock (single-flight build)
             r = subprocess.run(cmd, capture_output=True, timeout=120)
             if r.returncode == 0:
                 # brokerlint: ok=R14 atomic publish of the built library, still under the single-flight build lock
                 os.replace(tmp, so)
                 return True
-            _log.debug("native build with %s failed: %s", cc, r.stderr.decode())
+            _log.warning(
+                "native build of %s with %s failed: %s",
+                os.path.basename(src), cc, r.stderr.decode(errors="replace"),
+            )
         except (OSError, subprocess.SubprocessError) as e:
-            _log.debug("native build with %s failed: %s", cc, e)
+            _log.warning(
+                "native build of %s with %s failed: %s",
+                os.path.basename(src), cc, e,
+            )
         finally:
             if os.path.exists(tmp):
                 # brokerlint: ok=R14 temp-file cleanup on the single-flight build path
@@ -108,7 +125,7 @@ def _build(so: str) -> bool:
 def lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, building it on first use; None if
     unavailable (no toolchain / unsupported platform)."""
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _LIB_BUILT
     if _LIB is not None or _TRIED:
         return _LIB
     with _LOCK:
@@ -120,18 +137,17 @@ def lib() -> Optional[ctypes.CDLL]:
         if sys.byteorder != "little":
             # the C hashing assumes little-endian loads; on big-endian hosts
             # its hashes would silently disagree with the host-side oracle
-            _log.debug("native core disabled: big-endian host")
+            _log.warning("native core disabled: big-endian host")
             return None
-        so = _so_path()
+        so = _so_path("libmqtt_native", _SRC)
         try:
-            stale = (not os.path.exists(so)) or (
-                os.path.getmtime(so) < os.path.getmtime(_SRC)
-            )
-            if stale and not _build(so):
-                return None
+            if not os.path.exists(so):
+                if not _compile(_SRC, so, []):
+                    return None
+                _LIB_BUILT = True
             cdll = ctypes.CDLL(so)
         except OSError as e:
-            _log.debug("native library unavailable: %s", e)
+            _log.warning("native library unavailable: %s", e)
             return None
         _declare(cdll)
         _LIB = cdll
@@ -190,48 +206,13 @@ def available() -> bool:
     return lib() is not None
 
 
-def _accel_so_path() -> str:
-    return os.path.join(_HERE, f"mqtt_accel-{_so_tag()}.so")
-
-
-def _build_accel(so: str) -> bool:
-    """Compile accelmod.c → a CPython extension .so. Unlike mqtt_native.c
-    (plain C via ctypes), the materializer builds Python result objects, so
-    it compiles against the CPython headers and loads as a real extension
-    module."""
-    import sysconfig
-
-    include = sysconfig.get_paths()["include"]
-    for cc in (os.environ.get("CC"), "cc", "gcc", "clang"):
-        if not cc:
-            continue
-        # brokerlint: ok=R14 single-flight first-call build: the lock exists to serialize this compile; never on a frame path
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
-        os.close(fd)
-        try:
-            cmd = [cc, "-O3", "-shared", "-fPIC", *_extra_cflags(),
-                   f"-I{include}", "-o", tmp, _ACCEL_SRC]
-            # brokerlint: ok=R14 the compile is the whole point of the lock (single-flight build)
-            r = subprocess.run(cmd, capture_output=True, timeout=120)
-            if r.returncode == 0:
-                # brokerlint: ok=R14 atomic publish of the built library, still under the single-flight build lock
-                os.replace(tmp, so)
-                return True
-            _log.debug("accel build with %s failed: %s", cc, r.stderr.decode())
-        except (OSError, subprocess.SubprocessError) as e:
-            _log.debug("accel build with %s failed: %s", cc, e)
-        finally:
-            if os.path.exists(tmp):
-                # brokerlint: ok=R14 temp-file cleanup on the single-flight build path
-                os.unlink(tmp)
-    return False
-
-
 def accel():
-    """The C materializer extension module (PROFILE.md §4's planned native
-    result path), building it on first use; None when unavailable. Every
-    caller keeps the pure-Python path as fallback and source of truth."""
-    global _ACCEL, _ACCEL_TRIED
+    """The C materializer extension module, building it on first use;
+    None when unavailable. Unlike mqtt_native.c (plain C via ctypes) it
+    builds Python result objects, so it compiles against the CPython
+    headers and loads as a real extension module. Every caller keeps the
+    pure-Python path as fallback and source of truth."""
+    global _ACCEL, _ACCEL_TRIED, _ACCEL_BUILT
     if _ACCEL is not None or _ACCEL_TRIED:
         return _ACCEL
     with _LOCK:
@@ -240,13 +221,15 @@ def accel():
         _ACCEL_TRIED = True
         if os.environ.get("MQTT_TPU_NO_NATIVE"):
             return None
-        so = _accel_so_path()
+        so = _so_path("mqtt_accel", _ACCEL_SRC)
         try:
-            stale = (not os.path.exists(so)) or (
-                os.path.getmtime(so) < os.path.getmtime(_ACCEL_SRC)
-            )
-            if stale and not _build_accel(so):
-                return None
+            if not os.path.exists(so):
+                import sysconfig
+
+                include = sysconfig.get_paths()["include"]
+                if not _compile(_ACCEL_SRC, so, [f"-I{include}"]):
+                    return None
+                _ACCEL_BUILT = True
             import importlib.machinery
             import importlib.util
 
@@ -258,9 +241,27 @@ def accel():
             loader.exec_module(mod)
             _ACCEL = mod
         except (OSError, ImportError) as e:
-            _log.debug("accel module unavailable: %s", e)
+            _log.warning("accel module unavailable: %s", e)
             return None
         return _ACCEL
+
+
+def status() -> dict:
+    """Whether each native module is loaded, whether THIS process had to
+    build it, and the source digest its artifact is keyed on — what
+    chip_smoke.py reports and requires (both modules loaded)."""
+    return {
+        "lib": {
+            "loaded": lib() is not None,
+            "built": _LIB_BUILT,
+            "digest": source_digest(_SRC),
+        },
+        "accel": {
+            "loaded": accel() is not None,
+            "built": _ACCEL_BUILT,
+            "digest": source_digest(_ACCEL_SRC),
+        },
+    }
 
 
 # -- high-level wrappers ----------------------------------------------------

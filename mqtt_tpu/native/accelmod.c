@@ -8,9 +8,9 @@
  * inline subscriptions keyed on identifier — value-identical to the host
  * trie gather (reference gatherSubscriptions, topics.go:631-678).
  *
- * Pure-Python expansion caps the pipeline at the ~60-70K topics/s CPython
- * allocation floor measured in PROFILE.md §4 no matter how fast the device
- * kernel runs. This module performs the same expansion through the C API,
+ * Pure-Python expansion caps the pipeline at the CPython allocation floor
+ * no matter how fast the device kernel runs. This module performs the
+ * same expansion through the C API,
  * exploiting the slots layout of the result types (packets.Subscription,
  * topics.Subscribers are `slots` classes): a per-type descriptor-offset
  * table is read once from the class's member descriptors, after which a

@@ -2,7 +2,7 @@
 
 This package lifts the reference's hot loop — ``TopicsIndex.Subscribers()``
 (reference topics.go:583-628), the wildcard trie walk executed once per
-PUBLISH — onto the TPU as a multi-probe flat-hash join (PROFILE.md):
+PUBLISH — onto the TPU as a multi-probe flat-hash join:
 
 - ``flat``     — compiles the host trie into a device-resident flat hash
                  table keyed by whole-path hashes; the jitted match kernel

@@ -177,6 +177,9 @@ class DeltaMatcher:
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        # background rebuilds that raised (logged and retried; a smoke
+        # that must not pass on a degraded matcher reads this)
+        self.rebuild_errors = 0
         # ONE snapshot matcher reused across generations: both matcher kinds
         # swap their compiled state atomically, and the sharded one folds
         # deltas incrementally (per-shard) instead of recompiling the world
@@ -304,6 +307,7 @@ class DeltaMatcher:
             except Exception:
                 # never let the rebuild thread die: a degraded matcher keeps
                 # serving (host path), a dead one degrades forever
+                self.rebuild_errors += 1
                 _log.exception("background CSR rebuild failed; will retry")
                 self._stop.wait(1.0)
                 self._wake.set()

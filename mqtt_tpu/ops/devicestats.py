@@ -33,6 +33,7 @@ device_stats -> metrics_registry edge exists.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import weakref
@@ -116,6 +117,11 @@ class CompileLedger:
         self._counts: dict[str, int] = {}
         self._events: deque = deque(maxlen=_EVENT_RING)
         self._total = 0
+        # the compile clock (compile_clock): seconds of FINISHED
+        # first-signature calls, plus the start stamps of running ones
+        self._compile_done_s = 0.0
+        self._compiling: dict[int, float] = {}
+        self._compile_seq = 0
         self.compile_hist = Histogram(bounds=COMPILE_BOUNDS)
         self._registries: "weakref.WeakSet" = weakref.WeakSet()
 
@@ -149,6 +155,36 @@ class CompileLedger:
         )
 
     # -- event intake ------------------------------------------------------
+
+    @contextlib.contextmanager
+    def compiling(self):
+        """The compile clock runs while the body does: KernelWatch wraps
+        every first-signature call in it."""
+        with self._lock:
+            self._compile_seq += 1
+            token = self._compile_seq
+            self._compiling[token] = time.perf_counter()
+        try:
+            yield
+        finally:
+            now = time.perf_counter()
+            with self._lock:
+                self._compile_done_s += now - self._compiling.pop(token)
+
+    def compile_clock(self) -> float:
+        """Seconds this process has spent inside first-signature jit
+        calls, INCLUDING the ones still running. Cold compile is set-up,
+        not service and not a hang: the staging controller drops any
+        service-time sample during which this clock moved
+        (staging._drain_loop), and the dispatch watchdog runs on wall
+        time minus this clock (resilience._GuardTask.wait). Concurrent
+        compiles each count, so the clock may outrun the wall — it only
+        ever errs toward treating a window as set-up."""
+        now = time.perf_counter()
+        with self._lock:
+            return self._compile_done_s + sum(
+                now - t0 for t0 in self._compiling.values()
+            )
 
     def note_compile(self, kernel: str, shape_bucket: str, seconds: float) -> None:
         """Record one compile event; the single seam every jit entry
@@ -254,7 +290,8 @@ class KernelWatch:
         if key in self._seen:
             return self.fn(*args, **kwargs)
         t0 = time.perf_counter()
-        out = self.fn(*args, **kwargs)
+        with self.ledger.compiling():
+            out = self.fn(*args, **kwargs)
         seconds = time.perf_counter() - t0
         with self._lock:
             new = key not in self._seen
@@ -290,19 +327,23 @@ class DeviceStatsPlane:
         registry=None,
         hbm_watermark: float = 0.9,
         ledger: Optional[CompileLedger] = None,
+        devices: Optional[list] = None,
     ) -> None:
         self.registry = registry
         self.hbm_watermark = float(hbm_watermark)
         self.ledger = LEDGER if ledger is None else ledger
         self.profiler = None  # tracing.DeviceProfiler, per-device windows
         self.matcher = None  # ShardedTpuMatcher for tile/skew state
-        self._devices: list = []
-        try:
+        # ``devices=None`` enumerates the default backend, which
+        # INITIALIZES it — and a chip belongs to one process. A broker
+        # with no device engine passes ``[]`` (ledger-only plane) so a
+        # host-only worker never takes the chip from the process that
+        # serves from it (server.py).
+        if devices is None:
             import jax
 
-            self._devices = list(jax.devices())
-        except Exception:  # brokerlint: ok=R4 no jax backend: the plane degrades to ledger-only rather than failing broker boot
-            self._devices = []
+            devices = jax.devices()
+        self._devices: list = list(devices)
         if registry is not None:
             self.ledger.bind_registry(registry)
             for d in self._devices:

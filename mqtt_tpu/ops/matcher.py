@@ -7,9 +7,9 @@ and merges results host-side — bit-identical to
 every case the device cannot prove is re-walked on the host trie.
 
 The previous CSR/NFA trie-walk kernel was retired in round 4: it was
-gather-bound at ~65K topics/s on hardware whose random-gather rate caps
-any per-level walk two orders of magnitude below the 10M/s target; see
-PROFILE.md for the trace-backed analysis and the flat design's budget.
+gather-bound, and a per-level walk issues orders of magnitude more
+gathers per topic than the flat design's one row per probe shape
+(ops/flat.py).
 """
 
 from __future__ import annotations
@@ -358,6 +358,12 @@ class MatcherStats:
     compact_batches: int = 0
     compact_overflows: int = 0
     d2h_bytes: int = 0
+    # the LAST full rebuild, split into its host and device halves: the
+    # flat-index build, the (completed) H2D upload, and the host bytes
+    # of the arrays uploaded — what chip_smoke.py holds HBM in use to
+    table_bytes: int = 0
+    build_seconds: float = 0.0
+    upload_seconds: float = 0.0
     # optional per-rebuild duration observer (the telemetry plane's
     # compile/rebuild histogram — mqtt_tpu.telemetry); set by the server
     rebuild_observer: Optional[Callable[[float], None]] = None
@@ -479,19 +485,22 @@ class TpuMatcher:
             window=self.window,
             cooperative=self.cooperative,
         )
-        device_arrays = tuple(
-            jnp.asarray(a)
-            for a in (
-                flat.table,
-                flat.pat_kind,
-                flat.pat_depth,
-                flat.pat_mask,
-            )
-        )
+        t_built = time.perf_counter()
+        host_arrays = (flat.table, flat.pat_kind, flat.pat_depth, flat.pat_mask)
+        device_arrays = tuple(jnp.asarray(a) for a in host_arrays)
+        for arr in device_arrays:
+            # the upload belongs to the rebuild, not to the first match
+            # that would otherwise wait for it
+            arr.block_until_ready()
+        t_up = time.perf_counter()
         self._state = (flat, device_arrays, version)
         self._fold_poisoned = False
-        self.stats.rebuilds += 1
-        self.stats.note_rebuild(time.perf_counter() - t0)
+        stats = self.stats
+        stats.rebuilds += 1
+        stats.table_bytes = sum(int(a.nbytes) for a in host_arrays)
+        stats.build_seconds = t_built - t0
+        stats.upload_seconds = t_up - t_built
+        stats.note_rebuild(t_up - t0)
         # warm the C materializer off the publish path: its first use
         # otherwise triggers a synchronous cc compile inside the first
         # batch's resolve (seconds of publish latency on a cold host)
@@ -646,9 +655,7 @@ class TpuMatcher:
         tok1, tok2, lengths, is_dollar, len_overflow = tokenize_topics(
             padded, flat.max_levels, flat.salt
         )
-        # the host copy stays alive for the overflow fallback's re-upload:
-        # the compact dispatch may DONATE the device-side staging buffer
-        # (flat.donation_supported), after which it must not be reused
+        # the host copy stays alive for the overflow fallback's re-upload
         host_tokens = pack_tokens(tok1, tok2, lengths, is_dollar)
         P = flat.pat_depth.shape[0]
         use_compact = self.compact and P > 0 and self._compact_pays(P)
@@ -667,13 +674,10 @@ class TpuMatcher:
                 jnp.asarray(host_tokens),
                 max_levels=flat.max_levels,
             )
-        try:
-            # start the D2H as soon as the kernel finishes instead of when
-            # the resolver blocks: on a high-RTT tunneled link this overlaps
-            # the transfer with the pipeline's other in-flight batches
-            out_dev.copy_to_host_async()
-        except AttributeError:  # pragma: no cover - older jax arrays
-            pass
+        # start the D2H as soon as the kernel finishes instead of when
+        # the resolver blocks: the transfer overlaps the pipeline's other
+        # in-flight batches
+        out_dev.copy_to_host_async()
         if prof is not None:
             # device pipeline profiler: the issue leg (tokenize + H2D +
             # async dispatch) ends here; the device window opens now.

@@ -126,17 +126,14 @@ def _jit_agg_reduce():
 agg_reduce = _LazyJit(_jit_agg_reduce, kernel="agg_reduce")
 
 
-def agg_reduce_batch(pending: list) -> Optional[np.ndarray]:
+def agg_reduce_batch(pending: list) -> np.ndarray:
     """Host driver for one fused window-reduction dispatch. ``pending``
     is a list of ``(op_code, values)`` with ``values`` a non-empty
-    sequence of floats; returns float32 ``[len(pending)]`` aggregates,
-    or None when no jax backend is importable (the caller host-reduces).
+    sequence of floats; returns float32 ``[len(pending)]`` aggregates.
     Shapes are power-of-two bucketed so churn in window count or width
     reuses a handful of jitted executables."""
-    try:
-        import jax.numpy as jnp
-    except ImportError:
-        return None
+    import jax.numpy as jnp
+
     w = len(pending)
     n = max(len(values) for _op, values in pending)
     wp = _bucket(max(1, w), minimum=2)
@@ -225,12 +222,9 @@ class DeviceRuleEvaluator:
         rows_dev = rules_eval(
             *arrays, jnp.asarray(feats), jnp.asarray(cmask)
         )
-        try:
-            # overlap the D2H with the rest of the staged batch (the
-            # topic matcher does the same for its packed result)
-            rows_dev.copy_to_host_async()
-        except AttributeError:  # pragma: no cover - older jax arrays
-            pass
+        # overlap the D2H with the rest of the staged batch (the topic
+        # matcher does the same for its packed result)
+        rows_dev.copy_to_host_async()
 
         def resolve() -> np.ndarray:
             # brokerlint: ok=R15 the blessed resolve seam: ONE batched D2H after copy_to_host_async

@@ -35,7 +35,7 @@ host on live traffic.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable
 
 import numpy as np
 
@@ -282,10 +282,9 @@ def xor_into(data: bytes, ks_rows: np.ndarray) -> bytes:
 
 def keystream_async(
     key_table: np.ndarray, kidx: np.ndarray, counters: np.ndarray
-) -> Optional[Callable[[], np.ndarray]]:
+) -> Callable[[], np.ndarray]:
     """Dispatch one fused keystream batch on the device; returns a
-    zero-arg resolver yielding uint8 [N, 16] keystream rows, or None
-    when no jax backend is importable (the caller host-generates).
+    zero-arg resolver yielding uint8 [N, 16] keystream rows.
 
     The block axis is power-of-two bucketed (padding rows use key 0 /
     zero counters — don't-care work, sliced off at resolve) so fan-out
@@ -293,10 +292,8 @@ def keystream_async(
     ships at its true size (one executable per distinct key-count
     bucket would thrash — the table is tiny and `take` is shape-agnostic
     in the block axis only)."""
-    try:
-        import jax.numpy as jnp
-    except ImportError:
-        return None
+    import jax.numpy as jnp
+
     n = len(kidx)
     pad_n = _bucket(max(1, n), minimum=16)
     if pad_n != n:
@@ -309,12 +306,9 @@ def keystream_async(
     rows_dev = keystream(
         jnp.asarray(key_table), jnp.asarray(kidx), jnp.asarray(counters)
     )
-    try:
-        # overlap the D2H with the rest of the staged batch (the topic
-        # matcher and predicate kernels do the same)
-        rows_dev.copy_to_host_async()
-    except AttributeError:  # pragma: no cover - older jax arrays
-        pass
+    # overlap the D2H with the rest of the staged batch (the topic
+    # matcher and predicate kernels do the same)
+    rows_dev.copy_to_host_async()
 
     def resolve() -> np.ndarray:
         # brokerlint: ok=R15 the blessed resolve seam: ONE batched D2H after copy_to_host_async

@@ -117,8 +117,7 @@ class Subscription:
 
     ``slots=True`` pins every field at a fixed offset: the C materializer
     (native/accelmod.c) copies instances as nine pointer moves instead of
-    a dict clone — the difference between ~900ns and ~150ns per
-    subscription on the per-publish result path (PROFILE.md §4)."""
+    a dict clone on the per-publish result path."""
 
     filter: str = ""
     share_name: list[str] = field(default_factory=list)

@@ -1046,8 +1046,7 @@ class PredicateEngine:
                 values_out = agg_reduce_batch(
                     [(op, values) for _k, _t, _s, op, values in agg_pending]
                 )
-                if values_out is not None:
-                    self.breaker.record_success()
+                self.breaker.record_success()
             except Exception:
                 _log.exception("device window reduction failed; host path")
                 self.device_errors += 1

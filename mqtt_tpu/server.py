@@ -242,7 +242,7 @@ class Options:
     breaker_failure_threshold: int = 3
     # per-batch hang budget: a dispatch not resolved within this is
     # abandoned and served from the host walk. A last-resort hang bound,
-    # NOT a latency control — keep it above worst-case cold-compile time.
+    # NOT a latency control. Cold-compile time does not count against it.
     breaker_watchdog_ms: float = 5000.0
     # half-open probe schedule: exponential backoff from the base delay up
     # to the max, +/- the jitter fraction; this many verified-healthy
@@ -1204,9 +1204,19 @@ class Server:
             if opts.device_stats:
                 from .ops.devicestats import DeviceStatsPlane
 
+                # only a broker that keeps state on the device may
+                # enumerate (= initialize) the backend: a chip belongs
+                # to one process, so a host-only worker beside a
+                # device-matcher broker gets the ledger-only plane
                 plane = DeviceStatsPlane(
                     registry=self.telemetry.registry,
                     hbm_watermark=opts.device_hbm_watermark,
+                    devices=(
+                        None
+                        if self.matcher is not None
+                        or self._retained_engine is not None
+                        else []
+                    ),
                 )
                 if self.profiler is not None:
                     plane.attach_profiler(self.profiler)

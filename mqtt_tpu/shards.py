@@ -4,9 +4,9 @@ connections (ROADMAP item 4 / ISSUE 15).
 
 The inherited model — one asyncio loop, one read task per connection —
 serializes every socket wakeup, every decode, and every fan-out behind a
-single thread: receive flatness collapses ~10x going 10 -> 100 clients
-(BENCH_r05 receive_flatness ~0.095) while production MQTT means 100k-1M
-mostly-idle devices. The fabric splits that front-end:
+single thread: per-client receive rate collapses as clients grow, while
+production MQTT means 100k-1M mostly-idle devices. The fabric splits
+that front-end:
 
 - ``LoopShard``: a daemon thread running its own event loop, its own
   read-side :class:`~mqtt_tpu.clients.ScanGate` (decode batching is

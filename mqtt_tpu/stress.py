@@ -316,8 +316,8 @@ async def run_flatness(
     success criterion as one number): run the stresser workload at a
     small and a large client count against the same broker and report
     the ratio of per-client receive medians. A flat broker holds ~1.0;
-    today's thread-per-connection re-encode path collapses toward 0
-    as clients grow (8.3k -> 879 msgs/s going 10 -> 100 in BENCH_r05).
+    a thread-per-connection re-encode path collapses toward 0 as
+    clients grow.
     bench.py config 8 embeds this block so the stage gate can watch the
     number per round."""
     small = await run_stress(host, port, clients_small, msgs_small, **kw)
@@ -1319,8 +1319,9 @@ def _cluster_launcher(
     import tempfile
     import threading
 
-    from .cluster import worker_env
+    from .cluster import require_one_process_per_chip, worker_env
 
+    require_one_process_per_chip(workers, device_matcher)
     sock_dir = tempfile.mkdtemp(prefix="mqtt-tpu-cluster-")
     log_dir = os.environ.get("MQTT_TPU_WORKER_LOG_DIR", "")
     if log_dir:

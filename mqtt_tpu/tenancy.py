@@ -770,10 +770,6 @@ class RecryptEngine:
                 spans.append((j, off, off + j.n_blocks))
                 off += j.n_blocks
             resolver = keystream_async(table, kidx, counters)
-            if resolver is None:
-                if probing:
-                    breaker.record_probe_failure("no_backend")
-                return None
         except Exception:
             _log.exception("recrypt device issue failed; host path")
             self.device_errors += 1
@@ -946,13 +942,11 @@ class RecryptEngine:
             and self.breaker.allow()
         ):
             try:
-                resolver = keystream_async(table, kidx, counters)
-                if resolver is not None:
-                    rows = resolver()
-                    self.breaker.record_success()
-                    self.device_batches += 1
-                    self.device_blocks += total
-                    self._maybe_oracle(table, kidx, counters, rows)
+                rows = keystream_async(table, kidx, counters)()
+                self.breaker.record_success()
+                self.device_batches += 1
+                self.device_blocks += total
+                self._maybe_oracle(table, kidx, counters, rows)
             except Exception:
                 _log.exception("recrypt fan-out dispatch failed; host path")
                 self.device_errors += 1
@@ -1047,13 +1041,11 @@ class RecryptEngine:
             and self.breaker.allow()
         ):
             try:
-                resolver = keystream_async(table, kidx, counters)
-                if resolver is not None:
-                    rows = resolver()
-                    self.breaker.record_success()
-                    self.device_batches += 1
-                    self.device_blocks += 2 * total
-                    self._maybe_oracle(table, kidx, counters, rows)
+                rows = keystream_async(table, kidx, counters)()
+                self.breaker.record_success()
+                self.device_batches += 1
+                self.device_blocks += 2 * total
+                self._maybe_oracle(table, kidx, counters, rows)
             except Exception:
                 _log.exception("recrypt re-seal dispatch failed; host path")
                 self.device_errors += 1

@@ -4,8 +4,8 @@ The broker's hot paths (packet decode, publish fan-out, device-match
 result materialization) allocate hundreds of thousands of short-to-medium
 lived objects per second. CPython's default gen-0 threshold (700
 allocations) makes the collector run hundreds of times per match batch,
-re-scanning the same young survivors each time — measured at ~2x the
-entire resolve cost on a 16K-topic batch (PROFILE.md §4). The reference
+re-scanning the same young survivors each time, at a cost comparable to
+the resolve work itself on a large batch. The reference
 broker runs on Go's concurrent collector and never pays an equivalent
 stop-the-world tax, so tuning this is table stakes for host-plane parity.
 

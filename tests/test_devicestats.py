@@ -88,6 +88,44 @@ class TestCompileLedger:
         assert led.total() == 3
         assert len(calls) == 7  # the wrapped fn ran every time
 
+    def test_compile_clock_covers_running_and_finished_first_calls(self):
+        """The compile clock (staging + watchdog read it) runs while a
+        first-signature call is IN FLIGHT, keeps its seconds once the
+        call ends — also when it raises — and stands still on a warm
+        signature."""
+        import threading
+
+        led = CompileLedger()
+        entered, release = threading.Event(), threading.Event()
+
+        def kernel(x, fail=False):
+            entered.set()
+            release.wait(5)
+            if fail:
+                raise RuntimeError("compiler refused")
+
+        w = KernelWatch("k", kernel, ledger=led)
+        x = np.zeros((16, 4), np.int32)
+        assert led.compile_clock() == 0.0
+        t = threading.Thread(target=w, args=(x,))
+        t.start()
+        assert entered.wait(5)
+        c1 = led.compile_clock()
+        time.sleep(0.05)
+        c2 = led.compile_clock()
+        assert c2 > c1 >= 0.0  # in flight: the clock is running
+        release.set()
+        t.join(5)
+        assert not t.is_alive()
+        done = led.compile_clock()
+        assert done >= c2 and led.compile_clock() == done  # stopped
+        w(x)  # warm signature: no clock movement, no ledger event
+        assert led.compile_clock() == done and led.total() == 1
+        with pytest.raises(RuntimeError):
+            w(np.zeros((32, 4), np.int32), fail=True)
+        assert led.compile_clock() >= done and led.total() == 1
+        assert not led._compiling  # every window closed
+
     def test_attribution_names_kernel_and_shapes(self):
         led = CompileLedger()
         w = KernelWatch("flat_match_compact", lambda *a, **kw: None, ledger=led)
@@ -449,6 +487,20 @@ class TestHealthzDevices:
 
         run(scenario())
 
+    def test_host_only_broker_never_enumerates_the_backend(self):
+        """A chip belongs to one process: a broker with no device engine
+        keeps the ledger-only plane (compile counts, no per-device
+        rows), so a host-only worker cannot take the chip."""
+
+        async def scenario():
+            h = Harness(Options(inline_client=True))
+            snap = h.server.device_stats.snapshot()
+            assert snap["n_devices"] == 0 and snap["devices"] == []
+            assert "compiles" in snap
+            await h.shutdown()
+
+        run(scenario())
+
     def test_device_stats_off_removes_plane_and_endpoint(self):
         async def scenario():
             h = Harness(Options(inline_client=True, device_stats=False))
@@ -461,7 +513,9 @@ class TestHealthzDevices:
 
     def test_sys_tree_rows_published(self):
         async def scenario():
-            h = Harness(Options(inline_client=True))
+            # per-device rows need a device engine: a host-only broker
+            # never enumerates (= initializes) the backend
+            h = Harness(Options(inline_client=True, device_matcher=True))
             srv = h.server
             srv.publish_sys_topics()
             pks = srv.topics.messages(SYS_PREFIX + "/broker/devices/#")

@@ -346,7 +346,9 @@ class TestDeliveryDifferential:
                 )
                 await w.drain()
                 assert (await read_wire_packet(r, ver)).fixed_header.type == SUBACK
-                conns[cid] = (r, ver)
+                # the writer is HELD: Python 3.12's StreamWriter.__del__
+                # closes a dropped writer and disconnects the subscriber
+                conns[cid] = (r, ver, w)
             h.server.matcher.flush()
             pr, pw, _ = await h.connect("src")
             pid = 1
@@ -357,7 +359,7 @@ class TestDeliveryDifferential:
                 pid += 1
             await pw.drain()
             got = {}
-            for cid, (r, ver) in conns.items():
+            for cid, (r, ver, _w) in conns.items():
                 got[cid] = await asyncio.wait_for(
                     # generous: the first staged batch pays the XLA
                     # compile of the match kernel inside this wait

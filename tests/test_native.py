@@ -466,3 +466,63 @@ def test_expand_snap_matches_python():
     # empty snapshot
     empty = acc.expand_snap(((), (), ()), Subscribers)
     assert not empty.subscriptions and not empty.shared
+
+
+class TestArtifactNaming:
+    """The loader opens only the name computed from the source on disk
+    (ISSUE 21): a digest of the C source plus the build flags. A binary
+    built from other bytes has another name and is never looked at;
+    mtimes — which a copy of the tree does not preserve — decide
+    nothing."""
+
+    def test_name_carries_the_source_digest(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("MQTT_TPU_NATIVE_CFLAGS", raising=False)
+        src = tmp_path / "m.c"
+        src.write_bytes(b"int f(void) { return 1; }\n")
+        d1 = native.source_digest(str(src))
+        p1 = native._so_path("libmqtt_native", str(src))
+        assert p1.endswith(f"-{d1}.so") and len(d1) == 12
+        # same bytes, any mtime: same name
+        import os
+
+        os.utime(src, (1, 1))
+        assert native._so_path("libmqtt_native", str(src)) == p1
+        # other bytes: another name, so the old binary cannot load
+        src.write_bytes(b"int f(void) { return 2; }\n")
+        assert native.source_digest(str(src)) != d1
+        assert native._so_path("libmqtt_native", str(src)) != p1
+
+    def test_flags_change_the_name_and_mark_it_throwaway(
+        self, tmp_path, monkeypatch
+    ):
+        src = tmp_path / "m.c"
+        src.write_bytes(b"int f(void) { return 1; }\n")
+        monkeypatch.delenv("MQTT_TPU_NATIVE_CFLAGS", raising=False)
+        plain = native._so_path("mqtt_accel", str(src))
+        monkeypatch.setenv("MQTT_TPU_NATIVE_CFLAGS", "-fsanitize=address")
+        san = native._so_path("mqtt_accel", str(src))
+        assert san != plain
+        # tools/c_gate.sh sweeps sanitized artifacts by this mark
+        assert f"-x{native.source_digest(str(src))}.so" in san
+
+    @needs_native
+    def test_loaded_modules_report_their_digest(self):
+        st = native.status()
+        assert st["lib"]["loaded"] and st["accel"]["loaded"]
+        assert st["lib"]["digest"] == native.source_digest(native._SRC)
+        assert st["accel"]["digest"] == native.source_digest(
+            native._ACCEL_SRC
+        )
+
+    @needs_native
+    def test_stale_binary_under_the_current_name_is_impossible(self):
+        """Only artifacts whose name carries the digest of the sources
+        on disk exist for the loader."""
+        import os
+
+        here = os.path.dirname(native.__file__)
+        want = {
+            os.path.basename(native._so_path("libmqtt_native", native._SRC)),
+            os.path.basename(native._so_path("mqtt_accel", native._ACCEL_SRC)),
+        }
+        assert want <= {f for f in os.listdir(here) if f.endswith(".so")}

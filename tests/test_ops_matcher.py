@@ -266,7 +266,7 @@ def test_duplicate_client_merge_matches_host_exactly_and_does_not_accumulate():
 class TestExactMapFastPath:
     """Wildcard-free filter sets answer from the host exact-map — one dict
     probe per topic, no device dispatch, no fallback classes (SURVEY §7
-    hard part 4; VERDICT r4 item 5)."""
+    hard part 4)."""
 
     def _index(self):
         index = TopicsIndex()

@@ -441,9 +441,13 @@ class DeliveryCollector:
     keep draining, or its own transport backlog would make it a slow
     consumer); records delivered payload tags and first-arrival times."""
 
-    def __init__(self, reader) -> None:
+    def __init__(self, reader, writer=None) -> None:
         self.got: list = []
         self.seen_at: dict = {}
+        # held for the collector's lifetime: Python 3.12's
+        # StreamWriter.__del__ CLOSES a dropped writer, which would
+        # disconnect the subscriber the moment its creator returns
+        self._writer = writer
         self._done = asyncio.Event()
         self._task = asyncio.ensure_future(self._run(reader))
 
@@ -513,7 +517,7 @@ async def run_publish_storm(h, plan, slow_consumer=False, sub_filter="storm/#"):
         slow_w.transport.pause_reading()
         slow_conn = (slow_r, slow_w)
     h.server.matcher.flush()
-    collector = DeliveryCollector(sub_r)
+    collector = DeliveryCollector(sub_r, sub_w)
 
     schedules = plan.schedule()
     writers, acks, ack_tasks = [], [], []

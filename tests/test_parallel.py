@@ -66,8 +66,8 @@ def test_sharded_churn_rebuild():
 def test_incremental_rebuild_touches_one_shard():
     """A single subscription mutation must dirty exactly the stable-hash
     shard that owns it, and the incremental rebuild must recompile only
-    that shard's replica (VERDICT r1 weak #3/#4: round-robin resharding
-    made every mutation a full rebuild)."""
+    that shard's replica (round-robin resharding made every mutation a
+    full rebuild)."""
     from mqtt_tpu.parallel.sharded import shard_of
 
     index = TopicsIndex()

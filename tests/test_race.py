@@ -1,4 +1,4 @@
-"""Race hardening for the threaded matcher machinery (VERDICT r4 item 8).
+"""Race hardening for the threaded matcher machinery.
 
 The fold/rebuild/observer paths rest on hand-written concurrency
 contracts — the copy-on-write fold clone (ops/flat.py), the lock-order
@@ -269,7 +269,9 @@ def _lazy_view_churn(duration_s: float, seed: int) -> int:
             # slow-consumer analog): consuming them batches later must
             # still be safe
             if batches % 3 == 0:
-                held.extend(v for v in views[:4] if v is not None)
+                # views only: a host-routed row is a plain Subscribers
+                # with nothing lazy to outlive its batch
+                held.extend(v for v in views[:4] if hasattr(v, "materialize"))
                 if len(held) > 32:
                     for v in held[:16]:
                         mzd = v.materialize()
@@ -911,7 +913,9 @@ class _HandoffRig:
         self.port = int(
             self.srv.listeners.get("hand").address().rsplit(":", 1)[1]
         )
-        self.sub_r, sub_w = await self.conn("hand-stable")
+        # the writer is HELD: Python 3.12's StreamWriter.__del__ closes a
+        # dropped writer, which would disconnect the stable subscriber
+        self.sub_r, self._sub_w = sub_r, sub_w = await self.conn("hand-stable")
         sub_w.write(sub_packet(1, [Subscription(filter="hz/#", qos=0)]))
         await sub_w.drain()
         await asyncio.wait_for(read_wire_packet(self.sub_r, 4), 10)

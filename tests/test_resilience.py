@@ -338,6 +338,58 @@ class TestResilientMatcherFaults:
             faulty.release.set()
             rm.close()
 
+    def test_cold_compile_does_not_run_the_watchdog(self):
+        """Cold compile is set-up, not a hang: a dispatch that overruns
+        the budget only by the time the process spent in
+        first-signature jit calls is NOT abandoned (ISSUE 21)."""
+        ti = small_index()
+        clock = {"s": 0.0}
+
+        class CompilingMatcher(HostBatchMatcher):
+            def match_topics_async(self, topics):
+                resolve = super().match_topics_async(topics)
+
+                def slow():
+                    t0 = time.monotonic()
+                    while time.monotonic() - t0 < 0.5:  # 5x the budget
+                        time.sleep(0.01)
+                        clock["s"] = time.monotonic() - t0
+                    return resolve()
+
+                return slow
+
+        rm = ResilientMatcher(
+            CompilingMatcher(ti), ti, fast_config(watchdog_s=0.1),
+            compile_clock=lambda: clock["s"],
+        )
+        try:
+            assert_oracle(ti, self.TOPICS, rm.match_topics(self.TOPICS))
+            assert rm.breaker.failures == 0 and rm.pool.wedged == 0
+            assert rm.fallback_batches == 0
+        finally:
+            rm.close()
+
+    def test_compile_grace_is_bounded(self, monkeypatch):
+        """A wedged compiler is still a hang: the exclusion stops at
+        COMPILE_GRACE_S."""
+        import mqtt_tpu.resilience as res
+
+        monkeypatch.setattr(res, "COMPILE_GRACE_S", 0.1)
+        ti, _inner, faulty, rm = self.build(
+            FaultPlan(at={0: "hang"}, hang_s=10.0),
+            failure_threshold=1, watchdog_s=0.1,
+        )
+        t0 = time.monotonic()
+        rm._compile_clock = lambda: time.monotonic() - t0  # "compiling" forever
+        try:
+            results = rm.match_topics(self.TOPICS)
+            assert time.monotonic() - t0 < 2.0
+            assert_oracle(ti, self.TOPICS, results)
+            assert rm.breaker.failure_kinds.get("hang") == 1
+        finally:
+            faulty.release.set()
+            rm.close()
+
     def test_corrupt_result_caught_by_differential_rewalk(self):
         ti, _inner, _faulty, rm = self.build(
             FaultPlan(at={0: "corrupt"}), failure_threshold=1

@@ -95,6 +95,10 @@ class Harness:
         if allow:
             self.server.add_hook(AllowHook())
         self.tasks = []
+        # every client-side writer is HELD for the harness's lifetime:
+        # Python 3.12's StreamWriter.__del__ closes a dropped writer, so
+        # a test that keeps only the reader would disconnect its client
+        self._writers = []
 
     async def attach(self):
         """Create a socketpair; server side becomes an attached client."""
@@ -102,6 +106,7 @@ class Harness:
         s1.setblocking(False)
         s2.setblocking(False)
         client_reader, client_writer = await asyncio.open_connection(sock=s1)
+        self._writers.append(client_writer)
         server_reader, server_writer = await asyncio.open_connection(sock=s2)
         cl = self.server.new_client(server_reader, server_writer, "t1", "", False)
         task = asyncio.get_running_loop().create_task(self.server.attach_client(cl, "t1"))

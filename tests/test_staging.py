@@ -1,5 +1,5 @@
 """The publish staging loop: device_matcher=True end-to-end through the
-real broker (SURVEY.md §7 stage 4; round-3 VERDICT item 2).
+real broker (SURVEY.md §7 stage 4).
 
 Covers: >=100 concurrent publishers fanning out through batched device
 matches with correct per-subscriber delivery, proof that matching was
@@ -9,6 +9,8 @@ and stage shutdown draining via the host walk.
 """
 
 import asyncio
+import threading
+import time
 
 import pytest
 
@@ -122,7 +124,7 @@ class TestStagedBroker:
             out = await read_wire_packet(sub_r)
             assert out.topic_name == "q/1" and bytes(out.payload) == b"hello"
 
-            # $SYS matcher observability (round-3 VERDICT item 2 tail)
+            # $SYS matcher observability
             h.server.publish_sys_topics()
             retained = h.server.topics.retained
             batches = retained.get(SYS_PREFIX + "/broker/matcher/batches")
@@ -387,6 +389,84 @@ class TestAdaptiveWindow:
             assert stage._window() > 0.0
 
         run(scenario())
+
+
+class TestColdCompileIsSetUp:
+    """A batch whose drain overlapped a first-signature jit call (the
+    compile clock moved) is set-up, not a service-time sample: it must
+    reach neither the EWMA nor the deadline-aware admission test. At
+    the parent commit a cold broker answered most of its first burst
+    from the host trie (ISSUE 21)."""
+
+    class ColdMatcher:
+        """First batch 'compiles' (slow, clock advances); the rest are
+        fast and block until released, so a backlog parks behind them."""
+
+        def __init__(self) -> None:
+            self.clock = 0.0
+            self.batches = 0
+            self.release = threading.Event()
+
+        def match_topics_async(self, topics):
+            self.batches += 1
+            first = self.batches == 1
+
+            def resolve():
+                if first:
+                    time.sleep(0.15)
+                    self.clock += 0.15  # a KernelWatch first-signature call
+                else:
+                    self.release.wait(5)
+                return [Subscribers() for _ in topics]
+
+            return resolve
+
+    def _run(self, compile_aware: bool):
+        async def scenario():
+            m = self.ColdMatcher()
+            host_walks = []
+
+            def host(topic):
+                host_walks.append(topic)
+                return Subscribers()
+
+            stage = MatchStage(
+                m,
+                host,
+                window_s=0.001,
+                latency_budget_s=0.05,  # the cold batch is 3x this
+                min_batch=1,
+                compile_clock=(lambda: m.clock) if compile_aware else (lambda: 0.0),
+            )
+            stage.start()
+            await stage.submit("cold/1")  # the compile batch
+            # a burst right behind it: batches park behind the blocked
+            # resolver, so admission consults _past_deadline
+            futs = []
+            for i in range(40):
+                futs.append(stage.submit(f"warm/{i}"))
+                await asyncio.sleep(0.001)
+            m.release.set()
+            await asyncio.wait_for(asyncio.gather(*futs), 10)
+            out = (stage.admission_fallbacks, stage.compile_tainted_batches,
+                   stage._ewma_s, len(host_walks))
+            await stage.stop()
+            return out
+
+        return run(scenario())
+
+    def test_compile_batch_stays_out_of_the_controller(self):
+        fallbacks, tainted, ewma, host_walks = self._run(compile_aware=True)
+        assert tainted == 1
+        assert fallbacks == 0 and host_walks == 0
+        assert ewma < 0.15  # the cold 150 ms never entered the estimate
+
+    def test_blind_stage_reproduces_the_cold_fallbacks(self):
+        """The control: with a clock that never moves, the same run reads
+        the compile as load and refuses work (the parent's behaviour)."""
+        fallbacks, tainted, _ewma, host_walks = self._run(compile_aware=False)
+        assert tainted == 0
+        assert fallbacks > 0 and host_walks == fallbacks
 
 
 class TestSingleConnectionPipelining:

@@ -81,9 +81,9 @@ if [ "$SAN" = 1 ]; then
     if [ -n "$LIBASAN" ] && [ -e "$LIBASAN" ]; then
         ran=1
         say "== ASAN/UBSAN native test leg =="
-        # the sanitizer flags change the artifact tag (native/_so_tag),
-        # so this leg builds its own .so pair and the plain build's
-        # mtime cache stays untouched
+        # the sanitizer flags change the artifact name (its digest
+        # covers them: native/source_digest), so this leg builds its
+        # own .so pair and the plain build stays untouched
         # MQTT_TPU_SAN=1 deselects the jax-backed e2e tests: jaxlib is
         # not ASAN-instrumented and its XLA compiler aborts under the
         # preloaded runtime — the leg verifies OUR C (views, pool,
@@ -99,9 +99,9 @@ if [ "$SAN" = 1 ]; then
         else
             say "FAIL: native tests under ASAN/UBSAN"; rc=1
         fi
-        # sanitized artifacts are throwaway (tagged -x<hash>)
-        rm -f mqtt_tpu/native/libmqtt_native-*-x????????.so \
-              mqtt_tpu/native/mqtt_accel-*-x????????.so
+        # sanitized artifacts are throwaway (tagged -x<digest>)
+        rm -f mqtt_tpu/native/libmqtt_native-*-x????????????.so \
+              mqtt_tpu/native/mqtt_accel-*-x????????????.so
     else
         say "libasan unavailable; sanitizer leg skipped"
     fi
