@@ -105,7 +105,8 @@ scrape-devices:
 # script the chip itself is proven with, ISSUE 21) — served path end to
 # end, deliveries checked against the filters, zero breaker/staging
 # fallbacks, device-vs-host parity, every kernel vs its host oracle;
-# writes pipeline-smoke.json (uploaded as a CI artifact)
+# writes pipeline-smoke.json (summary line + verdict line; uploaded as a
+# CI artifact)
 pipeline-smoke:
 	set -o pipefail; env JAX_PLATFORMS=cpu $(PY) chip_smoke.py \
 	  --expect-platform cpu --subs 20000 --publishes 6000 \

@@ -35,10 +35,14 @@ The quickest proof that the system still starts on the chip. ONE process:
 
 Exits non-zero — and prints no result — unless
 ``jax.devices()[0].platform`` is ``tpu`` (``--expect-platform cpu`` is
-for the tiny dry run and the tier-1 test). The last stdout line is one
-JSON object; seconds in it are set-up times, not metrics, and ``claim``
-is null. Sets no ``JAX_PLATFORMS`` and no compile-cache path: the cache
-comes through the package (``mqtt_tpu.ops.backend``).
+for the tiny dry run and the tier-1 test). Standard output is two lines
+of JSON. The first is the summary: every counter checked above, the
+compile ledger, the cache and the native modules; seconds in it are
+set-up times, not metrics, and it ends with ``"claim": null``. The LAST
+is the verdict, exactly ``{"ok": ..., "device": {"platform", "kind",
+"count"}}`` with the device as JAX reports it. Sets no ``JAX_PLATFORMS``
+and no compile-cache path: the cache comes through the package
+(``mqtt_tpu.ops.backend``).
 """
 
 from __future__ import annotations
@@ -172,12 +176,6 @@ class Smoke:
         self.failures: list[str] = []
         self.topics_index = None  # the served trie, for the roll-call
         self.out: dict = {
-            "ok": False,
-            "device": {
-                "platform": device["platform"],
-                "kind": device["device_kind"],
-                "count": device["n_devices"],
-            },
             **device,
             "seed": args.seed,
             "subs": args.subs,
@@ -798,10 +796,18 @@ def main(argv=None) -> int:
     out["cache_entries_after"] = cache_entries(out["cache_dir"])
     out["wall_s"] = round(time.perf_counter() - t_all, 3)
     out["failures"] = smoke.failures
-    out["ok"] = not smoke.failures
     out["claim"] = None
     print(json.dumps(out), flush=True)
-    return 0 if out["ok"] else 1
+    verdict = {
+        "ok": not smoke.failures,
+        "device": {
+            "platform": device["platform"],
+            "kind": device["device_kind"],
+            "count": device["n_devices"],
+        },
+    }
+    print(json.dumps(verdict), flush=True)
+    return 0 if verdict["ok"] else 1
 
 
 if __name__ == "__main__":

@@ -27,12 +27,20 @@ def test_tiny_cpu_mode_passes_and_reports():
     r = _run(["--expect-platform", "cpu", "--subs", "4000",
               "--publishes", "4000", "--seed", "5"])
     assert r.returncode == 0, r.stderr[-3000:]
-    last = r.stdout.strip().splitlines()[-1]
-    out = json.loads(last)
-    assert out["ok"] is True and out["failures"] == []
-    assert out["device"] == {
-        "platform": "cpu", "kind": out["device_kind"], "count": out["n_devices"],
+    lines = r.stdout.strip().splitlines()
+    assert len(lines) == 2  # the summary, then the verdict
+    summary, out = lines[0], json.loads(lines[0])
+    # the LAST line is the verdict, with exactly these keys
+    verdict = json.loads(lines[1])
+    assert verdict == {
+        "ok": True,
+        "device": {
+            "platform": "cpu", "kind": out["device_kind"], "count": out["n_devices"],
+        },
     }
+    assert isinstance(verdict["device"]["kind"], str)
+    assert type(verdict["device"]["count"]) is int
+    assert out["failures"] == []
     assert (out["platform"], out["seed"], out["subs"], out["publishes"]) == (
         "cpu", 5, 4000, 4000,
     )
@@ -73,7 +81,7 @@ def test_tiny_cpu_mode_passes_and_reports():
     assert out["cache_entries_after"] >= out["cache_entries_before"]
     assert out["native"]["lib"]["loaded"] and out["native"]["accel"]["loaded"]
     # no end-to-end number is claimed: the summary ENDS with it
-    assert out["claim"] is None and last.endswith('"claim": null}')
+    assert out["claim"] is None and summary.endswith('"claim": null}')
 
 
 def test_cpu_only_box_is_refused_by_name():
