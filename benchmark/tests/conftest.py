@@ -1,0 +1,9 @@
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+# the tests drive the harness on the CPU, in this process and in children
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
