@@ -553,6 +553,13 @@ class Client:
         fast_eligible = self.ops.fast_publish_eligible
         fast_publish = self.ops.fast_publish
         telemetry = getattr(self.ops, "telemetry", None)
+        # device pipeline profiler (mqtt_tpu.tracing): while a profiler
+        # session is live, the loop time from a scan's frames in hand to
+        # their handlers returned (decode, admission, the fan-out tasks
+        # created) is counted as ingest, once a scan, over the publishes
+        # in it; the rest, up to submit(), is counted where the task
+        # runs (server._staged_fan_out)
+        prof = getattr(self.ops, "profiler", None)
         # the shard's own gate wins (per-shard decode batching is
         # default-on inside the fabric); the server-wide gate serves the
         # single-loop opt-in (Options.scan_coalesce)
@@ -575,6 +582,10 @@ class Client:
                     max_packet_size=caps.maximum_packet_size,
                 )
             # account for and process every complete packet
+            armed = prof is not None and prof.armed
+            if armed:
+                t_in = time.perf_counter_ns()
+                n_in = self._pub_count
             start = 0
             for f in frames:
                 fstart = start
@@ -627,6 +638,10 @@ class Client:
                     deferred.append(asyncio.get_running_loop().create_task(result))
                 if self.closed:
                     break
+            if armed and self._pub_count != n_in:
+                prof.note_ingest(
+                    time.perf_counter_ns() - t_in, self._pub_count - n_in
+                )
             if deferred is not None:
                 err0: Optional[BaseException] = None
                 for t in deferred:

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..packets import Subscription
 from ..topics import Subscribers, TopicsIndex
+from ..tracing import span
 from .flat import (
     KIND_CLIENT,
     KIND_INLINE,
@@ -623,11 +624,14 @@ class TpuMatcher:
         ``profile`` is an optional per-batch
         :class:`mqtt_tpu.tracing.BatchProfile` the caller (the staging
         loop) holds; with a profiler attached this method fills its
-        dispatch window and the resolver its D2H window — the batch's
-        own record, immune to concurrent/out-of-order resolution. When
-        the profiler is attached but no record is passed (bench,
-        resilience probes), a private one is opened so the duty-cycle
-        aggregates still see the batch.
+        tokenize and H2D + dispatch spans and the resolver its D2H sync
+        and resolve spans — the batch's own record, immune to
+        concurrent/out-of-order resolution; each boundary is one
+        ``perf_counter_ns`` read and the profiler's windows are fed from
+        the same reads. While a profiler session keeps the record the
+        spans are also ``TraceAnnotation`` blocks. When the profiler is
+        attached but no record is passed (bench, resilience probes), a
+        private one is opened so the aggregates still see the batch.
         """
         import jax.numpy as jnp
 
@@ -649,44 +653,48 @@ class TpuMatcher:
         rec = None
         if prof is not None:
             rec = profile if profile is not None else prof.open_batch()
-            t_issue0 = time.perf_counter()
         b = len(topics)
-        padded = topics + [""] * (_bucket(max(1, b), minimum=16) - b)
-        tok1, tok2, lengths, is_dollar, len_overflow = tokenize_topics(
-            padded, flat.max_levels, flat.salt
-        )
-        # the host copy stays alive for the overflow fallback's re-upload
-        host_tokens = pack_tokens(tok1, tok2, lengths, is_dollar)
+        with span(rec, "tokenize"):
+            padded = topics + [""] * (_bucket(max(1, b), minimum=16) - b)
+            tok1, tok2, lengths, is_dollar, len_overflow = tokenize_topics(
+                padded, flat.max_levels, flat.salt
+            )
+            # the host copy stays alive for the overflow fallback's re-upload
+            host_tokens = pack_tokens(tok1, tok2, lengths, is_dollar)
         P = flat.pat_depth.shape[0]
         use_compact = self.compact and P > 0 and self._compact_pays(P)
         capacity = 0
-        if use_compact:
-            capacity = self._compact_capacity_for(len(padded), flat)
-            out_dev = flat_match_compact(
-                *arrays,
-                jnp.asarray(host_tokens),
-                max_levels=flat.max_levels,
-                capacity=capacity,
-            )
-        else:
-            out_dev = flat_match_packed(
-                *arrays,
-                jnp.asarray(host_tokens),
-                max_levels=flat.max_levels,
-            )
-        # start the D2H as soon as the kernel finishes instead of when
-        # the resolver blocks: the transfer overlaps the pipeline's other
-        # in-flight batches
-        out_dev.copy_to_host_async()
+        with span(rec, "h2d_dispatch"):
+            if use_compact:
+                capacity = self._compact_capacity_for(len(padded), flat)
+                out_dev = flat_match_compact(
+                    *arrays,
+                    jnp.asarray(host_tokens),
+                    max_levels=flat.max_levels,
+                    capacity=capacity,
+                )
+            else:
+                out_dev = flat_match_packed(
+                    *arrays,
+                    jnp.asarray(host_tokens),
+                    max_levels=flat.max_levels,
+                )
+            # start the D2H as soon as the kernel finishes instead of when
+            # the resolver blocks: the transfer overlaps the pipeline's
+            # other in-flight batches
+            out_dev.copy_to_host_async()
         if prof is not None:
-            # device pipeline profiler: the issue leg (tokenize + H2D +
-            # async dispatch) ends here; the device window opens now.
-            # Stamp which chip ran the batch first so the per-device
-            # window replicas (ISSUE 18) attribute it correctly.
+            # the issue leg (tokenize + H2D + async dispatch) ends here;
+            # the batch's in-flight window opens now. Stamp which chip
+            # ran the batch first so the per-device window replicas
+            # (ISSUE 18) attribute it correctly.
             dev = getattr(out_dev, "device", None)
             did = getattr(dev() if callable(dev) else dev, "id", None)
             rec.devices = (did,) if did is not None else None
-            prof.note_dispatch(rec, t_issue0, time.perf_counter())
+            rec.bucket = len(padded)
+            prof.note_dispatch(
+                rec, rec.tokenize[0] / 1e9, rec.h2d_dispatch[1] / 1e9
+            )
         if route_to_host is None:
             pred = batch_pred = None
         elif hasattr(route_to_host, "affected_batch"):
@@ -705,36 +713,41 @@ class TpuMatcher:
         if not use_compact:
 
             def resolve() -> list[Subscribers]:
-                t_sync0 = time.perf_counter() if prof is not None else 0.0
-                # brokerlint: ok=R15 the blessed resolve seam: ONE batched D2H after copy_to_host_async, [B, 2P+2]
-                packed = np.asarray(out_dev)
+                with span(rec, "d2h_sync"):
+                    # brokerlint: ok=R15 the blessed resolve seam: ONE batched D2H after copy_to_host_async, [B, 2P+2]
+                    packed = np.asarray(out_dev)
                 if prof is not None:
                     # the blocking D2H sync just completed: close the
-                    # device window (kernel + transfer) on this record
+                    # in-flight window (kernel + transfer) on this record
                     self._stamp_bytes(rec, packed.nbytes, bytes_ranges, bytes_dense, False)
-                    prof.note_resolve(rec, t_sync0, time.perf_counter())
-                stats = self.stats
-                stats.batches += 1
-                stats.topics += len(topics)
-                stats.d2h_bytes += int(packed.nbytes)
-                # the ranges row carries per-topic totals: feed the same
-                # hits EWMA the compact path uses, so the encoding pick
-                # (_compact_pays) keeps adapting from EITHER path
-                self._observe_hits(
-                    int(packed[: len(topics), 2 * P].sum()), len(topics)
-                )
-                packed = packed[: len(topics)]  # drop bucket-padding rows
-                return self._resolve_ranges(
-                    packed, topics, flat, P,
-                    len_overflow[: len(topics)], pred, batch_pred,
-                )
+                    self._note_sync(prof, rec)
+                with span(rec, "resolve"):
+                    stats = self.stats
+                    stats.batches += 1
+                    stats.topics += len(topics)
+                    stats.d2h_bytes += int(packed.nbytes)
+                    # the ranges row carries per-topic totals: feed the same
+                    # hits EWMA the compact path uses, so the encoding pick
+                    # (_compact_pays) keeps adapting from EITHER path
+                    self._observe_hits(
+                        int(packed[: len(topics), 2 * P].sum()), len(topics)
+                    )
+                    packed = packed[: len(topics)]  # drop bucket-padding rows
+                    return self._resolve_ranges(
+                        packed, topics, flat, P,
+                        len_overflow[: len(topics)], pred, batch_pred,
+                    )
 
             return resolve
 
         def resolve_compact() -> list[Subscribers]:
-            t_sync0 = time.perf_counter() if prof is not None else 0.0
-            # brokerlint: ok=R15 the blessed resolve seam: ONE batched D2H after copy_to_host_async, [2 + 2B + 2K] ints
-            out = np.asarray(out_dev)
+            with span(rec, "d2h_sync"):
+                # brokerlint: ok=R15 the blessed resolve seam: ONE batched D2H after copy_to_host_async, [2 + 2B + 2K] ints
+                out = np.asarray(out_dev)
+            with span(rec, "resolve"):
+                return decode_compact(out)
+
+        def decode_compact(out) -> list[Subscribers]:
             bp = len(padded)
             n_hits = int(out[0])
             batch_ovf = bool(out[1])
@@ -759,15 +772,17 @@ class TpuMatcher:
                 d2h_bytes = int(out.nbytes + packed.nbytes)
                 stats.d2h_bytes += d2h_bytes
                 if prof is not None:
+                    # the re-run (dispatch + second sync) is part of this
+                    # batch's resolve span, not of its in-flight window
                     self._stamp_bytes(rec, d2h_bytes, bytes_ranges, bytes_dense, True, overflow=True)
-                    prof.note_resolve(rec, t_sync0, time.perf_counter())
+                    self._note_sync(prof, rec)
                 return self._resolve_ranges(
                     packed[: len(topics)], topics, flat, P,
                     len_overflow[: len(topics)], pred, batch_pred,
                 )
             if prof is not None:
                 self._stamp_bytes(rec, int(out.nbytes), bytes_ranges, bytes_dense, True)
-                prof.note_resolve(rec, t_sync0, time.perf_counter())
+                self._note_sync(prof, rec)
             stats.compact_batches += 1
             stats.d2h_bytes += int(out.nbytes)
             totals = out[2 : 2 + bp]
@@ -819,6 +834,13 @@ class TpuMatcher:
     def _observe_hits(self, n_hits: int, b: int) -> None:
         """Feed one batch's true hit count into the capacity EWMA."""
         self._hits_ewma = fold_hits_ewma(self._hits_ewma, n_hits, b)
+
+    @staticmethod
+    def _note_sync(prof, rec) -> None:
+        """The blocking D2H sync is done: the profiler's in-flight
+        window closes on the record's own span, from the same reads."""
+        t0_ns, t1_ns = rec.d2h_sync
+        prof.note_resolve(rec, t0_ns / 1e9, t1_ns / 1e9)
 
     @staticmethod
     def _stamp_bytes(

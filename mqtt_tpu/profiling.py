@@ -38,12 +38,16 @@ ON by default whenever telemetry is.
 from __future__ import annotations
 
 import collections
+import logging
 import os
 import re
 import sys
 import threading
 import time
 from typing import Any, Callable, Optional
+
+_log = logging.getLogger("mqtt_tpu.profiling")
+
 
 def _frame_label(frame: Any) -> str:
     """One collapsed-stack frame: ``func (file.py:line)`` with the
@@ -105,6 +109,11 @@ class SamplingProfiler:
         self._ring: collections.deque = collections.deque(maxlen=max(16, int(ring)))
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        # called after every sweep of the timer thread (not by a direct
+        # sample_once()): the server hangs DeviceProfiler.poll here, so
+        # a jax.profiler session is noticed within one period even when
+        # no batch forms (mqtt_tpu.tracing)
+        self.on_sweep: Optional[Callable[[], Any]] = None
         self.samples = 0  # sweeps taken
         self.thread_samples = 0  # per-thread stacks recorded
         self.dropped_stacks = 0  # distinct-stack cap overflows
@@ -168,6 +177,12 @@ class SamplingProfiler:
                 self.sample_once()
             except Exception:  # pragma: no cover  # brokerlint: ok=R4 a torn frame walk (thread exiting mid-sweep) costs one sample; the next sweep self-heals
                 pass
+            hook = self.on_sweep
+            if hook is not None:
+                try:
+                    hook()
+                except Exception:  # the sampler outlives a faulty hook
+                    _log.exception("profiler sweep hook failed")
 
     # -- sampling -----------------------------------------------------------
 

@@ -846,6 +846,10 @@ class _Ops:
         # read-side scan coalescer (clients.ScanGate); None = per-socket
         # scans. Set by the server when Options.scan_coalesce is on.
         self.scan_gate: Optional[Any] = None
+        # the device pipeline profiler (mqtt_tpu.tracing.DeviceProfiler);
+        # None = no device matcher or tracing off. Read loops feed it
+        # their per-publish ingest time while a profiler session is live.
+        self.profiler: Optional[Any] = None
 
 
 class Server:
@@ -1169,6 +1173,16 @@ class Server:
                     snap = getattr(self.matcher, "_snap", None)
                     if snap is not None and hasattr(snap, "profiler"):
                         snap.profiler = self.profiler
+                    # what its slice snapshots read, and who feeds and
+                    # polls it besides the staging loop: the read loops
+                    # and fan-out (per-publish loop counters while a
+                    # jax.profiler session is live), and the sampling
+                    # profiler's thread, so a session that ends after
+                    # the traffic stopped still closes the slice
+                    self.profiler.matcher_stats = stats
+                    self._ops.profiler = self.profiler
+                    if self.host_profiler is not None:
+                        self.host_profiler.on_sweep = self.profiler.poll
                 # mesh-sharded snapshot: per-shard compile times land in
                 # shard-local histograms on the rebuild path; the scrape
                 # merges them on demand (telemetry callback histogram)
@@ -2956,6 +2970,14 @@ class Server:
         batch resolves off the event loop and this client awaits only its
         own result (SURVEY.md §7 stage 4; seam: server.go:984-1021)."""
         if not pk.ignore:
+            # while a profiler session is live: loop time from here to
+            # submit() is ingest, and the drain loop leaves on the future
+            # the instant it was set (DeviceProfiler.note_ingest /
+            # note_fanout); off, the cost is this test and one more
+            prof = self.profiler
+            armed = prof is not None and prof.armed
+            if armed:
+                t_in = time.perf_counter_ns()
             self._stamp_publish_expiry(pk)
             # MQTT+ predicate plane: extract the payload features ONCE
             # on the host; the stage batches them to the device beside
@@ -2971,10 +2993,22 @@ class Server:
             # keystream dispatch rides the same staged batch
             # (mqtt_tpu.tenancy.RecryptJob through MatchStage)
             rjob = self._recrypt_job_for(cl, pk)
-            subscribers = await self._stage.submit(
-                pk.topic_name, getattr(pk, "_tclock", None), feats, rjob
-            )
-            self._fan_out(pk, subscribers, feats, rjob)
+            if not armed:
+                subscribers = await self._stage.submit(
+                    pk.topic_name, getattr(pk, "_tclock", None), feats, rjob
+                )
+                self._fan_out(pk, subscribers, feats, rjob)
+            else:
+                fut = self._stage.submit(
+                    pk.topic_name, getattr(pk, "_tclock", None), feats, rjob
+                )
+                prof.note_ingest(time.perf_counter_ns() - t_in)
+                subscribers = await fut
+                t_run = time.perf_counter_ns()
+                self._fan_out(pk, subscribers, feats, rjob)
+                set_ns = getattr(fut, "set_ns", 0)  # 0: batch not kept
+                if set_ns:
+                    prof.note_fanout(set_ns, t_run, time.perf_counter_ns())
             if self._cluster is not None:
                 self._cluster.forward_packet(pk)
             self._finish_publish_clock(pk)
@@ -5145,6 +5179,8 @@ class Server:
                 jax.profiler.stop_trace()
             except Exception:  # brokerlint: ok=R4 teardown; a failed profiler stop must not abort the drain
                 self.log.exception("jax.profiler trace failed to stop")
+            if self.profiler is not None:
+                self.profiler.poll()  # the session ended: freeze its slice
         if self.matcher is not None:
             self.matcher.close()
         if self.host_profiler is not None:

@@ -18,10 +18,13 @@ pipelined batch engine:
   kernel itself is asynchronous on the device — a ``pipeline_depth``-deep
   (default 3) overlapped pipeline in which batch N+2 tokenizes while
   N+1 matches and N drains, so the event loop never carries staging
-  work and the device never waits for the host between batches. Per-leg
-  handoff waits are measured into the telemetry plane
-  (``mqtt_tpu_staging_leg_wait_seconds{leg=h2d|d2h}``) — the numbers
-  that must sit near zero when the pipeline is actually full.
+  work and the device never waits for the host between batches. Every
+  boundary of a batch is stamped once, on its own record
+  (``tracing.BatchProfile``: the batch's span tree); the per-leg handoff
+  waits in the telemetry plane
+  (``mqtt_tpu_staging_leg_wait_seconds{leg=h2d|d2h}`` — the numbers
+  that must sit near zero when the pipeline is actually full) are read
+  off the same stamps.
 - The window and the batch cap ADAPT to the measured per-batch service
   time against ``latency_budget_s`` (SURVEY §7 hard part 4: "adaptive
   batch window + host fast-path"): under light load the window shrinks
@@ -58,6 +61,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
 from .topics import Subscribers
+from .tracing import BatchProfile, span
 from .utils.loopwitness import DEFAULT_LOOP_PLANE as _LOOP_PLANE
 
 _log = logging.getLogger("mqtt_tpu.staging")
@@ -232,6 +236,8 @@ class MatchStage:
         """Create the collector/drainer tasks on the running loop."""
         loop = asyncio.get_running_loop()
         self._loop = loop
+        if self.profiler is not None:
+            self.profiler.loop = loop  # where its armed heartbeat runs
         self._wake = asyncio.Event()
         self._executor = ThreadPoolExecutor(
             max_workers=max(2, self.max_inflight),
@@ -303,6 +309,11 @@ class MatchStage:
         growing the backlog."""
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
+        prof = self.profiler
+        if prof is not None and prof.armed:
+            # a live profiler session: the submit instant rides on the
+            # future, for the batch's mqtt/stage.wait (tracing)
+            fut.submit_ns = time.perf_counter_ns()
         if _LOOP_PLANE.active:
             w = _LOOP_PLANE.witness
             if w is not None:
@@ -427,45 +438,60 @@ class MatchStage:
             batch = [item for item in batch if not item[1].cancelled()]
             if not batch:
                 continue
+            # the batch's own record (mqtt_tpu.tracing): every boundary
+            # from here to the last future set is stamped on it, once,
+            # so concurrent or out-of-order resolution (the resilience
+            # guard pool) can never cross-attribute them. The profiler
+            # numbers it and, while a jax.profiler session is live,
+            # keeps it; without a profiler it still carries the stamps
+            # the leg-wait histograms read.
+            profiler = self.profiler
+            if profiler is not None:
+                profiler.poll()
+                rec = profiler.open_batch()
+            else:
+                rec = BatchProfile()
+            rec.formed_ns = time.perf_counter_ns()
             topics = [t for t, _, _, _, _ in batch]
             futs = [f for _, f, _, _, _ in batch]
             clocks = [c for _, _, c, _, _ in batch]
             feats = [p for _, _, _, p, _ in batch]
             rjobs = [r for _, _, _, _, r in batch]
+            rec.topics = len(topics)
+            rec.depth = self.inflight_batches
+            if rec.kept:
+                rec.stage_wait(
+                    [f.submit_ns for f in futs if hasattr(f, "submit_ns")]
+                )
             for c in clocks:
                 if c is not None:  # end of the accumulation/park wait
                     c.stamp("staging_wait")
+                    c.batch = rec.seq
             # the ISSUE leg runs on the dedicated h2d dispatch thread,
             # in batch order (single worker): host tokenize + H2D + the
             # async device dispatch leave the event loop free, and batch
             # N+2 tokenizes while N+1's kernel runs and N drains — the
             # 3-deep overlap the device profiler's duty cycle gates on
-            t_formed = time.perf_counter()
-            profiler = self.profiler
             predicates = self.predicates
             recrypt = self.recrypt
             matcher = self.matcher
             telemetry = self.telemetry
 
             def issue():
+                rec.issue_start_ns = time.perf_counter_ns()
                 if telemetry is not None:
                     # h2d-leg handoff wait: batch formed -> issue start
                     telemetry.observe_leg_wait(
-                        "h2d", time.perf_counter() - t_formed
+                        "h2d", (rec.issue_start_ns - rec.formed_ns) / 1e9
                     )
                 if profiler is not None:
-                    # per-batch device-timing record (mqtt_tpu.tracing):
-                    # the matcher fills its dispatch/D2H windows, the
-                    # drain loop sub-stamps sampled clocks from it — the
-                    # batch's OWN record, so concurrent or out-of-order
-                    # resolution (the resilience guard pool) can never
-                    # cross-attribute boundaries
-                    rec = profiler.open_batch()
+                    # the matcher fills the record's tokenize, dispatch,
+                    # D2H and resolve spans; the drain loop sub-stamps
+                    # sampled clocks from them
                     resolver = matcher.match_topics_async(
                         topics, profile=rec
                     )
                 else:
-                    rec = None
                     resolver = matcher.match_topics_async(topics)
                 # MQTT+ predicate evaluation rides the SAME staged
                 # batch: one extra async dispatch against the device
@@ -495,12 +521,13 @@ class MatchStage:
                         _log.exception(
                             "recrypt issue failed; host keystream"
                         )
-                return resolver, pred_resolver, rec_resolver, rec
+                rec.issue_end_ns = time.perf_counter_ns()
+                return resolver, pred_resolver, rec_resolver
 
             loop = asyncio.get_running_loop()
             try:
                 (
-                    resolver, pred_resolver, rec_resolver, rec,
+                    resolver, pred_resolver, rec_resolver,
                 ) = await loop.run_in_executor(self._h2d_executor, issue)
             except asyncio.CancelledError:
                 # stop() cancelled us with this batch in hand (in neither
@@ -513,13 +540,12 @@ class MatchStage:
                 _log.exception("stage issue failed; host fallback for batch")
                 self._fallback_all(batch, klass="issue_error")
                 continue
-            t_ready = time.perf_counter()
             self.inflight_batches += 1
             try:
                 await queue.put(
                     (
                         resolver, futs, topics, clocks, rec, pred_resolver,
-                        feats, rec_resolver, t_ready,
+                        feats, rec_resolver,
                     )
                 )
             except asyncio.CancelledError:
@@ -535,7 +561,7 @@ class MatchStage:
         while True:
             (
                 resolver, futs, topics, clocks, rec, pred_resolver, feats,
-                rec_resolver, t_ready,
+                rec_resolver,
             ) = await queue.get()
             try:
                 # the D2H sync blocks — run it off the loop. Queue depth is
@@ -549,11 +575,12 @@ class MatchStage:
                 pr, mr, rr = pred_resolver, resolver, rec_resolver
 
                 def sync():
+                    rec.sync_start_ns = time.perf_counter_ns()
                     if telemetry is not None:
-                        # d2h-leg handoff wait: dispatch returned (batch
+                        # d2h-leg handoff wait: issue returned (batch
                         # queued behind the pipeline) -> sync start
                         telemetry.observe_leg_wait(
-                            "d2h", time.perf_counter() - t_ready
+                            "d2h", (rec.sync_start_ns - rec.issue_end_ns) / 1e9
                         )
                     return (
                         mr(),
@@ -594,19 +621,38 @@ class MatchStage:
             # the exact-map fast path and host fallbacks leave them
             # None, and then the coarse device_batch stamp applies (no
             # phantom h2d for batches that never touched the device)
-            dispatch = rec.dispatch if rec is not None else None
-            d2h = rec.d2h if rec is not None else None
-            for fut, subs, ck in zip(futs, results, clocks):
-                if ck is not None:  # issue -> resolved (device round trip)
-                    if dispatch is not None and d2h is not None:
-                        # tokenize + device dispatch; then kernel queue +
-                        # execution; then the blocking result transfer
-                        ck.stamp_until("h2d", dispatch[1])
-                        ck.stamp_until("device_dispatch", d2h[0])
-                        ck.stamp_until("d2h", d2h[1])
-                    else:
-                        ck.stamp("device_batch")
-                self._resolve(fut, subs)
+            dispatch, d2h = rec.dispatch, rec.d2h
+            with span(rec, "deliver"):
+                if rec.kept:
+                    # a live profiler session: leave on each future the
+                    # instant it was set, for its fan-out's wait for the
+                    # loop (DeviceProfiler.note_fanout)
+                    set_sum = 0
+                    for fut, subs, ck in zip(futs, results, clocks):
+                        if ck is not None:
+                            self._stamp_round_trip(ck, dispatch, d2h)
+                        fut.set_ns = t_set = time.perf_counter_ns()
+                        set_sum += t_set
+                        self._resolve(fut, subs)
+                    rec.set_sum_ns = set_sum
+                else:
+                    for fut, subs, ck in zip(futs, results, clocks):
+                        if ck is not None:
+                            self._stamp_round_trip(ck, dispatch, d2h)
+                        self._resolve(fut, subs)
+
+    @staticmethod
+    def _stamp_round_trip(ck, dispatch, d2h) -> None:
+        """A sampled clock's issue -> resolved stretch (the device round
+        trip), from its batch's own windows."""
+        if dispatch is not None and d2h is not None:
+            # tokenize + device dispatch; then kernel queue + execution;
+            # then the blocking result transfer
+            ck.stamp_until("h2d", dispatch[1])
+            ck.stamp_until("device_dispatch", d2h[0])
+            ck.stamp_until("d2h", d2h[1])
+        else:
+            ck.stamp("device_batch")
 
     def _resolve(self, fut: "asyncio.Future", value) -> None:
         """Complete one caller future ON ITS OWN LOOP: a future parked

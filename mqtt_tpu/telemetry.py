@@ -511,11 +511,14 @@ class StageClock:
     Cheap by construction — two perf_counter calls and a list append per
     stage, and only 1-in-N publishes carry one at all."""
 
-    __slots__ = ("t0", "last", "stages")
+    __slots__ = ("t0", "last", "stages", "batch")
 
     def __init__(self) -> None:
         self.t0 = self.last = time.perf_counter()
         self.stages: list[tuple[str, float]] = []
+        # the number of the device batch that carried this publish
+        # (tracing.BatchProfile.seq), stamped by the staging loop
+        self.batch: Optional[int] = None
 
     def stamp(self, stage: str) -> None:
         now = time.perf_counter()

@@ -20,14 +20,23 @@ comparison axis (PAPERS.md). This module adds:
   rides the cluster frames — TD-MQTT-style transparent cross-broker
   tracing). The ring exports as Chrome trace-event JSON
   (Perfetto-loadable) at ``GET /traces`` and in trigger dumps.
-- ``DeviceProfiler``: sub-stamps every device batch (tokenize+dispatch
-  issue, blocking D2H sync) and folds the windows into the numbers that
-  gate ROADMAP item 1's 3-deep-pipeline work: kernel **duty cycle**
-  (union of device-busy windows over wall time), **overlap ratio**
-  (how much of the summed busy time was pipelined under another
-  batch's window), and the **staging idle-gap** histogram (device
-  sitting idle between batches — the time the pipeline work must
-  reclaim).
+- ``BatchProfile`` / ``DeviceProfiler``: one record per device batch,
+  carried with the batch, holding the batch's span tree on
+  ``perf_counter_ns`` (submit -> formed -> tokenize -> H2D + dispatch ->
+  D2H sync -> resolve -> futures set). The profiler folds the
+  in-flight windows (dispatch returned -> sync done) into a **duty
+  cycle** and an **overlap ratio** AS THE HOST SEES THEM — upper bounds
+  on device busy time, not device busy time (a chip the device trace
+  shows 0.04% busy reads tens of percent here) — and the **staging
+  idle-gap** histogram.
+- ``TraceSlice`` / ``last_slice()``: while a ``jax.profiler`` session is
+  live (``TraceAnnotation.is_enabled()``: ``start_trace``, the profiler
+  server, ``Options.trace_jax_profiler_dir``) the profiler keeps the
+  batch records, enters the busy spans as ``TraceAnnotation`` blocks (so
+  they lie on the device trace's own clock in the ``.xplane.pb``), and
+  brackets the session with two snapshots (per-thread CPU, matcher
+  topics, gen-2 collections, loop counters, loop heartbeat). When the
+  session ends the slice freezes; ``last_slice()`` returns the newest.
 - ``check_trace_events``: a ~20-line pure-Python validator for the
   exported JSON (the /traces analog of ``telemetry.check_exposition``),
   used by CI's trace-scrape gate and the test suite.
@@ -39,7 +48,11 @@ default behind ``Options.trace`` / the ``trace_*`` config knobs.
 from __future__ import annotations
 
 import collections
+import contextlib
+import gc
+import itertools
 import json
+import os
 import random
 import threading
 import time
@@ -200,7 +213,11 @@ class Tracer:
              # arrival->flush number the delivery-latency histogram
              # recorded, with the stage breakdown nested under it
              {"topic": topic, "qos": qos,
-              "delivery_ms": round(trace.total() * 1e3, 3)})
+              "delivery_ms": round(trace.total() * 1e3, 3),
+              # the device batch that carried this publish (its
+              # BatchProfile.seq: the ``batch`` arg of the mqtt/* host
+              # annotations in a profiler trace); None = never staged
+              **({} if trace.batch is None else {"batch": trace.batch})})
         )
         with self._lock:
             self.ring.extend(spans)
@@ -213,7 +230,10 @@ class Tracer:
         """The ring as a Chrome trace-event document (Perfetto loads it
         directly: open ui.perfetto.dev and drop the JSON in). Spans of
         one trace share a ``tid`` derived from the trace id, so
-        concurrent traces render as separate nested tracks."""
+        concurrent traces render as separate nested tracks. After them,
+        the newest profiler slice (``slice_events``): the span trees of
+        its device batches, which a sampled publish's ``batch`` arg joins
+        it to, and the full collections inside it."""
         with self._lock:
             spans = list(self.ring)
         events = []
@@ -237,6 +257,9 @@ class Tracer:
                     "args": a,
                 }
             )
+        sl = last_slice()
+        if sl is not None:
+            events.extend(slice_events(sl, self._anchor, self.pid))
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def export_json(self) -> str:
@@ -271,18 +294,38 @@ def check_trace_events(doc) -> int:
     return len(events)
 
 
+# the busy spans of a batch: record slot -> span name
+BUSY_SPANS = {
+    "tokenize": "mqtt/tokenize",
+    "h2d_dispatch": "mqtt/h2d_dispatch",
+    "d2h_sync": "mqtt/d2h.sync",
+    "resolve": "mqtt/resolve",
+    "deliver": "mqtt/deliver.futures",
+}
+
+
 class BatchProfile:
-    """One batch's device-timing record, created at issue and carried
-    WITH the batch (the resolver closure and the staging queue both hold
-    it), so profile boundaries can never be attributed to a different
+    """One device batch's span tree, created when the batch forms and
+    carried WITH the batch (the resolver closure and the staging queue
+    both hold it), so a boundary can never be attributed to a different
     batch — the resilience wrapper resolves batches eagerly on guard
     threads, concurrently and potentially out of order, which rules out
-    any "most recent resolve" pairing. Tuple assignments are atomic
-    under the GIL; a reader sees either None or a complete window."""
+    any "most recent resolve" pairing. Every boundary is one
+    ``time.perf_counter_ns()`` read where the work happens (``span``);
+    the ``dispatch`` / ``d2h`` windows (seconds, ``perf_counter``: the
+    same clock) and the staging leg-wait histograms are fed from the
+    same stamps. Assignments are atomic under the GIL; a reader sees
+    either None or a complete span. The exact-map fast path, host
+    fallbacks and the sharded matcher leave the matcher's spans None."""
 
     __slots__ = (
         "dispatch", "d2h", "d2h_bytes", "d2h_bytes_ranges",
         "d2h_bytes_dense", "compact", "compact_overflow", "devices",
+        "seq", "kept", "topics", "bucket", "depth",
+        "submit_first_ns", "wait_n", "wait_sum_ns",
+        "formed_ns", "issue_start_ns", "issue_end_ns", "sync_start_ns",
+        "tokenize", "h2d_dispatch", "d2h_sync", "resolve", "deliver",
+        "set_sum_ns",
     )
 
     def __init__(self) -> None:
@@ -308,6 +351,254 @@ class BatchProfile:
         # at dispatch (TpuMatcher: the output buffer's device; sharded:
         # every mesh device). None = unstamped, folds as device 0.
         self.devices: Optional[tuple] = None
+        # the identifier the batch's spans share (DeviceProfiler.
+        # open_batch numbers them; a record made without a profiler has
+        # none), and whether a live profiler session keeps this record
+        self.seq: Optional[int] = None
+        self.kept = False
+        # args of the root span: topics in the batch, the padded bucket
+        # they ran in, batches in the pipeline when this one formed
+        self.topics = 0
+        self.bucket = 0
+        self.depth = 0
+        # mqtt/stage.wait (kept records only): submit() -> batch formed
+        # over the ``wait_n`` members whose submit() was stamped (those
+        # parked while the session was live), as the oldest and the sum
+        self.submit_first_ns: Optional[int] = None
+        self.wait_n = 0
+        self.wait_sum_ns = 0
+        # staging's boundaries (perf_counter_ns): batch formed; issue()
+        # starts on the h2d thread; issue() returned; sync() starts on a
+        # resolver thread
+        self.formed_ns: Optional[int] = None
+        self.issue_start_ns: Optional[int] = None
+        self.issue_end_ns: Optional[int] = None
+        self.sync_start_ns: Optional[int] = None
+        # the busy spans, (start_ns, end_ns) each: BUSY_SPANS
+        self.tokenize: Optional[tuple[int, int]] = None
+        self.h2d_dispatch: Optional[tuple[int, int]] = None
+        self.d2h_sync: Optional[tuple[int, int]] = None
+        self.resolve: Optional[tuple[int, int]] = None
+        self.deliver: Optional[tuple[int, int]] = None
+        # sum over the members of the instant each future was set (kept
+        # records only): the mean is where a publish's wait for the
+        # resolve ends and its wait for the loop begins
+        self.set_sum_ns = 0
+
+    def stage_wait(self, submits_ns: list) -> None:
+        """Fold the stamped members' submit() instants into
+        mqtt/stage.wait (``formed_ns`` is already set)."""
+        if submits_ns:
+            self.wait_n = n = len(submits_ns)
+            self.submit_first_ns = min(submits_ns)
+            self.wait_sum_ns = n * self.formed_ns - sum(submits_ns)
+
+    def spans(self) -> list:
+        """The tree as ``(name, start_ns, end_ns, args)``, root first (a
+        batch with no stamped submit starts when it formed); a span
+        whose boundaries were never stamped is left out. Served on
+        ``/traces`` for the newest slice's batches (``slice_events``)."""
+        out = []
+        seq = self.seq
+        first = self.submit_first_ns
+        if self.formed_ns is not None and self.deliver is not None:
+            out.append((
+                "mqtt/batch",
+                self.formed_ns if first is None else first, self.deliver[1],
+                {"batch": seq, "topics": self.topics,
+                 "bucket": self.bucket, "depth": self.depth},
+            ))
+        if first is not None:
+            out.append((
+                "mqtt/stage.wait", first, self.formed_ns,
+                {"batch": seq, "n": self.wait_n, "sum_ns": self.wait_sum_ns},
+            ))
+        for name, t0, t1 in (
+            ("mqtt/issue.handoff", self.formed_ns, self.issue_start_ns),
+            ("mqtt/pipeline.wait", self.issue_end_ns, self.sync_start_ns),
+        ):
+            if t0 is not None and t1 is not None:
+                out.append((name, t0, t1, {"batch": seq}))
+        for slot, name in BUSY_SPANS.items():
+            window = getattr(self, slot)
+            if window is not None:
+                out.append((name, window[0], window[1], {"batch": seq}))
+        return out
+
+
+@contextlib.contextmanager
+def span(rec: Optional[BatchProfile], slot: str):
+    """One busy span of ``rec`` around the work: stamps
+    ``rec.<slot> = (start_ns, end_ns)`` when the work completes and,
+    while a profiler session keeps the record, is also a
+    ``jax.profiler.TraceAnnotation`` carrying the batch's number (so the
+    span lies on the device trace's own clock). ``rec`` None: nothing."""
+    if rec is None:
+        yield
+        return
+    if rec.kept:
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(BUSY_SPANS[slot], batch=rec.seq):
+            t0 = time.perf_counter_ns()
+            yield
+            setattr(rec, slot, (t0, time.perf_counter_ns()))
+    else:
+        t0 = time.perf_counter_ns()
+        yield
+        setattr(rec, slot, (t0, time.perf_counter_ns()))
+
+
+# -- gen-2 collections ---------------------------------------------------------
+
+
+class Gen2Pauses:
+    """The interpreter's full (generation-2) collections and how long
+    each held the process: a ``gc.callbacks`` hook that returns at once
+    for the young generations. Over a heap of a million subscriptions a
+    full collection is the first suspect for a whole-broker stall; this
+    is the counter that can convict it (``/metrics``:
+    ``mqtt_tpu_gc_gen2_pause_seconds``; ``/traces``: the pauses inside
+    the newest profiler slice)."""
+
+    def __init__(self) -> None:
+        # (end_ns, duration_ns) of the newest pauses, perf_counter_ns
+        self.recent: collections.deque = collections.deque(maxlen=64)
+        self.hist = Histogram()
+        self._t0 = 0
+        self._installed = False
+
+    def install(self) -> None:
+        if not self._installed:
+            self._installed = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        now = time.perf_counter_ns()
+        if phase == "start":
+            self._t0 = now
+            return
+        dt = now - self._t0
+        self.recent.append((now, dt))
+        self.hist.observe(dt / 1e9)
+
+
+# process-wide, as the collector is (ops/devicestats.LEDGER's posture);
+# hooked in by the first DeviceProfiler
+GC2 = Gen2Pauses()
+
+
+# -- the slice a profiler session leaves behind ---------------------------------
+
+MAX_SLICE_BATCHES = 16_384
+BEAT_NS = 5_000_000  # the loop heartbeat's interval while armed
+TRACES_BATCHES = 256  # a slice's newest batches, as /traces serves them
+
+
+def thread_group(name: str) -> str:
+    """Which of the slice's CPU groups a thread of this process is in:
+    the event loops; the match path off the loop (the h2d issue thread,
+    the resolver threads and the resilience guard pool, which is where
+    tokenize, dispatch, sync and resolve run when ``matcher_resilience``
+    is on: the threads cannot be told apart by stage); or ``other``
+    (rebuild thread, sampler, breaker probe, flight writers)."""
+    if name == "MainThread" or name.startswith("mqtt-tpu-shard-"):
+        return "loop"
+    if name.startswith(("mqtt-tpu-h2d", "mqtt-tpu-resolve", "mqtt-tpu-guard")):
+        return "match"
+    return "other"
+
+
+_TICK_NS = 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+
+
+def thread_cpu_ns() -> dict:
+    """CPU time (user + system) of every live Python-visible thread, by
+    name, from ``/proc/self/task/<tid>/stat``: the kernel's own
+    accounting, at its tick (10 ms), and safe for a thread that exits
+    meanwhile (the file is gone: skipped), which a ``pthread_t`` is not."""
+    out: dict[str, int] = {}
+    for t in threading.enumerate():
+        try:
+            with open(f"/proc/self/task/{t.native_id}/stat", "rb") as f:
+                # after "(comm)": state is field 3, utime 14, stime 15
+                fields = f.read().rpartition(b")")[2].split()
+            ns = (int(fields[11]) + int(fields[12])) * _TICK_NS
+        except (OSError, IndexError, ValueError):
+            continue  # the thread ended, or no procfs here
+        out[t.name] = out.get(t.name, 0) + ns
+    return out
+
+
+class TraceSlice:
+    """What one profiler session left: snapshot ``a`` from when the
+    program noticed the session, ``b`` from when it noticed its end, and
+    the records of the batches in flight or formed in between
+    (``batches``, at most ``MAX_SLICE_BATCHES``; one still in flight at
+    ``b`` completes later, on the same object). Plain data: the readers
+    (``benchmark/layer_metrics``) do the arithmetic."""
+
+    __slots__ = ("a", "b", "batches")
+
+    def __init__(self, a: dict, b: dict, batches: list) -> None:
+        self.a, self.b, self.batches = a, b, batches
+
+    def cpu_ns_by_group(self) -> dict:
+        """CPU between ``a`` and ``b`` by thread group; ``other`` also
+        takes what no named thread accounts for (process CPU less the
+        named sum: the XLA runtime's own threads)."""
+        out = {"loop": 0, "match": 0, "other": 0}
+        before = self.a["thread_cpu_ns"]
+        named = 0
+        for name, ns in self.b["thread_cpu_ns"].items():
+            d = ns - before.get(name, 0)
+            if d > 0:
+                out[thread_group(name)] += d
+                named += d
+        whole = self.b["process_cpu_ns"] - self.a["process_cpu_ns"]
+        out["other"] += max(0, whole - named)
+        return out
+
+    def gen2_pauses(self) -> list:
+        """``(end_ns, duration_ns)`` of the full collections that ended
+        between ``a`` and ``b``."""
+        return [
+            p for p in self.b["gc2_recent"]
+            if self.a["t_ns"] < p[0] <= self.b["t_ns"]
+        ]
+
+
+_LAST_SLICE: Optional[TraceSlice] = None
+
+
+def last_slice() -> Optional[TraceSlice]:
+    """The newest frozen slice of this process, or None."""
+    return _LAST_SLICE
+
+
+def slice_events(sl: TraceSlice, anchor: float, pid: int) -> list:
+    """The newest slice as Chrome trace events for ``/traces``: the span
+    tree of its newest ``TRACES_BATCHES`` batches, one track a batch
+    (``args.batch`` is the number a sampled publish's root span names),
+    and the full collections that ended inside it."""
+    events = []
+
+    def add(name, cat, t0_ns, t1_ns, tid, args):
+        events.append({
+            "name": name, "cat": cat, "ph": "X",
+            "ts": round((t0_ns / 1e9 + anchor) * 1e6, 3),
+            "dur": round((t1_ns - t0_ns) / 1e3, 3),
+            "pid": pid, "tid": tid, "args": args,
+        })
+
+    for rec in sl.batches[-TRACES_BATCHES:]:
+        for name, t0, t1, args in rec.spans():
+            add(name, "batch", t0, t1, 1_000_000 + rec.seq % 1_000_000, args)
+    for end, dur in sl.gen2_pauses():
+        add("gc/gen2", "gc", end - dur, end, 999_999, {})
+    return events
 
 
 # D2H transfer sizes: single compact rows (~tens of bytes) up to the
@@ -354,30 +645,40 @@ class _DevWindow:
 
 
 class DeviceProfiler:
-    """Host-side device pipeline profiler: each batch's dispatch and
-    D2H windows land on its own :class:`BatchProfile` record and fold
-    into duty-cycle / overlap / idle-gap aggregates.
+    """Host-side device pipeline profiler: each batch's boundaries land
+    on its own :class:`BatchProfile` record and fold into duty-cycle /
+    overlap / idle-gap aggregates.
 
-    A batch's **device window** runs from dispatch-return (the kernel is
-    queued and the host moves on) to the end of the blocking D2H sync —
-    kernel execution plus result transfer, the best host-observable
-    proxy without a device-side profiler (``Options.
-    trace_jax_profiler_dir`` hooks ``jax.profiler`` for the real
-    timeline). Aggregates:
+    A batch's **in-flight window** runs from dispatch-return (the kernel
+    is queued and the host moves on) to the end of the blocking D2H
+    sync. That is what the HOST can see: it contains the kernel and the
+    transfer but also every wait around them, so the aggregates are
+    upper bounds on device busy time, never device busy time (on the
+    v5e the device trace read 0.04% busy where these read tens of
+    percent; the device's own number is ``device_idle_share`` from a
+    ``jax.profiler`` trace). Aggregates:
 
-    - ``duty_cycle`` = union of device windows / wall time since the
-      first dispatch — how busy the device actually is (ROADMAP item 1:
-      "the kernel is idle most of the wall clock").
+    - ``duty_cycle`` = union of in-flight windows / wall time since the
+      first dispatch: the share of wall time with a batch in flight as
+      the host sees it.
     - ``overlap_ratio`` = overlapped window time / summed window time —
       how deep the staging pipeline actually runs (0 = strictly serial,
       approaching (depth-1)/depth for a depth-N pipeline).
-    - ``idle_gap`` histogram = device-idle stretches between windows —
-      exactly the gaps a 3-deep pipeline must close.
+    - ``idle_gap`` histogram = stretches with no batch in flight.
 
     Dispatches and resolves may come from different threads (the
-    staging loop issues on the event loop; resolves run in an executor
+    staging loop issues on the h2d thread; resolves run in an executor
     or on resilience guard threads); everything mutates under one lock,
-    held for arithmetic only."""
+    held for arithmetic only.
+
+    **The armed state.** ``poll()`` (once per batch from the staging
+    collector, once per sweep from the sampling profiler's thread)
+    compares ``jax.profiler.TraceAnnotation.is_enabled()`` with
+    ``armed``. Off -> on: snapshot A, keep every record from now on
+    (and those in flight), start the loop heartbeat. On -> off:
+    snapshot B, freeze a :class:`TraceSlice` (``last_slice()``). The
+    per-publish loop counters (``note_ingest`` / ``note_fanout``) count
+    only while armed."""
 
     def __init__(self, registry: Any = None) -> None:
         self._lock = threading.Lock()
@@ -402,6 +703,32 @@ class DeviceProfiler:
         self.d2h_bytes_ranges_total = 0
         self.d2h_bytes_dense_total = 0
         self._bytes_batches = 0  # batches that stamped transfer bytes
+        # -- the armed state (class docstring) --
+        self.armed = False
+        self._arm_lock = threading.Lock()
+        self._seq = itertools.count(1)
+        # the newest records, so that arming finds the batches in flight
+        self._recent: collections.deque = collections.deque(maxlen=8)
+        self._kept: list = []
+        self._snap_a: Optional[dict] = None
+        self._is_enabled: Any = None  # TraceAnnotation.is_enabled, on first poll
+        # set by whoever owns them: the served matcher's MatcherStats
+        # (server) and the loop the stage runs on (MatchStage.start)
+        self.matcher_stats: Any = None
+        self.loop: Any = None
+        # per-publish loop counters, cumulative ns / counts, armed only:
+        # frame scanned -> submit() (ingest), future set -> fan-out
+        # starts (fanout_wait), fan-out start -> flush done (fanout_busy)
+        self.ingest_busy_ns = 0
+        self.ingest_n = 0
+        self.fanout_wait_ns = 0
+        self.fanout_busy_ns = 0
+        self.fanout_n = 0
+        # the heartbeat's longest missed interval since arming
+        self.loop_stall_max_ns = 0
+        self._beat_ns = 0
+        self._beats = 0
+        GC2.install()
         if registry is not None:
             self.issue_hist = registry.histogram(
                 "mqtt_tpu_device_issue_seconds",
@@ -422,14 +749,23 @@ class DeviceProfiler:
             )
             registry.gauge(
                 "mqtt_tpu_device_duty_cycle_ratio",
-                "Union of device-busy windows over wall time since first dispatch",
+                "Share of wall time since first dispatch with a batch in "
+                "flight (dispatch returned to D2H sync done) as the host "
+                "sees it: an upper bound on device busy time, not device "
+                "busy time",
                 fn=self.duty_cycle,
             )
             registry.gauge(
                 "mqtt_tpu_device_overlap_ratio",
-                "Overlapped device-window time over summed window time "
-                "(pipeline depth proxy)",
+                "Overlapped in-flight window time over summed in-flight "
+                "window time, host-observed (pipeline depth proxy)",
                 fn=self.overlap_ratio,
+            )
+            registry.histogram(
+                "mqtt_tpu_gc_gen2_pause_seconds",
+                "Full (generation-2) garbage collections of the "
+                "interpreter: how long each held the process",
+                fn=lambda: GC2.hist,
             )
         else:
             self.issue_hist = Histogram()
@@ -440,9 +776,118 @@ class DeviceProfiler:
     # -- recording (matcher hooks) -----------------------------------------
 
     def open_batch(self) -> BatchProfile:
-        """A fresh per-batch record; the matcher fills it and whoever
-        holds the batch (staging drain loop, bench) reads it."""
-        return BatchProfile()
+        """A fresh, numbered per-batch record; staging and the matcher
+        fill it and whoever holds the batch (staging drain loop, bench)
+        reads it. While armed the record is kept for the slice."""
+        rec = BatchProfile()
+        rec.seq = next(self._seq)
+        if self.armed and len(self._kept) < MAX_SLICE_BATCHES:
+            rec.kept = True
+            self._kept.append(rec)
+        self._recent.append(rec)
+        return rec
+
+    # -- the armed state ------------------------------------------------------
+
+    def poll(self) -> bool:
+        """Follow the profiler session: arm on its start, freeze a slice
+        on its end. Returns ``armed``."""
+        is_enabled = self._is_enabled
+        if is_enabled is None:
+            from jax.profiler import TraceAnnotation
+
+            is_enabled = self._is_enabled = TraceAnnotation.is_enabled
+        on = bool(is_enabled())
+        if on != self.armed:
+            with self._arm_lock:
+                if on != self.armed:
+                    if on:
+                        self._arm()
+                    else:
+                        self._disarm()
+        return self.armed
+
+    def _snapshot(self) -> dict:
+        """One edge of a slice: the instant, CPU (process and per
+        thread), topics the matcher took in, the in-flight union so far
+        (``duty_cycle``'s numerator), the newest full collections, and
+        the armed-only loop counters."""
+        stats = self.matcher_stats
+        return {
+            "t_ns": time.perf_counter_ns(),
+            "process_cpu_ns": time.process_time_ns(),
+            "thread_cpu_ns": thread_cpu_ns(),
+            "topics": stats.topics if stats is not None else 0,
+            "inflight_s": self._busy_s,
+            "gc2_recent": list(GC2.recent),
+            "ingest_busy_ns": self.ingest_busy_ns,
+            "ingest_n": self.ingest_n,
+            "fanout_wait_ns": self.fanout_wait_ns,
+            "fanout_busy_ns": self.fanout_busy_ns,
+            "fanout_n": self.fanout_n,
+            "loop_beats": self._beats,
+            "loop_stall_max_ns": self.loop_stall_max_ns,
+        }
+
+    def _arm(self) -> None:
+        inflight = [r for r in self._recent if r.deliver is None]
+        for r in inflight:
+            r.kept = True
+        self._kept = inflight
+        self.loop_stall_max_ns = 0
+        self._beat_ns = 0
+        self._snap_a = self._snapshot()
+        self.armed = True
+        loop = self.loop
+        if loop is not None:
+            try:
+                loop.call_soon_threadsafe(self._beat)
+            except RuntimeError:
+                pass  # the loop is closed: no heartbeat, nothing to stall
+
+    def _disarm(self) -> None:
+        global _LAST_SLICE
+        self.armed = False
+        if self._beat_ns:  # a stall still in progress counts
+            self._note_beat(time.perf_counter_ns())
+        kept, self._kept = self._kept, []
+        a, self._snap_a = self._snap_a, None
+        if a is not None:
+            _LAST_SLICE = TraceSlice(a, self._snapshot(), kept)
+
+    def _note_beat(self, now: int) -> None:
+        late = now - self._beat_ns - BEAT_NS
+        if late > self.loop_stall_max_ns:
+            self.loop_stall_max_ns = late
+
+    def _beat(self) -> None:
+        """The loop heartbeat: every 5 ms while armed; how late the
+        latest one ran is how long the loop was held."""
+        if not self.armed:
+            self._beat_ns = 0
+            return
+        now = time.perf_counter_ns()
+        if self._beat_ns:
+            self._note_beat(now)
+        self._beat_ns = now
+        self._beats += 1
+        self.loop.call_later(BEAT_NS / 1e9, self._beat)
+
+    def note_ingest(self, busy_ns: int, n: int = 0) -> None:
+        """Loop time between publish frames being scanned and their
+        ``stage.submit()`` (armed only): the read loop reports a scan's
+        frame loop with its ``n`` publishes, the fan-out coroutine its own
+        stretch up to ``submit()`` with ``n`` 0."""
+        self.ingest_busy_ns += busy_ns
+        self.ingest_n += n
+
+    def note_fanout(self, set_ns: int, start_ns: int, done_ns: int) -> None:
+        """One publish's fan-out (armed only): its future was set at
+        ``set_ns``, its coroutine ran again at ``start_ns`` (the wait
+        for the loop) and its flush was done at ``done_ns``."""
+        self.fanout_wait_ns += start_ns - set_ns
+        self.fanout_busy_ns += done_ns - start_ns
+        self.fanout_n += 1
 
     def ensure_device(self, did: int) -> _DevWindow:
         """The window replica for one device id, creating it (and its
@@ -563,6 +1008,8 @@ class DeviceProfiler:
     # -- aggregates ---------------------------------------------------------
 
     def duty_cycle(self) -> float:
+        """Share of wall time with a batch in flight, host-observed: an
+        upper bound on device busy time (class docstring)."""
         with self._lock:
             if self._first_t is None or self._last_t <= self._first_t:
                 return 0.0

@@ -131,6 +131,26 @@ class TestTracer:
         root = [e for e in by_trace[tr.trace_id] if e["name"] == "publish"][0]
         assert root["args"]["topic"] == "a/b" and root["args"]["qos"] == 1
 
+    @pytest.mark.parametrize("batch", [None, 41])
+    def test_root_span_names_the_batch_that_carried_it(self, batch):
+        """A staged publish's clock is stamped with its device batch's
+        number (BatchProfile.seq): the root span's ``batch`` arg joins it
+        to the mqtt/* annotations of a profiler trace. A publish that
+        was never staged has no such arg; the children keep their shape."""
+        t = Tracer(seed=5)
+        tr = t.publish_trace()
+        assert tr.batch is None
+        tr.stamp("decode")
+        tr.batch = batch
+        tr.stamp("fanout")
+        t.finish_publish(tr, "a/b", 0)
+        events = spans_by_trace(t.export())[tr.trace_id]
+        assert_publish_tree(events)
+        root = [e for e in events if e["name"] == "publish"][0]
+        assert root["args"].get("batch") == batch
+        assert ("batch" in root["args"]) == (batch is not None)
+        assert all("batch" not in e["args"] for e in events if e["cat"] == "stage")
+
     def test_adopted_weird_trace_ids_export_safely(self):
         t = Tracer(seed=0)
         tr = t.publish_trace("client-chose-this-id/πß")
@@ -284,6 +304,9 @@ class TestStagedSpanTree:
                 assert_publish_tree(events)
                 names = {e["name"] for e in events if e["cat"] == "stage"}
                 assert names == expected, names
+                # the root names the device batch that carried the publish
+                root = [e for e in events if e["name"] == "publish"][0]
+                assert isinstance(root["args"]["batch"], int)
             # the sub-stages also landed in the histograms, and
             # device_batch aggregates them exactly once per publish
             tele = h.server.telemetry
