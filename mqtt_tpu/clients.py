@@ -345,6 +345,22 @@ class Client:
         # the read loop counts, read_delay() resets on window roll
         self._pub_epoch = -1
         self._pub_count = 0
+        # this connection's publishes in the staging loop
+        # (mqtt_tpu.staging): counted up where one is parked from the
+        # connection's own loop and down by its batch's completion
+        # (server._park_publish / _complete_staged). The read loop waits
+        # ONCE a scan on ``_staged_waiter`` until the count is back at
+        # zero, and raises there the first error a completion recorded.
+        self._staged = 0
+        self._staged_waiter: Optional[asyncio.Future] = None
+        self._staged_err: Optional[BaseException] = None
+        # the encoded packets this connection's handlers have written
+        # during the socket read in hand (its acks, mostly), or None
+        # between reads: they leave as ONE transport write when the
+        # read's frames are done (read / _uncork). A socket send is the
+        # dearest thing the loop does (a syscall against a handful of
+        # bytecodes), and a pipelining publisher draws one ack a frame.
+        self._cork: Optional[list] = None
         # priority-weighted shedding (mqtt_tpu.overload): the class and
         # its shed/publish-quota multiplier, resolved at CONNECT from
         # Options.overload_priority_users / overload_priority_classes
@@ -408,7 +424,7 @@ class Client:
             raise ConnectionClosedError()
         if self.net.writer is None:
             return
-        self.net.writer.write(data)
+        self._write(data)
         self.ops.info.bytes_sent += len(data)
         self.ops.info.packets_sent += 1
         self.ops.info.messages_sent += 1
@@ -423,6 +439,22 @@ class Client:
             # can keep $SYS housekeeping out of the amplification math
             tele.outbound_bytes.inc(len(data))
             tele.outbound_writes.inc()
+
+    def _write(self, data: bytes) -> None:
+        """One encoded packet to the transport, in order: behind the
+        packets of the socket read in hand while there is one."""
+        cork = self._cork
+        if cork is not None:
+            cork.append(data)
+        else:
+            self.net.writer.write(data)
+
+    def _uncork(self) -> None:
+        """Write what the socket read in hand has corked, as one
+        transport write, and stop corking."""
+        cork, self._cork = self._cork, None
+        if cork and self.net.writer is not None:
+            self.net.writer.write(cork[0] if len(cork) == 1 else b"".join(cork))
 
     def parse_connect(self, lid: str, pk: Packet) -> None:
         """Absorb CONNECT parameters into client state (clients.go:208-257)."""
@@ -546,6 +578,18 @@ class Client:
         buffer — one await per socket read instead of one per header byte,
         which is what keeps the asyncio data plane within reach of the
         reference's goroutine throughput (SURVEY.md §7 hard-part #5).
+
+        ``packet_handler(cl, pk)`` is synchronous. A PUBLISH it parked
+        with the staging loop (mqtt_tpu.staging) is still counted in
+        ``_staged`` when it returns: every publish of a scan reaches the
+        staging batch before this loop blocks, and it blocks ONCE a scan,
+        on one future, until the last of them has fanned out — no further
+        read before that (the back-pressure and the per-connection order
+        depend on it). An error a completion recorded for this
+        connection is raised here. What the handlers write to this
+        connection during one read (an ack a QoS>0 frame) is corked and
+        leaves as one transport write when the read's frames are done
+        (``_cork``): one socket send a read, not one a frame.
         """
         from .native import MAX_FRAMES_PER_SCAN, frame_scan, varint_decode
 
@@ -555,17 +599,16 @@ class Client:
         telemetry = getattr(self.ops, "telemetry", None)
         # device pipeline profiler (mqtt_tpu.tracing): while a profiler
         # session is live, the loop time from a scan's frames in hand to
-        # their handlers returned (decode, admission, the fan-out tasks
-        # created) is counted as ingest, once a scan, over the publishes
-        # in it; the rest, up to submit(), is counted where the task
-        # runs (server._staged_fan_out)
+        # their handlers returned (decode, admission, acks, the publish
+        # parked with the stage) is counted as ingest, once a scan, over
+        # the publishes in it
         prof = getattr(self.ops, "profiler", None)
         # the shard's own gate wins (per-shard decode batching is
         # default-on inside the fabric); the server-wide gate serves the
         # single-loop opt-in (Options.scan_coalesce)
         scan_gate = self.scan_gate or getattr(self.ops, "scan_gate", None)
         rbuf = bytearray()
-        deferred: Optional[list] = None
+        loop = asyncio.get_running_loop()
         self.refresh_deadline(self.state.keepalive)
         while True:
             if self.closed:
@@ -587,71 +630,69 @@ class Client:
                 t_in = time.perf_counter_ns()
                 n_in = self._pub_count
             start = 0
-            for f in frames:
-                fstart = start
-                fend = f.body_offset + f.remaining
-                self.ops.info.bytes_received += (f.body_offset - start) + f.remaining
-                start = fend
-                if (f.first_byte >> 4) == pkts.PUBLISH:
-                    # overload-governor accounting: publishes this window
-                    # (both the fast-path and decode legs land here)
-                    self._pub_count += 1
-                # QoS0 v4 PUBLISH passthrough (flags all zero): deliver the
-                # frame bytes without materializing a Packet when the
-                # server proves nothing can observe the difference. The
-                # session gate runs BEFORE any bytes are copied.
-                if (
-                    f.first_byte == 0x30
-                    and fast_publish is not None
-                    and fast_eligible(self)
-                ):
-                    frame = bytes(rbuf[fstart:fend])
-                    if fast_publish(self, frame, f.body_offset - fstart):
-                        continue
-                    body = frame[f.body_offset - fstart :]
-                else:
-                    body = bytes(rbuf[f.body_offset : fend])
-                # telemetry stage clock: 1-in-N publishes get stamped
-                # through decode -> admission -> staging -> fanout
-                # (mqtt_tpu.telemetry); the clock rides on the packet
-                clock = None
-                if telemetry is not None and (f.first_byte >> 4) == pkts.PUBLISH:
-                    clock = telemetry.publish_clock()
-                fh = FixedHeader()
-                fh.decode(f.first_byte)
-                fh.remaining = f.remaining
-                pk = self._decode_body(fh, body)
-                if clock is not None:
-                    clock.stamp("decode")
-                    # dynamic rider, not a Packet field: the clock never
-                    # touches the wire or dataclass equality
-                    setattr(pk, "_tclock", clock)
-                result = packet_handler(self, pk)
-                if asyncio.iscoroutine(result):
-                    # deferred (staged-publish) completions: schedule now,
-                    # await after the whole scan — every publish in this
-                    # socket read reaches the staging batch before we block
-                    # on any of them, so one pipelining client still fills
-                    # device batches instead of paying a round trip each
-                    if deferred is None:
-                        deferred = []
-                    deferred.append(asyncio.get_running_loop().create_task(result))
-                if self.closed:
-                    break
+            self._cork = []  # this read's acks leave as one write
+            try:
+                for f in frames:
+                    fstart = start
+                    fend = f.body_offset + f.remaining
+                    self.ops.info.bytes_received += (f.body_offset - start) + f.remaining
+                    start = fend
+                    if (f.first_byte >> 4) == pkts.PUBLISH:
+                        # overload-governor accounting: publishes this window
+                        # (both the fast-path and decode legs land here)
+                        self._pub_count += 1
+                    # QoS0 v4 PUBLISH passthrough (flags all zero): deliver the
+                    # frame bytes without materializing a Packet when the
+                    # server proves nothing can observe the difference. The
+                    # session gate runs BEFORE any bytes are copied.
+                    if (
+                        f.first_byte == 0x30
+                        and fast_publish is not None
+                        and fast_eligible(self)
+                    ):
+                        frame = bytes(rbuf[fstart:fend])
+                        if fast_publish(self, frame, f.body_offset - fstart):
+                            continue
+                        body = frame[f.body_offset - fstart :]
+                    else:
+                        body = bytes(rbuf[f.body_offset : fend])
+                    # telemetry stage clock: 1-in-N publishes get stamped
+                    # through decode -> admission -> staging -> fanout
+                    # (mqtt_tpu.telemetry); the clock rides on the packet
+                    clock = None
+                    if telemetry is not None and (f.first_byte >> 4) == pkts.PUBLISH:
+                        clock = telemetry.publish_clock()
+                    fh = FixedHeader()
+                    fh.decode(f.first_byte)
+                    fh.remaining = f.remaining
+                    pk = self._decode_body(fh, body)
+                    if clock is not None:
+                        clock.stamp("decode")
+                        # dynamic rider, not a Packet field: the clock never
+                        # touches the wire or dataclass equality
+                        setattr(pk, "_tclock", clock)
+                    packet_handler(self, pk)
+                    if self.closed:
+                        break
+            finally:
+                self._uncork()
             if armed and self._pub_count != n_in:
                 prof.note_ingest(
                     time.perf_counter_ns() - t_in, self._pub_count - n_in
                 )
-            if deferred is not None:
-                err0: Optional[BaseException] = None
-                for t in deferred:
-                    try:
-                        await t
-                    except BaseException as e:
-                        err0 = err0 or e
-                deferred = None
-                if err0 is not None:
-                    raise err0
+            if self._staged:
+                # publishes of this scan are still in the stage: one
+                # pipelining client fills device batches instead of
+                # paying a round trip each, and waits here for all of
+                # them at once
+                waiter = self._staged_waiter = loop.create_future()
+                try:
+                    await waiter
+                finally:
+                    self._staged_waiter = None
+            if self._staged_err is not None:
+                err0, self._staged_err = self._staged_err, None
+                raise err0
             if self.closed:
                 return
             del rbuf[:consumed]
@@ -765,7 +806,12 @@ class Client:
             self._writer_task.cancel()
         if self.net.writer is not None:
             try:
-                self.net.writer.close()
+                try:
+                    # a DISCONNECT written inside the read in hand goes
+                    # out before the transport closes behind it
+                    self._uncork()
+                finally:
+                    self.net.writer.close()
             except Exception:  # brokerlint: ok=R4 teardown; the transport is already dead and close() has no one to report to
                 pass
 
@@ -876,7 +922,7 @@ class Client:
         finally:
             put_buffer(buf)
 
-        self.net.writer.write(data)
+        self._write(data)
 
         self.ops.info.bytes_sent += len(data)
         self.ops.info.packets_sent += 1

@@ -86,6 +86,7 @@ from .packets import (
     Subscription,
     UserProperty,
 )
+from .staging import MatchStage, Parked
 from .system import Info
 from .utils.mempool import get_buffer, put_buffer
 from .utils.loopwitness import DEFAULT_LOOP_PLANE as _LOOP_PLANE
@@ -912,6 +913,9 @@ class Server:
         # never crosses this module's annotated signatures
         self.matcher: Optional[Any] = None  # device matcher; None = host walk
         self._stage: Optional[Any] = None  # publish staging loop (serve())
+        # every parked publish names this one object as its completion:
+        # the stage groups a batch's entries by it (staging._hand_over)
+        self._staged_completion = self._complete_staged
         self._jax_trace_active = False  # trace_jax_profiler_dir capture
         # broker-wide overload governor (mqtt_tpu.overload): admission,
         # backpressure, and graceful shedding under publish storms.
@@ -1365,8 +1369,6 @@ class Server:
             self.read_store()
 
         if self.matcher is not None:
-            from .staging import MatchStage
-
             budget_ms = self.options.matcher_stage_latency_budget_ms
             self._stage = MatchStage(
                 self.matcher,
@@ -1555,6 +1557,29 @@ class Server:
             "mqtt_tpu_stage_pending_depth",
             "Publishes parked in the staging loop",
             fn=lambda: 0 if self._stage is None else self._stage.pending_depth,
+        )
+        # how publishes leave the stage (mqtt_tpu.staging): through their
+        # batch's completion call (the served path: no task, future or
+        # coroutine a publish) or through submit()'s future (tests and
+        # embedders: 0 on the served path), and the completion calls
+        # made (one a slice of a batch: one read of the client registry)
+        for path, attr in (("batch", "batch_completed"), ("adapter", "adapter_completed")):
+            r.counter(
+                "mqtt_tpu_stage_completed_total",
+                "Publishes completed by the staging loop, by path: their "
+                "batch's completion call, or submit()'s future adapter",
+                fn=lambda attr=attr: (
+                    0 if self._stage is None else getattr(self._stage, attr)
+                ),
+                path=path,
+            )
+        r.counter(
+            "mqtt_tpu_stage_completion_calls_total",
+            "Batch completion calls made by the staging loop (one a slice "
+            "of a batch)",
+            fn=lambda: (
+                0 if self._stage is None else self._stage.batch_completions
+            ),
         )
         r.gauge(
             "mqtt_tpu_staging_pipeline_depth",
@@ -2301,24 +2326,17 @@ class Server:
             raise ERR_PROTOCOL_VIOLATION_REQUIRE_FIRST_CONNECT()
         return await cl.read_packet(fh)
 
-    def receive_packet(self, cl: Client, pk: Packet):
+    def receive_packet(self, cl: Client, pk: Packet) -> None:
         """Process one inbound packet; a v5 error code disconnects the client
-        (server.go:519-534). Returns a coroutine when processing defers to
-        the publish staging loop — the caller's read loop awaits it, so the
+        (server.go:519-534). Synchronous: a PUBLISH that went to the
+        publish staging loop is parked when this returns, and its fan-out,
+        ``on_published`` and ``on_packet_processed`` run in its batch's
+        completion (``_complete_staged``). The caller's read loop waits
+        once a socket read for its connection's parked publishes, so the
         publishing client blocks on its own fan-out (the reference's
         per-connection-goroutine semantics) while other clients proceed."""
         try:
-            result = self.process_packet(cl, pk)
-        except Code as code:
-            self._packet_error(cl, code)
-            raise
-        if asyncio.iscoroutine(result):
-            return self._receive_deferred(cl, result)
-        return None
-
-    async def _receive_deferred(self, cl: Client, coro) -> None:
-        try:
-            await coro
+            self.process_packet(cl, pk)
         except Code as code:
             self._packet_error(cl, code)
             raise
@@ -2494,19 +2512,15 @@ class Server:
 
     # -- packet processing -------------------------------------------------
 
-    def process_packet(self, cl: Client, pk: Packet):
+    def process_packet(self, cl: Client, pk: Packet) -> None:
         """Dispatch one inbound packet by type (server.go:667-730); raises a
-        Code on protocol errors. A staged PUBLISH returns a coroutine whose
-        await completes the fan-out (hook order — on_published before
-        on_packet_processed — is preserved inside it)."""
+        Code on protocol errors. A PUBLISH parked with the staging loop
+        leaves its post-processing (``on_packet_processed``, the quota
+        drain) to its batch's completion, after its fan-out (hook order —
+        on_published before on_packet_processed — is preserved there)."""
         t = pk.fixed_header.type
-        if (
-            t == pkts.PUBLISH
-            and self._stage is not None
-            and not cl.net.inline
-        ):
-            return self._process_publish_deferred(cl, pk)
         err: Optional[Exception] = None
+        parked = False
         try:
             if t == pkts.CONNECT:
                 self.process_connect(cl, pk)
@@ -2515,7 +2529,7 @@ class Server:
             elif t == pkts.PINGREQ:
                 self.process_pingreq(cl, pk)
             elif t == pkts.PUBLISH:
-                self._dispatch_publish(cl, pk)
+                parked = self._dispatch_publish(cl, pk)
             elif t == pkts.PUBACK:
                 self.process_puback(cl, pk)
             elif t == pkts.PUBREC:
@@ -2545,33 +2559,19 @@ class Server:
             err = e
             raise
         finally:
-            self.hooks.on_packet_processed(cl, pk, err)
+            if not parked:
+                self.hooks.on_packet_processed(cl, pk, err)
 
-        self._drain_quota_starved(cl)
+        if not parked:
+            self._drain_quota_starved(cl)
 
-    def _dispatch_publish(self, cl: Client, pk: Packet):
-        """Validate + process one PUBLISH — the single dispatch point shared
-        by the sync and staged paths; returns a coroutine when staged."""
+    def _dispatch_publish(self, cl: Client, pk: Packet) -> bool:
+        """Validate + process one PUBLISH; True when it was parked with
+        the staging loop (``process_publish``)."""
         code = pk.publish_validate(self.options.capabilities.topic_alias_maximum)
         if code != CODE_SUCCESS:
             raise code()
         return self.process_publish(cl, pk)
-
-    async def _process_publish_deferred(self, cl: Client, pk: Packet) -> None:
-        """The staged PUBLISH path: identical dispatch semantics to the sync
-        path (validate, process, on_packet_processed with the error, quota
-        drain) with the fan-out awaited through the staging loop."""
-        err: Optional[Exception] = None
-        try:
-            deferred = self._dispatch_publish(cl, pk)
-            if deferred is not None:
-                await deferred
-        except Exception as e:
-            err = e
-            raise
-        finally:
-            self.hooks.on_packet_processed(cl, pk, err)
-        self._drain_quota_starved(cl)
 
     def _drain_quota_starved(self, cl: Client) -> None:
         # post-process: drain one quota-starved inflight if quota freed up
@@ -2696,47 +2696,39 @@ class Server:
 
     def inject_packet(self, cl: Client, pk: Packet) -> None:
         """Process a packet as if sent by ``cl``, bypassing the network
-        (server.go:840-854). A staged PUBLISH completes its fan-out as a
-        scheduled task (or synchronously when no loop is running)."""
+        (server.go:840-854). A staged PUBLISH is parked when this returns
+        and fans out with its batch, like one read from a socket."""
         pk.protocol_version = cl.properties.protocol_version
-        result = self.process_packet(cl, pk)
-        if asyncio.iscoroutine(result):
-            try:
-                # found by brokerlint R13: the fan-out task was
-                # fire-and-forget, so asyncio's weak reference was the
-                # only thing keeping it alive mid-flight
-                task = asyncio.get_running_loop().create_task(result)
-                self.listeners.client_tasks.add(task)
-                task.add_done_callback(self.listeners.client_tasks.discard)
-            except RuntimeError:
-                asyncio.run(result)
+        self.process_packet(cl, pk)
         self.info.packets_received += 1
         if pk.fixed_header.type == pkts.PUBLISH:
             self.info.messages_received += 1
 
     # -- publish flow ------------------------------------------------------
 
-    def process_publish(self, cl: Client, pk: Packet):
+    def process_publish(self, cl: Client, pk: Packet) -> bool:
         """The publish hot path (server.go:857-968). With the staging loop
-        active, returns a coroutine completing the fan-out (QoS acks are
-        already written synchronously before it is returned)."""
+        active the publish is parked with it and True is returned: its
+        fan-out and everything after it run in its batch's completion
+        (``_complete_staged``); QoS acks are already written. False: the
+        publish was dealt with here (fanned out, refused or dropped)."""
         if not cl.net.inline and not is_valid_filter(pk.topic_name, True):
-            return
+            return False
 
         if cl.state.inflight.receive_quota == 0:
             self.disconnect_client(cl, ERR_RECEIVE_MAXIMUM)  # ~[MQTT-3.3.4-7/-8]
-            return
+            return False
 
         if not cl.net.inline and not self.hooks.on_acl_check(cl, pk.topic_name, True):
             if pk.fixed_header.qos == 0:
-                return
+                return False
             if cl.properties.protocol_version != 5:
                 self.disconnect_client(cl, ERR_NOT_AUTHORIZED)
-                return
+                return False
             ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
             ack = self.build_ack(pk.packet_id, ack_type, 0, pk.properties, ERR_NOT_AUTHORIZED)
             cl.write_packet(ack)
-            return
+            return False
 
         pk.origin = cl.id
         pk.created = int(time.time())  # brokerlint: ok=R3 packet creation stamp is wall-clock (persists/expires across restarts)
@@ -2755,7 +2747,7 @@ class Server:
                         pk.packet_id, pkts.PUBREC, 0, pk.properties, ERR_PACKET_IDENTIFIER_IN_USE
                     )
                     cl.write_packet(ack)
-                    return
+                    return False
                 if cl.state.inflight.delete(pk.packet_id):  # [MQTT-4.3.2-5]
                     self.info.inflight -= 1
 
@@ -2786,14 +2778,14 @@ class Server:
                 # visibly shaping who sheds (mqtt_tpu.tenancy)
                 cl.tenant.messages_dropped += 1
             if pk.fixed_header.qos == 0:
-                return
+                return False
             ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
             cl.write_packet(
                 self.build_ack(
                     pk.packet_id, ack_type, 0, pk.properties, ERR_QUOTA_EXCEEDED
                 )
             )
-            return
+            return False
 
         # telemetry stage clock (attached by the read loop on sampled
         # publishes): everything from decode's end to here — validation,
@@ -2833,12 +2825,12 @@ class Server:
             pk = self.hooks.on_publish(cl, pk)
         except Code as e:
             if e == ERR_REJECT_PACKET:
-                return
+                return False
             if e == CODE_SUCCESS_IGNORE:
                 pk.ignore = True
             elif cl.properties.protocol_version == 5 and pk.fixed_header.qos > 0:
                 cl.write_packet(self.build_ack(pk.packet_id, pkts.PUBACK, 0, pk.properties, e))
-                return
+                return False
             # other errors: continue with the original packet (reference
             # server.go:912-925 falls through)
 
@@ -2862,14 +2854,14 @@ class Server:
             if cl.tenant is not None:
                 cl.tenant.messages_dropped += 1
             if pk.fixed_header.qos == 0:
-                return
+                return False
             ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
             cl.write_packet(
                 self.build_ack(
                     pk.packet_id, ack_type, 0, pk.properties, ERR_QUOTA_EXCEEDED
                 )
             )
-            return
+            return False
 
         if pk.fixed_header.retain:  # [MQTT-3.3.1-5]
             self.retain_message(cl, pk)
@@ -2877,11 +2869,11 @@ class Server:
         # inline clients can't handle PUBREC/PUBREL: treat as qos 0 inbound
         if pk.fixed_header.qos == 0 or cl.net.inline:
             if self._stage is not None and not cl.net.inline:
-                return self._staged_fan_out(cl, pk)
+                return self._park_publish(cl, pk)
             self.publish_to_subscribers(pk)
             self._finish_publish_clock(pk)
             self.hooks.on_published(cl, pk)
-            return None
+            return False
 
         cl.state.inflight.decrease_receive_quota()
         ack = self.build_ack(
@@ -2905,11 +2897,11 @@ class Server:
             self.hooks.on_qos_complete(cl, ack)
 
         if self._stage is not None and not cl.net.inline:
-            return self._staged_fan_out(cl, pk)
+            return self._park_publish(cl, pk)
         self.publish_to_subscribers(pk)
         self._finish_publish_clock(pk)
         self.hooks.on_published(cl, pk)
-        return None
+        return False
 
     def _finish_publish_clock(self, pk: Packet) -> None:
         """Close out a sampled publish's stage clock after fan-out: the
@@ -2965,54 +2957,152 @@ class Server:
             clock.stamp("fanout")
         self._observe_delivery_sli(clock, pk, "remote")
 
-    async def _staged_fan_out(self, cl: Client, pk: Packet) -> None:
-        """Fan out one publish through the staging loop: the device match
-        batch resolves off the event loop and this client awaits only its
-        own result (SURVEY.md §7 stage 4; seam: server.go:984-1021)."""
-        if not pk.ignore:
-            # while a profiler session is live: loop time from here to
-            # submit() is ingest, and the drain loop leaves on the future
-            # the instant it was set (DeviceProfiler.note_ingest /
-            # note_fanout); off, the cost is this test and one more
-            prof = self.profiler
-            armed = prof is not None and prof.armed
-            if armed:
-                t_in = time.perf_counter_ns()
-            self._stamp_publish_expiry(pk)
-            # MQTT+ predicate plane: extract the payload features ONCE
-            # on the host; the stage batches them to the device beside
-            # the tokenized topics and stamps the resolved pass bits
-            # back onto this carrier (mqtt_tpu.predicates)
-            eng = self._predicates
-            feats = (
-                eng.features_for(bytes(pk.payload))
-                if eng is not None and eng.active
-                else None
-            )
+    def _park_publish(self, cl: Client, pk: Packet) -> bool:
+        """Park one publish with the staging loop: the device match batch
+        resolves off the event loop and the publish fans out in its
+        batch's completion, ``_complete_staged`` (SURVEY.md §7 stage 4;
+        seam: server.go:984-1021). No task, future or coroutine is made
+        for it. True: parked (an admission fallback has already
+        completed it, inside the call)."""
+        if pk.ignore:
+            self.hooks.on_published(cl, pk)
+            return False
+        self._stamp_publish_expiry(pk)
+        # MQTT+ predicate plane: extract the payload features ONCE
+        # on the host; the stage batches them to the device beside
+        # the tokenized topics and stamps the resolved pass bits
+        # back onto this carrier (mqtt_tpu.predicates)
+        eng = self._predicates
+        entry = Parked(
+            self._staged_completion,
+            getattr(pk, "_tclock", None),
+            eng.features_for(bytes(pk.payload))
+            if eng is not None and eng.active
+            else None,
             # encrypted-namespace publishes carry a decrypt job whose
             # keystream dispatch rides the same staged batch
             # (mqtt_tpu.tenancy.RecryptJob through MatchStage)
-            rjob = self._recrypt_job_for(cl, pk)
-            if not armed:
-                subscribers = await self._stage.submit(
-                    pk.topic_name, getattr(pk, "_tclock", None), feats, rjob
+            self._recrypt_job_for(cl, pk),
+            cl,
+            pk,
+        )
+        try:
+            here = entry.loop = asyncio.get_running_loop()
+        except RuntimeError:
+            here = None  # no loop on this thread: the stage's completes it
+        if here is not None and here is cl.net.loop:
+            # parked from the connection's own loop: its read loop does
+            # not read on before this publish has fanned out
+            entry.counted = True
+            cl._staged += 1
+        self._stage.park(pk.topic_name, entry)
+        return True
+
+    def _complete_staged(self, entries, results, t_set_ns: int = 0) -> None:
+        """Complete one slice of a staged batch (``staging.Parked``): fan
+        out its publishes in submit order and do for each what follows a
+        fan-out: cluster forward, the stage clock, ``on_published``,
+        ``on_packet_processed``, the quota drain. Synchronous, on the loop
+        that parked the entries.
+
+        What cannot change inside the slice is read once, at its start:
+        the hooks' ``provides()`` verdicts, the lazy view class, the
+        predicate engine's state, and the slice's target clients, under
+        ONE acquisition of the ``clients`` lock (released before any
+        delivery, hook or socket write runs). A result that is an
+        exception (a host walk that failed) or a publish whose fan-out
+        raises is that publish's error: it reaches ``on_packet_processed``
+        as ``err``, is recorded against its connection (the read loop
+        raises the first one, ``clients.read``) and does not stop the
+        slice. ``t_set_ns``: the instant the results were in hand while a
+        profiler session keeps the batch (``DeviceProfiler.note_fanout``),
+        else 0."""
+        hooks = self.hooks
+        observed = hooks.provides(ON_PACKET_ENCODE, ON_PACKET_SENT)
+        on_published = (
+            hooks.on_published if hooks.provides(ON_PUBLISHED) else None
+        )
+        on_processed = (
+            hooks.on_packet_processed
+            if hooks.provides(ON_PACKET_PROCESSED)
+            else None
+        )
+        cluster = self._cluster
+        fan_out = self._fan_out
+        finish_clock = self._finish_publish_clock
+        prof = self.profiler if t_set_ns else None
+        # the slice's fan-out plans and, from them, its target clients
+        vcls = _view_class()
+        eng = self._predicates
+        lazy = vcls is not None and (eng is None or not eng.active)
+        ids: list = []
+        work = []
+        for entry, subs in zip(entries, results):
+            targets = None
+            if type(subs) is vcls:
+                if lazy and not subs.has_shared and not subs.has_inline:
+                    targets = subs.targets()
+                    ids += [cid for cid, _ in targets]
+                else:
+                    subs = subs.materialize()
+            if targets is None and not isinstance(subs, BaseException):
+                ids += subs.subscriptions
+                for group in subs.shared.values():
+                    ids += group  # every candidate, before selection
+            work.append((entry, subs, targets))
+        lookup = self.clients.present(ids).get
+        for entry, subs, targets in work:
+            cl, pk = entry.cl, entry.pk
+            err: Optional[BaseException] = None
+            try:
+                if targets is None and isinstance(subs, BaseException):
+                    raise subs
+                if prof is not None:
+                    t_run = time.perf_counter_ns()
+                fan_out(
+                    pk, subs, entry.feats, entry.rjob,
+                    lookup, targets, observed,
                 )
-                self._fan_out(pk, subscribers, feats, rjob)
+                if prof is not None:
+                    prof.note_fanout(t_set_ns, t_run, time.perf_counter_ns())
+                if cluster is not None:
+                    cluster.forward_packet(pk)
+                if entry.clock is not None:  # a sampled publish
+                    finish_clock(pk)
+                if on_published is not None:
+                    on_published(cl, pk)
+            except Exception as e:
+                err = e
+            if on_processed is not None:
+                try:
+                    on_processed(cl, pk, err)
+                except Exception as e:
+                    err = err or e
+            if err is None:
+                self._drain_quota_starved(cl)
             else:
-                fut = self._stage.submit(
-                    pk.topic_name, getattr(pk, "_tclock", None), feats, rjob
-                )
-                prof.note_ingest(time.perf_counter_ns() - t_in)
-                subscribers = await fut
-                t_run = time.perf_counter_ns()
-                self._fan_out(pk, subscribers, feats, rjob)
-                set_ns = getattr(fut, "set_ns", 0)  # 0: batch not kept
-                if set_ns:
-                    prof.note_fanout(set_ns, t_run, time.perf_counter_ns())
-            if self._cluster is not None:
-                self._cluster.forward_packet(pk)
-            self._finish_publish_clock(pk)
-        self.hooks.on_published(cl, pk)
+                self._staged_error(cl, err, entry.counted)
+            if entry.counted:
+                cl._staged -= 1
+                if not cl._staged:
+                    waiter = cl._staged_waiter
+                    if waiter is not None and not waiter.done():
+                        waiter.set_result(None)  # brokerlint: ok=R12 a counted entry was parked from cl.net.loop and completes on it: the read loop's own
+
+    def _staged_error(self, cl: Client, err: BaseException, counted: bool) -> None:
+        """One staged publish failed in its completion: what
+        ``receive_packet`` does for a packet that fails in the handler
+        (a Code goes through ``_packet_error``), and the connection's
+        first such error is left for its read loop to raise."""
+        if isinstance(err, Code):
+            self._packet_error(cl, err)
+        else:
+            self.log.warning(
+                "error completing staged publish: error=%r client=%s",
+                err, cl.id,
+            )
+        if counted and cl._staged_err is None:
+            cl._staged_err = err
 
     def _retained_quota_refused(self, cl: Client, pk: Packet) -> bool:
         """Tenant retained COUNT cap (ISSUE 16): True refuses the publish
@@ -3076,8 +3166,8 @@ class Server:
         The synchronous path always walks the host trie: its callers are
         the housekeeping flows ($SYS ticks, LWT, retained delivery, inline
         publishes), which must never pay a device round trip on the event
-        loop. Client PUBLISH traffic takes ``_staged_fan_out`` instead when
-        the device matcher is active (mqtt_tpu.staging)."""
+        loop. Client PUBLISH traffic is parked with the staging loop
+        instead when the device matcher is active (``_park_publish``)."""
         if pk.ignore:
             return
         self._stamp_publish_expiry(pk)
@@ -3397,9 +3487,20 @@ class Server:
         self._stamp_publish_expiry(pk)
         return pk
 
-    def _fan_out(self, pk: Packet, subscribers, feats=None, rjob=None) -> None:
+    def _fan_out(
+        self, pk: Packet, subscribers, feats=None, rjob=None,
+        lookup=None, targets=None, observed=None,
+    ) -> None:
         """Deliver one matched publish: shared-group selection, inline
         handlers, per-subscriber delivery (server.go:1000-1021).
+
+        A staged batch's completion (``_complete_staged``) hands in what
+        it read once for its slice: ``lookup``, client id -> connected
+        Client or None over at least this publish's targets (default:
+        ``self.clients.get``, one lock pair a call); ``targets``, the
+        lazy view's plan where it has already built it; ``observed``,
+        whether a hook provides ON_PACKET_ENCODE / ON_PACKET_SENT
+        (default: asked here).
 
         MQTT+ predicate filtering happens here — the one choke point
         every delivery path funnels through (staged fan-out, the host
@@ -3426,17 +3527,22 @@ class Server:
         here, counted, and the eager path serves bit-identically."""
         emissions = ()
         eng = self._predicates
-        targets = None  # the lazy (client_id, Subscription) plan
-        vcls = _view_class()
-        if vcls is not None and type(subscribers) is vcls:
-            if (
-                (eng is None or not eng.active)
-                and not subscribers.has_shared
-                and not subscribers.has_inline
-            ):
-                targets = subscribers.targets()
-            else:
-                subscribers = subscribers.materialize()
+        if lookup is None:
+            lookup = self.clients.get
+        if observed is None:
+            observed = self.hooks.provides(ON_PACKET_ENCODE, ON_PACKET_SENT)
+        # ``targets``: the lazy (client_id, Subscription) plan
+        if targets is None:
+            vcls = _view_class()
+            if vcls is not None and type(subscribers) is vcls:
+                if (
+                    (eng is None or not eng.active)
+                    and not subscribers.has_shared
+                    and not subscribers.has_inline
+                ):
+                    targets = subscribers.targets()
+                else:
+                    subscribers = subscribers.materialize()
         if targets is None:
             if eng is not None and eng.active:
                 subscribers, emissions = eng.apply(
@@ -3455,7 +3561,7 @@ class Server:
         # stays untouched — the caller still forwards it to the cluster
         dpk = pk
         enc_tenant = None
-        if pk.topic_name[:1] == NS_CHAR and self._tenancy is not None:
+        if self._tenancy is not None and pk.topic_name[:1] == NS_CHAR:
             dpk = pk.copy(False)
             dpk.topic_name = ns_local(pk.topic_name)
             tenant = self._tenancy.tenant_of_topic(pk.topic_name)
@@ -3472,7 +3578,8 @@ class Server:
 
         if enc_tenant is not None:
             self._fan_out_encrypted(
-                enc_tenant, pk, dpk, subscribers, rjob, targets
+                enc_tenant, pk, dpk, subscribers, rjob, targets,
+                lookup, observed,
             )
         else:
             items = (
@@ -3480,20 +3587,16 @@ class Server:
                 if targets is not None
                 else subscribers.subscriptions.items()
             )
-            if self._fanout_batch and not self.hooks.provides(
-                ON_PACKET_ENCODE, ON_PACKET_SENT
-            ):
+            if self._fanout_batch and not observed:
                 # encode-once variant-grouped delivery with the batched
                 # GIL-released flush (ISSUE 13 / ROADMAP item 3)
-                self._fan_out_batched(pk, dpk, items)
+                self._fan_out_batched(pk, dpk, items, lookup)
             else:
                 # legacy path (hooks that observe encodes/sends, or the
                 # batching knob off): QoS0 still shares frames through
                 # the per-publish cache; QoS>0 re-encodes per subscriber
                 fast = None
-                if dpk.fixed_header.qos == 0 and not self.hooks.provides(
-                    ON_PACKET_ENCODE, ON_PACKET_SENT
-                ):
+                if dpk.fixed_header.qos == 0 and not observed:
                     # $SYS housekeeping republishes every interval with no
                     # inbound publish behind it: keep it out of the encode/
                     # delivery amplification accounting (ROADMAP item 3's
@@ -3506,7 +3609,7 @@ class Server:
                     )
 
                 for id_, subs in items:
-                    cl = self.clients.get(id_)
+                    cl = lookup(id_)
                     if cl is not None:
                         try:
                             delivered = self._deliver_to_client(
@@ -3546,7 +3649,7 @@ class Server:
                         target,
                     )
 
-    def _fan_out_batched(self, pk: Packet, dpk: Packet, items) -> None:
+    def _fan_out_batched(self, pk: Packet, dpk: Packet, items, lookup) -> None:
         """Encode-once variant-grouped fan-out (ISSUE 13 / ROADMAP item
         3). Targets are grouped by (protocol version, effective QoS,
         retain) — the complete set of per-target wire differences once
@@ -3566,11 +3669,10 @@ class Server:
         amp_tele = None if sys_topic else tele
         caps = self.options.capabilities
         origin = dpk.origin
-        clients_get = self.clients.get
         groups: dict[tuple, list] = {}
         slow: list = []
         for cid, sub in items:
-            cl = clients_get(cid)
+            cl = lookup(cid)
             if cl is None or (sub.no_local and cid == origin):
                 continue  # [MQTT-3.8.3-3]
             props = cl.properties
@@ -3722,6 +3824,7 @@ class Server:
                 fd = -1
                 if (
                     cl.state.outbound_qty == 0
+                    and not cl._cork  # packets of its read in hand go first
                     and writer.get_extra_info("sslcontext") is None
                     and writer.transport.get_write_buffer_size() == 0
                 ):
@@ -3955,7 +4058,7 @@ class Server:
 
     def _fan_out_encrypted(
         self, tenant, pk: Packet, dpk: Packet, subscribers, rjob,
-        targets=None,
+        targets, lookup, observed,
     ) -> None:
         """The MQT-TZ re-encryption fan-out (mqtt_tpu.tenancy): decrypt
         the publish once with the publisher's key (the staged keystream
@@ -3989,20 +4092,20 @@ class Server:
             if targets is not None
             else list(subscribers.subscriptions.items())
         )
-        if self._fanout_batch and not self.hooks.provides(
-            ON_PACKET_ENCODE, ON_PACKET_SENT
-        ):
+        if self._fanout_batch and not observed:
             if self._fan_out_encrypted_batched(
-                tenant, dpk, plaintext, items
+                tenant, dpk, plaintext, items, lookup
             ):
                 return
-        key_targets = [(cid, self._key_idents(cid)) for cid, _sub in items]
+        key_targets = [
+            (cid, self._key_idents(cid, lookup(cid))) for cid, _sub in items
+        ]
         sealed = renc.seal_fanout(tenant, plaintext, key_targets)
         for id_, subs in items:
             data = sealed.get(id_)
             if data is None:
                 continue  # keyless subscriber: withheld, counted
-            cl = self.clients.get(id_)
+            cl = lookup(id_)
             if cl is None:
                 continue
             out = dpk.copy(False)
@@ -4024,7 +4127,7 @@ class Server:
                     tenant.bytes_out += len(data)
 
     def _fan_out_encrypted_batched(
-        self, tenant, dpk: Packet, plaintext: bytes, items: list
+        self, tenant, dpk: Packet, plaintext: bytes, items: list, lookup
     ) -> bool:
         """The re-encrypt fan-out's encode-once leg (ISSUE 13 satellite,
         PR 12 residual): ONE keystream dispatch for every keyed target,
@@ -4040,11 +4143,10 @@ class Server:
 
         renc = self._recrypt
         caps = self.options.capabilities
-        clients_get = self.clients.get
         origin = dpk.origin
         live: list = []  # (cid, cl, sub, eff, pv, retain, shareable)
         for cid, sub in items:
-            cl = clients_get(cid)
+            cl = lookup(cid)
             if cl is None or (sub.no_local and cid == origin):
                 continue
             props = cl.properties

@@ -6,10 +6,16 @@ The reference matches synchronously inside ``processPublish``
 when it is a device round trip. The stage turns the device matcher into a
 pipelined batch engine:
 
-- ``submit(topic)`` parks the publish on a future and returns immediately;
-  the caller (one asyncio task per client, mirroring the reference's
-  goroutine-per-connection) awaits it, so *that* client blocks while every
-  other client keeps being served.
+- ``park(topic, entry)`` parks the publish and returns immediately: no
+  task, future or coroutine a publish. The entry (:class:`Parked`) names
+  its completion, which the drain loop calls ONCE A BATCH with the
+  batch's entries and results, in submit order, a slice of
+  ``COMPLETION_SLICE`` publishes at a time (the server fans a slice out
+  under one read of the client registry). The publishing connection
+  waits once a socket read for its own publishes (clients.read), so
+  *that* client blocks while every other client keeps being served.
+  ``submit(topic)`` is the same path for callers that want a future:
+  an entry whose completion sets it.
 - A collector task gathers everything submitted within the accumulation
   window (or up to the batch cap) and issues ONE ``match_topics_async``
   dispatch. The issue leg (host tokenize + H2D + async device dispatch)
@@ -39,11 +45,11 @@ pipelined batch engine:
   test ever reads a compile as load. Without this a cold broker answered
   most of its first burst from the host trie (PR 21).
 - A drainer task resolves batches IN ORDER off the event loop (the D2H
-  sync blocks, so it runs in the executor) and completes the futures in
+  sync blocks, so it runs in the executor) and completes the entries in
   submission order — per-publish fan-out order is exactly submission
   order, as in the reference.
-- A matcher failure degrades, never drops: the affected futures fall back
-  to the bit-identical host trie walk.
+- A matcher failure degrades, never drops: the affected entries complete
+  with the bit-identical host trie walk, through the same completion.
 - Admission is BOUNDED (``max_pending``): under a publish storm the
   parked list never grows past its cap — overflow (and submissions whose
   projected pipeline wait already exceeds the deadline) resolves via the
@@ -65,6 +71,60 @@ from .tracing import BatchProfile, span
 from .utils.loopwitness import DEFAULT_LOOP_PLANE as _LOOP_PLANE
 
 _log = logging.getLogger("mqtt_tpu.staging")
+
+# publishes a completion call takes: the synchronous unit of a batch's
+# fan-out (about 12 ms of loop time). Between two slices of a batch the
+# drain loop yields to the event loop once, so a 2,048-topic batch holds
+# no timer or socket for longer than one slice.
+COMPLETION_SLICE = 256
+
+
+class Parked:
+    """One parked publish: what the stage ships with its batch
+    (``clock``, ``feats``, ``rjob``) and what its completion needs.
+
+    ``complete(entries, results, t_set_ns)`` is called with a slice of
+    one batch's entries that share this callable and their results
+    (``Subscribers``, or the exception a failed host walk raised), in
+    submit order, on ``loop`` (the loop that parked them, filled in by
+    ``park()`` when its caller has not; None: the stage's own). ``t_set_ns`` is the instant the batch's results were
+    in hand while a profiler session keeps the batch, else 0. The
+    server fills ``cl`` / ``pk`` / ``counted`` (server._park_publish);
+    ``MatchStage.submit`` fills ``fut``."""
+
+    __slots__ = (
+        "complete", "clock", "feats", "rjob", "submit_ns", "loop",
+        "cl", "pk", "counted", "fut",
+    )
+
+    def __init__(
+        self, complete, clock=None, feats=None, rjob=None, cl=None, pk=None
+    ) -> None:
+        self.complete = complete
+        self.clock = clock
+        self.feats = feats
+        self.rjob = rjob
+        # perf_counter_ns at park(), stamped only while a profiler
+        # session is live: the batch's mqtt/stage.wait (tracing)
+        self.submit_ns = 0
+        self.loop: Optional[asyncio.AbstractEventLoop] = None
+        self.cl = cl
+        self.pk = pk
+        self.counted = False
+        self.fut: Optional[asyncio.Future] = None
+
+
+def _set_futures(entries, results, t_set_ns: int = 0) -> None:
+    """``submit()``'s completion: each entry's result lands on its
+    future, on the loop that made it."""
+    for entry, value in zip(entries, results):
+        fut = entry.fut
+        if fut.done():
+            continue  # the caller cancelled it
+        if isinstance(value, BaseException):
+            fut.set_exception(value)  # brokerlint: ok=R12 _hand_over groups entries by parking loop and completes each group on it
+        else:
+            fut.set_result(value)  # brokerlint: ok=R12 as above: this runs on the loop that made the future
 
 
 class MatchStage:
@@ -135,14 +195,19 @@ class MatchStage:
         self.max_pending = max(1, max_pending)
         self.admission_fallbacks = 0
         self.peak_pending = 0
-        # parked publishes: (topic, future, stage clock or None).
-        # Guarded by _plock: under the event-loop shard fabric
-        # (mqtt_tpu.shards) submit() runs on every shard's loop while
-        # the collector drains on the stage's own loop — the park list
-        # is the one cross-thread hand-off point. Futures are created
-        # on the SUBMITTING loop and resolved back onto it
-        # (call_soon_threadsafe when it is not the stage loop), so each
-        # publisher awaits a loop-local future exactly as before.
+        # how publishes left the stage: through their batch's completion
+        # call (the served path), the calls that took (one a slice),
+        # and through submit()'s future (0 on the served path)
+        self.batch_completed = 0
+        self.batch_completions = 0
+        self.adapter_completed = 0
+        # parked publishes: (topic, Parked). Guarded by _plock: under
+        # the event-loop shard fabric (mqtt_tpu.shards) park() runs on
+        # every shard's loop while the collector drains on the stage's
+        # own loop — the park list is the one cross-thread hand-off
+        # point. An entry completes on the loop that parked it (one
+        # call_soon_threadsafe a batch and loop when that is not the
+        # stage's), so each shard's publisher fans out on its own loop.
         self._pending: list[tuple] = []
         self._plock = threading.Lock()
         # the loop the collector/drainer run on (start()'s loop)
@@ -258,8 +323,8 @@ class MatchStage:
         ]
 
     async def stop(self) -> None:
-        """Stop the pipeline; anything still parked resolves via the host
-        walk so no publish is ever lost."""
+        """Stop the pipeline; anything still parked completes via the
+        host walk so no publish is ever lost."""
         self._stopping = True
         if self._wake is not None:
             self._wake.set()
@@ -272,12 +337,12 @@ class MatchStage:
         queue = self._queue
         if queue is not None:
             while not queue.empty():
-                _resolver, futs, topics, *_rest = queue.get_nowait()
+                _resolver, entries, topics, *_rest = queue.get_nowait()
                 self.inflight_batches -= 1
-                self._fallback_all(list(zip(topics, futs)), klass="stop")
+                self._fallback_all(list(zip(topics, entries)), klass="stop")
         if self._executor is not None:
             # in-flight resolves may finish on their own time; queued
-            # ones are dead (their futures just resolved via fallback)
+            # ones are dead (their entries just completed via fallback)
             self._executor.shutdown(wait=False, cancel_futures=True)
             self._executor = None
         if self._h2d_executor is not None:
@@ -289,31 +354,49 @@ class MatchStage:
     def submit(
         self, topic: str, clock=None, feats=None, rjob=None
     ) -> "asyncio.Future[Subscribers]":
-        """Park one publish; the future resolves with its Subscribers.
-        ``clock`` is an optional sampled stage clock (mqtt_tpu.telemetry)
-        stamped at batch issue (staging_wait) and resolve (device_batch).
-        ``feats`` is the publish's optional payload-feature carrier
+        """Park one publish and return a future for its Subscribers: the
+        adapter over :meth:`park` for tests, embedders and anything else
+        that wants to await one publish (an entry whose completion sets
+        the future, on the loop that called). The served path parks its
+        own entries and makes no future (server._park_publish);
+        ``adapter_completed`` counts what came through here."""
+        entry = Parked(_set_futures, clock, feats, rjob)
+        entry.fut = asyncio.get_running_loop().create_future()
+        self.park(topic, entry)
+        return entry.fut
+
+    def park(self, topic: str, entry: Parked) -> None:
+        """Park one publish with the stage; ``entry.complete`` is called
+        with its result when its batch resolves (:class:`Parked`).
+        ``entry.clock`` is an optional sampled stage clock
+        (mqtt_tpu.telemetry) stamped at batch issue (staging_wait) and
+        resolve (device_batch). ``entry.feats`` is the publish's
+        optional payload-feature carrier
         (mqtt_tpu.predicates.PublishFeatures): the batch ships it to the
         device rule table and the resolved pass bits come back ON the
         carrier — host-fallback resolutions simply leave it unstamped
-        and the fan-out path's host interpreter decides. ``rjob`` is
-        the publish's optional decrypt carrier
+        and the fan-out path's host interpreter decides. ``entry.rjob``
+        is the publish's optional decrypt carrier
         (mqtt_tpu.tenancy.RecryptJob) for encrypted-namespace publishes:
         its keystream dispatch rides the same batch and the resolved
         rows come back on the carrier the same way.
 
         Admission is bounded: once ``max_pending`` publishes are parked,
         or the pipeline's projected wait already exceeds the deadline
-        (2x the latency budget), the publish resolves immediately via
-        the host walk — the degraded-but-bounded mode — instead of
-        growing the backlog."""
-        loop = asyncio.get_running_loop()
-        fut = loop.create_future()
+        (2x the latency budget), the publish completes at once via the
+        host walk, inside this call — the degraded-but-bounded mode —
+        instead of growing the backlog."""
+        loop = entry.loop
+        if loop is None:
+            try:
+                loop = entry.loop = asyncio.get_running_loop()
+            except RuntimeError:
+                pass  # no loop on this thread: completes on the stage's
         prof = self.profiler
         if prof is not None and prof.armed:
-            # a live profiler session: the submit instant rides on the
-            # future, for the batch's mqtt/stage.wait (tracing)
-            fut.submit_ns = time.perf_counter_ns()
+            # a live profiler session: the park instant rides on the
+            # entry, for the batch's mqtt/stage.wait (tracing)
+            entry.submit_ns = time.perf_counter_ns()
         if _LOOP_PLANE.active:
             w = _LOOP_PLANE.witness
             if w is not None:
@@ -322,22 +405,20 @@ class MatchStage:
                 )
         wake = self._wake
         if self._stopping or wake is None:
-            fut.set_result(self.host_fallback(topic))
-            return fut
+            self._fallback_all([(topic, entry)], klass=None)
+            return
         with self._plock:
             if len(self._pending) >= self.max_pending or self._past_deadline():
                 admitted = False
             else:
                 admitted = True
-                self._pending.append((topic, fut, clock, feats, rjob))
+                self._pending.append((topic, entry))
                 if len(self._pending) > self.peak_pending:
                     self.peak_pending = len(self._pending)
         if not admitted:
             self.admission_fallbacks += 1
-            if self.telemetry is not None:
-                self.telemetry.note_fallback("admission")
-            fut.set_result(self.host_fallback(topic))
-            return fut
+            self._fallback_all([(topic, entry)], klass="admission")
+            return
         # the wake Event is loop-affine: shard-loop submitters marshal
         # the set() onto the stage's loop (mqtt_tpu.shards). A never-
         # started stage (_loop None: unit harnesses that drive the
@@ -348,10 +429,14 @@ class MatchStage:
             try:
                 self._loop.call_soon_threadsafe(wake.set)
             except RuntimeError:
-                # stage loop gone mid-shutdown: serve the host walk now
-                if not fut.done():
-                    fut.set_result(self.host_fallback(topic))
-        return fut
+                # stage loop gone mid-shutdown: serve the host walk now,
+                # unless stop() has already taken the entry
+                with self._plock:
+                    try:
+                        self._pending.remove((topic, entry))
+                    except ValueError:
+                        return
+                self._fallback_all([(topic, entry)], klass=None)
 
     def _past_deadline(self) -> bool:
         """Deadline-aware admission: a new submission waits behind every
@@ -431,15 +516,16 @@ class MatchStage:
                 leftovers = bool(self._pending)
             if leftovers:
                 wake.set()  # leftovers start the next window now
-            # a caller future cancelled mid-window (client disconnected
-            # during accumulation) is dead weight: drop it here so the
-            # device never matches for it and no resolver path trips on
-            # an already-cancelled future
-            batch = [item for item in batch if not item[1].cancelled()]
+            # a submit() future cancelled mid-window is dead weight: drop
+            # it here so the device never matches for it
+            batch = [
+                item for item in batch
+                if item[1].fut is None or not item[1].fut.cancelled()
+            ]
             if not batch:
                 continue
             # the batch's own record (mqtt_tpu.tracing): every boundary
-            # from here to the last future set is stamped on it, once,
+            # from here to the batch's hand-over is stamped on it, once,
             # so concurrent or out-of-order resolution (the resilience
             # guard pool) can never cross-attribute them. The profiler
             # numbers it and, while a jax.profiler session is live,
@@ -452,28 +538,28 @@ class MatchStage:
             else:
                 rec = BatchProfile()
             rec.formed_ns = time.perf_counter_ns()
-            topics = [t for t, _, _, _, _ in batch]
-            futs = [f for _, f, _, _, _ in batch]
-            clocks = [c for _, _, c, _, _ in batch]
-            feats = [p for _, _, _, p, _ in batch]
-            rjobs = [r for _, _, _, _, r in batch]
+            topics = [t for t, _ in batch]
+            entries = [e for _, e in batch]
+            predicates = self.predicates
+            recrypt = self.recrypt
+            feats = (
+                [e.feats for e in entries] if predicates is not None else None
+            )
+            rjobs = [e.rjob for e in entries] if recrypt is not None else None
             rec.topics = len(topics)
             rec.depth = self.inflight_batches
             if rec.kept:
-                rec.stage_wait(
-                    [f.submit_ns for f in futs if hasattr(f, "submit_ns")]
-                )
-            for c in clocks:
-                if c is not None:  # end of the accumulation/park wait
-                    c.stamp("staging_wait")
-                    c.batch = rec.seq
+                rec.stage_wait([e.submit_ns for e in entries if e.submit_ns])
+            # the sampled stage clocks (1 publish in 64 carries one)
+            clocks = [e.clock for e in entries if e.clock is not None]
+            for c in clocks:  # end of the accumulation/park wait
+                c.stamp("staging_wait")
+                c.batch = rec.seq
             # the ISSUE leg runs on the dedicated h2d dispatch thread,
             # in batch order (single worker): host tokenize + H2D + the
             # async device dispatch leave the event loop free, and batch
             # N+2 tokenizes while N+1's kernel runs and N drains — the
             # 3-deep overlap the device profiler's duty cycle gates on
-            predicates = self.predicates
-            recrypt = self.recrypt
             matcher = self.matcher
             telemetry = self.telemetry
 
@@ -544,8 +630,8 @@ class MatchStage:
             try:
                 await queue.put(
                     (
-                        resolver, futs, topics, clocks, rec, pred_resolver,
-                        feats, rec_resolver,
+                        resolver, entries, topics, clocks, rec,
+                        pred_resolver, feats, rec_resolver,
                     )
                 )
             except asyncio.CancelledError:
@@ -560,7 +646,7 @@ class MatchStage:
         telemetry = self.telemetry
         while True:
             (
-                resolver, futs, topics, clocks, rec, pred_resolver, feats,
+                resolver, entries, topics, clocks, rec, pred_resolver, feats,
                 rec_resolver,
             ) = await queue.get()
             try:
@@ -608,12 +694,14 @@ class MatchStage:
                 # stop() cancelled us with this batch already popped: it is
                 # invisible to stop()'s queue drain, so resolve it here
                 self.inflight_batches -= 1
-                self._fallback_all(list(zip(topics, futs)), klass="stop")
+                self._fallback_all(list(zip(topics, entries)), klass="stop")
                 raise
             except Exception:
                 self.inflight_batches -= 1
                 _log.exception("stage resolve failed; host fallback for batch")
-                self._fallback_all(list(zip(topics, futs)), klass="resolve_error")
+                self._fallback_all(
+                    list(zip(topics, entries)), klass="resolve_error"
+                )
                 continue
             self.inflight_batches -= 1
             # this batch's own device-timing record: both windows are
@@ -622,24 +710,104 @@ class MatchStage:
             # None, and then the coarse device_batch stamp applies (no
             # phantom h2d for batches that never touched the device)
             dispatch, d2h = rec.dispatch, rec.d2h
+            for ck in clocks:
+                self._stamp_round_trip(ck, dispatch, d2h)
             with span(rec, "deliver"):
+                t_set = 0
                 if rec.kept:
-                    # a live profiler session: leave on each future the
-                    # instant it was set, for its fan-out's wait for the
-                    # loop (DeviceProfiler.note_fanout)
-                    set_sum = 0
-                    for fut, subs, ck in zip(futs, results, clocks):
-                        if ck is not None:
-                            self._stamp_round_trip(ck, dispatch, d2h)
-                        fut.set_ns = t_set = time.perf_counter_ns()
-                        set_sum += t_set
-                        self._resolve(fut, subs)
-                    rec.set_sum_ns = set_sum
-                else:
-                    for fut, subs, ck in zip(futs, results, clocks):
-                        if ck is not None:
-                            self._stamp_round_trip(ck, dispatch, d2h)
-                        self._resolve(fut, subs)
+                    # a live profiler session: the instant the batch's
+                    # results were in hand is where every member's wait
+                    # for the resolve ends and its wait for the loop
+                    # begins (DeviceProfiler.note_fanout)
+                    t_set = time.perf_counter_ns()
+                    rec.set_sum_ns = len(entries) * t_set
+                await self._complete(entries, results, t_set)
+
+    async def _complete(self, entries, results, t_set_ns: int) -> None:
+        """Hand one resolved batch to its completions, in submit order:
+        a slice at a time, with one yield to the event loop between
+        slices. stop() cancelling the drain loop at such a yield
+        completes the rest at once."""
+        todo = list(self._slices(self._hand_over(entries, results, t_set_ns)))
+        i = 0
+        try:
+            while i < len(todo):
+                if i:
+                    await asyncio.sleep(0)
+                i += 1
+                self._call(*todo[i - 1], t_set_ns)
+        except asyncio.CancelledError:
+            for item in todo[i:]:
+                self._call(*item, t_set_ns)
+            raise
+
+    def _hand_over(self, entries, results, t_set_ns: int) -> list:
+        """Group a batch's entries by owning loop and completion. The
+        groups of other loops (a shard's publishers, mqtt_tpu.shards)
+        are sent there, one ``call_soon_threadsafe`` a group: an entry's
+        completion must run on the loop that parked it. Returns the
+        groups to complete here, ``(complete, entries, results)``."""
+        try:
+            here: Optional[asyncio.AbstractEventLoop] = (
+                asyncio.get_running_loop()
+            )
+        except RuntimeError:
+            here = None
+        first = entries[0]
+        witness = _LOOP_PLANE.witness if _LOOP_PLANE.active else None
+        if len({(e.loop, e.complete) for e in entries}) == 1:
+            groups = {(first.loop, first.complete): (entries, results)}
+        else:
+            groups = {}
+            for e, r in zip(entries, results):
+                es, rs = groups.setdefault((e.loop, e.complete), ([], []))
+                es.append(e)
+                rs.append(r)
+        local = []
+        for (loop, complete), (es, rs) in groups.items():
+            if loop is None or loop is here:
+                if witness is not None:
+                    witness.note("match_stage", "resolve_local")
+                local.append((complete, es, rs))
+                continue
+            if witness is not None:
+                witness.note("match_stage", "resolve_marshal")
+            try:
+                loop.call_soon_threadsafe(
+                    self._run_slices, complete, es, rs, t_set_ns
+                )
+            except RuntimeError:
+                pass  # the parking loop closed: nobody is left to serve
+        return local
+
+    @staticmethod
+    def _slices(groups):
+        """``(complete, entries, results)`` groups cut into slices."""
+        for complete, es, rs in groups:
+            for lo in range(0, len(es), COMPLETION_SLICE):
+                hi = lo + COMPLETION_SLICE
+                yield complete, es[lo:hi], rs[lo:hi]
+
+    def _run_slices(self, complete, entries, results, t_set_ns: int) -> None:
+        """One group's completion, slice after slice without a yield: a
+        shard loop's share of a batch, and the fallbacks."""
+        for item in self._slices([(complete, entries, results)]):
+            self._call(*item, t_set_ns)
+
+    def _call(self, complete, entries, results, t_set_ns: int) -> None:
+        if complete is _set_futures:
+            self.adapter_completed += len(entries)
+        else:
+            self.batch_completed += len(entries)
+            self.batch_completions += 1
+        try:
+            complete(entries, results, t_set_ns)
+        except Exception:
+            # a completion accounts for its own publishes' errors; one
+            # that raises anyway must not take the drain loop with it
+            _log.exception(
+                "staged completion failed for %d publishes", len(entries)
+            )
 
     @staticmethod
     def _stamp_round_trip(ck, dispatch, d2h) -> None:
@@ -654,80 +822,25 @@ class MatchStage:
         else:
             ck.stamp("device_batch")
 
-    def _resolve(self, fut: "asyncio.Future", value) -> None:
-        """Complete one caller future ON ITS OWN LOOP: a future parked
-        by a shard-loop submitter (mqtt_tpu.shards) must not have
-        set_result called from the stage's loop — done-callbacks would
-        be scheduled cross-thread. Stage-loop futures resolve inline
-        (the single-loop path, unchanged)."""
-        loop = fut.get_loop()
-        local = self._loop is None or loop is self._loop
-        if _LOOP_PLANE.active:
-            w = _LOOP_PLANE.witness
-            if w is not None:
-                w.note(
-                    "match_stage",
-                    "resolve_local" if local else "resolve_marshal",
-                )
-        if local:
-            if not fut.done():
-                fut.set_result(value)
+    def _fallback_all(self, items, klass: Optional[str] = "stop") -> None:
+        """Complete parked ``(topic, entry)`` items via the host walk,
+        now, through the entries' own completions (each on the loop that
+        parked it). A host walk that raises hands its exception to the
+        completion as that publish's result. ``klass`` is the fallback
+        class counted (None: not a counted fallback)."""
+        if not items:
             return
-
-        def _set() -> None:
-            if not fut.done():
-                fut.set_result(value)
-
-        try:
-            loop.call_soon_threadsafe(_set)
-        except RuntimeError:
-            pass  # submitter's loop closed; nobody is awaiting
-
-    def _reject(self, fut: "asyncio.Future", exc: BaseException) -> None:
-        """The exception leg of :meth:`_resolve`: fail a caller future
-        ON ITS OWN LOOP. Found by brokerlint R12 — the old inline
-        ``fut.set_exception`` from ``_fallback_all`` ran the waiter's
-        done-callbacks on the stage's thread when the future was parked
-        by a shard-loop submitter."""
-        loop = fut.get_loop()
-        local = self._loop is None or loop is self._loop
-        if _LOOP_PLANE.active:
-            w = _LOOP_PLANE.witness
-            if w is not None:
-                w.note(
-                    "match_stage",
-                    "resolve_local" if local else "resolve_marshal",
-                )
-        if local:
-            if not fut.done():
-                fut.set_exception(exc)
-            return
-
-        def _set() -> None:
-            if not fut.done():
-                fut.set_exception(exc)
-
-        try:
-            loop.call_soon_threadsafe(_set)
-        except RuntimeError:
-            pass  # submitter's loop closed; nobody is awaiting
-
-    def _fallback_all(self, items, klass: str = "stop") -> None:
-        """Resolve parked items via the host walk. ``items`` yield
-        ``(topic, future, ...)`` — both the 3-tuple _pending form and the
-        2-tuple ``zip(topics, futs)`` form are accepted."""
-        n = 0
-        for item in items:
-            topic, fut = item[0], item[1]
-            if fut.done():
-                continue
-            n += 1
+        entries, results = [], []
+        for topic, entry in items:
+            entries.append(entry)
             try:
-                self._resolve(fut, self.host_fallback(topic))
+                results.append(self.host_fallback(topic))
             except Exception as e:  # pragma: no cover - host walk is total
-                self._reject(fut, e)
-        if n and self.telemetry is not None:
-            self.telemetry.note_fallback(klass, n)
+                results.append(e)
+        if klass is not None and self.telemetry is not None:
+            self.telemetry.note_fallback(klass, len(entries))
+        for complete, es, rs in self._hand_over(entries, results, 0):
+            self._run_slices(complete, es, rs, 0)
 
 
 # -- restart re-registration (the durable session plane's bulk path) ---------

@@ -22,8 +22,9 @@ comparison axis (PAPERS.md). This module adds:
   (Perfetto-loadable) at ``GET /traces`` and in trigger dumps.
 - ``BatchProfile`` / ``DeviceProfiler``: one record per device batch,
   carried with the batch, holding the batch's span tree on
-  ``perf_counter_ns`` (submit -> formed -> tokenize -> H2D + dispatch ->
-  D2H sync -> resolve -> futures set). The profiler folds the
+  ``perf_counter_ns`` (parked -> formed -> tokenize -> H2D + dispatch ->
+  D2H sync -> resolve -> handed over to its completion and fanned out).
+  The profiler folds the
   in-flight windows (dispatch returned -> sync done) into a **duty
   cycle** and an **overlap ratio** AS THE HOST SEES THEM — upper bounds
   on device busy time, not device busy time (a chip the device trace
@@ -300,6 +301,9 @@ BUSY_SPANS = {
     "h2d_dispatch": "mqtt/h2d_dispatch",
     "d2h_sync": "mqtt/d2h.sync",
     "resolve": "mqtt/resolve",
+    # the batch's hand-over: its results in hand -> its last publish
+    # fanned out (the name dates from one future a publish; readers
+    # know it by this name)
     "deliver": "mqtt/deliver.futures",
 }
 
@@ -361,8 +365,8 @@ class BatchProfile:
         self.topics = 0
         self.bucket = 0
         self.depth = 0
-        # mqtt/stage.wait (kept records only): submit() -> batch formed
-        # over the ``wait_n`` members whose submit() was stamped (those
+        # mqtt/stage.wait (kept records only): park() -> batch formed
+        # over the ``wait_n`` members whose park() was stamped (those
         # parked while the session was live), as the oldest and the sum
         self.submit_first_ns: Optional[int] = None
         self.wait_n = 0
@@ -380,13 +384,14 @@ class BatchProfile:
         self.d2h_sync: Optional[tuple[int, int]] = None
         self.resolve: Optional[tuple[int, int]] = None
         self.deliver: Optional[tuple[int, int]] = None
-        # sum over the members of the instant each future was set (kept
-        # records only): the mean is where a publish's wait for the
-        # resolve ends and its wait for the loop begins
+        # sum over the members of the instant their result was in hand
+        # (kept records only): ``topics`` x the first instant of the
+        # batch's hand-over to its completion, where a publish's wait
+        # for the resolve ends and its wait for the loop begins
         self.set_sum_ns = 0
 
     def stage_wait(self, submits_ns: list) -> None:
-        """Fold the stamped members' submit() instants into
+        """Fold the stamped members' park() instants into
         mqtt/stage.wait (``formed_ns`` is already set)."""
         if submits_ns:
             self.wait_n = n = len(submits_ns)
@@ -717,8 +722,9 @@ class DeviceProfiler:
         self.matcher_stats: Any = None
         self.loop: Any = None
         # per-publish loop counters, cumulative ns / counts, armed only:
-        # frame scanned -> submit() (ingest), future set -> fan-out
-        # starts (fanout_wait), fan-out start -> flush done (fanout_busy)
+        # a scan's frames in hand -> its publishes parked (ingest), the
+        # batch's results in hand -> this publish's fan-out starts
+        # (fanout_wait), fan-out start -> flush done (fanout_busy)
         self.ingest_busy_ns = 0
         self.ingest_n = 0
         self.fanout_wait_ns = 0
@@ -873,18 +879,21 @@ class DeviceProfiler:
         self._beats += 1
         self.loop.call_later(BEAT_NS / 1e9, self._beat)
 
-    def note_ingest(self, busy_ns: int, n: int = 0) -> None:
-        """Loop time between publish frames being scanned and their
-        ``stage.submit()`` (armed only): the read loop reports a scan's
-        frame loop with its ``n`` publishes, the fan-out coroutine its own
-        stretch up to ``submit()`` with ``n`` 0."""
+    def note_ingest(self, busy_ns: int, n: int) -> None:
+        """Loop time of one socket read's frame loop (armed only): from
+        the scan's frames in hand to their handlers returned, with its
+        ``n`` publishes decoded, admitted, acknowledged and parked with
+        the stage (``clients.read``, once a scan)."""
         self.ingest_busy_ns += busy_ns
         self.ingest_n += n
 
     def note_fanout(self, set_ns: int, start_ns: int, done_ns: int) -> None:
-        """One publish's fan-out (armed only): its future was set at
-        ``set_ns``, its coroutine ran again at ``start_ns`` (the wait
-        for the loop) and its flush was done at ``done_ns``."""
+        """One publish's fan-out in its batch's completion (a kept batch
+        only): the batch's results were in hand at ``set_ns``
+        (``BatchProfile.set_sum_ns``), this publish's ``_fan_out``
+        started at ``start_ns`` (the wait for the loop: the publishes
+        ahead of it in the batch, and the yields between slices) and its
+        flush was done at ``done_ns`` (``server._complete_staged``)."""
         self.fanout_wait_ns += start_ns - set_ns
         self.fanout_busy_ns += done_ns - start_ns
         self.fanout_n += 1

@@ -54,7 +54,7 @@ from __future__ import annotations
 import random
 import threading
 from time import perf_counter, sleep
-from typing import Any, Callable, Generic, Hashable, Optional, TypeVar
+from typing import Any, Callable, Generic, Hashable, Iterable, Optional, TypeVar
 
 from ..telemetry import Histogram
 
@@ -543,6 +543,15 @@ class LockedMap(Generic[K, V]):
     def get(self, key: K) -> Optional[V]:
         with self._lock:
             return self.internal.get(key)
+
+    def present(self, keys: Iterable[K]) -> dict[K, V]:
+        """The entries whose key is among ``keys``, read under ONE
+        acquisition with plain dict probes inside: a batch completion
+        resolves a whole slice's fan-out targets this way instead of
+        one ``get`` (an instrumented lock pair) a target."""
+        with self._lock:
+            internal = self.internal
+            return {k: internal[k] for k in internal.keys() & keys}
 
     def get_all(self) -> dict[K, V]:
         with self._lock:

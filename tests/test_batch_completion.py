@@ -1,0 +1,725 @@
+"""Batch-granular completion (mqtt_tpu.staging / server._complete_staged):
+a device batch completes as a batch. The stage parks entries, not
+futures; the drain loop calls the entries' completion once a slice with
+the batch's entries and results in submit order; the server fans a slice
+out under one read of the client registry; a connection waits once a
+socket read for its own publishes. CPU backend: order, counts and
+exactly-once, never a rate."""
+
+import asyncio
+import random
+import threading
+import time
+
+import pytest
+
+from mqtt_tpu import Options, staging
+from mqtt_tpu.hooks import ON_PACKET_PROCESSED, ON_PUBLISHED, Hook
+from mqtt_tpu.packets import (
+    ERR_UNSPECIFIED_ERROR,
+    PINGREQ,
+    PINGRESP,
+    PUBACK,
+    PUBLISH,
+    SUBACK,
+    FixedHeader,
+    Packet,
+    Subscription,
+    encode_packet,
+)
+from mqtt_tpu.staging import MatchStage, Parked
+from mqtt_tpu.topics import Subscribers
+
+from tests.test_server import (
+    TIMEOUT,
+    Harness,
+    connect_packet,
+    pub_packet,
+    read_wire_packet,
+    run,
+    sub_packet,
+)
+
+
+def staged_options(**kw):
+    return Options(
+        inline_client=True,
+        device_matcher=True,
+        matcher_stage_window_ms=kw.pop("window_ms", 2.0),
+        matcher_opts={"max_levels": 4, "background": False},
+        **kw,
+    )
+
+
+class Tagged(Subscribers):
+    """A result that names the topic it answers."""
+
+    __slots__ = ("topic", "via")
+
+    def __init__(self, topic, via):
+        super().__init__()
+        self.topic, self.via = topic, via
+
+
+class GateMatcher:
+    """Resolves each batch when ``release`` is set; ``fail`` raises in
+    the issue leg or in the resolver."""
+
+    def __init__(self, fail=None):
+        self.fail = fail
+        self.batches = []
+        self.release = threading.Event()
+        self.release.set()
+        self.issued = threading.Event()
+
+    def match_topics_async(self, topics, profile=None):
+        self.batches.append(list(topics))
+        self.issued.set()
+        if self.fail == "issue":
+            raise RuntimeError("no device")
+
+        def resolve():
+            assert self.release.wait(10)
+            if self.fail == "resolve":
+                raise RuntimeError("sync failed")
+            return [Tagged(t, "device") for t in topics]
+
+        return resolve
+
+
+class Recorder:
+    """A completion that writes down every call it gets."""
+
+    def __init__(self):
+        self.calls = []  # (thread id, loop, [topics], [results' via])
+        self.between = 0  # loop callbacks that ran between two calls
+
+    def __call__(self, entries, results, t_set_ns=0):
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            loop = None
+        self.calls.append(
+            (threading.get_ident(), loop, [e.pk for e in entries],
+             [(r.topic, r.via) for r in results])
+        )
+        if loop is not None:
+            loop.call_soon(self._tick)
+
+    def _tick(self):
+        self.between += 1
+
+    @property
+    def order(self):
+        return [t for _tid, _loop, topics, _r in self.calls for t in topics]
+
+
+def park(stage, rec, topic):
+    entry = Parked(rec)
+    entry.pk = topic  # the recorder reads the topic back off the entry
+    stage.park(topic, entry)
+    return entry
+
+
+def host(topic):
+    return Tagged(topic, "host")
+
+
+async def until(cond, what, timeout_s=TIMEOUT):
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, what
+        await asyncio.sleep(0.002)
+
+
+class TestStageCompletion:
+    def test_submit_order_across_batches_and_slices(self, monkeypatch):
+        """20 publishes, batches of 8, slices of 3: the completion sees
+        them in park order, never more than a slice a call, each call's
+        results its own entries', and the loop runs between two slices
+        of one batch."""
+        monkeypatch.setattr(staging, "COMPLETION_SLICE", 3)
+
+        async def scenario():
+            m = GateMatcher()
+            stage = MatchStage(
+                m, host, window_s=0.001, max_batch=8, latency_budget_s=None
+            )
+            stage.start()
+            rec = Recorder()
+            topics = [f"o/{i}" for i in range(20)]
+            for t in topics:
+                park(stage, rec, t)
+            await until(lambda: len(rec.order) == 20, "all completed")
+            assert rec.order == topics
+            assert [len(b) for b in m.batches] == [8, 8, 4]
+            assert [len(c[2]) for c in rec.calls] == [3, 3, 2, 3, 3, 2, 3, 1]
+            for _tid, _loop, got, results in rec.calls:
+                assert results == [(t, "device") for t in got]
+            assert stage.batch_completed == 20
+            assert stage.batch_completions == len(rec.calls) == 8
+            assert stage.adapter_completed == 0
+            # a yield to the loop between the slices of one batch: the
+            # recorder's call_soon ticks ran before the last call did
+            assert rec.between >= 5
+            await stage.stop()
+
+        run(scenario())
+
+    @pytest.mark.parametrize(
+        "klass", ["admission", "stop", "issue_error", "resolve_error"]
+    )
+    def test_fallback_reaches_the_completion_exactly_once(self, klass):
+        from mqtt_tpu.telemetry import Telemetry
+
+        async def scenario():
+            tel = Telemetry(sample=0)
+            fail = {"issue_error": "issue", "resolve_error": "resolve"}.get(klass)
+            m = GateMatcher(fail=fail)
+            stage = MatchStage(
+                m, host, window_s=0.001, max_pending=4, telemetry=tel,
+                latency_budget_s=None,
+            )
+            rec = Recorder()
+            if klass == "admission":
+                stage._wake = asyncio.Event()  # armed, never drained
+                topics = [f"a/{i}" for i in range(6)]
+                for t in topics:
+                    park(stage, rec, t)
+                # the two past max_pending completed inside park()
+                assert rec.order == topics[4:]
+                assert stage.admission_fallbacks == 2
+                await stage.stop()
+                expect_fallbacks = {"admission": 2, "stop": 4}
+                assert sorted(rec.order) == sorted(topics)
+            else:
+                stage.start()
+                if klass == "stop":
+                    m.release.clear()  # the first batch hangs in its sync
+                topics = [f"f/{i}" for i in range(4)]
+                for t in topics:
+                    park(stage, rec, t)
+                if klass == "stop":
+                    await until(m.issued.is_set, "batch issued")
+                    await asyncio.sleep(0.02)
+                    await stage.stop()
+                    m.release.set()
+                else:
+                    await until(lambda: len(rec.order) == 4, "fell back")
+                    await stage.stop()
+                expect_fallbacks = {klass: 4}
+                assert rec.order == topics
+            # exactly once, and by the host walk
+            assert len(rec.order) == len(set(rec.order))
+            for _tid, _loop, got, results in rec.calls:
+                assert results == [(t, "host") for t in got]
+            for k, c in tel.fallback.items():
+                assert int(c.value) == expect_fallbacks.get(k, 0), k
+            assert stage.batch_completed == len(rec.order)
+
+        run(scenario())
+
+    @pytest.mark.parametrize("where", ["pending", "queued", "between_slices"])
+    def test_stop_with_parked_entries_loses_no_publish(self, where, monkeypatch):
+        monkeypatch.setattr(staging, "COMPLETION_SLICE", 2)
+
+        async def scenario():
+            m = GateMatcher()
+            stage = MatchStage(
+                m, host, window_s=0.001, max_batch=4, latency_budget_s=None,
+                pipeline_depth=2,
+            )
+            rec = Recorder()
+            topics = [f"s/{i}" for i in range(12)]
+            if where == "pending":
+                stage._wake = asyncio.Event()
+                for t in topics:
+                    park(stage, rec, t)
+            else:
+                stage.start()
+                if where == "queued":
+                    m.release.clear()
+                for t in topics:
+                    park(stage, rec, t)
+                if where == "queued":
+                    # one batch hangs in its sync, more wait in the queue
+                    # and in _pending behind it
+                    await until(lambda: len(m.batches) >= 2, "queued")
+                else:
+                    # stop() lands at the yield after a batch's first slice
+                    await until(lambda: len(rec.order) >= 2, "first slice")
+            await stage.stop()
+            m.release.set()
+            assert sorted(rec.order) == sorted(topics)
+            assert len(rec.order) == 12  # none twice
+            if where == "between_slices":
+                assert rec.order[:4] == topics[:4]  # the cut batch, in order
+
+        run(scenario())
+
+    def test_entries_parked_from_a_second_loop_complete_there(self):
+        """One batch holds entries of two loops: each loop's share is
+        completed on it, in order, by one hand-over."""
+
+        async def scenario():
+            stage = MatchStage(
+                GateMatcher(), host, window_s=0.02, latency_budget_s=None
+            )
+            stage.start()
+            here = asyncio.get_running_loop()
+            loop2 = asyncio.new_event_loop()
+            t = threading.Thread(target=loop2.run_forever, daemon=True)
+            t.start()
+            rec = Recorder()
+            try:
+                async def park_there():
+                    for i in range(5):
+                        park(stage, rec, f"shard/{i}")
+
+                for i in range(3):
+                    park(stage, rec, f"main/{i}")
+                asyncio.run_coroutine_threadsafe(park_there(), loop2).result(5)
+                await until(lambda: len(rec.order) == 8, "both loops completed")
+                by_loop = {loop: topics for _tid, loop, topics, _r in rec.calls}
+                assert by_loop == {
+                    here: [f"main/{i}" for i in range(3)],
+                    loop2: [f"shard/{i}" for i in range(5)],
+                }
+                tids = {loop: tid for tid, loop, _t, _r in rec.calls}
+                assert tids[loop2] == t.ident != tids[here]
+                assert len(stage.matcher.batches) == 1  # one batch, two loops
+                await stage.stop()
+            finally:
+                loop2.call_soon_threadsafe(loop2.stop)
+                t.join(5)
+                loop2.close()
+
+        run(scenario())
+
+    def test_adapter_and_batch_path_resolve_alike(self):
+        """``submit()`` is the same pipeline: one batch holds both kinds,
+        each future gets what the batch completion gets."""
+
+        async def scenario():
+            stage = MatchStage(
+                GateMatcher(), host, window_s=0.005, latency_budget_s=None
+            )
+            stage.start()
+            rec = Recorder()
+            rng = random.Random(26)
+            topics = [f"d/{rng.randrange(50)}/{i}" for i in range(40)]
+            futs = []
+            for t in topics:
+                futs.append(stage.submit(t))
+                park(stage, rec, t)
+            got = await asyncio.gather(*futs)
+            await until(lambda: len(rec.order) == 40, "batch path done")
+            assert [(r.topic, r.via) for r in got] == [
+                pair for _tid, _loop, _t, results in rec.calls for pair in results
+            ]
+            assert rec.order == topics
+            assert stage.adapter_completed == stage.batch_completed == 40
+            await stage.stop()
+
+        run(scenario())
+
+
+class ProcessedHook(Hook):
+    """Records on_packet_processed; fails on_published for one payload."""
+
+    def __init__(self):
+        super().__init__()
+        self.processed = []
+
+    def id(self):
+        return "processed"
+
+    def provides(self, b):
+        return b in (ON_PUBLISHED, ON_PACKET_PROCESSED)
+
+    def on_published(self, cl, pk):
+        if bytes(pk.payload) == b"poison":
+            raise ERR_UNSPECIFIED_ERROR()
+
+    def on_packet_processed(self, cl, pk, err):
+        if pk.fixed_header.type == PUBLISH:
+            self.processed.append((cl.id, bytes(pk.payload), err))
+
+
+async def subscriber(h, client_id, flt, qos=0):
+    r, w, _ = await h.connect(client_id)
+    w.write(sub_packet(1, [Subscription(filter=flt, qos=qos)]))
+    await w.drain()
+    assert (await read_wire_packet(r)).fixed_header.type == SUBACK
+    return r, w
+
+
+class TestServedPath:
+    def test_order_across_batches_and_slices_and_the_counter(self, monkeypatch):
+        """Two publishers, 30 publishes each in one socket write, batches
+        of 8 and slices of 3: the subscriber sees each publisher's
+        messages in order; every publish left the stage through the batch
+        callback and none through a future, and no task was made."""
+        monkeypatch.setattr(staging, "COMPLETION_SLICE", 3)
+
+        async def scenario():
+            h = Harness(
+                staged_options(
+                    matcher_stage_max_batch=8, matcher_stage_latency_budget_ms=0
+                )
+            )
+            await h.server.serve()
+            sub_r, _sub_w = await subscriber(h, "sub", "t/#")
+            h.server.matcher.flush()
+            pubs = [await h.connect(f"pub{k}") for k in range(2)]
+            tasks_before = asyncio.all_tasks()
+            for k, (_r, w, _t) in enumerate(pubs):
+                w.write(
+                    b"".join(
+                        pub_packet(f"t/{k}/{i}", f"{k}:{i}".encode())
+                        for i in range(30)
+                    )
+                )
+            seen = {0: [], 1: []}
+            for _ in range(60):
+                pk = await read_wire_packet(sub_r)
+                k, i = bytes(pk.payload).decode().split(":")
+                seen[int(k)].append(int(i))
+            assert seen == {0: list(range(30)), 1: list(range(30))}
+            assert asyncio.all_tasks() == tasks_before  # no task a publish
+            stage = h.server._stage
+            assert stage.batch_completed == 60
+            assert stage.adapter_completed == 0
+            assert stage.batch_completions >= 60 // 3
+            assert h.server.matcher.stats.batches >= 60 // 8
+            text = h.server.telemetry.registry.exposition()
+            assert 'mqtt_tpu_stage_completed_total{path="batch"} 60' in text
+            assert 'mqtt_tpu_stage_completed_total{path="adapter"} 0' in text
+            assert "mqtt_tpu_stage_completion_calls_total" in text
+            await h.server.close()
+            await h.shutdown()
+
+        run(scenario())
+
+    def test_one_failing_publish_closes_only_its_connection(self):
+        """A publish whose completion raises: its connection is closed,
+        the error reaches on_packet_processed, and the other publishes of
+        the same slice are delivered."""
+
+        async def scenario():
+            h = Harness(staged_options(window_ms=50.0))
+            hook = ProcessedHook()
+            h.server.add_hook(hook)
+            await h.server.serve()
+            sub_r, _sub_w = await subscriber(h, "sub", "t/#")
+            h.server.matcher.flush()
+            _ar, aw, atask = await h.connect("bad", version=5)
+            _br, bw, btask = await h.connect("good")
+            # one 50 ms window: all three land in one batch, one slice
+            bw.write(pub_packet("t/g", b"g1"))
+            aw.write(pub_packet("t/a", b"poison", version=5))
+            bw.write(pub_packet("t/g", b"g2"))
+            await bw.drain()
+            await aw.drain()
+            got = [bytes((await read_wire_packet(sub_r)).payload) for _ in range(3)]
+            # the poisoned publish was fanned out too: on_published (which
+            # failed) runs after the fan-out
+            assert sorted(got) == [b"g1", b"g2", b"poison"]
+            await asyncio.wait_for(atask, TIMEOUT)  # its connection ended
+            assert not btask.done()
+            errs = {(cid, p): e for cid, p, e in hook.processed}
+            assert errs[("bad", b"poison")] == ERR_UNSPECIFIED_ERROR
+            assert errs[("good", b"g1")] is None and errs[("good", b"g2")] is None
+            # the survivor still publishes
+            bw.write(pub_packet("t/g", b"g3"))
+            await bw.drain()
+            assert bytes((await read_wire_packet(sub_r)).payload) == b"g3"
+            await h.server.close()
+            await h.shutdown()
+
+        run(scenario())
+
+    def test_next_scan_waits_for_the_last_staged_publish(self):
+        """A connection's next socket read is not taken before its staged
+        publishes have fanned out: a PINGREQ sent behind a publish is
+        answered only after the publish's batch resolved; another
+        connection is served meanwhile."""
+
+        async def scenario():
+            h = Harness(staged_options())
+            await h.server.serve()
+            sub_r, _sub_w = await subscriber(h, "sub", "t/#")
+            h.server.matcher.flush()
+            gate = threading.Event()
+            inner = h.server._stage.matcher
+
+            class Gated:
+                def match_topics_async(self, topics, profile=None):
+                    resolve = inner.match_topics_async(topics, profile=profile)
+
+                    def gated():
+                        assert gate.wait(10)
+                        return resolve()
+
+                    return gated
+
+            h.server._stage.matcher = Gated()
+            pub_r, pub_w, _ = await h.connect("pub")
+            other_r, other_w, _ = await h.connect("other")
+            ping = encode_packet(Packet(fixed_header=FixedHeader(type=PINGREQ)))
+            pub_w.write(pub_packet("t/1", b"one", qos=1, pid=7))
+            await pub_w.drain()
+            assert (await read_wire_packet(pub_r)).fixed_header.type == PUBACK
+            cl = h.server.clients.get("pub")
+            assert cl._staged == 1 and cl._staged_waiter is not None
+            pub_w.write(ping)
+            await pub_w.drain()
+            other_w.write(ping)
+            await other_w.drain()
+            assert (await read_wire_packet(other_r)).fixed_header.type == PINGRESP
+            with pytest.raises(asyncio.TimeoutError):
+                await asyncio.wait_for(pub_r.readexactly(1), 0.1)
+            gate.set()
+            assert bytes((await read_wire_packet(sub_r)).payload) == b"one"
+            assert (await read_wire_packet(pub_r)).fixed_header.type == PINGRESP
+            assert cl._staged == 0 and cl._staged_waiter is None
+            await h.server.close()
+            await h.shutdown()
+
+        run(scenario())
+
+    def test_close_with_parked_publishes_delivers_them(self):
+        """``Server.close()`` stops the stage with publishes parked in it:
+        each is completed by the host walk and delivered."""
+
+        async def scenario():
+            h = Harness(staged_options(window_ms=200.0))
+            await h.server.serve()
+            sub_r, _sub_w = await subscriber(h, "sub", "t/#")
+            h.server.matcher.flush()
+            _r, w, _ = await h.connect("pub")
+            w.write(b"".join(pub_packet(f"t/{i}", b"%d" % i) for i in range(5)))
+            await w.drain()
+            stage = h.server._stage
+            await until(lambda: stage.pending_depth == 5, "parked")
+            await stage.stop()
+            got = [bytes((await read_wire_packet(sub_r)).payload) for _ in range(5)]
+            assert got == [b"%d" % i for i in range(5)]
+            assert stage.batch_completed == 5
+            assert int(h.server.telemetry.fallback["stop"].value) == 5
+            await h.server.close()
+            await h.shutdown()
+
+        run(scenario())
+
+    def test_adapter_and_batch_path_deliver_alike(self):
+        """Differential, one seeded mix: broker A takes it over a socket
+        (parked entries, batch completion); broker B resolves each
+        publish through ``submit()``'s future and fans it out as the
+        synchronous callers do. Every subscriber receives the same
+        messages in the same order."""
+        rng = random.Random(2026)
+        filters = {
+            "w1": ("m/+/x", 0), "w2": ("m/#", 1), "e1": ("m/3/x", 1),
+            "e2": ("m/5/y", 0), "s1": ("$share/g/m/+/y", 0), "none": ("q/#", 0),
+        }
+        mix = [
+            (f"m/{rng.randrange(8)}/{rng.choice('xy')}", b"p%d" % i, rng.randrange(2))
+            for i in range(80)
+        ]
+
+        async def broker():
+            h = Harness(staged_options(matcher_stage_max_batch=16))
+            await h.server.serve()
+            readers = {}
+            for cid, (flt, qos) in filters.items():
+                readers[cid] = (await subscriber(h, cid, flt, qos))[0]
+            h.server.matcher.flush()
+            return h, readers
+
+        async def drain(readers):
+            out = {}
+            for cid, r in readers.items():
+                got = []
+                while True:
+                    try:
+                        pk = await asyncio.wait_for(read_wire_packet(r), 0.2)
+                    except asyncio.TimeoutError:
+                        break
+                    got.append((pk.topic_name, bytes(pk.payload), pk.fixed_header.qos))
+                out[cid] = got
+            return out
+
+        async def scenario():
+            ha, ra = await broker()
+            pub_r, pub_w, _ = await ha.connect("pub")
+            for n, (topic, payload, qos) in enumerate(mix):
+                pub_w.write(pub_packet(topic, payload, qos=qos, pid=n + 1))
+            await pub_w.drain()
+            stage = ha.server._stage
+            await until(lambda: stage.batch_completed == len(mix), "fanned out")
+            via_batch = await drain(ra)
+            assert stage.adapter_completed == 0
+            await ha.server.close()
+            await ha.shutdown()
+
+            hb, rb = await broker()
+            stage = hb.server._stage
+            for topic, payload, qos in mix:
+                pk = Packet(
+                    fixed_header=FixedHeader(type=PUBLISH, qos=qos),
+                    topic_name=topic, payload=payload, origin="pub",
+                )
+                hb.server._fan_out(pk, await stage.submit(topic))
+            via_adapter = await drain(rb)
+            assert stage.adapter_completed == len(mix)
+            assert stage.batch_completed == 0
+            await hb.server.close()
+            await hb.shutdown()
+            return via_batch, via_adapter
+
+        via_batch, via_adapter = run(scenario())
+        assert via_batch == via_adapter
+        assert sum(len(v) for v in via_batch.values()) > len(mix)
+        assert via_batch["none"] == []
+
+    def test_registry_is_read_once_a_slice(self):
+        """The slice's targets resolve under one acquisition of the
+        ``clients`` lock: its acquisition count moves by the completion
+        calls, not by the matched subscribers."""
+        from mqtt_tpu.utils.locked import DEFAULT_PLANE
+
+        async def scenario():
+            h = Harness(staged_options(window_ms=20.0))
+            await h.server.serve()
+            readers = [
+                (await subscriber(h, f"s{i}", "t/#"))[0] for i in range(6)
+            ]
+            h.server.matcher.flush()
+            _r, w, _ = await h.connect("pub")
+            DEFAULT_PLANE.arm()
+            try:
+                stats = DEFAULT_PLANE.stats("clients")
+                before = stats.acquisitions
+                w.write(b"".join(pub_packet(f"t/{i}", b"x") for i in range(10)))
+                await w.drain()
+                for r in readers:
+                    for _ in range(10):
+                        await read_wire_packet(r)
+                stage = h.server._stage
+                assert stage.batch_completed == 10
+                # 60 matched subscribers; one read a completion call (a
+                # housekeeping tick inside the window may add its own few)
+                taken = stats.acquisitions - before
+                assert stage.batch_completions <= taken < 30
+            finally:
+                DEFAULT_PLANE.disarm()
+            await h.server.close()
+            await h.shutdown()
+
+        run(scenario())
+
+
+def test_locked_map_present_reads_under_one_acquisition():
+    from mqtt_tpu.utils.locked import DEFAULT_PLANE, LockedMap
+
+    m = LockedMap(name="present_probe")
+    for i in range(5):
+        m.add(f"k{i}", i)
+    DEFAULT_PLANE.arm()
+    try:
+        stats = DEFAULT_PLANE.stats("present_probe")
+        before = stats.acquisitions
+        assert m.present(["k1", "zz", "k4", "k1"]) == {"k1": 1, "k4": 4}
+        assert m.present(iter(["zz"])) == {}
+        assert m.present({"k0": None}) == {"k0": 0}
+        assert stats.acquisitions - before == 3
+    finally:
+        DEFAULT_PLANE.disarm()
+
+
+class TestScanWrites:
+    """What a connection's handlers write during one socket read leaves
+    as one transport write (``Client._cork``), in order."""
+
+    def test_a_reads_acks_leave_as_one_write_in_order(self):
+        async def scenario():
+            h = Harness(staged_options())
+            await h.server.serve()
+            pub_r, pub_w, _ = await h.connect("pub")
+            cl = h.server.clients.get("pub")
+            writes = []
+            inner = cl.net.writer.write
+
+            def spy(data):
+                writes.append(bytes(data))
+                return inner(data)
+
+            cl.net.writer.write = spy
+            pub_w.write(
+                b"".join(
+                    pub_packet(f"t/{i}", b"x", qos=1, pid=10 + i) for i in range(6)
+                )
+            )
+            await pub_w.drain()
+            acks = [await read_wire_packet(pub_r) for _ in range(6)]
+            assert [a.fixed_header.type for a in acks] == [PUBACK] * 6
+            assert [a.packet_id for a in acks] == [10 + i for i in range(6)]
+            assert len(writes) == 1 and len(writes[0]) == 6 * 4
+            assert cl._cork is None  # between reads nothing is held back
+            await h.server.close()
+            await h.shutdown()
+
+        run(scenario())
+
+    def test_disconnect_written_in_a_read_goes_out_before_the_close(self):
+        """A v5 protocol error behind a QoS1 publish in one read: the
+        PUBACK and the DISCONNECT both reach the client, in that order,
+        before EOF."""
+        from mqtt_tpu.packets import DISCONNECT
+
+        async def scenario():
+            h = Harness()
+            r, w, task = await h.connect("v5", version=5)
+            # a second CONNECT: the handler raises a protocol error, which
+            # for a v5 client writes a DISCONNECT and stops the client
+            bad = connect_packet("v5", 5)
+            w.write(pub_packet("a/b", b"ok", qos=1, pid=3, version=5) + bad)
+            await w.drain()
+            ack = await read_wire_packet(r, 5)
+            assert ack.fixed_header.type == PUBACK and ack.packet_id == 3
+            bye = await read_wire_packet(r, 5)
+            assert bye.fixed_header.type == DISCONNECT
+            assert await asyncio.wait_for(r.read(16), TIMEOUT) == b""
+            await asyncio.wait_for(task, TIMEOUT)
+            await h.shutdown()
+
+        run(scenario())
+
+    def test_own_delivery_stays_behind_the_ack(self):
+        """No stage: the fan-out runs inside the read. A publisher
+        subscribed to its own topic gets the PUBACK of a publish before
+        the publish itself: a direct socket write may not overtake the
+        packets its read has corked."""
+
+        async def scenario():
+            h = Harness()
+            r, w = await subscriber(h, "self", "own/#", qos=0)
+            w.write(
+                pub_packet("own/1", b"a", qos=1, pid=1)
+                + pub_packet("own/2", b"b", qos=1, pid=2)
+            )
+            await w.drain()
+            got = [await read_wire_packet(r) for _ in range(4)]
+            kinds = [
+                (p.fixed_header.type, p.packet_id or bytes(p.payload)) for p in got
+            ]
+            assert sorted(kinds, key=str) == sorted(
+                [(PUBACK, 1), (PUBACK, 2), (PUBLISH, b"a"), (PUBLISH, b"b")], key=str
+            )
+            assert kinds.index((PUBACK, 1)) < kinds.index((PUBLISH, b"a"))
+            assert kinds.index((PUBACK, 2)) < kinds.index((PUBLISH, b"b"))
+            assert kinds.index((PUBLISH, b"a")) < kinds.index((PUBLISH, b"b"))
+            await h.shutdown()
+
+        run(scenario())

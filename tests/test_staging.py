@@ -256,14 +256,16 @@ class TestCancelledCallerFutures:
 
 class TestCrossLoopResolution:
     def test_fallback_rejection_marshals_to_submitter_loop(self):
-        """Regression for the brokerlint R12 finding fixed in PR 19
-        (staging._reject): ``_fallback_all`` used to call
-        ``fut.set_exception`` INLINE on whatever thread ran the
-        fallback, scheduling the waiter's done-callbacks cross-thread.
-        The submitter loop runs in DEBUG mode here, so the old inline
-        shape trips asyncio's non-thread-safe-operation check and the
-        test fails loudly if the marshal seam regresses."""
+        """Regression for the brokerlint R12 finding fixed in PR 19: a
+        fallback used to fail the waiter's future INLINE on whatever
+        thread ran it, scheduling its done-callbacks cross-thread. An
+        entry completes on the loop that parked it (``_hand_over``'s
+        marshal). The submitter loop runs in DEBUG mode here, so the old
+        inline shape trips asyncio's non-thread-safe-operation check and
+        the test fails loudly if the marshal seam regresses."""
         import threading
+
+        from mqtt_tpu.staging import Parked, _set_futures
 
         class Boom(Exception):
             pass
@@ -277,21 +279,22 @@ class TestCrossLoopResolution:
             target=loop_b.run_forever, name="submitter-loop", daemon=True
         )
         t.start()
-        stage_loop = asyncio.new_event_loop()  # never running: just != loop_b
         try:
 
             async def park():
-                return asyncio.get_running_loop().create_future()
+                entry = Parked(_set_futures)
+                entry.loop = asyncio.get_running_loop()
+                entry.fut = entry.loop.create_future()
+                return entry
 
-            fut = asyncio.run_coroutine_threadsafe(park(), loop_b).result(5)
+            entry = asyncio.run_coroutine_threadsafe(park(), loop_b).result(5)
             rej = MatchStage(None, exploding_host)
-            rej._loop = stage_loop
             # the old code raises RuntimeError (non-thread-safe op) here
-            rej._fallback_all([("x/y", fut)])
+            rej._fallback_all([("x/y", entry)])
 
             async def reap():
                 try:
-                    await fut
+                    await entry.fut
                 except Boom:
                     return threading.get_ident()
                 raise AssertionError("future resolved without the host error")
@@ -302,31 +305,30 @@ class TestCrossLoopResolution:
                 == t.ident
             )
 
-            # the success leg rides the same seam (_resolve's marshal)
-            fut2 = asyncio.run_coroutine_threadsafe(park(), loop_b).result(5)
+            # the success leg rides the same seam
+            entry2 = asyncio.run_coroutine_threadsafe(park(), loop_b).result(5)
             ok = MatchStage(None, lambda t: Subscribers())
-            ok._loop = stage_loop
-            ok._fallback_all([("x/z", fut2)])
+            ok._fallback_all([("x/z", entry2)])
 
             async def reap_ok():
-                return await fut2
+                return await entry2.fut
 
             assert isinstance(
                 asyncio.run_coroutine_threadsafe(reap_ok(), loop_b).result(5),
                 Subscribers,
             )
+            assert rej.adapter_completed == ok.adapter_completed == 1
         finally:
             loop_b.call_soon_threadsafe(loop_b.stop)
             t.join(5)
             loop_b.close()
-            stage_loop.close()
 
-    def test_inject_packet_tracks_fan_out_task(self):
-        """Regression for the brokerlint R13 finding fixed in PR 19
-        (server.inject_packet): the staged fan-out task was
-        fire-and-forget — asyncio's weak reference was the only thing
-        keeping it alive mid-flight. It must register in the tracked
-        listener task set and discard itself on completion."""
+    def test_inject_packet_parks_without_a_task(self):
+        """``server.inject_packet`` of a staged PUBLISH used to spawn a
+        fan-out task (brokerlint R13 wanted it tracked, PR 19). It now
+        parks the publish like one read from a socket: no task is made,
+        the publish fans out with its batch, and the stage counts it as
+        completed by the batch callback, not through a future."""
 
         async def scenario():
             h = Harness(staged_options())
@@ -337,23 +339,26 @@ class TestCrossLoopResolution:
             await read_wire_packet(sub_r)
             h.server.matcher.flush()
             cl = h.server.clients.get("inj-sub")
-            before = set(h.server.listeners.client_tasks)
-            h.server.inject_packet(
+            stage = h.server._stage
+            tasks_before = set(h.server.listeners.client_tasks)
+            all_before = asyncio.all_tasks()
+            assert h.server.inject_packet(
                 cl,
                 Packet(
                     fixed_header=FixedHeader(type=PUBLISH),
                     topic_name="in/t",
                     payload=b"injected",
                 ),
-            )
-            spawned = set(h.server.listeners.client_tasks) - before
-            assert len(spawned) == 1, "staged fan-out task must be tracked"
+            ) is None
+            assert set(h.server.listeners.client_tasks) == tasks_before
+            assert asyncio.all_tasks() == all_before, "no task a publish"
+            assert stage.pending_depth == 1 and cl._staged == 1
             pk = await read_wire_packet(sub_r)
             assert bytes(pk.payload) == b"injected"
-            task = spawned.pop()
-            await task
-            await asyncio.sleep(0)  # let the done-callback run
-            assert task not in h.server.listeners.client_tasks
+            assert cl._staged == 0
+            assert stage.batch_completed == 1
+            assert stage.batch_completions == 1
+            assert stage.adapter_completed == 0
             await h.server.close()
             await h.shutdown()
 
