@@ -448,6 +448,7 @@ class Client:
             cork.append(data)
         else:
             self.net.writer.write(data)
+            self.ops.socket_sends += 1
 
     def _uncork(self) -> None:
         """Write what the socket read in hand has corked, as one
@@ -455,6 +456,7 @@ class Client:
         cork, self._cork = self._cork, None
         if cork and self.net.writer is not None:
             self.net.writer.write(cork[0] if len(cork) == 1 else b"".join(cork))
+            self.ops.socket_sends += 1
 
     def parse_connect(self, lid: str, pk: Packet) -> None:
         """Absorb CONNECT parameters into client state (clients.go:208-257)."""

@@ -851,6 +851,10 @@ class _Ops:
         # None = no device matcher or tracing off. Read loops feed it
         # their per-publish ingest time while a profiler session is live.
         self.profiler: Optional[Any] = None
+        # calls that reached a client's socket: a transport write (one a
+        # packet, or one a socket read's corked packets) and each send
+        # of the native fan-out flush. A plain add on the writing loop.
+        self.socket_sends = 0
 
 
 class Server:
@@ -1184,6 +1188,7 @@ class Server:
                     # profiler's thread, so a session that ends after
                     # the traffic stopped still closes the slice
                     self.profiler.matcher_stats = stats
+                    self.profiler.counters = self._slice_counters
                     self._ops.profiler = self.profiler
                     if self.host_profiler is not None:
                         self.host_profiler.on_sweep = self.profiler.poll
@@ -1508,6 +1513,18 @@ class Server:
             return 0
         return acc.view_stats()["materializations"]
 
+    def _slice_counters(self) -> dict:
+        """The broker's running counts a profiler slice's snapshots take
+        (``tracing.DeviceProfiler.counters``): fallbacks the stage held
+        in their publisher's order, frames handed to subscribers'
+        sockets, and calls that reached a socket."""
+        stage = self._stage
+        return {
+            "order_held": 0 if stage is None else stage.order_held,
+            "deliveries": self.telemetry.fanout_deliveries.value,
+            "socket_sends": self._ops.socket_sends,
+        }
+
     def _register_core_gauges(self) -> None:
         """Scrape-time gauges over state other layers already maintain:
         the $SYS Info counters, matcher stats, and governor posture all
@@ -1580,6 +1597,12 @@ class Server:
             fn=lambda: (
                 0 if self._stage is None else self._stage.batch_completions
             ),
+        )
+        r.counter(
+            "mqtt_tpu_stage_order_held_total",
+            "Staging fallbacks (admission, issue_error) that joined their "
+            "publisher's order as held members instead of completing at once",
+            fn=lambda: 0 if self._stage is None else self._stage.order_held,
         )
         r.gauge(
             "mqtt_tpu_staging_pipeline_depth",
@@ -2962,8 +2985,10 @@ class Server:
         resolves off the event loop and the publish fans out in its
         batch's completion, ``_complete_staged`` (SURVEY.md §7 stage 4;
         seam: server.go:984-1021). No task, future or coroutine is made
-        for it. True: parked (an admission fallback has already
-        completed it, inside the call)."""
+        for it. True: parked. A publish the stage does not admit is
+        walked on the host inside the call and still completes in its
+        place behind this connection's earlier publishes; only when the
+        connection has none in the stage has it completed already."""
         if pk.ignore:
             self.hooks.on_published(cl, pk)
             return False
@@ -2992,8 +3017,11 @@ class Server:
             here = None  # no loop on this thread: the stage's completes it
         if here is not None and here is cl.net.loop:
             # parked from the connection's own loop: its read loop does
-            # not read on before this publish has fanned out
+            # not read on before this publish has fanned out. With
+            # nothing of this connection in the stage a fallback can
+            # overtake nothing ([MQTT-4.6.0-5]) and may complete at once.
             entry.counted = True
+            entry.alone = not cl._staged
             cl._staged += 1
         self._stage.park(pk.topic_name, entry)
         return True
@@ -3888,6 +3916,7 @@ class Server:
                 )
                 try:
                     cl.net.writer.write(frame[wrote:])
+                    self._ops.socket_sends += 1
                 except Exception as e:
                     self.log.debug(
                         "fan-out flush tail failed: error=%s client=%s",
@@ -3978,6 +4007,7 @@ class Server:
         self.info.bytes_sent += nbytes
         self.info.packets_sent += 1
         self.info.messages_sent += 1
+        self._ops.socket_sends += 1  # the native flush's send to this socket
         st = cl.state
         st.out_bytes += nbytes
         st.out_writes += 1
@@ -5124,6 +5154,9 @@ class Server:
                 topics[
                     SYS_PREFIX + "/broker/overload/stage_admission_fallbacks"
                 ] = str(st.admission_fallbacks)
+                topics[
+                    SYS_PREFIX + "/broker/overload/stage_order_held"
+                ] = str(st.order_held)
         if self.telemetry is not None:
             # telemetry-plane observability (mqtt_tpu.telemetry): stage
             # histogram percentiles, batch occupancy, fallback classes,

@@ -51,10 +51,19 @@ pipelined batch engine:
 - A matcher failure degrades, never drops: the affected entries complete
   with the bit-identical host trie walk, through the same completion.
 - Admission is BOUNDED (``max_pending``): under a publish storm the
-  parked list never grows past its cap — overflow (and submissions whose
-  projected pipeline wait already exceeds the deadline) resolves via the
-  host walk immediately, and the overload governor (mqtt_tpu.overload)
+  device's backlog never grows past its cap — overflow (and submissions
+  whose projected pipeline wait already exceeds the deadline) is walked
+  on the host at once, and the overload governor (mqtt_tpu.overload)
   watches the same depth as its staging pressure signal.
+- A fallback JOINS the order, it does not jump it ([MQTT-4.6.0-5]): a
+  publish whose result came from the host walk while earlier publishes
+  may still be in the stage rides on as a *held* member — through
+  ``_pending``, its batch and the drain queue, with its result attached
+  (``Parked.held``) — and completes in its place in submit order. It is
+  left out of tokenize, H2D and the device program and out of every
+  depth the admission test and the cap controller reckon with. Only a
+  publish that can overtake nothing completes inside ``park()``
+  (``Parked.alone``; a stage that is stopping or was never started).
 """
 
 from __future__ import annotations
@@ -89,12 +98,12 @@ class Parked:
     submit order, on ``loop`` (the loop that parked them, filled in by
     ``park()`` when its caller has not; None: the stage's own). ``t_set_ns`` is the instant the batch's results were
     in hand while a profiler session keeps the batch, else 0. The
-    server fills ``cl`` / ``pk`` / ``counted`` (server._park_publish);
-    ``MatchStage.submit`` fills ``fut``."""
+    server fills ``cl`` / ``pk`` / ``counted`` / ``alone``
+    (server._park_publish); ``MatchStage.submit`` fills ``fut``."""
 
     __slots__ = (
         "complete", "clock", "feats", "rjob", "submit_ns", "loop",
-        "cl", "pk", "counted", "fut",
+        "cl", "pk", "counted", "alone", "fut", "held", "held_ns",
     )
 
     def __init__(
@@ -111,7 +120,15 @@ class Parked:
         self.cl = cl
         self.pk = pk
         self.counted = False
+        # the parker's word that nothing it parked earlier is still in
+        # the stage: a fallback of this publish can overtake nothing and
+        # may complete inside park(). False: it joins the order.
+        self.alone = False
         self.fut: Optional[asyncio.Future] = None
+        # a held member (module docstring): the host walk's result (or
+        # the exception it raised) and the instant it was in hand
+        self.held = None
+        self.held_ns = 0
 
 
 def _set_futures(entries, results, t_set_ns: int = 0) -> None:
@@ -188,13 +205,21 @@ class MatchStage:
         # throughput-optimal point needs this)
         self.latency_budget_s = latency_budget_s
         self.min_batch = max(1, min_batch)
-        # bounded admission: _pending may never grow past this; overflow
-        # (and submissions whose projected pipeline wait already blows
-        # the deadline) resolves via the host walk instead of queueing —
-        # a publish storm costs bounded memory, not an OOM
+        # bounded admission: the publishes parked for the device may
+        # never grow past this; overflow (and submissions whose projected
+        # pipeline wait already blows the deadline) resolves via the host
+        # walk instead of queueing for the device — a publish storm
+        # costs bounded memory, not an OOM
         self.max_pending = max(1, max_pending)
         self.admission_fallbacks = 0
         self.peak_pending = 0
+        # fallbacks that joined the order as held members instead of
+        # completing at once (admission and issue_error), and how many of
+        # them sit in _pending (under _plock) and how many batches made
+        # of held members alone sit in the queue: neither is device work
+        self.order_held = 0
+        self._held_pending = 0
+        self._held_batches = 0
         # how publishes left the stage: through their batch's completion
         # call (the served path), the calls that took (one a slice),
         # and through submit()'s future (0 on the served path)
@@ -263,7 +288,7 @@ class MatchStage:
         budget = self.latency_budget_s
         if budget is None or self._ewma_s <= 0.0:
             return self.window_s
-        depth = 1 if self._queue is None else self._queue.qsize() + 1
+        depth = self._queue_depth() + 1
         headroom = budget - depth * self._ewma_s
         if headroom <= 0.0:
             return 0.0  # over budget already: dispatch immediately
@@ -331,15 +356,20 @@ class MatchStage:
         for t in self._tasks:
             t.cancel()
         await asyncio.gather(*self._tasks, return_exceptions=True)
-        with self._plock:
-            parked, self._pending = self._pending, []
-        self._fallback_all(parked, klass="stop")
+        # oldest first: the drain loop completed the batch it held when
+        # it was cancelled; then the queued batches, then _pending (at
+        # its head the batch the collector held)
         queue = self._queue
         if queue is not None:
             while not queue.empty():
-                _resolver, entries, topics, *_rest = queue.get_nowait()
+                _resolver, _entries, batch, *_rest = queue.get_nowait()
                 self.inflight_batches -= 1
-                self._fallback_all(list(zip(topics, entries)), klass="stop")
+                self._fallback_all(batch, klass="stop")
+        self._held_batches = 0
+        with self._plock:
+            parked, self._pending = self._pending, []
+            self._held_pending = 0
+        self._fallback_all(parked, klass="stop")
         if self._executor is not None:
             # in-flight resolves may finish on their own time; queued
             # ones are dead (their entries just completed via fallback)
@@ -381,11 +411,19 @@ class MatchStage:
         its keystream dispatch rides the same batch and the resolved
         rows come back on the carrier the same way.
 
-        Admission is bounded: once ``max_pending`` publishes are parked,
-        or the pipeline's projected wait already exceeds the deadline
-        (2x the latency budget), the publish completes at once via the
-        host walk, inside this call — the degraded-but-bounded mode —
-        instead of growing the backlog."""
+        Admission is bounded: once ``max_pending`` publishes are parked
+        for the device, or the pipeline's projected wait already exceeds
+        the deadline (2x the latency budget), the publish is walked on
+        the host at once, inside this call — the degraded-but-bounded
+        mode — instead of growing the device's backlog. Its result then
+        JOINS the order as a held member (module docstring) and its
+        completion runs in its place in submit order; only an entry that
+        can overtake nothing (``entry.alone``) completes inside this
+        call. Held members cost no device work and are bounded by their
+        parkers: a connection's read loop does not read on while a
+        publish of its last socket read is in the stage (clients.read),
+        so it holds at most one read's frames; a ``submit()`` caller
+        holds what it has futures for."""
         loop = entry.loop
         if loop is None:
             try:
@@ -408,17 +446,30 @@ class MatchStage:
             self._fallback_all([(topic, entry)], klass=None)
             return
         with self._plock:
-            if len(self._pending) >= self.max_pending or self._past_deadline():
+            parked = len(self._pending) - self._held_pending
+            if parked >= self.max_pending or self._past_deadline():
                 admitted = False
             else:
                 admitted = True
                 self._pending.append((topic, entry))
-                if len(self._pending) > self.peak_pending:
-                    self.peak_pending = len(self._pending)
+                if parked >= self.peak_pending:
+                    self.peak_pending = parked + 1
         if not admitted:
             self.admission_fallbacks += 1
-            self._fallback_all([(topic, entry)], klass="admission")
-            return
+            if entry.alone:
+                self._fallback_all([(topic, entry)], klass="admission")
+                return
+            self._hold([(topic, entry)], klass="admission")
+            with self._plock:
+                # stop() takes _pending under this lock, after it set
+                # _stopping: a held member is never left behind it
+                stopping = self._stopping
+                if not stopping:
+                    self._pending.append((topic, entry))
+                    self._held_pending += 1
+            if stopping:
+                self._fallback_all([(topic, entry)], klass=None)
+                return
         # the wake Event is loop-affine: shard-loop submitters marshal
         # the set() onto the stage's loop (mqtt_tpu.shards). A never-
         # started stage (_loop None: unit harnesses that drive the
@@ -436,11 +487,34 @@ class MatchStage:
                         self._pending.remove((topic, entry))
                     except ValueError:
                         return
+                    if entry.held is not None:
+                        self._held_pending -= 1
                 self._fallback_all([(topic, entry)], klass=None)
+
+    def _hold(self, items, klass: str) -> None:
+        """Walk ``(topic, entry)`` items on the host now and leave each
+        result on its entry (``held``): the fallback of class ``klass``
+        that joins the order instead of completing at once."""
+        for topic, entry in items:
+            try:
+                entry.held = self.host_fallback(topic)
+            except Exception as e:  # pragma: no cover - host walk is total
+                entry.held = e
+            entry.held_ns = time.perf_counter_ns()
+        self.order_held += len(items)
+        if self.telemetry is not None:
+            self.telemetry.note_fallback(klass, len(items))
+
+    def _queue_depth(self) -> int:
+        """Device batches waiting in the drain queue."""
+        if self._queue is None:
+            return 0
+        return self._queue.qsize() - self._held_batches
 
     def _past_deadline(self) -> bool:
         """Deadline-aware admission: a new submission waits behind every
-        queued batch plus every parked batch-worth of _pending; when that
+        queued batch plus every parked batch-worth of _pending (held
+        members, which the device never sees, left out); when that
         projected wait exceeds twice the latency budget, queueing only
         deepens an already-lost backlog — the host walk serves it now.
 
@@ -452,10 +526,11 @@ class MatchStage:
         budget = self.latency_budget_s
         if budget is None or self._ewma_s <= 0.0:
             return False
-        qdepth = self._queue.qsize() if self._queue is not None else 0
-        if qdepth == 0 and not self._pending:
+        qdepth = self._queue_depth()
+        parked = len(self._pending) - self._held_pending
+        if qdepth == 0 and not parked:
             return False  # idle: admit, and let the EWMA re-learn
-        depth = 1 + qdepth + len(self._pending) // max(1, self._batch_cap)
+        depth = 1 + qdepth + parked // max(1, self._batch_cap)
         return depth * self._ewma_s > 2.0 * budget
 
     @property
@@ -477,10 +552,10 @@ class MatchStage:
         admission depth against its cap, plus the batch queue's fill at
         half weight (a full queue is normal pipelining; sustained
         _pending growth is the real overload signal)."""
-        p = len(self._pending) / self.max_pending
+        p = (len(self._pending) - self._held_pending) / self.max_pending
         q = 0.0
-        if self._queue is not None and self.pipeline_depth > 0:
-            q = self._queue.qsize() / self.pipeline_depth
+        if self.pipeline_depth > 0:
+            q = self._queue_depth() / self.pipeline_depth
         return max(p, 0.5 * q)
 
     # -- pipeline ----------------------------------------------------------
@@ -514,6 +589,10 @@ class MatchStage:
                     self._pending[cap:],
                 )
                 leftovers = bool(self._pending)
+                n_held = 0
+                if self._held_pending:
+                    n_held = sum(1 for _, e in batch if e.held is not None)
+                    self._held_pending -= n_held
             if leftovers:
                 wake.set()  # leftovers start the next window now
             # a submit() future cancelled mid-window is dead weight: drop
@@ -538,20 +617,26 @@ class MatchStage:
             else:
                 rec = BatchProfile()
             rec.formed_ns = time.perf_counter_ns()
-            topics = [t for t, _ in batch]
             entries = [e for _, e in batch]
+            # held members ride along with their results attached: the
+            # device sees the others only
+            sent = [e for e in entries if e.held is None] if n_held else entries
+            topics = (
+                [t for t, e in batch if e.held is None] if n_held
+                else [t for t, _ in batch]
+            )
             predicates = self.predicates
             recrypt = self.recrypt
             feats = (
-                [e.feats for e in entries] if predicates is not None else None
+                [e.feats for e in sent] if predicates is not None else None
             )
-            rjobs = [e.rjob for e in entries] if recrypt is not None else None
+            rjobs = [e.rjob for e in sent] if recrypt is not None else None
             rec.topics = len(topics)
             rec.depth = self.inflight_batches
             if rec.kept:
-                rec.stage_wait([e.submit_ns for e in entries if e.submit_ns])
+                rec.stage_wait([e.submit_ns for e in sent if e.submit_ns])
             # the sampled stage clocks (1 publish in 64 carries one)
-            clocks = [e.clock for e in entries if e.clock is not None]
+            clocks = [e.clock for e in sent if e.clock is not None]
             for c in clocks:  # end of the accumulation/park wait
                 c.stamp("staging_wait")
                 c.batch = rec.seq
@@ -611,99 +696,66 @@ class MatchStage:
                 return resolver, pred_resolver, rec_resolver
 
             loop = asyncio.get_running_loop()
+            resolver = pred_resolver = rec_resolver = None
             try:
-                (
-                    resolver, pred_resolver, rec_resolver,
-                ) = await loop.run_in_executor(self._h2d_executor, issue)
+                if topics:  # else held members alone: nothing to issue
+                    (
+                        resolver, pred_resolver, rec_resolver,
+                    ) = await loop.run_in_executor(self._h2d_executor, issue)
             except asyncio.CancelledError:
                 # stop() cancelled us with this batch in hand (in neither
-                # _pending nor the queue): resolve it before going down.
-                # An issue that already reached the device is harmless —
-                # its result is simply never synced.
-                self._fallback_all(batch, klass="stop")
+                # _pending nor the queue): back to the head of _pending,
+                # where stop() finds it in its place. An issue that
+                # already reached the device is harmless — its result is
+                # simply never synced.
+                with self._plock:
+                    self._pending[:0] = batch
                 raise
             except Exception:
+                # the batch falls back whole, and waits its turn behind
+                # the batches in the queue as held members
                 _log.exception("stage issue failed; host fallback for batch")
-                self._fallback_all(batch, klass="issue_error")
-                continue
+                self._hold(
+                    [item for item in batch if item[1].held is None],
+                    klass="issue_error",
+                )
+                resolver = None
+            if resolver is None:
+                self._held_batches += 1
             self.inflight_batches += 1
             try:
                 await queue.put(
                     (
-                        resolver, entries, topics, clocks, rec,
+                        resolver, entries, batch, clocks, rec,
                         pred_resolver, feats, rec_resolver,
                     )
                 )
             except asyncio.CancelledError:
                 self.inflight_batches -= 1
-                self._fallback_all(batch, klass="stop")
+                with self._plock:
+                    self._pending[:0] = batch
                 raise
 
     async def _drain_loop(self) -> None:
-        loop = asyncio.get_running_loop()
         queue = self._queue
         assert queue is not None  # start() created us
-        telemetry = self.telemetry
         while True:
             (
-                resolver, entries, topics, clocks, rec, pred_resolver, feats,
+                resolver, entries, batch, clocks, rec, pred_resolver, feats,
                 rec_resolver,
             ) = await queue.get()
-            try:
-                # the D2H sync blocks — run it off the loop. Queue depth is
-                # sampled at resolve time: batches still queued waited for
-                # this one, so the controller budgets depth x service.
-                # The predicate rows sync in the SAME executor leg (the
-                # pred resolver never raises — failures degrade to None).
-                depth = queue.qsize() + 1
-                t0 = loop.time()
-                c0 = self._compile_clock()
-                pr, mr, rr = pred_resolver, resolver, rec_resolver
-
-                def sync():
-                    rec.sync_start_ns = time.perf_counter_ns()
-                    if telemetry is not None:
-                        # d2h-leg handoff wait: issue returned (batch
-                        # queued behind the pipeline) -> sync start
-                        telemetry.observe_leg_wait(
-                            "d2h", (rec.sync_start_ns - rec.issue_end_ns) / 1e9
-                        )
-                    return (
-                        mr(),
-                        pr() if pr is not None else None,
-                        rr() if rr is not None else None,
-                    )
-
-                results, pred_rows, rec_rows = await loop.run_in_executor(
-                    self._executor, sync
-                )
-                if pred_rows is not None and self.predicates is not None:
-                    self.predicates.attach_rows(feats, pred_rows)
-                if rec_rows is not None and self.recrypt is not None:
-                    self.recrypt.attach(rec_rows)
-                dt = loop.time() - t0
-                if self._compile_clock() != c0:
-                    # a first-signature jit call ran during this drain:
-                    # set-up, not a service-time sample
-                    self.compile_tainted_batches += 1
-                else:
-                    self._observe_service(dt, len(topics), depth)
-                if telemetry is not None:
-                    telemetry.observe_batch(dt, len(topics), self._batch_cap)
-            except asyncio.CancelledError:
-                # stop() cancelled us with this batch already popped: it is
-                # invisible to stop()'s queue drain, so resolve it here
+            if resolver is None:
+                # held members alone (or a batch that fell back whole at
+                # issue): no device leg, no service-time sample
+                self._held_batches -= 1
                 self.inflight_batches -= 1
-                self._fallback_all(list(zip(topics, entries)), klass="stop")
-                raise
-            except Exception:
-                self.inflight_batches -= 1
-                _log.exception("stage resolve failed; host fallback for batch")
-                self._fallback_all(
-                    list(zip(topics, entries)), klass="resolve_error"
+                results: list = []
+            else:
+                results = await self._resolve(
+                    resolver, batch, rec, pred_resolver, feats, rec_resolver
                 )
-                continue
-            self.inflight_batches -= 1
+                if results is None:
+                    continue  # fell back, and completed at the head of the order
             # this batch's own device-timing record: both windows are
             # set only when the batch actually dispatched AND synced —
             # the exact-map fast path and host fallbacks leave them
@@ -720,8 +772,83 @@ class MatchStage:
                     # for the resolve ends and its wait for the loop
                     # begins (DeviceProfiler.note_fanout)
                     t_set = time.perf_counter_ns()
-                    rec.set_sum_ns = len(entries) * t_set
+                    rec.set_sum_ns = rec.topics * t_set
+                if len(results) != len(entries):
+                    # held members: each takes its place in submit order,
+                    # and what the order cost it ends here
+                    rec.order_hold(
+                        [e.held_ns for e in entries if e.held is not None],
+                        time.perf_counter_ns(),
+                    )
+                    live = iter(results)
+                    results = [
+                        next(live) if e.held is None else e.held
+                        for e in entries
+                    ]
                 await self._complete(entries, results, t_set)
+
+    async def _resolve(
+        self, resolver, batch, rec, pred_resolver, feats, rec_resolver
+    ):
+        """One device batch's blocking leg: sync its results off the
+        loop and feed the controller. Returns the device's results, one
+        a topic sent; None when the batch fell back instead (and has
+        completed: it was at the head of the order)."""
+        loop = asyncio.get_running_loop()
+        telemetry = self.telemetry
+        try:
+            # the D2H sync blocks — run it off the loop. Queue depth is
+            # sampled at resolve time: batches still queued waited for
+            # this one, so the controller budgets depth x service.
+            # The predicate rows sync in the SAME executor leg (the
+            # pred resolver never raises — failures degrade to None).
+            depth = self._queue_depth() + 1
+            t0 = loop.time()
+            c0 = self._compile_clock()
+
+            def sync():
+                rec.sync_start_ns = time.perf_counter_ns()
+                if telemetry is not None:
+                    # d2h-leg handoff wait: issue returned (batch
+                    # queued behind the pipeline) -> sync start
+                    telemetry.observe_leg_wait(
+                        "d2h", (rec.sync_start_ns - rec.issue_end_ns) / 1e9
+                    )
+                return (
+                    resolver(),
+                    pred_resolver() if pred_resolver is not None else None,
+                    rec_resolver() if rec_resolver is not None else None,
+                )
+
+            results, pred_rows, rec_rows = await loop.run_in_executor(
+                self._executor, sync
+            )
+            if pred_rows is not None and self.predicates is not None:
+                self.predicates.attach_rows(feats, pred_rows)
+            if rec_rows is not None and self.recrypt is not None:
+                self.recrypt.attach(rec_rows)
+            dt = loop.time() - t0
+            if self._compile_clock() != c0:
+                # a first-signature jit call ran during this drain:
+                # set-up, not a service-time sample
+                self.compile_tainted_batches += 1
+            else:
+                self._observe_service(dt, rec.topics, depth)
+            if telemetry is not None:
+                telemetry.observe_batch(dt, rec.topics, self._batch_cap)
+        except asyncio.CancelledError:
+            # stop() cancelled us with this batch already popped: it is
+            # invisible to stop()'s queue drain, so resolve it here
+            self.inflight_batches -= 1
+            self._fallback_all(batch, klass="stop")
+            raise
+        except Exception:
+            self.inflight_batches -= 1
+            _log.exception("stage resolve failed; host fallback for batch")
+            self._fallback_all(batch, klass="resolve_error")
+            return None
+        self.inflight_batches -= 1
+        return results
 
     async def _complete(self, entries, results, t_set_ns: int) -> None:
         """Hand one resolved batch to its completions, in submit order:
@@ -824,21 +951,28 @@ class MatchStage:
 
     def _fallback_all(self, items, klass: Optional[str] = "stop") -> None:
         """Complete parked ``(topic, entry)`` items via the host walk,
-        now, through the entries' own completions (each on the loop that
-        parked it). A host walk that raises hands its exception to the
-        completion as that publish's result. ``klass`` is the fallback
-        class counted (None: not a counted fallback)."""
+        now, in the order given, through the entries' own completions
+        (each on the loop that parked it). The caller sees to it that
+        nothing parked before them is still in the stage. A held member
+        among them keeps the result it has. A host walk that raises hands its exception
+        to the completion as that publish's result. ``klass`` is the
+        fallback class counted (None: not a counted fallback)."""
         if not items:
             return
         entries, results = [], []
+        walked = 0
         for topic, entry in items:
             entries.append(entry)
+            if entry.held is not None:
+                results.append(entry.held)
+                continue
+            walked += 1
             try:
                 results.append(self.host_fallback(topic))
             except Exception as e:  # pragma: no cover - host walk is total
                 results.append(e)
-        if klass is not None and self.telemetry is not None:
-            self.telemetry.note_fallback(klass, len(entries))
+        if klass is not None and walked and self.telemetry is not None:
+            self.telemetry.note_fallback(klass, walked)
         for complete, es, rs in self._hand_over(entries, results, 0):
             self._run_slices(complete, es, rs, 0)
 

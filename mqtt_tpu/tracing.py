@@ -329,7 +329,7 @@ class BatchProfile:
         "submit_first_ns", "wait_n", "wait_sum_ns",
         "formed_ns", "issue_start_ns", "issue_end_ns", "sync_start_ns",
         "tokenize", "h2d_dispatch", "d2h_sync", "resolve", "deliver",
-        "set_sum_ns",
+        "set_sum_ns", "hold", "hold_n", "hold_sum_ns",
     )
 
     def __init__(self) -> None:
@@ -389,6 +389,22 @@ class BatchProfile:
         # batch's hand-over to its completion, where a publish's wait
         # for the resolve ends and its wait for the loop begins
         self.set_sum_ns = 0
+        # mqtt/order.hold: the held members that rode with this batch
+        # (staging: fallbacks that joined the order), from each one's
+        # host walk done to the batch's hand-over, as the oldest's
+        # (start_ns, end_ns), the count and the sum: what the order
+        # guarantee cost them
+        self.hold: Optional[tuple[int, int]] = None
+        self.hold_n = 0
+        self.hold_sum_ns = 0
+
+    def order_hold(self, held_ns: list, now_ns: int) -> None:
+        """Fold the held members' host-walk-done instants into
+        mqtt/order.hold; their completion starts at ``now_ns``."""
+        if held_ns:
+            self.hold_n = n = len(held_ns)
+            self.hold = (min(held_ns), now_ns)
+            self.hold_sum_ns = n * now_ns - sum(held_ns)
 
     def stage_wait(self, submits_ns: list) -> None:
         """Fold the stamped members' park() instants into
@@ -400,16 +416,19 @@ class BatchProfile:
 
     def spans(self) -> list:
         """The tree as ``(name, start_ns, end_ns, args)``, root first (a
-        batch with no stamped submit starts when it formed); a span
+        batch with no stamped submit starts when it formed, or with its
+        oldest held member's host walk); a span
         whose boundaries were never stamped is left out. Served on
         ``/traces`` for the newest slice's batches (``slice_events``)."""
         out = []
         seq = self.seq
         first = self.submit_first_ns
         if self.formed_ns is not None and self.deliver is not None:
+            start = self.formed_ns if first is None else first
+            if self.hold is not None:
+                start = min(start, self.hold[0])
             out.append((
-                "mqtt/batch",
-                self.formed_ns if first is None else first, self.deliver[1],
+                "mqtt/batch", start, self.deliver[1],
                 {"batch": seq, "topics": self.topics,
                  "bucket": self.bucket, "depth": self.depth},
             ))
@@ -424,6 +443,11 @@ class BatchProfile:
         ):
             if t0 is not None and t1 is not None:
                 out.append((name, t0, t1, {"batch": seq}))
+        if self.hold is not None:
+            out.append((
+                "mqtt/order.hold", self.hold[0], self.hold[1],
+                {"batch": seq, "n": self.hold_n, "sum_ns": self.hold_sum_ns},
+            ))
         for slot, name in BUSY_SPANS.items():
             window = getattr(self, slot)
             if window is not None:
@@ -718,9 +742,12 @@ class DeviceProfiler:
         self._snap_a: Optional[dict] = None
         self._is_enabled: Any = None  # TraceAnnotation.is_enabled, on first poll
         # set by whoever owns them: the served matcher's MatcherStats
-        # (server) and the loop the stage runs on (MatchStage.start)
+        # (server), the loop the stage runs on (MatchStage.start), and
+        # the broker's own running counts for the snapshots (server:
+        # ``order_held``, ``deliveries``, ``socket_sends``)
         self.matcher_stats: Any = None
         self.loop: Any = None
+        self.counters: Any = None
         # per-publish loop counters, cumulative ns / counts, armed only:
         # a scan's frames in hand -> its publishes parked (ingest), the
         # batch's results in hand -> this publish's fan-out starts
@@ -816,8 +843,8 @@ class DeviceProfiler:
     def _snapshot(self) -> dict:
         """One edge of a slice: the instant, CPU (process and per
         thread), topics the matcher took in, the in-flight union so far
-        (``duty_cycle``'s numerator), the newest full collections, and
-        the armed-only loop counters."""
+        (``duty_cycle``'s numerator), the newest full collections, the
+        armed-only loop counters, and the owner's ``counters()``."""
         stats = self.matcher_stats
         return {
             "t_ns": time.perf_counter_ns(),
@@ -833,6 +860,7 @@ class DeviceProfiler:
             "fanout_n": self.fanout_n,
             "loop_beats": self._beats,
             "loop_stall_max_ns": self.loop_stall_max_ns,
+            **(self.counters() if self.counters is not None else {}),
         }
 
     def _arm(self) -> None:

@@ -16,6 +16,7 @@ import pytest
 from mqtt_tpu import Options, staging
 from mqtt_tpu.hooks import ON_PACKET_PROCESSED, ON_PUBLISHED, Hook
 from mqtt_tpu.packets import (
+    CONNACK,
     ERR_UNSPECIFIED_ERROR,
     PINGREQ,
     PINGRESP,
@@ -39,6 +40,23 @@ from tests.test_server import (
     run,
     sub_packet,
 )
+
+
+def load_benchmark_module(name):
+    """A module of ``benchmark/`` by path: the plain reference and the
+    deployments live beside the benchmark and import nothing of the
+    program."""
+    import importlib.util
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_" + name.replace("/", "_"),
+        os.path.join(root, "benchmark", name + ".py"),
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def staged_options(**kw):
@@ -186,12 +204,14 @@ class TestStageCompletion:
                 topics = [f"a/{i}" for i in range(6)]
                 for t in topics:
                     park(stage, rec, t)
-                # the two past max_pending completed inside park()
-                assert rec.order == topics[4:]
-                assert stage.admission_fallbacks == 2
+                # the two past max_pending were walked on the host inside
+                # park() and wait their turn behind the four before them
+                assert rec.order == []
+                assert stage.admission_fallbacks == stage.order_held == 2
+                assert stage.peak_pending == 4 and stage.pending_depth == 6
                 await stage.stop()
                 expect_fallbacks = {"admission": 2, "stop": 4}
-                assert sorted(rec.order) == sorted(topics)
+                assert rec.order == topics
             else:
                 stage.start()
                 if klass == "stop":
@@ -216,6 +236,155 @@ class TestStageCompletion:
             for k, c in tel.fallback.items():
                 assert int(c.value) == expect_fallbacks.get(k, 0), k
             assert stage.batch_completed == len(rec.order)
+
+        run(scenario())
+
+    def test_fallback_with_nothing_parked_completes_inside_park(self):
+        """A parker with nothing else in the stage overtakes nothing: its
+        admission fallback completes inside ``park()`` and is no held
+        member, while the stage is full of other parkers' publishes."""
+
+        async def scenario():
+            stage = MatchStage(
+                GateMatcher(), host, max_pending=2, latency_budget_s=None
+            )
+            stage._wake = asyncio.Event()  # armed, never drained
+            rec = Recorder()
+            for t in ("x/0", "x/1"):
+                park(stage, rec, t)
+            entry = Parked(rec)
+            entry.pk, entry.alone = "x/alone", True
+            stage.park("x/alone", entry)
+            assert rec.order == ["x/alone"]
+            assert rec.calls[0][3] == [("x/alone", "host")]
+            assert stage.admission_fallbacks == 1 and stage.order_held == 0
+            assert stage.pending_depth == 2
+            await stage.stop()
+            assert rec.order == ["x/alone", "x/0", "x/1"]
+
+        run(scenario())
+
+    def test_held_members_keep_their_place_and_never_reach_the_matcher(
+        self, monkeypatch
+    ):
+        """Ten publishes against a device backlog of four, while the
+        first batch hangs in its sync: the six not admitted are walked on
+        the host at once, the matcher sees the four admitted only, and
+        the completion sees all ten in park order, each held member with
+        its host result, under one ``mqtt/order.hold`` a batch."""
+
+        records = []
+        profile = staging.BatchProfile
+        monkeypatch.setattr(
+            staging, "BatchProfile",
+            lambda: records.append(profile()) or records[-1],
+        )
+
+        async def scenario():
+            m = GateMatcher()
+            m.release.clear()
+            walked = []
+
+            def walk(topic):
+                walked.append(topic)
+                return host(topic)
+
+            stage = MatchStage(
+                m, walk, window_s=0.001, max_batch=3, max_pending=4,
+                latency_budget_s=None,
+            )
+            stage.start()
+            rec = Recorder()
+            topics = [f"h/{i}" for i in range(10)]
+            for t in topics:
+                park(stage, rec, t)
+            assert walked == topics[4:] and rec.order == []
+            assert stage.admission_fallbacks == stage.order_held == 6
+            assert stage.peak_pending == 4
+            # no depth the admission test reckons with counts them
+            assert stage.pressure() == pytest.approx(1.0)
+            m.release.set()
+            await until(lambda: len(rec.order) == 10, "all completed")
+            assert rec.order == topics
+            assert m.batches == [topics[:3], topics[3:4]]
+            via = [v for _tid, _loop, _t, results in rec.calls for _, v in results]
+            assert via == ["device"] * 4 + ["host"] * 6
+            assert stage.batch_completed == 10
+            # batches of 3: [0 1 2] [3 h h] [h h h] [h]
+            assert [r.topics for r in records] == [3, 1, 0, 0]
+            assert [r.hold_n for r in records] == [0, 2, 3, 1]
+            for r in records[1:]:
+                assert r.hold[0] <= r.hold[1] and r.hold_sum_ns >= 0
+                names = [name for name, *_ in r.spans()]
+                assert names[0] == "mqtt/batch" and "mqtt/order.hold" in names
+            await stage.stop()
+
+        run(scenario())
+
+    def test_issue_error_waits_behind_the_batch_in_its_sync(self):
+        """Batch N fails at issue while batch N-1 hangs in its sync: N is
+        walked on the host at once and completes after N-1."""
+
+        class FailsSecond(GateMatcher):
+            def match_topics_async(self, topics, profile=None):
+                self.fail = "issue" if self.batches else None
+                return super().match_topics_async(topics, profile=profile)
+
+        async def scenario():
+            from mqtt_tpu.telemetry import Telemetry
+
+            tel = Telemetry(sample=0)
+            m = FailsSecond()
+            m.release.clear()
+            stage = MatchStage(
+                m, host, window_s=0.001, max_batch=3, latency_budget_s=None,
+                telemetry=tel,
+            )
+            stage.start()
+            rec = Recorder()
+            topics = [f"i/{i}" for i in range(6)]
+            for t in topics:
+                park(stage, rec, t)
+            await until(lambda: stage.order_held == 3, "batch 2 fell back")
+            assert len(m.batches) == 2 and rec.order == []
+            m.release.set()
+            await until(lambda: len(rec.order) == 6, "all completed")
+            assert rec.order == topics
+            via = [v for _tid, _loop, _t, results in rec.calls for _, v in results]
+            assert via == ["device"] * 3 + ["host"] * 3
+            assert int(tel.fallback["issue_error"].value) == 3
+            assert stage.inflight_batches == 0 and stage._held_batches == 0
+            await stage.stop()
+
+        run(scenario())
+
+    @pytest.mark.parametrize("where", ["pending", "queued"])
+    def test_stop_with_held_members_completes_each_once_in_order(self, where):
+        async def scenario():
+            m = GateMatcher()
+            m.release.clear()
+            stage = MatchStage(
+                m, host, window_s=0.001, max_batch=2, max_pending=3,
+                latency_budget_s=None, pipeline_depth=2,
+            )
+            rec = Recorder()
+            topics = [f"s/{i}" for i in range(9)]
+            if where == "pending":
+                stage._wake = asyncio.Event()  # armed, never drained
+                for t in topics:
+                    park(stage, rec, t)
+            else:
+                stage.start()
+                for t in topics:
+                    park(stage, rec, t)
+                # one batch hangs in its sync, one waits in the queue, the
+                # collector holds a third, the rest is in _pending
+                await until(lambda: len(m.batches) >= 2, "queued")
+            assert stage.order_held == 6 and rec.order == []
+            await stage.stop()
+            m.release.set()
+            assert rec.order == topics  # none lost, none twice, in order
+            assert stage.batch_completed == 9
 
         run(scenario())
 
@@ -400,6 +569,106 @@ class TestServedPath:
             await h.shutdown()
 
         run(scenario())
+
+    @pytest.mark.parametrize("seed", [27, 2003, 2**31 + 7])
+    def test_fallbacks_keep_each_publishers_order_against_the_reference(
+        self, seed
+    ):
+        """stresser's shape over loopback TCP with the stage's backlog cut
+        below one socket read (8 against 16-frame writes): in every read
+        the frames past the eighth fall back while the first eight sit in
+        the stage. What the sockets saw equals the plain reference
+        (``benchmark/reference.py``): every message back, once, in the
+        order sent."""
+        reference = load_benchmark_module("reference")
+        stresser = load_benchmark_module("deployments/stresser")
+        params = {
+            "clients": 4, "offline_subscriptions": [["ops-dashboard", "$SYS/#"]],
+        }
+        plan = stresser.plan(params, seed, None)
+        subs = plan["subscriptions"]
+        chunk, rounds = 16, 3
+
+        async def client(port, k, received):
+            cid, flt, qos = subs[plan["live"][k]]
+            r, w = await asyncio.open_connection("127.0.0.1", port)
+            w.write(connect_packet(cid))
+            assert (await read_wire_packet(r)).fixed_header.type == CONNACK
+            w.write(sub_packet(1, [Subscription(filter=flt, qos=qos)]))
+            assert (await read_wire_packet(r)).fixed_header.type == SUBACK
+            return r, w
+
+        async def echo(r, w, k, received):
+            topics = stresser.topics(params, seed, k)
+            got = received[subs[plan["live"][k]][0]] = []
+            for n in range(rounds):
+                w.write(b"".join(
+                    pub_packet(next(topics), b"%d:%d" % (k, n * chunk + i))
+                    for i in range(chunk)
+                ))
+                for _ in range(chunk):
+                    pk = await read_wire_packet(r)
+                    pub, seq = bytes(pk.payload).split(b":")
+                    got.append(reference.pack_delivery(
+                        int(pub), int(seq), pk.fixed_header.qos,
+                        int(pk.fixed_header.dup),
+                        reference.topic_tag(pk.topic_name.encode()),
+                    ))
+
+        async def scenario():
+            from mqtt_tpu.listeners import Config as LConfig
+            from mqtt_tpu.listeners.tcp import TCP
+
+            h = Harness(
+                staged_options(
+                    overload_stage_max_pending=8,
+                    matcher_stage_latency_budget_ms=0,
+                )
+            )
+            srv = h.server
+            srv.add_listener(TCP(LConfig(type="tcp", id="t", address="127.0.0.1:0")))
+            await srv.serve()
+            port = int(srv.listeners.get("t").address().rsplit(":", 1)[1])
+            staging.bulk_register(
+                srv.topics,
+                ((c, Subscription(filter=f, qos=q)) for c, f, q in subs[4:]),
+            )
+            received: dict = {}
+            conns = [await client(port, k, received) for k in range(4)]
+            srv.matcher.flush()
+            await asyncio.gather(
+                *(echo(r, w, k, received) for k, (r, w) in enumerate(conns))
+            )
+            stage = srv._stage
+            out = (stage.order_held, stage.admission_fallbacks,
+                   stage.peak_pending, stage.batch_completed)
+            text = srv.telemetry.registry.exposition()
+            assert f"mqtt_tpu_stage_order_held_total {out[0]}" in text
+            for _r, w in conns:
+                w.close()
+            await srv.close()
+            await h.shutdown()
+            return received, out
+
+        received, (held, fallbacks, peak, completed) = run(scenario())
+        live = reference.FilterSet(subs[row] for row in plan["live"])
+        sent = (
+            (k, seq, topic, 0)
+            for k in range(4)
+            for seq, topic in zip(
+                range(chunk * rounds), stresser.topics(params, seed, k)
+            )
+        )
+        expected = reference.expected_deliveries(live, sent)
+        verdict = reference.compare_deliveries(expected, received)
+        assert verdict["errors"] == 0 and verdict["misordered"] == 0, verdict
+        assert sum(len(v) for v in received.values()) == 4 * chunk * rounds
+        # the frames of a read past the backlog fell back: behind frames
+        # of their own connection they joined its order (at least the
+        # first read's last eight); a connection with nothing in the
+        # stage had its fallbacks completed inside park()
+        assert peak <= 8 and completed == 4 * chunk * rounds
+        assert chunk - 8 <= held <= fallbacks
 
     def test_one_failing_publish_closes_only_its_connection(self):
         """A publish whose completion raises: its connection is closed,

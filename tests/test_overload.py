@@ -31,7 +31,7 @@ from mqtt_tpu.overload import (
 )
 from mqtt_tpu.packets import DISCONNECT, PINGREQ, PUBACK, PUBLISH, SUBACK
 from mqtt_tpu.packets import FixedHeader, Packet, Subscription, encode_packet
-from mqtt_tpu.staging import MatchStage
+from mqtt_tpu.staging import MatchStage, Parked
 from mqtt_tpu.topics import SYS_PREFIX, Subscribers
 
 from tests.test_server import (
@@ -261,15 +261,30 @@ class TestBoundedStagingAdmission:
             stage._wake = asyncio.Event()
             parked = [stage.submit(f"t/{i}") for i in range(3)]
             assert all(not f.done() for f in parked)
+            done = []
+            for i, f in enumerate(parked):
+                f.add_done_callback(lambda _f, i=i: done.append(i))
             over = stage.submit("t/over")
-            assert over.done()  # resolved NOW via the host walk
+            # walked on the host NOW, outside the device's backlog; its
+            # completion waits its turn behind the three parked before it
             assert hits == ["t/over"]
-            assert stage.admission_fallbacks == 1
+            assert not over.done()
+            over.add_done_callback(lambda _f: done.append("over"))
+            assert stage.admission_fallbacks == stage.order_held == 1
             assert stage.peak_pending == 3
-            assert stage.pending_depth == 3
+            assert stage.pending_depth == 4  # the held member rides along
             assert stage.pressure() == pytest.approx(1.0)
+            # a parker that has nothing else in the stage overtakes
+            # nothing: its fallback completes inside park()
+            entry = Parked(lambda es, rs, t=0: done.append("alone"))
+            entry.alone = True
+            stage.park("t/alone", entry)
+            assert done == ["alone"] and stage.order_held == 1
             await stage.stop()  # drains the parked entries via host walk
-            assert all(f.done() for f in parked)
+            assert all(f.done() for f in parked) and over.done()
+            await asyncio.sleep(0)  # the futures' callbacks
+            assert done == ["alone", 0, 1, 2, "over"]
+            assert hits == ["t/over", "t/alone", "t/0", "t/1", "t/2"]
 
         run(scenario())
 
@@ -289,12 +304,17 @@ class TestBoundedStagingAdmission:
             assert not f1.done()
             for _ in range(4):
                 stage._queue.put_nowait(None)
-            # projected wait (1 + 4) * 0.05 = 0.25 > 2 x 0.1: host walk
+            # projected wait (1 + 4) * 0.05 = 0.25 > 2 x 0.1: host walk,
+            # held behind "a" (it joins the order, PR 27)
             f2 = stage.submit("b")
-            assert f2.done()
-            assert stage.admission_fallbacks == 1
+            assert not f2.done()
+            assert stage.admission_fallbacks == stage.order_held == 1
+            # a held member is no device work: the projected wait of the
+            # next submission does not count it
+            assert stage._held_pending == 1 and stage.pending_depth == 2
             stage._queue = None
             await stage.stop()
+            assert f1.done() and f2.done()
 
         run(scenario())
 

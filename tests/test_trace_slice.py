@@ -389,7 +389,8 @@ class TestUndispatchedPaths:
 
     def test_host_fallback_leaves_the_matcher_spans_none(self):
         """An issue that fails falls back to the host walk: the record
-        never sees a dispatch."""
+        never sees a dispatch. The batch still takes its turn through
+        the drain queue, as held members under ``mqtt/order.hold``."""
         index, topic_gen = build_index(13)
 
         class Broken:
@@ -411,8 +412,14 @@ class TestUndispatchedPaths:
         (rec,) = list(prof._recent)
         assert rec.formed_ns is not None and rec.issue_start_ns is not None
         for slot in ("dispatch", "d2h", "tokenize", "h2d_dispatch", "d2h_sync",
-                     "resolve", "deliver"):
+                     "resolve"):
             assert getattr(rec, slot) is None, slot
+        assert rec.deliver is not None and stage.order_held == 10
+        assert rec.hold_n == 10 and rec.hold[0] <= rec.deliver[0] <= rec.hold[1]
+        hold = [s for s in rec.spans() if s[0] == "mqtt/order.hold"]
+        assert hold[0][3] == {
+            "batch": rec.seq, "n": 10, "sum_ns": rec.hold_sum_ns,
+        }
 
 
 class TestGen2Pauses:
@@ -525,6 +532,11 @@ class TestLoopCounters:
         assert prof.fanout_n == 12  # nothing counted after the session
         for key in ("ingest_busy_ns", "fanout_busy_ns", "fanout_wait_ns"):
             assert sl.b[key] - sl.a[key] > 0, key
+        # the broker's own counts ride the snapshots: one delivery and
+        # one socket send a publish here, no fallback held
+        assert sl.b["deliveries"] - sl.a["deliveries"] == 12
+        assert sl.b["socket_sends"] - sl.a["socket_sends"] == 12
+        assert sl.b["order_held"] == sl.a["order_held"] == 0
         roots = [e for e in doc["traceEvents"] if e["name"] == "publish"]
         assert len(roots) == 24
         kept = {r.seq for r in sl.batches}
