@@ -39,6 +39,9 @@ from .utils.mempool import get_buffer, put_buffer
 DEFAULT_KEEPALIVE = 10  # default connection keepalive seconds (clients.go:25)
 DEFAULT_CLIENT_PROTOCOL_VERSION = 4  # (clients.go:26)
 MINIMUM_KEEPALIVE = 5  # below this a warning is logged (clients.go:27)
+# a socket's cork (Client._cork) is written out early once it holds this
+# many bytes: large payloads are never joined into one buffer
+CORK_MAX_BYTES = 64 * 1024
 
 
 class ConnectionClosedError(Exception):
@@ -354,13 +357,15 @@ class Client:
         self._staged = 0
         self._staged_waiter: Optional[asyncio.Future] = None
         self._staged_err: Optional[BaseException] = None
-        # the encoded packets this connection's handlers have written
-        # during the socket read in hand (its acks, mostly), or None
-        # between reads: they leave as ONE transport write when the
-        # read's frames are done (read / _uncork). A socket send is the
-        # dearest thing the loop does (a syscall against a handful of
-        # bytecodes), and a pipelining publisher draws one ack a frame.
-        self._cork: Optional[list] = None
+        # the encoded packets held back for this socket, joined in order,
+        # or None while nothing is: they leave as ONE transport write when
+        # whoever opened the cork closes it (_uncork). Two openers: the
+        # connection's own socket read (read: its handlers' acks,
+        # mostly) and a completion slice that delivers to this socket
+        # more than once (server._complete_staged). Never open across a
+        # return to the event loop. A socket send is the dearest thing
+        # the loop does (a syscall against a handful of bytecodes).
+        self._cork: Optional[bytearray] = None
         # priority-weighted shedding (mqtt_tpu.overload): the class and
         # its shed/publish-quota multiplier, resolved at CONNECT from
         # Options.overload_priority_users / overload_priority_classes
@@ -442,20 +447,26 @@ class Client:
 
     def _write(self, data: bytes) -> None:
         """One encoded packet to the transport, in order: behind the
-        packets of the socket read in hand while there is one."""
+        packets already held for this socket while its cork is open (its
+        own read's, or a completion slice's). A cork is bounded: past
+        ``CORK_MAX_BYTES`` it is written out early and stays open."""
         cork = self._cork
-        if cork is not None:
-            cork.append(data)
-        else:
+        if cork is None:
             self.net.writer.write(data)
             self.ops.socket_sends += 1
+            return
+        cork += data
+        if len(cork) >= CORK_MAX_BYTES:
+            self._uncork()
+            self._cork = bytearray()
 
     def _uncork(self) -> None:
-        """Write what the socket read in hand has corked, as one
-        transport write, and stop corking."""
+        """Write what this socket's cork holds, as one transport write,
+        and stop corking. For the opener (the read in hand, or the
+        completion slice) and for the teardown."""
         cork, self._cork = self._cork, None
         if cork and self.net.writer is not None:
-            self.net.writer.write(cork[0] if len(cork) == 1 else b"".join(cork))
+            self.net.writer.write(bytes(cork))
             self.ops.socket_sends += 1
 
     def parse_connect(self, lid: str, pk: Packet) -> None:
@@ -591,7 +602,9 @@ class Client:
         connection is raised here. What the handlers write to this
         connection during one read (an ack a QoS>0 frame) is corked and
         leaves as one transport write when the read's frames are done
-        (``_cork``): one socket send a read, not one a frame.
+        (``_cork``): one socket send a read, not one a frame. The read is
+        one of the cork's two openers; a delivery that reaches this socket
+        while it is open (a publisher that hears its own topic) joins it.
         """
         from .native import MAX_FRAMES_PER_SCAN, frame_scan, varint_decode
 
@@ -632,7 +645,7 @@ class Client:
                 t_in = time.perf_counter_ns()
                 n_in = self._pub_count
             start = 0
-            self._cork = []  # this read's acks leave as one write
+            self._cork = bytearray()  # this read's acks leave as one write
             try:
                 for f in frames:
                     fstart = start
@@ -809,8 +822,9 @@ class Client:
         if self.net.writer is not None:
             try:
                 try:
-                    # a DISCONNECT written inside the read in hand goes
-                    # out before the transport closes behind it
+                    # what the cork holds (a DISCONNECT written inside
+                    # the read in hand, a slice's deliveries) goes out
+                    # before the transport closes behind it
                     self._uncork()
                 finally:
                     self.net.writer.close()

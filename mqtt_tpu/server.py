@@ -852,8 +852,9 @@ class _Ops:
         # their per-publish ingest time while a profiler session is live.
         self.profiler: Optional[Any] = None
         # calls that reached a client's socket: a transport write (one a
-        # packet, or one a socket read's corked packets) and each send
-        # of the native fan-out flush. A plain add on the writing loop.
+        # packet, or one a cork: a socket read's packets, a completion
+        # slice's deliveries) and each send of the native fan-out flush.
+        # A plain add on the writing loop.
         self.socket_sends = 0
 
 
@@ -3044,7 +3045,15 @@ class Server:
         raises the first one, ``clients.read``) and does not stop the
         slice. ``t_set_ns``: the instant the results were in hand while a
         profiler session keeps the batch (``DeviceProfiler.note_fanout``),
-        else 0."""
+        else 0.
+
+        The slice is the second opener of a socket's cork
+        (``Client._cork``; the first is the connection's own read): a
+        socket of this loop that the slice targets more than once has
+        its cork opened here, its deliveries join it in submit order
+        (``_flush_variant``) and leave as ONE write when the slice ends,
+        before this method returns and so before any yield to the event
+        loop. A socket targeted once is written at its own publish."""
         hooks = self.hooks
         observed = hooks.provides(ON_PACKET_ENCODE, ON_PACKET_SENT)
         on_published = (
@@ -3078,44 +3087,88 @@ class Server:
                 for group in subs.shared.values():
                     ids += group  # every candidate, before selection
             work.append((entry, subs, targets))
-        lookup = self.clients.present(ids).get
-        for entry, subs, targets in work:
-            cl, pk = entry.cl, entry.pk
-            err: Optional[BaseException] = None
-            try:
-                if targets is None and isinstance(subs, BaseException):
-                    raise subs
+        present = self.clients.present(ids)
+        lookup = present.get
+        corked = self._cork_repeated(ids, present)
+        try:
+            for entry, subs, targets in work:
+                cl, pk = entry.cl, entry.pk
+                err: Optional[BaseException] = None
+                try:
+                    if targets is None and isinstance(subs, BaseException):
+                        raise subs
+                    if prof is not None:
+                        t_run = time.perf_counter_ns()
+                    fan_out(
+                        pk, subs, entry.feats, entry.rjob,
+                        lookup, targets, observed,
+                    )
+                    if prof is not None:
+                        prof.note_fanout(
+                            t_set_ns, t_run, time.perf_counter_ns()
+                        )
+                    if cluster is not None:
+                        cluster.forward_packet(pk)
+                    if entry.clock is not None:  # a sampled publish
+                        finish_clock(pk)
+                    if on_published is not None:
+                        on_published(cl, pk)
+                except Exception as e:
+                    err = e
+                if on_processed is not None:
+                    try:
+                        on_processed(cl, pk, err)
+                    except Exception as e:
+                        err = err or e
+                if err is None:
+                    self._drain_quota_starved(cl)
+                else:
+                    self._staged_error(cl, err, entry.counted)
+                if entry.counted:
+                    cl._staged -= 1
+                    if not cl._staged:
+                        waiter = cl._staged_waiter
+                        if waiter is not None and not waiter.done():
+                            waiter.set_result(None)  # brokerlint: ok=R12 a counted entry was parked from cl.net.loop and completes on it: the read loop's own
+        finally:
+            if corked:
                 if prof is not None:
                     t_run = time.perf_counter_ns()
-                fan_out(
-                    pk, subs, entry.feats, entry.rjob,
-                    lookup, targets, observed,
-                )
+                for cl in corked:
+                    try:
+                        cl._uncork()
+                    except Exception as e:
+                        self.log.debug(
+                            "slice flush failed: error=%s client=%s", e, cl.id
+                        )
                 if prof is not None:
-                    prof.note_fanout(t_set_ns, t_run, time.perf_counter_ns())
-                if cluster is not None:
-                    cluster.forward_packet(pk)
-                if entry.clock is not None:  # a sampled publish
-                    finish_clock(pk)
-                if on_published is not None:
-                    on_published(cl, pk)
-            except Exception as e:
-                err = e
-            if on_processed is not None:
-                try:
-                    on_processed(cl, pk, err)
-                except Exception as e:
-                    err = err or e
-            if err is None:
-                self._drain_quota_starved(cl)
-            else:
-                self._staged_error(cl, err, entry.counted)
-            if entry.counted:
-                cl._staged -= 1
-                if not cl._staged:
-                    waiter = cl._staged_waiter
-                    if waiter is not None and not waiter.done():
-                        waiter.set_result(None)  # brokerlint: ok=R12 a counted entry was parked from cl.net.loop and completes on it: the read loop's own
+                    prof.note_slice_flush(time.perf_counter_ns() - t_run)
+
+    def _cork_repeated(self, ids: list, present: dict) -> list:
+        """Open the cork of every socket a completion slice targets more
+        than once (``ids``: the slice's target ids in submit order,
+        ``present``: those of them that are connected) and return the
+        clients whose cork this call opened: the slice closes them. A
+        cork that is open already (the slice runs inside that
+        connection's read) stays its opener's; a socket another shard's
+        loop owns is written there, outside any slice."""
+        hits = list(filter(present.__contains__, ids))
+        if len(hits) == len(present):
+            return []  # every socket once: each is written at its publish
+        fabric = self._fabric is not None
+        seen: set = set()
+        corked = []
+        for cid in hits:
+            if cid not in seen:
+                seen.add(cid)
+                continue
+            cl = present[cid]
+            if cl._cork is None and (
+                not fabric or self._client_loop_local(cl)
+            ):
+                cl._cork = bytearray()
+                corked.append(cl)
+        return corked
 
     def _staged_error(self, cl: Client, err: BaseException, counted: bool) -> None:
         """One staged publish failed in its completion: what
@@ -3791,7 +3844,11 @@ class Server:
         sockets (idle transport + empty outbound queue, no TLS) flush
         through ONE GIL-released native call; everything else rides the
         bounded outbound queue with the existing backpressure, eviction
-        and drop accounting.
+        and drop accounting. A ready socket whose cork is open
+        (``Client._cork``: the completion slice in hand targets it again,
+        or its own read is in hand) takes the frame into the cork, in
+        order behind what it holds, and is written when the cork's
+        opener closes it.
 
         Under the shard fabric the group is split BY OWNING SHARD
         first: each remote shard receives its whole sub-group as one
@@ -3852,10 +3909,22 @@ class Server:
                 fd = -1
                 if (
                     cl.state.outbound_qty == 0
-                    and not cl._cork  # packets of its read in hand go first
                     and writer.get_extra_info("sslcontext") is None
                     and writer.transport.get_write_buffer_size() == 0
                 ):
+                    if cl._cork is not None:
+                        # an open cork (the slice targets this socket
+                        # again, or its own read is in hand): the frame
+                        # joins it, behind what it holds
+                        frame = (
+                            data if id_off < 0
+                            else self._patch_id(data, id_off, pid)
+                        )
+                        if self._transport_write_frame(
+                            cl, frame, count_delivery
+                        ):
+                            self._note_tenant_out(cl, dpk)
+                        continue
                     sock = writer.get_extra_info("socket")
                     if sock is not None:
                         try:
@@ -3906,6 +3975,7 @@ class Server:
         n = len(data)
         for (cl, _fd, pid), wrote in zip(flush, sent.tolist()):
             if wrote == n:
+                self._ops.socket_sends += 1  # the native flush's send
                 self._note_direct_write(cl, n, count_delivery)
             elif wrote >= 0:
                 # short write (kernel buffer filled mid-frame): finish
@@ -3916,7 +3986,7 @@ class Server:
                 )
                 try:
                     cl.net.writer.write(frame[wrote:])
-                    self._ops.socket_sends += 1
+                    self._ops.socket_sends += 2  # the flush's and the tail's
                 except Exception as e:
                     self.log.debug(
                         "fan-out flush tail failed: error=%s client=%s",
@@ -4001,13 +4071,13 @@ class Server:
     def _note_direct_write(
         self, cl: Client, nbytes: int, count_delivery: bool
     ) -> None:
-        """Accounting for one completed direct-socket delivery — the
+        """Accounting for one frame the native flush delivered — the
         union of clients.write_frame's io counters and _enqueue_frame's
-        delivery count."""
+        delivery count. Frames only: the caller counts the sends
+        (``_Ops.socket_sends``)."""
         self.info.bytes_sent += nbytes
         self.info.packets_sent += 1
         self.info.messages_sent += 1
-        self._ops.socket_sends += 1  # the native flush's send to this socket
         st = cl.state
         st.out_bytes += nbytes
         st.out_writes += 1

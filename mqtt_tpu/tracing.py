@@ -926,6 +926,12 @@ class DeviceProfiler:
         self.fanout_busy_ns += done_ns - start_ns
         self.fanout_n += 1
 
+    def note_slice_flush(self, busy_ns: int) -> None:
+        """The joined writes at a completion slice's end (the sockets the
+        slice corked, ``server._complete_staged``): fan-out time of the
+        slice's publishes, and no publish of its own."""
+        self.fanout_busy_ns += busy_ns
+
     def ensure_device(self, did: int) -> _DevWindow:
         """The window replica for one device id, creating it (and its
         ``device``-labeled metric children) on first sight. Idempotent;

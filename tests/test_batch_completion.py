@@ -523,6 +523,99 @@ async def subscriber(h, client_id, flt, qos=0):
     return r, w
 
 
+def run_echo(seed, n_clients, chunk, rounds, **options):
+    """stresser's echo loop (``benchmark/deployments/stresser.py``) over
+    loopback TCP: ``n_clients`` connections, each writing ``rounds``
+    chunks of ``chunk`` frames to its own topic and reading each chunk
+    back before the next. Returns the plain reference's verdict on what
+    the sockets saw (``benchmark/reference.py``), the count of
+    deliveries, and the broker's counts read before it closed."""
+    reference = load_benchmark_module("reference")
+    stresser = load_benchmark_module("deployments/stresser")
+    params = {
+        "clients": n_clients,
+        "offline_subscriptions": [["ops-dashboard", "$SYS/#"]],
+    }
+    plan = stresser.plan(params, seed, None)
+    subs = plan["subscriptions"]
+
+    async def client(port, k):
+        cid, flt, qos = subs[plan["live"][k]]
+        r, w = await asyncio.open_connection("127.0.0.1", port)
+        w.write(connect_packet(cid))
+        assert (await read_wire_packet(r)).fixed_header.type == CONNACK
+        w.write(sub_packet(1, [Subscription(filter=flt, qos=qos)]))
+        assert (await read_wire_packet(r)).fixed_header.type == SUBACK
+        return r, w
+
+    async def echo(r, w, k, received):
+        topics = stresser.topics(params, seed, k)
+        got = received[subs[plan["live"][k]][0]] = []
+        for n in range(rounds):
+            w.write(b"".join(
+                pub_packet(next(topics), b"%d:%d" % (k, n * chunk + i))
+                for i in range(chunk)
+            ))
+            for _ in range(chunk):
+                pk = await read_wire_packet(r)
+                pub, seq = bytes(pk.payload).split(b":")
+                got.append(reference.pack_delivery(
+                    int(pub), int(seq), pk.fixed_header.qos,
+                    int(pk.fixed_header.dup),
+                    reference.topic_tag(pk.topic_name.encode()),
+                ))
+
+    async def scenario():
+        from mqtt_tpu.listeners import Config as LConfig
+        from mqtt_tpu.listeners.tcp import TCP
+
+        h = Harness(staged_options(matcher_stage_latency_budget_ms=0, **options))
+        srv = h.server
+        srv.add_listener(TCP(LConfig(type="tcp", id="t", address="127.0.0.1:0")))
+        await srv.serve()
+        port = int(srv.listeners.get("t").address().rsplit(":", 1)[1])
+        staging.bulk_register(
+            srv.topics,
+            ((c, Subscription(filter=f, qos=q)) for c, f, q in subs[n_clients:]),
+        )
+        conns = [await client(port, k) for k in range(n_clients)]
+        srv.matcher.flush()
+        received: dict = {}
+        sends = srv._ops.socket_sends
+        await asyncio.gather(
+            *(echo(r, w, k, received) for k, (r, w) in enumerate(conns))
+        )
+        stage = srv._stage
+        counts = {
+            "held": stage.order_held, "fallbacks": stage.admission_fallbacks,
+            "peak": stage.peak_pending, "completed": stage.batch_completed,
+            "sends": srv._ops.socket_sends - sends,
+            "loops": len({
+                srv.clients.get(subs[row][0]).net.loop for row in plan["live"]
+            }),
+            "exposition": srv.telemetry.registry.exposition(),
+        }
+        for _r, w in conns:
+            w.close()
+        await srv.close()
+        await h.shutdown()
+        return received, counts
+
+    received, counts = run(scenario())
+    live = reference.FilterSet(subs[row] for row in plan["live"])
+    sent = (
+        (k, seq, topic, 0)
+        for k in range(n_clients)
+        for seq, topic in zip(
+            range(chunk * rounds), stresser.topics(params, seed, k)
+        )
+    )
+    verdict = reference.compare_deliveries(
+        reference.expected_deliveries(live, sent), received
+    )
+    return verdict, sum(len(v) for v in received.values()), counts
+
+
 class TestServedPath:
     def test_order_across_batches_and_slices_and_the_counter(self, monkeypatch):
         """Two publishers, 30 publishes each in one socket write, batches
@@ -580,95 +673,19 @@ class TestServedPath:
         the stage. What the sockets saw equals the plain reference
         (``benchmark/reference.py``): every message back, once, in the
         order sent."""
-        reference = load_benchmark_module("reference")
-        stresser = load_benchmark_module("deployments/stresser")
-        params = {
-            "clients": 4, "offline_subscriptions": [["ops-dashboard", "$SYS/#"]],
-        }
-        plan = stresser.plan(params, seed, None)
-        subs = plan["subscriptions"]
         chunk, rounds = 16, 3
-
-        async def client(port, k, received):
-            cid, flt, qos = subs[plan["live"][k]]
-            r, w = await asyncio.open_connection("127.0.0.1", port)
-            w.write(connect_packet(cid))
-            assert (await read_wire_packet(r)).fixed_header.type == CONNACK
-            w.write(sub_packet(1, [Subscription(filter=flt, qos=qos)]))
-            assert (await read_wire_packet(r)).fixed_header.type == SUBACK
-            return r, w
-
-        async def echo(r, w, k, received):
-            topics = stresser.topics(params, seed, k)
-            got = received[subs[plan["live"][k]][0]] = []
-            for n in range(rounds):
-                w.write(b"".join(
-                    pub_packet(next(topics), b"%d:%d" % (k, n * chunk + i))
-                    for i in range(chunk)
-                ))
-                for _ in range(chunk):
-                    pk = await read_wire_packet(r)
-                    pub, seq = bytes(pk.payload).split(b":")
-                    got.append(reference.pack_delivery(
-                        int(pub), int(seq), pk.fixed_header.qos,
-                        int(pk.fixed_header.dup),
-                        reference.topic_tag(pk.topic_name.encode()),
-                    ))
-
-        async def scenario():
-            from mqtt_tpu.listeners import Config as LConfig
-            from mqtt_tpu.listeners.tcp import TCP
-
-            h = Harness(
-                staged_options(
-                    overload_stage_max_pending=8,
-                    matcher_stage_latency_budget_ms=0,
-                )
-            )
-            srv = h.server
-            srv.add_listener(TCP(LConfig(type="tcp", id="t", address="127.0.0.1:0")))
-            await srv.serve()
-            port = int(srv.listeners.get("t").address().rsplit(":", 1)[1])
-            staging.bulk_register(
-                srv.topics,
-                ((c, Subscription(filter=f, qos=q)) for c, f, q in subs[4:]),
-            )
-            received: dict = {}
-            conns = [await client(port, k, received) for k in range(4)]
-            srv.matcher.flush()
-            await asyncio.gather(
-                *(echo(r, w, k, received) for k, (r, w) in enumerate(conns))
-            )
-            stage = srv._stage
-            out = (stage.order_held, stage.admission_fallbacks,
-                   stage.peak_pending, stage.batch_completed)
-            text = srv.telemetry.registry.exposition()
-            assert f"mqtt_tpu_stage_order_held_total {out[0]}" in text
-            for _r, w in conns:
-                w.close()
-            await srv.close()
-            await h.shutdown()
-            return received, out
-
-        received, (held, fallbacks, peak, completed) = run(scenario())
-        live = reference.FilterSet(subs[row] for row in plan["live"])
-        sent = (
-            (k, seq, topic, 0)
-            for k in range(4)
-            for seq, topic in zip(
-                range(chunk * rounds), stresser.topics(params, seed, k)
-            )
+        verdict, delivered, n = run_echo(
+            seed, 4, chunk, rounds, overload_stage_max_pending=8
         )
-        expected = reference.expected_deliveries(live, sent)
-        verdict = reference.compare_deliveries(expected, received)
         assert verdict["errors"] == 0 and verdict["misordered"] == 0, verdict
-        assert sum(len(v) for v in received.values()) == 4 * chunk * rounds
+        assert delivered == 4 * chunk * rounds
+        assert f"mqtt_tpu_stage_order_held_total {n['held']}" in n["exposition"]
         # the frames of a read past the backlog fell back: behind frames
         # of their own connection they joined its order (at least the
         # first read's last eight); a connection with nothing in the
         # stage had its fallbacks completed inside park()
-        assert peak <= 8 and completed == 4 * chunk * rounds
-        assert chunk - 8 <= held <= fallbacks
+        assert n["peak"] <= 8 and n["completed"] == 4 * chunk * rounds
+        assert chunk - 8 <= n["held"] <= n["fallbacks"]
 
     def test_one_failing_publish_closes_only_its_connection(self):
         """A publish whose completion raises: its connection is closed,
@@ -992,3 +1009,491 @@ class TestScanWrites:
             await h.shutdown()
 
         run(scenario())
+
+
+# -- the cork's second opener: a completion slice ---------------------------
+
+
+class SliceRig:
+    """A broker whose completion slices are run by hand: subscribers on
+    socketpairs, one log of every call that reaches a socket (the native
+    flush's fds, a transport write's bytes) and of every publish's end
+    (``on_published``), in the order they happened."""
+
+    def __init__(self, monkeypatch, options=None):
+        import mqtt_tpu.native as native
+
+        self.h = Harness(options)
+        self.server = self.h.server
+        self.log = []
+        self.fd_owner = {}
+        rig = self
+
+        class Marks(ProcessedHook):
+            def on_published(self, cl, pk):
+                rig.log.append(("published", bytes(pk.payload)))
+                if rig.after_publish is not None:
+                    rig.after_publish(bytes(pk.payload))
+
+        self.after_publish = None
+        self.hook = Marks()
+        self.server.add_hook(self.hook)
+        inner = native.fan_flush
+
+        def flush_spy(fds, data, id_off=-1, pids=None):
+            self.log.append(("flush", [self.fd_owner.get(fd, fd) for fd in fds]))
+            return inner(fds, data, id_off, pids)
+
+        monkeypatch.setattr(native, "fan_flush", flush_spy)
+
+    async def join(self, client_id, *filters, version=4, qos=0, props=None):
+        """Connect, subscribe, and spy on the server side's transport
+        writes. ``filters``: a filter, or (filter, subscription id)."""
+        r, w, task = await self.h.connect(client_id, version=version)
+        for n, flt in enumerate(filters):
+            sub = (
+                Subscription(filter=flt[0], qos=qos, identifier=flt[1])
+                if isinstance(flt, tuple)
+                else Subscription(filter=flt, qos=qos)
+            )
+            w.write(self.subscribe_packet(n + 1, sub, version))
+            await w.drain()
+            assert (await read_wire_packet(r, version)).fixed_header.type == SUBACK
+        cl = self.server.clients.get(client_id)
+        self.fd_owner[cl.net.writer.get_extra_info("socket").fileno()] = client_id
+        inner = cl.net.writer.write
+
+        def spy(data):
+            self.log.append(("write", client_id, bytes(data)))
+            return inner(data)
+
+        cl.net.writer.write = spy
+        return cl, r, w, task
+
+    @staticmethod
+    def subscribe_packet(pid, sub, version):
+        if not sub.identifier:
+            return sub_packet(pid, [sub], version=version)
+        from mqtt_tpu.packets import SUBSCRIBE, Properties
+
+        return encode_packet(
+            Packet(
+                fixed_header=FixedHeader(type=SUBSCRIBE, qos=1),
+                protocol_version=version,
+                packet_id=pid,
+                filters=[sub],
+                properties=Properties(subscription_identifier=[sub.identifier]),
+            )
+        )
+
+    def slice_of(self, origin, publishes, qos=0):
+        """One slice as the stage hands it over: ``(topic, payload)``
+        publishes of ``origin`` in submit order, each with the host
+        trie's answer."""
+        entries, results = [], []
+        for topic, payload in publishes:
+            pk = Packet(
+                fixed_header=FixedHeader(type=PUBLISH, qos=qos),
+                protocol_version=4, topic_name=topic, payload=payload,
+                origin=origin.id,
+            )
+            entries.append(Parked(None, None, None, None, origin, pk))
+            results.append(self.server.topics.subscribers(topic))
+        return entries, results
+
+    def run_slice(self, origin, publishes, qos=0):
+        del self.log[:]
+        self.server._complete_staged(*self.slice_of(origin, publishes, qos))
+        return list(self.log)
+
+    def writes(self, client_id):
+        return [e[2] for e in self.log if e[0] == "write" and e[1] == client_id]
+
+
+def frames(publishes, version=4):
+    return [pub_packet(t, p, version=version) for t, p in publishes]
+
+
+class TestSliceWrites:
+    """A slice's deliveries to a socket it targets more than once leave
+    as one write when the slice ends; everything else as before."""
+
+    def test_64_publishes_to_one_socket_are_one_write_in_submit_order(
+        self, monkeypatch
+    ):
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            sub, sub_r, *_ = await rig.join("sub", "t/#")
+            pubs = [(f"t/{i % 5}", b"m%02d" % i) for i in range(64)]
+            log = rig.run_slice(pub, pubs)
+            assert rig.writes("sub") == [b"".join(frames(pubs))]
+            assert [e for e in log if e[0] == "flush"] == []
+            # the write came after the slice's last publish was done
+            assert log[-1][0] == "write" and log[-2] == ("published", b"m63")
+            assert sub._cork is None  # nothing is held past the slice
+            got = [bytes((await read_wire_packet(sub_r)).payload) for _ in pubs]
+            assert got == [p for _t, p in pubs]
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_a_socket_targeted_once_is_written_at_its_own_publish(
+        self, monkeypatch
+    ):
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            _once, once_r, *_ = await rig.join("once", "t/a")
+            _wide, wide_r, *_ = await rig.join("wide", "t/#")
+            pubs = [("t/a", b"a"), ("t/b", b"b1"), ("t/b", b"b2"), ("t/c", b"c")]
+            log = rig.run_slice(pub, pubs)
+            # "once" goes out through the native flush inside publish "a";
+            # "wide", targeted four times, in one write at the slice's end
+            assert log.index(("flush", ["once"])) < log.index(("published", b"a"))
+            assert rig.writes("once") == []
+            assert rig.writes("wide") == [b"".join(frames(pubs))]
+            assert log.index(("published", b"c")) < len(log) - 1
+            assert bytes((await read_wire_packet(once_r)).payload) == b"a"
+            for _t, p in pubs:
+                assert bytes((await read_wire_packet(wide_r)).payload) == p
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_no_repeat_no_cork(self, monkeypatch):
+        """A broadcast to sockets each targeted once: today's one native
+        flush a publish over all of them, no transport write."""
+
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            for k in range(3):
+                await rig.join(f"s{k}", "t/one")
+            log = rig.run_slice(pub, [("t/one", b"x"), ("q/none", b"y")])
+            assert [e for e in log if e[0] != "published"] == [
+                ("flush", ["s0", "s1", "s2"])
+            ]
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_qos1_frames_in_a_cork_carry_their_own_packet_ids(self, monkeypatch):
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            sub, sub_r, *_ = await rig.join("sub", "t/#", qos=1)
+            pubs = [(f"t/{i}", b"q%d" % i) for i in range(5)]
+            rig.run_slice(pub, pubs, qos=1)
+            assert len(rig.writes("sub")) == 1
+            got = [await read_wire_packet(sub_r) for _ in pubs]
+            assert [bytes(p.payload) for p in got] == [p for _t, p in pubs]
+            assert [p.fixed_header.qos for p in got] == [1] * 5
+            pids = [p.packet_id for p in got]
+            assert len(set(pids)) == 5 and 0 not in pids
+            inflight = {p.packet_id: bytes(p.payload) for p in sub.state.inflight.get_all(False)}
+            assert inflight == {p.packet_id: bytes(p.payload) for p in got}
+            assert rig.server.info.inflight == 5
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    @pytest.mark.parametrize("slow", ["identifier", "alias"])
+    def test_slow_path_delivery_arrives_behind_the_collected_frames(
+        self, monkeypatch, slow
+    ):
+        """A delivery that needs a rewrite of its own (a subscription
+        identifier; an outbound topic alias) rides the outbound queue,
+        which drains after the slice's flush: the socket sees submit
+        order either way."""
+
+        async def scenario():
+            from mqtt_tpu.packets import Properties
+
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            if slow == "identifier":
+                sub, r, w, _ = await rig.join("sub", "a/#", ("b/#", 7), version=5)
+            else:
+                r, w, _ = await rig.h.attach()
+                cp = Packet(
+                    fixed_header=FixedHeader(type=1), protocol_version=5,
+                    properties=Properties(topic_alias_maximum=8),
+                )
+                cp.connect.protocol_name = b"MQTT"
+                cp.connect.clean = True
+                cp.connect.keepalive = 30
+                cp.connect.client_identifier = "sub"
+                w.write(encode_packet(cp))
+                assert (await read_wire_packet(r, 5)).fixed_header.type == CONNACK
+                w.write(sub_packet(1, [Subscription(filter="+/#")], version=5))
+                assert (await read_wire_packet(r, 5)).fixed_header.type == SUBACK
+                sub = rig.server.clients.get("sub")
+            pubs = [("a/1", b"1"), ("a/2", b"2"), ("b/1", b"3"), ("a/3", b"4")]
+            rig.run_slice(pub, pubs)
+            assert sub._cork is None
+            got = [await read_wire_packet(r, 5) for _ in pubs]
+            assert [bytes(p.payload) for p in got] == [b"1", b"2", b"3", b"4"]
+            if slow == "identifier":
+                assert [p.properties.subscription_identifier for p in got] == [
+                    [], [], [7], []
+                ]
+            else:
+                assert [p.properties.topic_alias for p in got] == [1, 2, 3, 4]
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_a_socket_corked_by_its_own_read_takes_the_delivery_into_it(self):
+        """No stage: the fan-out runs inside the read. A publisher that
+        hears its own topic gets acks and deliveries of one read as ONE
+        write, in the order they were made."""
+
+        async def scenario():
+            h = Harness()
+            r, w = await subscriber(h, "self", "own/#", qos=0)
+            cl = h.server.clients.get("self")
+            writes = []
+            inner = cl.net.writer.write
+
+            def spy(data):
+                writes.append(bytes(data))
+                return inner(data)
+
+            cl.net.writer.write = spy
+            sends = h.server._ops.socket_sends
+            w.write(b"".join(
+                pub_packet(f"own/{i}", b"%d" % i, qos=1, pid=i + 1) for i in range(3)
+            ))
+            await w.drain()
+            got = [await read_wire_packet(r) for _ in range(6)]
+            kinds = [
+                (p.fixed_header.type, p.packet_id or bytes(p.payload)) for p in got
+            ]
+            assert kinds == [
+                (PUBACK, 1), (PUBLISH, b"0"), (PUBACK, 2), (PUBLISH, b"1"),
+                (PUBACK, 3), (PUBLISH, b"2"),
+            ]
+            assert len(writes) == 1
+            assert h.server._ops.socket_sends - sends == 1
+            await h.shutdown()
+
+        run(scenario())
+
+    @pytest.mark.parametrize("how", ["closes", "evicted"])
+    def test_a_socket_that_goes_mid_slice_loses_nothing_written_before(
+        self, monkeypatch, how
+    ):
+        async def scenario():
+            from mqtt_tpu.packets import DISCONNECT, ERR_QUOTA_EXCEEDED, Code
+
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            sub, sub_r, _w, sub_task = await rig.join("sub", "t/#", version=5)
+            _other, other_r, *_ = await rig.join("other", "t/#")
+
+            def after(payload):
+                if payload != b"2":
+                    return
+                if how == "closes":
+                    sub.stop()
+                else:
+                    try:
+                        rig.server.disconnect_client(sub, ERR_QUOTA_EXCEEDED)
+                    except Code:
+                        pass
+
+            rig.after_publish = after
+            pubs = [("t/x", b"%d" % i) for i in range(1, 6)]
+            rig.run_slice(pub, pubs)  # raises nothing
+            assert [e for _c, _p, e in rig.hook.processed] == [None] * 5
+            got = [await read_wire_packet(sub_r, 5) for _ in range(2)]
+            assert [bytes(p.payload) for p in got] == [b"1", b"2"]
+            if how == "evicted":
+                bye = await read_wire_packet(sub_r, 5)
+                assert bye.fixed_header.type == DISCONNECT
+                assert bye.reason_code == ERR_QUOTA_EXCEEDED.code
+            assert await asyncio.wait_for(sub_r.read(16), TIMEOUT) == b""
+            assert sub._cork is None
+            # the socket beside it got all five, as one write
+            assert rig.writes("other") == [b"".join(frames(pubs))]
+            await asyncio.wait_for(sub_task, TIMEOUT)
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_a_short_write_at_the_flush_finishes_through_the_transport(
+        self, monkeypatch
+    ):
+        """A joined write larger than the socket's buffer: the transport
+        keeps the rest and finishes it in order; a later slice finds the
+        transport busy and takes the outbound queue behind it."""
+        import socket as socketlib
+
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            sub, sub_r, *_ = await rig.join("sub", "t/#")
+            sock = sub.net.writer.get_extra_info("socket")
+            sock.setsockopt(socketlib.SOL_SOCKET, socketlib.SO_SNDBUF, 4096)
+            first = [(f"t/{i}", bytes([65 + i]) * 3000) for i in range(20)]
+            rig.run_slice(pub, first)
+            assert len(rig.writes("sub")) == 1
+            assert sub.net.writer.transport.get_write_buffer_size() > 0
+            second = [("t/z", b"tail-%d" % i) for i in range(3)]
+            log = rig.run_slice(pub, second)
+            assert [e for e in log if e[0] != "published"] == []  # queued
+            for _t, p in first + second:
+                assert bytes((await read_wire_packet(sub_r)).payload) == p
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_the_byte_cap_flushes_a_cork_early(self, monkeypatch):
+        import mqtt_tpu.clients as clients_mod
+
+        async def scenario():
+            monkeypatch.setattr(clients_mod, "CORK_MAX_BYTES", 300)
+            rig = SliceRig(monkeypatch)
+            pub, *_ = await rig.join("pub")
+            sub, sub_r, *_ = await rig.join("sub", "t/#")
+            pubs = [(f"t/{i}", b"%02d" % i * 32) for i in range(30)]
+            rig.run_slice(pub, pubs)
+            writes = rig.writes("sub")
+            size = len(frames(pubs)[0])
+            assert len(writes) > 3 and b"".join(writes) == b"".join(frames(pubs))
+            assert all(len(w) < 300 + size for w in writes)
+            assert all(len(w) >= 300 for w in writes[:-1])
+            assert sub._cork is None
+            for _t, p in pubs:
+                assert bytes((await read_wire_packet(sub_r)).payload) == p
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_sends_count_sends_and_frames_count_frames(self, monkeypatch):
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            srv = rig.server
+            pub, *_ = await rig.join("pub")
+            sub, *_ = await rig.join("sub", "t/#")
+            once, *_ = await rig.join("once", "t/7")
+            before = (
+                srv._ops.socket_sends, srv.info.packets_sent, srv.info.messages_sent,
+                srv.info.bytes_sent, srv.telemetry.fanout_deliveries.value,
+                sub.state.out_writes, once.state.out_writes,
+                srv._slice_counters()["deliveries"],
+            )
+            pubs = [(f"t/{i}", b"p%02d" % i) for i in range(64)]
+            rig.run_slice(pub, pubs)
+            nbytes = sum(len(f) for f in frames(pubs)) + len(frames([pubs[7]])[0])
+            after = (
+                srv._ops.socket_sends, srv.info.packets_sent, srv.info.messages_sent,
+                srv.info.bytes_sent, srv.telemetry.fanout_deliveries.value,
+                sub.state.out_writes, once.state.out_writes,
+                srv._slice_counters()["deliveries"],
+            )
+            assert [a - b for a, b in zip(after, before)] == [
+                2, 65, 65, nbytes, 65, 64, 1, 65,
+            ]
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_the_slices_flush_is_counted_as_fan_out_time(self, monkeypatch):
+        """While a profiler session keeps the batch, the joined writes at
+        the slice's end add to the fan-out's busy time
+        (``loop_us_per_pub.fanout``) and to no publish's count."""
+
+        class Profiler:
+            def __init__(self):
+                self.fanouts, self.flushes = 0, []
+
+            def note_fanout(self, set_ns, start_ns, done_ns):
+                assert set_ns == 1 and done_ns >= start_ns
+                self.fanouts += 1
+
+            def note_slice_flush(self, busy_ns):
+                self.flushes.append(busy_ns)
+
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            prof = rig.server.profiler = Profiler()
+            pub, *_ = await rig.join("pub")
+            await rig.join("sub", "t/#")
+            rig.server._complete_staged(
+                *rig.slice_of(pub, [("t/1", b"a"), ("q/1", b"b")]), 1
+            )
+            assert (prof.fanouts, prof.flushes) == (2, [])  # nothing corked
+            rig.server._complete_staged(
+                *rig.slice_of(pub, [("t/1", b"a"), ("t/2", b"b")]), 1
+            )
+            assert prof.fanouts == 4 and len(prof.flushes) == 1
+            assert prof.flushes[0] > 0
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    @pytest.mark.parametrize("error", [RuntimeError, KeyboardInterrupt])
+    def test_a_failing_publish_still_flushes_every_open_cork(
+        self, monkeypatch, error
+    ):
+        """An exception in one publish's fan-out is that publish's (the
+        slice goes on); one that nothing may swallow leaves the slice at
+        once. Either way every cork the slice opened is written and
+        closed before ``_complete_staged`` returns."""
+
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            srv = rig.server
+            pub, *_ = await rig.join("pub")
+            a, a_r, *_ = await rig.join("a", "t/#")
+            b, b_r, *_ = await rig.join("b", "t/+")
+            inner = srv._fan_out
+
+            def failing(pk, *args):
+                if bytes(pk.payload) == b"boom":
+                    raise error("fan-out failed")
+                return inner(pk, *args)
+
+            monkeypatch.setattr(srv, "_fan_out", failing)
+            pubs = [("t/1", b"1"), ("t/2", b"2"), ("t/3", b"boom"), ("t/4", b"4")]
+            if error is RuntimeError:
+                rig.run_slice(pub, pubs)
+                want = [pubs[0], pubs[1], pubs[3]]
+            else:
+                with pytest.raises(KeyboardInterrupt):
+                    rig.run_slice(pub, pubs)
+                want = pubs[:2]
+            assert a._cork is None and b._cork is None
+            for cid, r in (("a", a_r), ("b", b_r)):
+                assert rig.writes(cid) == [b"".join(frames(want))]
+                for _t, p in want:
+                    assert bytes((await read_wire_packet(r)).payload) == p
+            await rig.h.shutdown()
+
+        run(scenario())
+
+
+class TestEchoServed:
+    """ISSUE 28's served check: stresser's echo loop over loopback TCP
+    against the plain reference, with and without the loop shard fabric
+    (where a remote shard's sockets are written on their own loop,
+    outside any slice)."""
+
+    @pytest.mark.parametrize("shards", [1, 3])
+    @pytest.mark.parametrize("seed", [28, 4099, 2**31 + 11])
+    def test_every_message_back_in_order_exactly_once(self, seed, shards):
+        n_clients, chunk, rounds = 20, 64, 3
+        total = n_clients * chunk * rounds
+        verdict, delivered, n = run_echo(
+            seed, n_clients, chunk, rounds, loop_shards=shards
+        )
+        assert verdict["errors"] == 0 and verdict["misordered"] == 0, verdict
+        assert delivered == total
+        if shards == 1:
+            # one loop owns every socket: a 64-frame chunk leaves in a few
+            # writes (a count, not a rate), where the parent made 3,840
+            assert n["loops"] == 1 and n["sends"] < total // 8
+        else:
+            assert n["loops"] > 1

@@ -532,10 +532,12 @@ class TestLoopCounters:
         assert prof.fanout_n == 12  # nothing counted after the session
         for key in ("ingest_busy_ns", "fanout_busy_ns", "fanout_wait_ns"):
             assert sl.b[key] - sl.a[key] > 0, key
-        # the broker's own counts ride the snapshots: one delivery and
-        # one socket send a publish here, no fallback held
+        # the broker's own counts ride the snapshots: one delivery a
+        # publish here, no fallback held; one socket send a completion
+        # slice (the subscriber's frames of a slice leave as one write),
+        # so at most one a publish and as a rule one for all twelve
         assert sl.b["deliveries"] - sl.a["deliveries"] == 12
-        assert sl.b["socket_sends"] - sl.a["socket_sends"] == 12
+        assert 1 <= sl.b["socket_sends"] - sl.a["socket_sends"] <= 12
         assert sl.b["order_held"] == sl.a["order_held"] == 0
         roots = [e for e in doc["traceEvents"] if e["name"] == "publish"]
         assert len(roots) == 24
