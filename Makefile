@@ -6,7 +6,7 @@
 SHELL := /bin/bash
 PY ?= python
 
-.PHONY: verify chaos-smoke test lint typecheck c-gate san-gate stage-gate lockgraph loopgraph pipeline-smoke conn-smoke recovery-smoke bench-trend scrape-cluster scrape-devices scenario-smoke scenario-matrix
+.PHONY: verify chaos-smoke test lint typecheck c-gate san-gate lockgraph loopgraph pipeline-smoke conn-smoke recovery-smoke scrape-cluster scrape-devices scenario-smoke scenario-matrix
 
 # static analysis: the repo-specific concurrency/invariant lint pass
 # (tools/brokerlint, README "Static analysis"), the mypy gate over the
@@ -76,17 +76,6 @@ chaos-smoke:
 	  -q -m slow \
 	  -p no:cacheprovider -p no:xdist -p no:randomly
 
-# per-stage regression gate over the checked-in BENCH artifacts
-# (exp/stage_gate.py): fails on a >25% p99 regression in any stage
-stage-gate:
-	$(PY) exp/stage_gate.py
-
-# bench-history trend gate (exp/bench_trend.py): fails when the newest
-# ledger round's headline fell >25% below the median of the prior
-# rounds in the window (BENCH_HISTORY.jsonl, appended by bench.py)
-bench-trend:
-	$(PY) exp/bench_trend.py
-
 # mesh federation scrape gate (exp/scrape_cluster.py): boot a 3-worker
 # tree mesh, drive a cross-worker burst, scrape the root's
 # /metrics/cluster + /healthz, validate the federated exposition and
@@ -125,8 +114,7 @@ conn-smoke:
 # SLO engine's burn-rate objectives. The smoke tier runs in the CI
 # verify job (artifact: exp/artifacts/scenario_lab.json); the full
 # matrix — QoS2 kill -9 exactly-once, will storm, 3-worker federation,
-# live tenant re-key — rides the nightly chaos leg and appends its
-# round to BENCH_HISTORY.jsonl for the bench-trend gate
+# live tenant re-key — rides the nightly chaos leg
 scenario-smoke:
 	env JAX_PLATFORMS=cpu $(PY) exp/scenario_lab.py --smoke
 

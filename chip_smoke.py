@@ -4,8 +4,8 @@
 The quickest proof that the system still starts on the chip. ONE process:
 
 1. **Served leg.** BASELINE.json config 2 (1,000,000 subscriptions,
-   3-level topics, 10% ``+``; the stream ``bench.cfg2_subscriptions``
-   draws from ``--seed``) is loaded into a
+   3-level topics, 10% ``+``; the stream ``cfg2_subscriptions`` draws
+   from ``--seed``) is loaded into a
    ``Server(Options(device_matcher=True))`` with default options and a
    TCP listener on ``127.0.0.1:0``, by a route a user of the broker has:
    ``topics.subscribe_bulk`` (through the durable restore's own
@@ -25,7 +25,7 @@ The quickest proof that the system still starts on the chip. ONE process:
    says (not what the live trie says), through the scenario lab's
    delivery oracle; >= 1024 sampled topics agree device vs
    ``TopicsIndex.subscribers`` on the full Subscribers set
-   (``bench.canon``); the breaker, the staging fallbacks, the overlay
+   (``canon``); the breaker, the staging fallbacks, the overlay
    and the rebuild thread are all quiet; >= 90% of publishes resolved
    from device results; nothing compiled in the second half of the
    publishes; both native modules are loaded; HBM in use covers the
@@ -60,7 +60,6 @@ import warnings
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from bench import build_cfg2, canon, cfg2_subscriptions, cfg2_topic  # noqa: E402
 from mqtt_tpu.packets import (  # noqa: E402
     CONNACK,
     CONNECT,
@@ -84,6 +83,53 @@ ROLLCALL_BATCH = 4096  # Options.matcher_stage_max_batch
 DEVICE_SHARE_MIN = 0.90
 WAIT_S = 300.0  # any single wait on the broker
 HARD_TIMEOUT_S = 1150  # the contract's 1200 s, minus room to say why
+
+
+def canon(s):
+    """Order-free digest of a Subscribers set for parity checks."""
+    return (
+        {c: (sub.qos, tuple(sorted(sub.identifiers.items()))) for c, sub in s.subscriptions.items()},
+        {f: set(m) for f, m in s.shared.items()},
+        set(s.inline_subscriptions),
+    )
+
+
+_CFG2_VOCAB = tuple(
+    [f"{name}{i}" for i in range(100)] for name in ("region", "device", "metric")
+)
+
+
+def cfg2_subscriptions(n_subs, rng):
+    """BASELINE.json config 2's subscription stream as ``(client, filter,
+    qos)``: 3-level filters over a 100^3 vocabulary, 10% with one level
+    replaced by ``+``. The served leg loads this stream into a broker, so
+    the draw order is part of the deployment."""
+    v0, v1, v2 = _CFG2_VOCAB
+    for i in range(n_subs):
+        parts = [rng.choice(v0), rng.choice(v1), rng.choice(v2)]
+        if rng.random() < 0.10:
+            parts[rng.randrange(3)] = "+"
+        yield f"cl{i}", "/".join(parts), i % 3
+
+
+def cfg2_topic(rng) -> str:
+    """One publish topic on config 2's (uniform) topic distribution."""
+    v0, v1, v2 = _CFG2_VOCAB
+    return f"{rng.choice(v0)}/{rng.choice(v1)}/{rng.choice(v2)}"
+
+
+def build_cfg2(n_subs, rng):
+    """3-level topics, 10% single-level + wildcards (north star)."""
+    from mqtt_tpu.topics import TopicsIndex
+
+    index = TopicsIndex()
+    for client, flt, qos in cfg2_subscriptions(n_subs, rng):
+        index.subscribe(client, Subscription(filter=flt, qos=qos))
+
+    def topic_gen():
+        return cfg2_topic(rng)
+
+    return index, topic_gen
 
 
 def filter_matches(flt: tuple, topic: tuple) -> bool:
@@ -603,7 +649,7 @@ class Smoke:
         return detail
 
     def _rc_predicates(self) -> dict:
-        """rules_eval at bench cfg9's shape (one distinct rule per
+        """rules_eval at the predicate plane's shape (one distinct rule per
         predicated subscription, scaled with --subs) vs
         ``eval_rule_host``; agg_reduce vs ``host_reduce_window``."""
         import numpy as np

@@ -4,29 +4,16 @@
 Executes the declarative workload/fault scenarios in
 ``mqtt_tpu/scenarios.py`` — each one a seeded fleet + traffic mix +
 fault script judged by a delivery oracle AND the SLO engine's
-burn-rate objectives — and writes the machine-readable verdicts the
-rest of the repo's gating already consumes:
-
-- a JSON artifact (``--out``, default ``exp/artifacts/scenario_lab.json``)
-  with the full per-scenario result docs (oracle counts, SLO objective
-  states, driver metrics, wall time, seed) for CI upload;
-- a ``BENCH_HISTORY.jsonl`` entry (via ``bench.append_history`` — the
-  ONE ledger schema) whose headline is the matrix's aggregate delivery
-  rate under its own metric name, so ``exp/bench_trend.py`` trends
-  scenario rounds against scenario rounds and bench rounds against
-  bench rounds without cross-contamination. Per-scenario scalar blocks
-  land under ``configs["scenario_<name>"]`` where the trend gate's
-  CONFIG_SCALARS rows watch them.
-
-History appends only for the canonical selections (``--smoke`` /
-``--all``): an ad-hoc named run or a ``--seed`` override is not a
-comparable round and must not enter the trend window.
+burn-rate objectives — and writes a JSON artifact (``--out``, default
+``exp/artifacts/scenario_lab.json``) with the full per-scenario result
+docs (oracle counts, SLO objective states, driver metrics, wall time,
+seed) for CI upload: the lab's one record.
 
 Usage:
     python exp/scenario_lab.py --smoke            # CI verify-job gate
     python exp/scenario_lab.py --all              # nightly full matrix
     python exp/scenario_lab.py tenant_rekey       # one scenario, ad hoc
-    python exp/scenario_lab.py --all --seed 7     # reseeded (no ledger)
+    python exp/scenario_lab.py --all --seed 7     # reseeded
 Exit code is non-zero when any selected scenario fails its oracle or
 breaches an SLO objective.
 """
@@ -42,47 +29,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
 from mqtt_tpu.scenarios import SCENARIOS, run_matrix, scenario_names  # noqa: E402
-
-
-def _config_block(res: dict) -> dict:
-    """The per-scenario scalar slice kept in the history ledger: oracle
-    counts, pass bit, wall time, throughput, plus every numeric the
-    driver reported (bench.py's ``_history_config_block`` drops
-    non-scalars on append, so richer values are safe to include)."""
-    oracle = res.get("oracle") or {}
-    wall = res.get("wall_s") or 0.0
-    delivered = oracle.get("delivered", 0)
-    block: dict = {
-        "passed": bool(res.get("passed")),
-        "expected": oracle.get("expected", 0),
-        "delivered": delivered,
-        "gaps": oracle.get("gaps", 0),
-        "duplicates": oracle.get("duplicates", 0),
-        "faults": oracle.get("faults", 0),
-        "wall_s": wall,
-        "deliveries_per_sec": (delivered / wall) if wall > 0 else 0,
-        "seed": res.get("seed"),
-    }
-    for k, v in (res.get("metrics") or {}).items():
-        if isinstance(v, (int, float, bool)) and k not in block:
-            block[k] = v
-    return block
-
-
-def _history_doc(results: list[dict], selection: str) -> dict:
-    """A bench-document-shaped dict for ``bench.append_history``: the
-    headline is the matrix aggregate delivery rate, named per selection
-    (smoke vs full matrices are different workloads — bench_trend's
-    same-metric rule keeps their trend lines separate)."""
-    delivered = sum((r.get("oracle") or {}).get("delivered", 0) for r in results)
-    wall = sum(r.get("wall_s") or 0.0 for r in results)
-    return {
-        "metric": f"scenario_deliveries_per_sec@{selection}",
-        "value": round(delivered / wall, 1) if wall > 0 else None,
-        "configs": {
-            f"scenario_{r['scenario']}": _config_block(r) for r in results
-        },
-    }
 
 
 def main() -> int:
@@ -104,17 +50,12 @@ def main() -> int:
         "--seed",
         type=int,
         default=None,
-        help="override every spec's seed (disables the history append)",
+        help="override every spec's seed",
     )
     ap.add_argument(
         "--out",
         default=os.path.join(_REPO, "exp", "artifacts", "scenario_lab.json"),
         help="artifact path for the full result docs",
-    )
-    ap.add_argument(
-        "--no-history",
-        action="store_true",
-        help="skip the BENCH_HISTORY.jsonl append even for canonical runs",
     )
     ap.add_argument("--list", action="store_true", help="list scenarios")
     args = ap.parse_args()
@@ -164,16 +105,6 @@ def main() -> int:
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(artifact, f, indent=2, default=str)
     print(f"scenario-lab: artifact written to {args.out}")
-
-    # failed rounds never enter the ledger: a red matrix's delivery
-    # rate is not a comparable baseline, and CI already fails on rc=1
-    canonical = (
-        selection in ("smoke", "full") and args.seed is None and not failed
-    )
-    if canonical and not args.no_history:
-        from bench import append_history
-
-        append_history(_history_doc(results, selection))
 
     if failed:
         print(f"scenario-lab: FAILED: {', '.join(failed)}")

@@ -95,8 +95,11 @@ async def main(out_path: str) -> int:
             print("FAIL: clients lock saw no acquisitions", file=sys.stderr)
             return 1
 
-        block = srv.host_profile_block()
-        amp = block.get("fanout", {}).get("delivery_amplification")
+        amp = round(
+            srv.telemetry.fanout_deliveries.value
+            / max(1, srv.info.messages_received),
+            4,
+        )
         with open(out_path, "w") as f:
             f.write(collapsed)
         print(

@@ -162,12 +162,8 @@ def from_bytes(b: bytes) -> Optional[Options]:
         # overlapped staging + device-resident hit compaction
         # (mqtt_tpu.staging + ops/flat.flat_match_compact)
         "matcher_stage_pipeline_depth",
-        "matcher_compact",
         "matcher_compact_capacity",
-        # zero-materialization fan-out + encode-once write path
-        # (ISSUE 13) and read-side decode batching
-        "matcher_lazy_views",
-        "fanout_batch",
+        # read-side decode batching
         "scan_coalesce",
         # event-loop shard fabric (mqtt_tpu.shards / ISSUE 15)
         "loop_shards",

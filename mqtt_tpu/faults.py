@@ -42,9 +42,8 @@ behave like that hardware — reproducibly, from one seed:
   topic/payload/qos sequence, deterministic from the seed) plus
   :func:`drive_storm`, the async driver that blasts the schedule through
   raw writers at an offered load far above sustainable. The chaos suite
-  (tests/test_overload.py) and the bench's storm scenario (bench.py)
-  both replay the same plans against the overload governor
-  (mqtt_tpu.overload).
+  (tests/test_overload.py) and ``stress.run_storm`` both replay the
+  same plans against the overload governor (mqtt_tpu.overload).
 
 - Durable-store crash plans — :class:`StorageCrashPlan` kills a
   :class:`~mqtt_tpu.hooks.storage.logkv.LogKVStore` at a seeded append

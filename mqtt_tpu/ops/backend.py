@@ -7,7 +7,7 @@ one place:
 
 - :func:`ensure_compile_cache` places JAX's persistent compilation cache.
   Every jit entry point reaches it (``ops/flat._LazyJit`` and the two
-  ``jax.jit`` sites in ``parallel/sharded.py``), so bench.py,
+  ``jax.jit`` sites in ``parallel/sharded.py``), so ``benchmark/run.py``,
   chip_smoke.py and the ``exp/`` gates get the cache through the package
   and set none of their own.
 - :func:`device_summary` names the device results were produced on; every

@@ -151,13 +151,11 @@ class DeltaMatcher:
         self,
         topics: TopicsIndex,
         max_levels: int = 8,
-        frontier: int = 16,
         out_slots: int = 64,
         rebuild_after: int = 1024,
         rebuild_interval: float = 1.0,
         background: bool = True,
         mesh=None,
-        transfer_slots: Optional[int] = None,
         window: int = 16,
         compact: bool = True,
         compact_capacity: int = 0,
@@ -166,7 +164,6 @@ class DeltaMatcher:
     ) -> None:
         self.topics = topics
         self.max_levels = max_levels
-        self.frontier = frontier
         self.out_slots = out_slots
         self.window = window
         self.rebuild_after = rebuild_after
@@ -198,10 +195,8 @@ class DeltaMatcher:
         else:
             snap = _Snapshot(
                 topics,
-                max_levels,
-                frontier,
-                out_slots,
-                transfer_slots=transfer_slots,
+                max_levels=max_levels,
+                out_slots=out_slots,
                 window=window,
                 # background rebuilds must not starve the serving thread's
                 # match latency for the build duration (churn p99)

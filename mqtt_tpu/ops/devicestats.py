@@ -70,8 +70,7 @@ def _sig_of(args: tuple, kwargs: dict) -> tuple:
         if shp is not None:
             # the dtype OBJECT, not str(dtype): numpy/jax dtypes hash and
             # compare by identity semantics, and their __str__ costs ~4us
-            # per array — 20x the rest of the probe (bench cfg 2's
-            # sig_probe_ns_per_dispatch watches this)
+            # per array — 20x the rest of the probe
             key.append((tuple(shp), getattr(a, "dtype", None)))
         else:
             key.append(a if isinstance(a, (int, float, bool, str, type(None))) else type(a).__name__)
@@ -254,21 +253,6 @@ class CompileLedger:
 
 LEDGER = CompileLedger()
 
-# A/B switch for the bench overhead block: with the watch disabled the
-# wrapped kernels skip signature computation entirely (the exact
-# sampled-path cost the <=2% acceptance bound covers)
-_ENABLED = True
-
-
-def set_watch_enabled(enabled: bool) -> None:
-    global _ENABLED
-    _ENABLED = bool(enabled)
-
-
-def watch_enabled() -> bool:
-    return _ENABLED
-
-
 class KernelWatch:
     """Wrap a jitted callable; time the first call per new signature and
     note it as a compile event. The steady-state cost is one signature
@@ -284,8 +268,6 @@ class KernelWatch:
         self._lock = threading.Lock()  # anonymous: guards _seen only, never calls out
 
     def __call__(self, *args, **kwargs):
-        if not _ENABLED:
-            return self.fn(*args, **kwargs)
         key = _sig_of(args, kwargs)
         if key in self._seen:
             return self.fn(*args, **kwargs)

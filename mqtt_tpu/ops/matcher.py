@@ -402,23 +402,18 @@ class MatcherStats:
 class TpuMatcher:
     """Broker-facing device matcher over the flat-hash index.
 
-    ``frontier`` is accepted for API continuity with the retired NFA
-    kernel and ignored — the flat matcher has no frontier; wildcard-shape
-    fan-out is a build-time property of the filter set (ops/flat.py).
-    ``out_slots`` caps the per-topic device result on the slot-expanding
-    core (the mesh-sharded form); ``window`` caps ids per filter path.
-    ``transfer_slots`` is accepted for API continuity and unused: the
-    production packed path transfers per-probe RANGES, which carry the
-    complete result in 2P ints per topic.
+    Wildcard-shape fan-out is a build-time property of the filter set
+    (ops/flat.py). ``out_slots`` caps the per-topic device result on the
+    slot-expanding core (the mesh-sharded form); ``window`` caps ids per
+    filter path. The packed path transfers per-probe RANGES, which carry
+    the complete result in 2P+2 ints per topic.
     """
 
     def __init__(
         self,
         topics: TopicsIndex,
         max_levels: int = 8,
-        frontier: int = 16,  # ignored (flat matcher); kept for API compat
         out_slots: int = 64,
-        transfer_slots: Optional[int] = None,
         window: int = 16,
         cooperative: bool = False,
         compact: bool = True,
@@ -428,15 +423,11 @@ class TpuMatcher:
     ) -> None:
         self.topics = topics
         self.max_levels = max_levels
-        self.frontier = frontier
         self.out_slots = out_slots
         self.window = window
         # cooperative rebuilds yield the GIL periodically — set by owners
         # that rebuild on a background thread while another thread serves
         self.cooperative = cooperative
-        # retired knob (kept for API continuity): the packed transfer is
-        # per-probe ranges — complete results at 2P+2 ints/topic
-        self.transfer_slots = min(transfer_slots or out_slots, out_slots)
         # device-resident hit compaction (ROADMAP item 1): results come
         # back as packed (topic_idx, sid) pairs sized for the hits that
         # exist. compact_capacity pins the pair buffer (0 = adaptive from
@@ -458,7 +449,7 @@ class TpuMatcher:
         self._caps: dict[int, int] = {}
         self.stats = MatcherStats()
         # device pipeline profiler (mqtt_tpu.tracing.DeviceProfiler) or
-        # None; set by the server (or bench.py). match_topics_async
+        # None; set by the server. match_topics_async
         # feeds it the dispatch window, the resolver the D2H sync —
         # duty cycle / overlap / idle-gap accounting lives there.
         self.profiler: Optional[Any] = None
@@ -630,7 +621,7 @@ class TpuMatcher:
         ``perf_counter_ns`` read and the profiler's windows are fed from
         the same reads. While a profiler session keeps the record the
         spans are also ``TraceAnnotation`` blocks. When the profiler is
-        attached but no record is passed (bench, resilience probes), a
+        attached but no record is passed (resilience probes), a
         private one is opened so the aggregates still see the batch.
         """
         import jax.numpy as jnp
@@ -703,12 +694,6 @@ class TpuMatcher:
         else:
             pred = route_to_host
             batch_pred = None
-        # the pre-compaction transfer geometries, stamped per batch so the
-        # bench's device_pipeline block reports the measured reduction:
-        # ranges = the previous production path ([B, 2P+2] ints), dense =
-        # the classic padded slot buffer ([B, out_slots] ints)
-        bytes_ranges = len(padded) * (2 * P + 2) * 4
-        bytes_dense = len(padded) * self.out_slots * 4
 
         if not use_compact:
 
@@ -719,7 +704,7 @@ class TpuMatcher:
                 if prof is not None:
                     # the blocking D2H sync just completed: close the
                     # in-flight window (kernel + transfer) on this record
-                    self._stamp_bytes(rec, packed.nbytes, bytes_ranges, bytes_dense, False)
+                    self._stamp_bytes(rec, packed.nbytes, False)
                     self._note_sync(prof, rec)
                 with span(rec, "resolve"):
                     stats = self.stats
@@ -774,14 +759,14 @@ class TpuMatcher:
                 if prof is not None:
                     # the re-run (dispatch + second sync) is part of this
                     # batch's resolve span, not of its in-flight window
-                    self._stamp_bytes(rec, d2h_bytes, bytes_ranges, bytes_dense, True, overflow=True)
+                    self._stamp_bytes(rec, d2h_bytes, True)
                     self._note_sync(prof, rec)
                 return self._resolve_ranges(
                     packed[: len(topics)], topics, flat, P,
                     len_overflow[: len(topics)], pred, batch_pred,
                 )
             if prof is not None:
-                self._stamp_bytes(rec, int(out.nbytes), bytes_ranges, bytes_dense, True)
+                self._stamp_bytes(rec, int(out.nbytes), True)
                 self._note_sync(prof, rec)
             stats.compact_batches += 1
             stats.d2h_bytes += int(out.nbytes)
@@ -843,20 +828,14 @@ class TpuMatcher:
         prof.note_resolve(rec, t0_ns / 1e9, t1_ns / 1e9)
 
     @staticmethod
-    def _stamp_bytes(
-        rec, d2h_bytes: int, bytes_ranges: int, bytes_dense: int,
-        compact: bool, overflow: bool = False,
-    ) -> None:
+    def _stamp_bytes(rec, d2h_bytes: int, compact: bool) -> None:
         """Stamp one batch's transfer accounting onto its BatchProfile
         (mqtt_tpu.tracing) — the device profiler folds these into the
-        bench device_pipeline block's reduction ratios."""
+        per-device D2H byte totals and the compact-sync histogram."""
         if rec is None:
             return
         rec.d2h_bytes = d2h_bytes
-        rec.d2h_bytes_ranges = bytes_ranges
-        rec.d2h_bytes_dense = bytes_dense
         rec.compact = compact
-        rec.compact_overflow = overflow
 
     def _materialize_pairs(
         self,

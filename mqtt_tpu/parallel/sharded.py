@@ -145,8 +145,6 @@ class ShardedTpuMatcher:
     With ``incremental=True`` (default) the matcher subscribes to the
     trie's mutation stream and ``rebuild()`` recompiles only the shards
     whose subscriptions changed; call :meth:`close` to detach the observer.
-    ``frontier`` is accepted for API continuity and ignored (the flat
-    matcher has no frontier).
     """
 
     # rebuild() retries torn walks and quiesces internally — callers (the
@@ -159,7 +157,6 @@ class ShardedTpuMatcher:
         topics: TopicsIndex,
         mesh: Optional[Mesh] = None,
         max_levels: int = 8,
-        frontier: int = 16,  # ignored (flat matcher); kept for API compat
         out_slots: int = 64,
         window: int = 16,
         incremental: bool = True,
@@ -171,7 +168,6 @@ class ShardedTpuMatcher:
         self.topics = topics
         self.mesh = mesh or make_mesh()
         self.max_levels = max_levels
-        self.frontier = frontier
         self.out_slots = out_slots
         self.window = window
         self.n_shards = self.mesh.shape["subs"]
@@ -198,7 +194,7 @@ class ShardedTpuMatcher:
         # device pipeline profiler (mqtt_tpu.tracing.DeviceProfiler) or
         # None; same seam as TpuMatcher.profiler (ops/matcher.py) — the
         # SPMD step's dispatch and D2H windows feed duty-cycle/overlap/
-        # idle-gap accounting when the server (or bench) attaches one
+        # idle-gap accounting when the server attaches one
         self.profiler = None
         # one (arrays, tables, salt, step) tuple swapped atomically so a
         # concurrent match never mixes generations
@@ -745,9 +741,6 @@ class ShardedTpuMatcher:
         # the delta overlay object exposing .affected
         if route_to_host is not None and hasattr(route_to_host, "affected"):
             route_to_host = route_to_host.affected
-        # the pre-compaction transfer geometry: the full gathered slot
-        # buffer — what the resolver synced before this PR
-        bytes_padded = self.n_shards * bp * self.out_slots * 4
 
         def resolve_full(t_sync0: float) -> list[Subscribers]:
             # brokerlint: ok=R15 the blessed resolve seam: one D2H per array after copy_to_host_async, [S, B, K]
@@ -757,8 +750,6 @@ class ShardedTpuMatcher:
             self.stats.d2h_bytes += int(out.nbytes)
             if prof is not None:
                 rec.d2h_bytes += int(out.nbytes)
-                rec.d2h_bytes_ranges += int(out.nbytes)
-                rec.d2h_bytes_dense += bytes_padded
                 prof.note_resolve(rec, t_sync0, time.perf_counter())
             results = []
             stats = self.stats
@@ -814,15 +805,12 @@ class ShardedTpuMatcher:
                 stats.d2h_bytes += int(rows.nbytes)
                 if rec is not None:
                     rec.compact = True
-                    rec.compact_overflow = True
                     rec.d2h_bytes = int(rows.nbytes)
                 return resolve_full(t_sync0)
             stats.compact_batches += 1
             stats.d2h_bytes += int(rows.nbytes)
             if prof is not None:
                 rec.d2h_bytes = int(rows.nbytes)
-                rec.d2h_bytes_ranges = bytes_padded
-                rec.d2h_bytes_dense = bytes_padded
                 rec.compact = True
                 prof.note_resolve(rec, t_sync0, time.perf_counter())
             # stitch the per-tile streams back into one topic-major batch

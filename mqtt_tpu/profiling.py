@@ -74,8 +74,8 @@ class SamplingProfiler:
     broker lock plane: the profiler must observe contention, not add
     to it.
 
-    ``sample_once()`` is the deterministic seam — tests (and the bench
-    overhead probe) drive sweeps directly, with an injectable
+    ``sample_once()`` is the deterministic seam — tests drive sweeps
+    directly, with an injectable
     ``frames_fn``/``clock``, so collapsed output for a known thread
     workload is reproducible without racing a timer thread.
     """
@@ -199,7 +199,7 @@ class SamplingProfiler:
             if tid == own:
                 # never profile the sweeping thread: on the timer thread
                 # that is the sampler observing itself; a direct
-                # sample_once() caller (tests, bench probe) is likewise
+                # sample_once() caller (tests) is likewise
                 # measurement machinery, not broker work
                 continue
             stack: list[str] = []
@@ -314,34 +314,6 @@ class SamplingProfiler:
             close_from(tid, 0, last_t + period)
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
-    def top_stacks(self, k: int = 5) -> list[tuple[str, int]]:
-        """The k hottest collapsed stacks (bench/test convenience)."""
-        with self._mutex:
-            items = sorted(self._agg.items(), key=lambda kv: -kv[1])[:k]
-        return [
-            (";".join((tname,) + stack), count)
-            for (tname, stack), count in items
-        ]
-
-    def bench_block(self) -> dict:
-        """The BENCH-json host-profile block."""
-        top = self.top_stacks(3)
-        return {
-            "samples": self.samples,
-            "thread_samples": self.thread_samples,
-            "threads_live": self.last_thread_count,
-            "distinct_stacks": len(self._agg),
-            "dropped_stacks": self.dropped_stacks,
-            "sweep_p99_ms": (
-                round(self.sweep_hist.percentile(0.99) * 1e3, 3)
-                if self.sweep_hist is not None and self.sweep_hist.count
-                else None
-            ),
-            "top_stacks": [
-                {"stack": s[-160:], "count": c} for s, c in top
-            ],
-        }
-
 
 _COLLAPSED_RE = re.compile(r"^\S.* [0-9]+$")
 
@@ -442,13 +414,3 @@ class TopicSketch:
             if self.admissions == 0:
                 return 0.0
             return self.total / self.admissions
-
-    def bench_block(self, top_n: int = 5) -> dict:
-        return {
-            "observed": self.total,
-            "tracked": self.tracked,
-            "admissions": self.admissions,
-            "evictions": self.evictions,
-            "avg_hits_per_topic": round(self.avg_hits_per_topic(), 3),
-            "top_topics": self.top(top_n),
-        }

@@ -5,8 +5,8 @@ Every scenario is a declarative :class:`ScenarioSpec` — fleet shape,
 traffic mix, seeded fault script, SLO objectives, pass/fail oracles —
 executed by one runner that composes the machinery the repo already has:
 
-- the broker itself boots in-process on a real TCP listener (the
-  bench.py idiom, port 0 so parallel runs never collide);
+- the broker itself boots in-process on a real TCP listener (port 0,
+  so parallel runs never collide);
 - traffic drives through wire-true MQTT clients (:class:`ScenarioClient`
   speaks the full QoS0/1/2 state machine, wills, v5 properties);
 - faults come from mqtt_tpu.faults (seeded storms, ``drop_fleet`` mass
@@ -15,10 +15,7 @@ executed by one runner that composes the machinery the repo already has:
   the scenario's own delivery-oracle counters
   (``mqtt_tpu_scenario_*_total``), and the verdict is "no objective
   breached" — the same alerting math production runs, pointed at a
-  reproducible drill;
-- results append to ``BENCH_HISTORY.jsonl`` via exp/scenario_lab.py so
-  a regressing scenario trips exp/bench_trend.py in CI like a bench
-  regression would.
+  reproducible drill.
 
 Determinism: every scenario runs from its spec seed (``run_scenario``
 accepts an override) — fault victims, payload padding, and key material
@@ -597,8 +594,7 @@ async def _drive_payload_sweep(run: ScenarioRun) -> None:
     encode-once plaintext fan-out and the per-subscriber recrypt path
     (client-side sealed publishes re-keyed to each subscriber). On the
     CPU backend the keystream serves from the vectorized host path
-    (``recrypt_device_min_blocks`` pushed high, the bench.py default
-    off-accelerator)."""
+    (``recrypt_device_min_blocks`` pushed high)."""
     from .server import Options
 
     p = run.spec.params

@@ -173,7 +173,7 @@ class Options:
     # are bit-identical, the index lives on the TPU (SURVEY.md north star)
     device_matcher: bool = False
     # kwargs forwarded to DeltaMatcher (max_levels, out_slots, window,
-    # transfer_slots, rebuild_after, rebuild_interval, mesh, ...)
+    # rebuild_after, rebuild_interval, mesh, compact, lazy, ...)
     matcher_opts: Optional[dict] = None
     # publish staging loop (mqtt_tpu.staging): accumulation window and batch
     # cap for device match batches; pipeline depth for in-flight batches
@@ -188,31 +188,10 @@ class Options:
     # (ROADMAP item 1); <= 0 falls back to matcher_stage_max_inflight
     matcher_stage_pipeline_depth: int = 3
     # device-resident hit compaction (ops/flat.flat_match_compact):
-    # match results transfer as packed (topic_idx, sid) pairs sized for
-    # the hits that exist; a batch whose hits outgrow the pair buffer
-    # falls back to the padded path for that batch only
-    matcher_compact: bool = True
     # pinned pair-buffer capacity; 0 = adaptive from the observed
     # hits-per-topic EWMA (seeded by the TopicSketch's avg_hits_per_topic
     # when the host observatory is on)
     matcher_compact_capacity: int = 0
-    # zero-materialization fan-out (ISSUE 13): device match results stay
-    # lazy SubscribersView objects over the compacted pair stream /
-    # ranges rows (native/accelmod.c); fan-out consumes (client, sub)
-    # targets straight off the view and per-hit objects come from a
-    # bounded freelist pool. Consumers needing dict semantics
-    # (predicates, shared groups, the resilience differential)
-    # transparently materialize — bit-identical to the eager path, which
-    # stays on as the differential oracle. No C toolchain = eager.
-    matcher_lazy_views: bool = True
-    # encode-once batched fan-out (ISSUE 13 / ROADMAP item 3): group
-    # fan-out targets by (protocol version, effective QoS, retain)
-    # variant, encode each variant's wire frame ONCE, patch per-target
-    # packet ids in a C writev-style flush that releases the GIL across
-    # the delivery batch (per-socket backpressure, slow-consumer
-    # eviction and overload accounting all preserved). False = the
-    # per-subscriber encode path everywhere.
-    fanout_batch: bool = True
     # read-side decode batching: coalesce frame scans from read loops
     # that wake in the same event-loop tick into one native multi-buffer
     # scan call. Opt-in: it adds one loop-callback hop per socket read,
@@ -516,8 +495,7 @@ class Options:
     # frame flushed, riding the sampled stage clocks — the unsampled
     # hot path pays nothing, the sampled path one dict probe) and the
     # multi-window burn-rate engine over declared objectives. Default
-    # on; False disables SLI stamping AND the engine (the bench A/B
-    # arm).
+    # on; False disables SLI stamping AND the engine.
     slo: bool = True
     # declarative objectives, e.g. ["p99 delivery < 50ms over 5m",
     # "shed ratio < 0.1%"] — grammar in mqtt_tpu.slo; unparseable lines
@@ -783,48 +761,6 @@ def publish_frame_topic(frame: bytes):
         return None
 
 
-class _FrameCache:
-    """One-encode-per-publish outbound frames for the QoS0 fan-out fast
-    path: every eligible subscriber of a publish shares the same wire
-    bytes, keyed by (protocol version, effective retain flag). The copy
-    drops inbound topic aliases exactly like the per-subscriber slow path
-    ([MQTT-3.3.2-7] via ``Packet.copy``)."""
-
-    __slots__ = ("pk", "frames", "telemetry")
-
-    def __init__(self, pk: "Packet", telemetry: Optional[Any] = None) -> None:
-        self.pk = pk
-        self.frames: dict[tuple[int, bool], bytes] = {}
-        self.telemetry = telemetry
-
-    def get(self, version: int, retain: bool) -> bytes:
-        key = (version, bool(retain))
-        data = self.frames.get(key)
-        if data is None:
-            # a real encode (cache hits share the bytes): fan-out
-            # amplification accounting counts exactly these
-            if self.telemetry is not None:
-                self.telemetry.publish_encodes.inc()
-            out = self.pk.copy(False)
-            out.fixed_header.retain = bool(retain)
-            out.protocol_version = version
-            if out.expiry > 0:
-                # the send-time expiry rewrite [MQTT-3.3.2-6], computed once
-                # per publish instead of per subscriber write (the queue
-                # drains within the same tick)
-                out.properties.message_expiry_interval = max(
-                    1, out.expiry - int(time.time())  # brokerlint: ok=R3 message expiry is an absolute wall-clock stamp
-                )
-            buf = get_buffer()
-            try:
-                pkts.ENCODERS[pkts.PUBLISH](out, buf)
-                data = bytes(buf)
-            finally:
-                put_buffer(buf)
-            self.frames[key] = data
-        return data
-
-
 class _Ops:
     """Server values propagated to clients (server.go:159-164).
     ``fast_publish`` is the server's QoS0 frame-passthrough entry point
@@ -883,9 +819,6 @@ class Server:
         self._ops.fast_publish_eligible = self.fast_publish_eligible
         self._fastpub_gate_gen = -1  # hooks generation the gate was cached at
         self._fastpub_gate_ok = False
-        # encode-once batched fan-out (ISSUE 13): variant grouping + the
-        # GIL-released native flush; False = legacy per-subscriber path
-        self._fanout_batch = opts.fanout_batch
         if opts.scan_coalesce:
             # read-side decode batching: frame scans from read loops that
             # wake in the same event-loop tick coalesce into one native
@@ -1122,15 +1055,11 @@ class Server:
         if opts.device_matcher:
             from .ops.delta import DeltaMatcher
 
-            # compaction knobs ride beside matcher_opts (which wins on
-            # conflict); the hits-per-topic capacity seed comes from the
-            # TopicSketch when the host observatory is on (its EWMA then
-            # keeps learning from every compacted batch)
-            mopts: dict = {
-                "compact": opts.matcher_compact,
-                "compact_capacity": opts.matcher_compact_capacity,
-                "lazy": opts.matcher_lazy_views,
-            }
+            # the pair-buffer capacity rides beside matcher_opts (which
+            # wins on conflict); the hits-per-topic capacity seed comes
+            # from the TopicSketch when the host observatory is on (its
+            # EWMA then keeps learning from every compacted batch)
+            mopts: dict = {"compact_capacity": opts.matcher_compact_capacity}
             if self.topic_sketch is not None:
                 mopts["hits_estimate"] = max(
                     2.0, self.topic_sketch.avg_hits_per_topic()
@@ -1940,25 +1869,6 @@ class Server:
             except Exception:  # pragma: no cover  # brokerlint: ok=R4 best-effort dump context; the flight dump itself still fires
                 pass
             self.telemetry.trigger_dump("overload_shed", extra)
-
-    def host_profile_block(self) -> dict:
-        """The BENCH-json host-profile block: profiler aggregates, the
-        topic sketch, the fan-out amplification numbers, and the top-3
-        contended locks — config 8's artifact fields (the ROADMAP item 3
-        success criteria, measured per round)."""
-        out: dict = {}
-        if self.host_profiler is not None:
-            out["profiler"] = self.host_profiler.bench_block()
-        if self.topic_sketch is not None:
-            out["topics"] = self.topic_sketch.bench_block()
-        if self.telemetry is not None:
-            out["fanout"] = self.telemetry.fanout_block(
-                self.info.messages_received
-            )
-            plane = self.telemetry.lock_plane
-            if plane is not None:
-                out["top_contended_locks"] = plane.top_contended(3)
-        return out
 
     # -- overload control plane (mqtt_tpu.overload) ------------------------
 
@@ -3309,10 +3219,11 @@ class Server:
     def _shared_frame_ok(props: "ClientProperties", sub: Subscription) -> bool:
         """Target eligibility for shared-frame delivery (nothing forces a
         per-subscriber rewrite of the encoded publish): no positive
-        subscription identifiers, no outbound aliasing, no size cap.
+        subscription identifiers (zero-valued ones never reach the wire:
+        properties.py encodes only v > 0), no outbound aliasing, no size
+        cap.
 
-        Used verbatim by publish_to_client's frame-cache branch and by
-        BOTH batched fan-out paths (_fan_out_batched's variant/slow
+        Used verbatim by BOTH batched fan-out paths (_fan_out_batched's variant/slow
         split and _fan_out_encrypted_batched's shareable gate).
         try_fast_publish intentionally SPLITS the same predicate: the
         subscription half (identifiers) is precomputed into the cached
@@ -3583,6 +3494,14 @@ class Server:
         whether a hook provides ON_PACKET_ENCODE / ON_PACKET_SENT
         (default: asked here).
 
+        Which path serves, decided by what the code sees, never by an
+        option: the encode-once batched flush (``_fan_out_batched``)
+        unless a hook provides ON_PACKET_ENCODE / ON_PACKET_SENT, which
+        must see every subscriber's own packet and so takes the
+        per-subscriber loop; lazy views when the C module is present
+        and no dict-semantics consumer is ahead (below); compact or
+        packed device results by ``TpuMatcher._compact_pays``.
+
         MQTT+ predicate filtering happens here — the one choke point
         every delivery path funnels through (staged fan-out, the host
         sync path, cluster-forwarded decode deliveries). ``feats`` is
@@ -3668,33 +3587,19 @@ class Server:
                 if targets is not None
                 else subscribers.subscriptions.items()
             )
-            if self._fanout_batch and not observed:
+            if not observed:
                 # encode-once variant-grouped delivery with the batched
                 # GIL-released flush (ISSUE 13 / ROADMAP item 3)
                 self._fan_out_batched(pk, dpk, items, lookup)
             else:
-                # legacy path (hooks that observe encodes/sends, or the
-                # batching knob off): QoS0 still shares frames through
-                # the per-publish cache; QoS>0 re-encodes per subscriber
-                fast = None
-                if dpk.fixed_header.qos == 0 and not observed:
-                    # $SYS housekeeping republishes every interval with no
-                    # inbound publish behind it: keep it out of the encode/
-                    # delivery amplification accounting (ROADMAP item 3's
-                    # metric must measure client fan-out, not the $SYS tick)
-                    fast = _FrameCache(
-                        dpk,
-                        None
-                        if dpk.topic_name.startswith("$SYS")
-                        else self.telemetry,
-                    )
-
+                # a hook observes encodes or sends: one encode and one
+                # hook call a subscriber
                 for id_, subs in items:
                     cl = lookup(id_)
                     if cl is not None:
                         try:
                             delivered = self._deliver_to_client(
-                                cl, subs, dpk, fast, account=True
+                                cl, subs, dpk, account=True
                             )
                         except Exception as e:
                             self.log.debug(
@@ -4192,7 +4097,7 @@ class Server:
             if targets is not None
             else list(subscribers.subscriptions.items())
         )
-        if self._fanout_batch and not observed:
+        if not observed:
             if self._fan_out_encrypted_batched(
                 tenant, dpk, plaintext, items, lookup
             ):
@@ -4396,7 +4301,6 @@ class Server:
         cl: Client,
         sub: Subscription,
         pk: Packet,
-        fast: Optional["_FrameCache"] = None,
         account: bool = False,
     ) -> bool:
         """``publish_to_client`` with shard-loop affinity (mqtt_tpu.shards):
@@ -4412,18 +4316,18 @@ class Server:
         (the owner-loop callback logs failures and, with ``account``,
         performs the tenant accounting itself)."""
         if self._fabric is None or self._client_loop_local(cl):
-            self.publish_to_client(cl, sub, pk, fast)
+            self.publish_to_client(cl, sub, pk)
             return True
         eff = pk.fixed_header.qos
         if eff > sub.qos:
             eff = sub.qos
         if eff == 0 and cl.properties.props.topic_alias_maximum == 0:
-            self.publish_to_client(cl, sub, pk, fast)
+            self.publish_to_client(cl, sub, pk)
             return True
         loop = cl.net.loop
         try:
             loop.call_soon_threadsafe(  # type: ignore[union-attr]
-                self._deliver_remote, cl, sub, pk, fast, account
+                self._deliver_remote, cl, sub, pk, account
             )
         except RuntimeError:
             pass  # owner shard gone; the client is going away with it
@@ -4434,7 +4338,6 @@ class Server:
         cl: Client,
         sub: Subscription,
         pk: Packet,
-        fast: Optional["_FrameCache"],
         account: bool,
     ) -> None:
         """The owner-shard half of a marshaled delivery."""
@@ -4448,7 +4351,7 @@ class Server:
                     detail=cl.id,
                 )
         try:
-            self.publish_to_client(cl, sub, pk, fast)
+            self.publish_to_client(cl, sub, pk)
         except Exception as e:
             self.log.debug(
                 "failed publishing packet: error=%s client=%s", e, cl.id
@@ -4462,7 +4365,6 @@ class Server:
         cl: Client,
         sub: Subscription,
         pk: Packet,
-        fast: Optional["_FrameCache"] = None,
     ) -> Packet:
         """Deliver one publish to one subscriber (server.go:1023-1113).
 
@@ -4491,27 +4393,6 @@ class Server:
         topic = pk.topic_name
         if topic[:1] == NS_CHAR:
             topic = ns_local(topic)
-
-        # zero-valued identifiers never reach the wire (properties.py
-        # encodes only v > 0), so they don't disqualify the shared frame
-        if fast is not None and self._shared_frame_ok(cl.properties, sub):
-            if not self.hooks.on_acl_check(cl, topic, False):
-                raise ERR_NOT_AUTHORIZED()
-            retain = pk.fixed_header.retain and (
-                sub.fwd_retained_flag
-                or (cl.properties.protocol_version == 5 and sub.retain_as_published)
-            )
-            data = fast.get(cl.properties.protocol_version, retain)
-            if cl.net.writer is None or cl.closed:
-                raise CODE_DISCONNECT()
-            if not self._enqueue_frame(
-                cl,
-                data,
-                lambda: pk,
-                count_delivery=not topic.startswith("$SYS"),
-            ):
-                raise ERR_PENDING_CLIENT_WRITES_EXCEEDED()
-            return pk
 
         out = pk.copy(False)
         out.topic_name = topic

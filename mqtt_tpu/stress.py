@@ -10,7 +10,7 @@ match, per-subscriber copy/encode, bounded outbound queue, write coalescing.
 
 Usage:
     python -m mqtt_tpu.stress --broker 127.0.0.1:1883 -c 10 -m 1000
-or from bench.py, which spawns a broker subprocess and runs the workload.
+against a running broker (``--serve`` starts one).
 """
 
 from __future__ import annotations
@@ -284,9 +284,8 @@ async def ramp_idle(
 ) -> list:
     """Attach ``n`` mostly-idle device connections (CONNECT, then
     silence; keepalive 0 so the broker never reaps them) — the
-    connection-scale axis of bench cfg 8 and exp/conn_smoke.py
-    (ISSUE 15). Returns the writers; close them to drop the
-    population."""
+    connection-scale axis of exp/conn_smoke.py (ISSUE 15). Returns the
+    writers; close them to drop the population."""
     writers: list = []
 
     async def one(i: int) -> None:
@@ -301,52 +300,6 @@ async def ramp_idle(
             *(one(i) for i in range(base, min(base + batch, n)))
         )
     return writers
-
-
-async def run_flatness(
-    host: str,
-    port: int,
-    clients_small: int = 10,
-    clients_large: int = 100,
-    msgs_small: int = 1000,
-    msgs_large: int = 500,
-    **kw,
-) -> dict:
-    """The per-client receive-rate FLATNESS probe (ROADMAP item 3's
-    success criterion as one number): run the stresser workload at a
-    small and a large client count against the same broker and report
-    the ratio of per-client receive medians. A flat broker holds ~1.0;
-    a thread-per-connection re-encode path collapses toward 0 as
-    clients grow.
-    bench.py config 8 embeds this block so the stage gate can watch the
-    number per round."""
-    small = await run_stress(host, port, clients_small, msgs_small, **kw)
-    large = await run_stress(host, port, clients_large, msgs_large, **kw)
-    return {
-        "clients": [clients_small, clients_large],
-        "small": small,
-        "large": large,
-        # per-cell medians in one flat, diffable list (the matrix shape
-        # rounds diff cell-by-cell — ISSUE 13 satellite): each cell is
-        # keyed by (clients, qos) and carries ITS OWN medians instead of
-        # only the cross-cell ratio
-        "cells": [
-            {
-                "clients": r["clients"],
-                "qos": r.get("qos", 0),
-                "msgs_per_client": r["msgs_per_client"],
-                "publish_median_per_sec": r["publish_median_per_sec"],
-                "receive_median_per_sec": r["receive_median_per_sec"],
-                "aggregate_msgs_per_sec": r["aggregate_msgs_per_sec"],
-            }
-            for r in (small, large)
-        ],
-        "receive_flatness_ratio": round(
-            large["receive_median_per_sec"]
-            / max(1e-9, small["receive_median_per_sec"]),
-            4,
-        ),
-    }
 
 
 # -- publish storm (overload-governor drill) ---------------------------------
@@ -400,7 +353,7 @@ async def run_storm(
     while one subscriber on ``storm/#`` measures what actually gets
     through. Returns offered/admitted/shed/delivered accounting and the
     admitted-traffic delivery p99 — the artifact fields the overload
-    governor is judged on (bench.py storm scenario)."""
+    governor is judged on."""
     from .faults import StormPlan, drive_storm
 
     plan = StormPlan(
@@ -522,7 +475,7 @@ async def run_storm(
         "acked_admitted_qos1": acks.get("admitted", 0),
         "shed_qos1_0x97": acks.get("shed", 0),
         # client-visible sheds only: QoS0 sheds are silent drops, so the
-        # broker-side governor gauge is the total (bench reads it)
+        # broker-side governor gauge is the total
         "shed_rate_qos1": round(
             acks.get("shed", 0) / max(1, offered["qos1"]), 4
         ),
@@ -1115,8 +1068,8 @@ def broker_main(
     cluster_base_port: int = 0,
     kill_root_after_s: float = 0.0,
 ) -> None:
-    """Run a bench broker on ``address`` until stdin closes (the bench
-    driver's subprocess entry; prints READY once serving).
+    """Run a broker on ``address`` until stdin closes (the drills'
+    subprocess entry; prints READY once serving).
 
     ``workers > 1`` starts the multi-core data plane (mqtt_tpu.cluster):
     this process becomes the launcher, spawning one worker process per
@@ -1172,18 +1125,7 @@ def broker_main(
             # publishers — v4 PUBACK has no reason code), which reads as
             # a routing loss when it is the overload plane doing its job
             opt_kw["overload_control"] = False
-        if os.environ.get("BENCH_LAZY", "1") == "0":
-            # bench A/B knob (ISSUE 13): the serve-side broker honors
-            # the same switch the in-process bench brokers use, so the
-            # subprocess config-8 legs A/B cleanly too
-            opt_kw["matcher_lazy_views"] = False
-            opt_kw["fanout_batch"] = False
         shards = int(os.environ.get("MQTT_TPU_LOOP_SHARDS", "0") or 0)
-        if os.environ.get("BENCH_SHARDS") == "1":
-            # bench A/B knob (ISSUE 15): BENCH_SHARDS=1 forces the
-            # single-loop front-end whatever MQTT_TPU_LOOP_SHARDS says,
-            # so the cfg-8 connections matrix A/Bs the fabric cleanly
-            shards = 1
         if shards > 1:
             opt_kw["loop_shards"] = shards
             accept = os.environ.get("MQTT_TPU_LOOP_SHARD_ACCEPT", "")
@@ -1388,18 +1330,12 @@ def main() -> None:
     p.add_argument("-c", "--clients", type=int, default=10)
     p.add_argument("-m", "--messages", type=int, default=1000)
     p.add_argument("--payload-size", type=int, default=64)
-    p.add_argument("--serve", action="store_true", help="run the bench broker instead")
+    p.add_argument("--serve", action="store_true", help="run a broker instead")
     p.add_argument("--device-matcher", action="store_true")
     p.add_argument(
         "--storm", action="store_true",
         help="publish-storm overload drill (mqtt_tpu.overload) instead of "
         "the throughput workload",
-    )
-    p.add_argument(
-        "--flatness", action="store_true",
-        help="per-client receive-rate flatness probe: the stress workload "
-        "at 10 clients and at --clients, reporting the receive-median "
-        "ratio (ROADMAP item 3's success criterion)",
     )
     p.add_argument(
         "--partition", action="store_true",
@@ -1551,14 +1487,6 @@ def main() -> None:
             run_partition(
                 host, int(port), args.clients, args.messages,
                 sys_port=args.sys_port,
-            )
-        )
-    elif args.flatness:
-        out = asyncio.run(
-            run_flatness(
-                host, int(port),
-                clients_large=args.clients,
-                msgs_small=args.messages, msgs_large=args.messages,
             )
         )
     elif args.storm:

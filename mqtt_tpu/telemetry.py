@@ -48,11 +48,9 @@ from typing import Any, Callable, Optional
 _log = logging.getLogger("mqtt_tpu.telemetry")
 
 # the publish pipeline's stage names, in pipeline order (the flight
-# recorder and the bench telemetry block both key on these). The trace
-# plane (mqtt_tpu.tracing) resolves ``device_batch`` into the three
-# device sub-stages when the device profiler is wired; ``device_batch``
-# stays populated as their sum so rounds diff across the split
-# (exp/stage_gate.py).
+# recorder keys on these). The trace plane (mqtt_tpu.tracing) resolves
+# ``device_batch`` into the three device sub-stages when the device
+# profiler is wired; ``device_batch`` stays populated as their sum.
 PUBLISH_STAGES = (
     "decode",
     "admission",
@@ -74,7 +72,7 @@ DEVICE_SUBSTAGES = ("h2d", "device_dispatch", "d2h")
 # ``encode`` covers variant grouping + the per-variant frame encodes,
 # ``flush`` the delivery flush (batched writev + queue fallbacks).
 # ``fanout`` stays populated as their sum — same continuity contract as
-# the device_batch split (exp/stage_gate.py diffs old rounds unchanged).
+# the device_batch split.
 FANOUT_SUBSTAGES = ("encode", "flush")
 
 # the MQTT v5 user-property key a trace id rides on (client-visible
@@ -708,7 +706,7 @@ class Telemetry:
     publish histograms, the flight recorder, and the sampling counters.
     Every instrumented layer (server, staging, clients, matcher,
     cluster) talks to this object; every exposition surface (/metrics,
-    $SYS, BENCH json) renders from it."""
+    $SYS) renders from it."""
 
     def __init__(
         self,
@@ -889,8 +887,8 @@ class Telemetry:
 
     def delivery_summary(self) -> dict:
         """Per-path delivery-latency fold across every (tenant, qos)
-        cell — the bench/stage-gate face of the SLI family (rows
-        ``delivery_local`` / ``delivery_remote`` in bench_block)."""
+        cell of the SLI family (rows ``delivery_local`` /
+        ``delivery_remote``)."""
         out: dict = {}
         for path in DELIVERY_PATHS:
             merged: Optional[Histogram] = None
@@ -992,38 +990,6 @@ class Telemetry:
                 fn=lambda n=name: plane.wait_share(n),
             )
 
-    def fanout_block(self, inbound_publishes: int) -> dict:
-        """The BENCH-json fan-out amplification block: encodes and
-        deliveries per inbound PUBLISH — the number ROADMAP item 3's
-        encode-once rewrite must drive toward ~1 encode/publish."""
-        inbound = max(1, int(inbound_publishes))
-        return {
-            "inbound_publishes": int(inbound_publishes),
-            "publish_encodes": self.publish_encodes.value,
-            "fanout_deliveries": self.fanout_deliveries.value,
-            "outbound_bytes": self.outbound_bytes.value,
-            "outbound_writes": self.outbound_writes.value,
-            "fanout_variants": self.fanout_variants.value,
-            "fanout_writev_batches": self.fanout_writev_batches.value,
-            "encode_amplification": round(
-                self.publish_encodes.value / inbound, 4
-            ),
-            "delivery_amplification": round(
-                self.fanout_deliveries.value / inbound, 4
-            ),
-            # encodes per VARIANT-GROUPED fan-out tick: ~1 when the
-            # batched path is doing its job (the ISSUE 13 acceptance
-            # number). Ticks that never grouped (legacy path) keep the
-            # plain encode_amplification as their signal.
-            "encode_per_variant": round(
-                self.publish_encodes.value
-                / max(1, self.fanout_variants.value),
-                4,
-            )
-            if self.fanout_variants.value
-            else None,
-        }
-
     def publish_clock(self) -> Optional[StageClock]:
         """A StageClock for 1-in-N publishes, None for the rest; when
         the trace plane is attached, 1-in-trace_sample publishes get a
@@ -1099,14 +1065,14 @@ class Telemetry:
                 explicit_fanout = True
         if have_sub and not explicit_batch:
             # continuity across the sub-stage split: device_batch stays
-            # populated as the sum, so stage_gate diffs old rounds (an
-            # explicitly-stamped device_batch — the exact-map / host
-            # fallback path — must not be observed twice)
+            # populated as the sum (an explicitly-stamped device_batch —
+            # the exact-map / host fallback path — must not be observed
+            # twice)
             hist["device_batch"].observe(sub_total, trace_id)
         if have_fan and not explicit_fanout:
             # same continuity contract for the fan-out split: the batched
             # write path stamps encode/flush, legacy paths stamp fanout —
-            # either way the coarse stage keeps diffing across rounds
+            # either way the coarse stage stays populated
             hist["fanout"].observe(fan_total, trace_id)
         self.sampled_publishes.inc()
         record = {
@@ -1242,45 +1208,6 @@ class Telemetry:
         out["flight/dumps"] = self.recorder.dumps
         out["flight/dumps_suppressed"] = self.recorder.dumps_suppressed
         return out
-
-    def bench_block(self) -> dict:
-        """The BENCH-json telemetry block: per-stage p50/p99, batch
-        occupancy, and the host-fallback breakdown — so future PRs can
-        diff stage-level regressions, not just end-to-end rate."""
-        stages = {}
-        for s, h in self.stage_hist.items():
-            if h.count:
-                stages[s] = {
-                    "count": h.count,
-                    "p50_ms": round(h.percentile(0.5) * 1e3, 3),
-                    "p99_ms": round(h.percentile(0.99) * 1e3, 3),
-                }
-        for leg, h in self.leg_wait.items():
-            # per-leg pipeline handoff waits render as stage rows so
-            # exp/stage_gate.py diffs them round over round (new names
-            # pass through its new_stage_names notice on round one)
-            if h.count:
-                stages[f"leg_wait_{leg}"] = {
-                    "count": h.count,
-                    "p50_ms": round(h.percentile(0.5) * 1e3, 3),
-                    "p99_ms": round(h.percentile(0.99) * 1e3, 3),
-                }
-        # delivery-latency SLI rows (ISSUE 14): per-path folds render as
-        # stage rows so exp/stage_gate.py diffs them round over round
-        # (their first round passes through its new_stage_names notice)
-        stages.update(self.delivery_summary())
-        fill = self.batch_fill.summary()
-        return {
-            "stages": stages,
-            "batch_service": {
-                "count": self.batch_service.count,
-                "p50_ms": round(self.batch_service.percentile(0.5) * 1e3, 3),
-                "p99_ms": round(self.batch_service.percentile(0.99) * 1e3, 3),
-            },
-            "batch_fill": {"count": fill["count"], "p50": fill["p50"], "p99": fill["p99"]},
-            "fallbacks": {k: c.value for k, c in self.fallback.items()},
-            "flight_dumps": self.recorder.dumps,
-        }
 
 
 class ClusterMetrics:

@@ -1083,7 +1083,7 @@ class RecryptEngine:
                 tenant=tenant,
             )
 
-    # -- client-side helpers (tests, embedders, bench) ---------------------
+    # -- client-side helpers (tests, embedders) ----------------------------
 
     def seal_with_key(
         self, key: bytes, plaintext: bytes, nonce: Optional[bytes] = None

@@ -323,8 +323,7 @@ class BatchProfile:
     fallbacks and the sharded matcher leave the matcher's spans None."""
 
     __slots__ = (
-        "dispatch", "d2h", "d2h_bytes", "d2h_bytes_ranges",
-        "d2h_bytes_dense", "compact", "compact_overflow", "devices",
+        "dispatch", "d2h", "d2h_bytes", "compact", "devices",
         "seq", "kept", "topics", "bucket", "depth",
         "submit_first_ns", "wait_n", "wait_sum_ns",
         "formed_ns", "issue_start_ns", "issue_end_ns", "sync_start_ns",
@@ -339,18 +338,11 @@ class BatchProfile:
         self.dispatch: Optional[tuple[float, float]] = None
         # (start, end) of the blocking D2H result sync
         self.d2h: Optional[tuple[float, float]] = None
-        # transfer accounting (ROADMAP item 1's compaction gap): the
-        # actual D2H result bytes this batch moved, beside the bytes the
-        # pre-compaction geometries would have moved — ranges = the
-        # packed [B, 2P+2] form, dense = the padded [B, max_hits] slot
-        # buffer. 0 = the matcher did not stamp this batch.
+        # the D2H result bytes this batch moved; 0 = the matcher did not
+        # stamp this batch
         self.d2h_bytes = 0
-        self.d2h_bytes_ranges = 0
-        self.d2h_bytes_dense = 0
-        # True when the result came back as compacted (topic, sid) pairs;
-        # compact_overflow marks the per-batch padded-path fallback
+        # True when the result came back as compacted (topic, sid) pairs
         self.compact = False
-        self.compact_overflow = False
         # device ids this batch's window ran on, stamped by the matcher
         # at dispatch (TpuMatcher: the output buffer's device; sharded:
         # every mesh device). None = unstamped, folds as device 0.
@@ -722,16 +714,6 @@ class DeviceProfiler:
         self._busy_s = 0.0  # union of device windows
         self._window_s = 0.0  # sum of device windows
         self._overlap_s = 0.0
-        # device-resident compaction accounting (ROADMAP item 1): bytes
-        # actually transferred vs the pre-compaction geometries, and the
-        # compacted-batch / overflow-fallback split — stamped per batch
-        # on its BatchProfile by the matcher
-        self.compact_batches = 0
-        self.compact_overflows = 0
-        self.d2h_bytes_total = 0
-        self.d2h_bytes_ranges_total = 0
-        self.d2h_bytes_dense_total = 0
-        self._bytes_batches = 0  # batches that stamped transfer bytes
         # -- the armed state (class docstring) --
         self.armed = False
         self._arm_lock = threading.Lock()
@@ -810,7 +792,7 @@ class DeviceProfiler:
 
     def open_batch(self) -> BatchProfile:
         """A fresh, numbered per-batch record; staging and the matcher
-        fill it and whoever holds the batch (staging drain loop, bench)
+        fill it and whoever holds the batch (the staging drain loop)
         reads it. While armed the record is kept for the slice."""
         rec = BatchProfile()
         rec.seq = next(self._seq)
@@ -1003,16 +985,6 @@ class DeviceProfiler:
         # (each chip moved ~1/n of the result) — exact for one device
         per_dev_bytes = getattr(rec, "d2h_bytes", 0) // len(devs)
         with self._lock:
-            if getattr(rec, "d2h_bytes", 0):
-                self._bytes_batches += 1
-                self.d2h_bytes_total += rec.d2h_bytes
-                self.d2h_bytes_ranges_total += rec.d2h_bytes_ranges
-                self.d2h_bytes_dense_total += rec.d2h_bytes_dense
-            if getattr(rec, "compact", False):
-                if rec.compact_overflow:
-                    self.compact_overflows += 1
-                else:
-                    self.compact_batches += 1
             end = max(sync_end, t_disp)
             self.batches += 1
             if self._first_t is None:
@@ -1081,47 +1053,4 @@ class DeviceProfiler:
                         dw.idle_hist.percentile(0.99) * 1e3, 3
                     ),
                 }
-        return out
-
-    def bench_block(self) -> dict:
-        """The BENCH-json device-pipeline block (configs 2 and 8): the
-        exact numbers ROADMAP item 1's overlapped-staging work must
-        move, baselined per round so the gap is diffable."""
-        out = {
-            "batches": self.batches,
-            "duty_cycle": round(self.duty_cycle(), 4),
-            "overlap_ratio": round(self.overlap_ratio(), 4),
-            "issue_p99_ms": round(self.issue_hist.percentile(0.99) * 1e3, 3),
-            "d2h_p99_ms": round(self.d2h_hist.percentile(0.99) * 1e3, 3),
-            "idle_gap_p99_ms": round(
-                self.idle_gap_hist.percentile(0.99) * 1e3, 3
-            ),
-            "idle_gap_count": self.idle_gap_hist.count,
-        }
-        with self._lock:
-            nb = self._bytes_batches
-            if nb:
-                # the compaction transfer ledger (ROADMAP item 1's D2H
-                # criterion): actual result bytes per batch beside the
-                # pre-compaction geometries and the reduction they imply
-                out["d2h_bytes_per_batch"] = round(self.d2h_bytes_total / nb)
-                out["d2h_bytes_ranges_per_batch"] = round(
-                    self.d2h_bytes_ranges_total / nb
-                )
-                out["d2h_bytes_padded_per_batch"] = round(
-                    self.d2h_bytes_dense_total / nb
-                )
-                out["d2h_reduction_vs_padded"] = round(
-                    self.d2h_bytes_dense_total / max(1, self.d2h_bytes_total), 2
-                )
-                out["d2h_reduction_vs_ranges"] = round(
-                    self.d2h_bytes_ranges_total / max(1, self.d2h_bytes_total),
-                    2,
-                )
-            out["compact_batches"] = self.compact_batches
-            out["compact_overflows"] = self.compact_overflows
-        if self.compact_d2h_hist.count:
-            out["compact_d2h_p99_ms"] = round(
-                self.compact_d2h_hist.percentile(0.99) * 1e3, 3
-            )
         return out

@@ -311,7 +311,7 @@ class LockPlane:
     """The process-wide registry of named lock stats, plus the optional
     order witness and preemption-fuzz hook. Armed/disarmed by the server
     (``Options.profile_locks``); arming is refcounted so two in-process
-    brokers (tests, bench) cannot disarm each other.
+    brokers (tests) cannot disarm each other.
 
     ``active`` is the single fast-path test ``InstrumentedLock.acquire``
     reads: true when ANY of stats arming, the witness, or the fuzz hook
@@ -383,7 +383,7 @@ class LockPlane:
             self._refresh_active_locked()
 
     def reset(self) -> None:
-        """Zero every stats record (tests and bench A/B rounds) — in
+        """Zero every stats record (tests) — in
         place, so locks and metric closures created BEFORE the reset
         keep feeding the same records afterwards."""
         with self._names_mutex:
@@ -398,8 +398,7 @@ class LockPlane:
         return sum(st.wait_s for st in self.snapshot())
 
     def top_contended(self, k: int = 3) -> list[dict]:
-        """The k most-contended lock names by total wait time — the
-        bench artifact's "which locks own the collapse" field."""
+        """The k most-contended lock names by total wait time."""
         ranked = sorted(self.snapshot(), key=lambda s: s.wait_s, reverse=True)
         return [st.as_dict() for st in ranked[:k] if st.acquisitions]
 

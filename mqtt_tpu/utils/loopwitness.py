@@ -17,8 +17,7 @@ Shape and cost discipline copied from :class:`locked.LockPlane`:
 
 - instrumented touch points guard on ONE plane flag
   (``DEFAULT_LOOP_PLANE.active``) — disarmed cost is a single
-  attribute read + branch (bench cfg 8 holds it to the LockWitness
-  bar);
+  attribute read + branch;
 - ``arm_witness(raise_on_violation=True)`` ESCALATES an existing
   recording witness to the raising tripwire and never de-escalates
   (the schedule fuzzer must get hard failures even when conftest
@@ -47,7 +46,7 @@ def current_loop() -> Optional[asyncio.AbstractEventLoop]:
     ``asyncio.events.__all__`` since 3.7): the armed witness probes loop
     identity on EVERY instrumented queue touch, and paying the
     exception machinery of ``get_running_loop()`` in plain-thread
-    context would triple the per-touch cost bench cfg 8 gates."""
+    context would triple the per-touch cost."""
     return asyncio._get_running_loop()
 
 
@@ -167,8 +166,8 @@ class LoopPlane:
             self.active = False
 
     def reset(self) -> None:
-        """Drop recorded evidence IN PLACE (bench A/B rounds, test
-        isolation) without detaching the witness."""
+        """Drop recorded evidence IN PLACE (test isolation) without
+        detaching the witness."""
         with self._mutex:
             w = self.witness
             if w is not None:

@@ -32,7 +32,7 @@ DEFAULT_PLANE.arm_witness()
 # set. tests/test_zz_loopwitness.py asserts observed ⊆ the blessed
 # LOOP_AFFINITY table (tools/brokerlint/loopgraph.py) and that zero
 # guarded touches ran off their owning loop. Disarmed cost at every
-# touch point: one plane-flag read + branch (bench cfg 8).
+# touch point: one plane-flag read + branch.
 from mqtt_tpu.utils.loopwitness import DEFAULT_LOOP_PLANE  # noqa: E402
 
 DEFAULT_LOOP_PLANE.arm_witness()

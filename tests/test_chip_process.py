@@ -78,8 +78,7 @@ class TestLaunchers:
 
 
 def test_host_only_broker_never_imports_a_backend():
-    """bench.py's parent touches JAX and later spawns brokers; cluster
-    workers run beside a device-matcher process. Such a broker (device
+    """Cluster workers run beside a device-matcher process. Such a broker (device
     matcher off) must never import jax — at the parent commit every
     Server enumerated jax.devices() for its stats plane."""
     code = """

@@ -22,9 +22,7 @@ from mqtt_tpu.ops.devicestats import (
     CompileLedger,
     DeviceStatsPlane,
     KernelWatch,
-    set_watch_enabled,
     skew_of,
-    watch_enabled,
 )
 from mqtt_tpu.packets import Subscription
 from mqtt_tpu.telemetry import Telemetry, check_exposition
@@ -136,19 +134,6 @@ class TestCompileLedger:
         assert "flat_match_compact[64x8,capacity=512]" in text
         assert led.attribution(led.total()) == "no compile events recorded"
 
-    def test_disabled_watch_skips_signature_work_entirely(self):
-        led = CompileLedger()
-        w = KernelWatch("k", lambda *a, **kw: None, ledger=led)
-        assert watch_enabled()
-        set_watch_enabled(False)
-        try:
-            w(np.zeros((8,), np.int32))
-            assert led.total() == 0
-        finally:
-            set_watch_enabled(True)
-        w(np.zeros((8,), np.int32))
-        assert led.total() == 1
-
     def test_registry_binding_exports_counter_and_histogram(self):
         led = CompileLedger()
         tele = Telemetry()
@@ -245,16 +230,19 @@ class TestPerDeviceWindows:
         oracle that proves the per-device replica arithmetic."""
         prof = DeviceProfiler()
         self._feed(prof, None)
-        agg = prof.bench_block()
         dev = prof.device_snapshot()
         assert list(dev.keys()) == [0]
         d0 = dev[0]
-        assert d0["batches"] == agg["batches"] == 4
-        assert d0["duty_cycle"] == agg["duty_cycle"]
-        assert d0["overlap_ratio"] == agg["overlap_ratio"]
-        assert d0["issue_p99_ms"] == agg["issue_p99_ms"]
-        assert d0["d2h_p99_ms"] == agg["d2h_p99_ms"]
-        assert d0["idle_gap_p99_ms"] == agg["idle_gap_p99_ms"]
+
+        def p99_ms(hist):
+            return round(hist.percentile(0.99) * 1e3, 3)
+
+        assert d0["batches"] == prof.batches == 4
+        assert d0["duty_cycle"] == round(prof.duty_cycle(), 4)
+        assert d0["overlap_ratio"] == round(prof.overlap_ratio(), 4)
+        assert d0["issue_p99_ms"] == p99_ms(prof.issue_hist)
+        assert d0["d2h_p99_ms"] == p99_ms(prof.d2h_hist)
+        assert d0["idle_gap_p99_ms"] == p99_ms(prof.idle_gap_hist)
 
     def test_multi_device_stamp_splits_bytes_evenly(self):
         prof = DeviceProfiler()

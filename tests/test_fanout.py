@@ -16,12 +16,14 @@ import gc
 import numpy as np
 import pytest
 
-from mqtt_tpu import Options
+from mqtt_tpu import Capabilities, Options
+from mqtt_tpu.hooks import ON_PACKET_ENCODE, ON_PACKET_SENT
 from mqtt_tpu.packets import PUBLISH, SUBACK, Subscription
 from mqtt_tpu.topics import Subscribers
 
 from tests.test_server import (
     Harness,
+    ObservingHook,
     pub_packet,
     read_wire_packet,
     run,
@@ -299,7 +301,10 @@ def _collect(r, n, version=4):
 @needs_jax
 class TestDeliveryDifferential:
     """Delivered wire frames must be bit-identical between the lazy
-    batched path and the legacy eager path across subscription shapes."""
+    batched path and the eager per-subscriber path across subscription
+    shapes. The eager side is selected the way a deployment selects it:
+    ``matcher_opts={"lazy": False}`` for the eager resolver, a hook that
+    observes sends for the per-subscriber loop."""
 
     SCENARIO = [
         # (client id, version, filters [(filter, qos)])
@@ -328,11 +333,13 @@ class TestDeliveryDifferential:
                 Options(
                     inline_client=True,
                     device_matcher=True,
-                    matcher_opts={"max_levels": 4, "background": False},
-                    matcher_lazy_views=lazy,
-                    fanout_batch=lazy,
+                    matcher_opts={
+                        "max_levels": 4, "background": False, "lazy": lazy,
+                    },
                 )
             )
+            if not lazy:
+                h.server.add_hook(ObservingHook())
             await h.server.serve()
             conns = {}
             for cid, ver, filters in self.SCENARIO:
@@ -379,6 +386,112 @@ class TestDeliveryDifferential:
         assert {k: len(v) for k, v in lazy.items()} == self.EXPECTED
 
 
+async def _read_publish_frames(reader, n):
+    """``n`` raw PUBLISH frames off a subscriber's stream, each with
+    its packet id (QoS > 0) zeroed: ids are per-client counters, every
+    other byte of the frame is the broker's choice."""
+    frames = []
+    while len(frames) < n:
+        head = bytearray(await asyncio.wait_for(reader.readexactly(1), 5))
+        remaining, shift = 0, 0
+        while True:
+            b = (await asyncio.wait_for(reader.readexactly(1), 5))[0]
+            head.append(b)
+            remaining |= (b & 0x7F) << shift
+            shift += 7
+            if not b & 0x80:
+                break
+        body = bytearray(await asyncio.wait_for(reader.readexactly(remaining), 5))
+        if head[0] >> 4 != PUBLISH:
+            continue
+        if (head[0] >> 1) & 3:
+            pid_at = 2 + ((body[0] << 8) | body[1])
+            body[pid_at : pid_at + 2] = b"\x00\x00"
+        frames.append(bytes(head + body))
+    return frames
+
+
+class TestObservedHookDifferential:
+    """The per-subscriber loop a hook on ON_PACKET_ENCODE / ON_PACKET_SENT
+    selects must put the same frames on the wire as the encode-once
+    batched flush it replaces. Nothing else selects that loop, so the
+    hook is the way in."""
+
+    PUBLISHES = [("f/t/1", b"plain", False), ("f/t/1", b"kept", True)]
+
+    def _frames(self, event, qos, version):
+        async def scenario():
+            # no expiry interval to count down: the send-time rewrite
+            # reads the wall clock, so two runs could differ by a second
+            h = Harness(
+                Options(
+                    inline_client=True,
+                    capabilities=Capabilities(
+                        maximum_message_expiry_interval=0
+                    ),
+                )
+            )
+            hook = None
+            if event is not None:
+                hook = ObservingHook(event)
+                h.server.add_hook(hook)
+            await h.server.serve()
+            conns = {}
+            for cid, ver, sub in (
+                ("exact", version, Subscription(filter="f/t/1", qos=2)),
+                ("plus", version, Subscription(filter="f/+/1", qos=1)),
+                (
+                    "rap", 5,
+                    Subscription(
+                        filter="f/t/1", qos=2, retain_as_published=True
+                    ),
+                ),
+            ):
+                r, w, _ = await h.connect(cid, version=ver)
+                w.write(sub_packet(1, [sub], version=ver))
+                await w.drain()
+                assert (await read_wire_packet(r, ver)).fixed_header.type == SUBACK
+                conns[cid] = (r, w)
+            # a v5 publisher: a v4 QoS0 frame would take the passthrough
+            # (try_fast_publish) on this host-only broker, not _fan_out
+            _pr, pw, _ = await h.connect("src", version=5)
+            for pid, (topic, payload, retain) in enumerate(self.PUBLISHES, 1):
+                pw.write(
+                    pub_packet(
+                        topic, payload, qos=qos, pid=pid if qos else 0,
+                        version=5, retain=retain,
+                    )
+                )
+            await pw.drain()
+            got = {
+                cid: await _read_publish_frames(r, len(self.PUBLISHES))
+                for cid, (r, _w) in conns.items()
+            }
+            variants = h.server.telemetry.fanout_variants.value
+            await h.server.close()
+            await h.shutdown()
+            return got, variants, hook.seen if hook is not None else 0
+
+        return run(scenario())
+
+    @pytest.mark.parametrize("version", [4, 5])
+    @pytest.mark.parametrize("qos", [0, 1, 2])
+    @pytest.mark.parametrize(
+        "event", [ON_PACKET_ENCODE, ON_PACKET_SENT], ids=["encode", "sent"]
+    )
+    def test_observed_loop_matches_batched_frame_for_frame(
+        self, event, qos, version
+    ):
+        batched, variants, _ = self._frames(None, qos, version)
+        observed, variants_observed, seen = self._frames(event, qos, version)
+        assert observed == batched
+        assert all(len(f) == len(self.PUBLISHES) for f in batched.values())
+        # each side really took its own path: the batched flush groups
+        # variants, the observed loop never does and feeds the hook
+        assert variants > 0 and variants_observed == 0
+        assert seen >= 3 * len(self.PUBLISHES)
+
+
 @needs_jax
 class TestTenantScopedDifferential:
     """Tenant-scoped delivery through the lazy path: namespace-scoped
@@ -391,9 +504,9 @@ class TestTenantScopedDifferential:
                 Options(
                     inline_client=True,
                     device_matcher=True,
-                    matcher_opts={"max_levels": 4, "background": False},
-                    matcher_lazy_views=lazy,
-                    fanout_batch=lazy,
+                    matcher_opts={
+                        "max_levels": 4, "background": False, "lazy": lazy,
+                    },
                     tenancy=True,
                     tenants={"acme": {}, "globex": {}},
                     tenant_users={
@@ -401,6 +514,8 @@ class TestTenantScopedDifferential:
                     },
                 )
             )
+            if not lazy:
+                h.server.add_hook(ObservingHook())
             await h.server.serve()
             a_r, a_w, _ = await h.connect("a-sub")
             a_w.write(sub_packet(1, [Subscription(filter="t/+", qos=1)]))

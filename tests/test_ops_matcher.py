@@ -7,9 +7,11 @@ import random
 
 import pytest
 
+from mqtt_tpu import Options, Server
+from mqtt_tpu.ops import TpuMatcher
+from mqtt_tpu.ops.delta import DeltaMatcher
 from mqtt_tpu.packets import Subscription
 from mqtt_tpu.topics import SHARE_PREFIX, InlineSubscription, TopicsIndex
-from mqtt_tpu.ops import TpuMatcher
 
 from tests.test_topics import FIND_MATRIX
 
@@ -136,12 +138,12 @@ def test_overflow_falls_back_to_host():
     assert "deep" in matcher2.subscribers(deep).subscriptions
 
 
-def test_frontier_overflow_falls_back():
+def test_many_plus_forks_resolve_completely():
     index = TopicsIndex()
-    # many '+' forks at each level explode the frontier beyond 2 slots
+    # many '+' forks at each level: five wildcard shapes, one topic
     for i, flt in enumerate(["+/+/+/a", "+/+/a/+", "+/a/+/+", "a/+/+/+", "a/a/a/a"]):
         index.subscribe(f"w{i}", Subscription(filter=flt))
-    matcher = TpuMatcher(index, frontier=2)
+    matcher = TpuMatcher(index)
     subs = matcher.subscribers("a/a/a/a")
     assert len(subs.subscriptions) == 5
 
@@ -149,15 +151,14 @@ def test_frontier_overflow_falls_back():
 def test_ranges_transfer_carries_large_fanouts_without_fallback():
     """The packed ranges output carries the COMPLETE result (2P ints per
     topic), so a fan-out that would have exceeded any slot prefix still
-    resolves entirely from the device — no host fallback class for it.
-    (``transfer_slots`` remains accepted for API compatibility.)"""
+    resolves entirely from the device — no host fallback class for it."""
     index = TopicsIndex()
     # 12 subs all matching 'hot/x'; 1 sub matching 'cold/y'
     for i in range(6):
         index.subscribe(f"e{i}", Subscription(filter="hot/x", qos=1))
         index.subscribe(f"w{i}", Subscription(filter="hot/+", qos=2))
     index.subscribe("solo", Subscription(filter="cold/y"))
-    matcher = TpuMatcher(index, max_levels=4, out_slots=32, transfer_slots=4)
+    matcher = TpuMatcher(index, max_levels=4, out_slots=4)
     hot = matcher.subscribers("hot/x")
     cold = matcher.subscribers("cold/y")
     assert canon(hot) == canon(index.subscribers("hot/x"))
@@ -166,6 +167,38 @@ def test_ranges_transfer_carries_large_fanouts_without_fallback():
     assert matcher.stats.host_fallbacks == 0
     assert matcher.stats.overflows == 0
     assert matcher.stats.topics == 2
+
+
+@pytest.mark.parametrize(
+    "keyword,build",
+    [
+        pytest.param(
+            "frontier",
+            lambda: TpuMatcher(TopicsIndex(), frontier=2),
+            id="TpuMatcher-frontier",
+        ),
+        pytest.param(
+            "transfer_slots",
+            lambda: DeltaMatcher(
+                TopicsIndex(), background=False, transfer_slots=4
+            ),
+            id="DeltaMatcher-transfer_slots",
+        ),
+        pytest.param(
+            "frontier",
+            lambda: Server(
+                Options(device_matcher=True, matcher_opts={"frontier": 2})
+            ),
+            id="Server-matcher_opts-frontier",
+        ),
+    ],
+)
+def test_retired_matcher_keyword_is_refused_by_name(keyword, build):
+    """The parameters of the retired NFA kernel were accepted and
+    ignored for many rounds; a config that still names one must fail at
+    construction, as any unknown keyword does, not be swallowed."""
+    with pytest.raises(TypeError, match=keyword):
+        build()
 
 
 def test_saturated_bucket_routes_to_host():
@@ -323,8 +356,6 @@ class TestExactMapFastPath:
         assert m2.csr.exact_map is None
 
     def test_fold_maintains_map(self):
-        from mqtt_tpu.ops.delta import DeltaMatcher
-
         index = self._index()
         m = DeltaMatcher(index, max_levels=4, background=False)
         assert m._snap.csr.exact_map is not None

@@ -31,6 +31,7 @@ from mqtt_tpu.utils.locked import (
 
 from tests.test_server import (
     Harness,
+    ObservingHook,
     pub_packet,
     read_wire_packet,
     run,
@@ -319,7 +320,7 @@ class TestLockPlane:
     def test_disarm_mid_hold_keeps_depth_coherent(self):
         """Disarming while a thread HOLDS the lock must still unwind the
         re-entrancy depth on release, or stats go silently blind after a
-        later re-arm (bench storm -> flatness rounds)."""
+        later re-arm."""
         plane = LockPlane()
         plane.arm()
         lk = InstrumentedLock("overload_governor", plane=plane)
@@ -432,12 +433,12 @@ class TestTopicSketch:
         true_avg = 50 / 11
         assert 0 < sk.avg_hits_per_topic() <= true_avg + 1e-9
 
-    def test_bench_block_shape(self):
+    def test_counters_and_top_after_one_observation(self):
         sk = TopicSketch(k=8)
         sk.observe("a")
-        b = sk.bench_block()
-        assert b["observed"] == 1 and b["tracked"] == 1
-        assert b["top_topics"][0]["topic"] == "a"
+        assert sk.total == 1 and sk.tracked == 1
+        assert sk.admissions == 1 and sk.evictions == 0
+        assert sk.top(5)[0]["topic"] == "a"
 
 
 # -- amplification accounting vs a known fan-out -----------------------------
@@ -477,30 +478,23 @@ class TestFanoutAmplification:
                 # encode (ids are per-client spaces [MQTT-2.2.1])
                 assert pk.packet_id > 0
             tele = h.server.telemetry
-            block = tele.fanout_block(h.server.info.messages_received)
-            assert block["inbound_publishes"] == 1
-            assert block["publish_encodes"] == 1
-            assert block["fanout_variants"] == 1
-            assert block["fanout_deliveries"] == n
-            assert block["encode_amplification"] == pytest.approx(1)
-            assert block["encode_per_variant"] == pytest.approx(1)
-            assert block["outbound_bytes"] > 0
+            assert h.server.info.messages_received == 1
+            assert tele.publish_encodes.value == 1
+            assert tele.fanout_variants.value == 1
+            assert tele.fanout_deliveries.value == n
+            assert tele.outbound_bytes.value > 0
             await h.shutdown()
 
         run(scenario())
 
-    def test_qos1_fanout_legacy_knob_encodes_per_target(self):
-        """``fanout_batch=False`` restores the per-subscriber encode
-        path — the A/B the bench's BENCH_LAZY knob drives, kept as the
-        differential oracle for the batched path."""
+    def test_qos1_fanout_under_observing_hook_encodes_per_target(self):
+        """A hook that observes sends takes the fan-out onto the
+        per-subscriber encode path — kept as the differential oracle
+        for the batched path."""
 
         async def scenario():
-            h = Harness(
-                Options(
-                    inline_client=True, telemetry_sample=1,
-                    fanout_batch=False,
-                )
-            )
+            h = Harness(Options(inline_client=True, telemetry_sample=1))
+            h.server.add_hook(ObservingHook())
             subs = []
             n = 4
             for i in range(n):
@@ -521,18 +515,17 @@ class TestFanoutAmplification:
                 assert pk.topic_name == "amp/t"
                 assert pk.fixed_header.qos == 1
             tele = h.server.telemetry
-            block = tele.fanout_block(h.server.info.messages_received)
-            assert block["publish_encodes"] == n
-            assert block["fanout_deliveries"] == n
-            assert block["fanout_variants"] == 0
+            assert tele.publish_encodes.value == n
+            assert tele.fanout_deliveries.value == n
+            assert tele.fanout_variants.value == 0
             await h.shutdown()
 
         run(scenario())
 
-    def test_qos0_frame_cache_encodes_once_per_variant(self):
-        """QoS0 publish to N shareable v5 subscribers rides the frame
-        cache: ONE encode per (version, retain) variant, N deliveries —
-        the flat-amplification shape already achieved on this path."""
+    def test_qos0_v5_fanout_encodes_once_per_variant(self):
+        """QoS0 publish to N shareable v5 subscribers: ONE encode per
+        (version, retain) variant, N deliveries — the flat-amplification
+        shape of the batched path."""
 
         async def scenario():
             h = Harness(Options(inline_client=True, telemetry_sample=1))
@@ -555,11 +548,9 @@ class TestFanoutAmplification:
                 pk = await read_wire_packet(r, 5)
                 assert pk.topic_name == "amp/t"
             tele = h.server.telemetry
-            block = tele.fanout_block(h.server.info.messages_received)
-            assert block["publish_encodes"] == 1
-            assert block["fanout_deliveries"] == n
-            assert block["encode_amplification"] == pytest.approx(1.0)
-            assert block["delivery_amplification"] == pytest.approx(n)
+            inbound = h.server.info.messages_received
+            assert tele.publish_encodes.value / inbound == pytest.approx(1.0)
+            assert tele.fanout_deliveries.value / inbound == pytest.approx(n)
             await h.shutdown()
 
         run(scenario())
@@ -588,10 +579,9 @@ class TestFanoutAmplification:
                 pk = await read_wire_packet(r)
                 assert pk.topic_name == "amp/t"
             tele = h.server.telemetry
-            block = tele.fanout_block(h.server.info.messages_received)
-            assert block["fanout_deliveries"] == n
-            assert block["publish_encodes"] == 0
-            assert block["delivery_amplification"] == pytest.approx(n)
+            inbound = h.server.info.messages_received
+            assert tele.publish_encodes.value == 0
+            assert tele.fanout_deliveries.value / inbound == pytest.approx(n)
             # per-client mirrors saw the writes
             total_writes = sum(
                 cl.state.out_writes

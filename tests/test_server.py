@@ -14,6 +14,7 @@ from mqtt_tpu.hooks import (
     ON_CONNECT,
     ON_CONNECT_AUTHENTICATE,
     ON_PACKET_READ,
+    ON_PACKET_SENT,
     ON_PUBLISH,
     ON_QOS_DROPPED,
     Hook,
@@ -85,6 +86,32 @@ async def read_wire_packet(reader, version=4):
     if remaining:
         buf += await asyncio.wait_for(reader.readexactly(remaining), TIMEOUT)
     return decode_packet(bytes(buf), version)
+
+
+class ObservingHook(Hook):
+    """Provides ONE of ON_PACKET_ENCODE / ON_PACKET_SENT and changes
+    nothing. A hook that observes encodes or sends is what takes a
+    fan-out off the encode-once batched flush and onto the
+    per-subscriber loop (``Server._fan_out``): the way a deployment
+    reaches that path, so the way the tests do."""
+
+    def __init__(self, event=ON_PACKET_SENT):
+        super().__init__()
+        self.event = event
+        self.seen = 0
+
+    def id(self):
+        return "observing"
+
+    def provides(self, b):
+        return b == self.event
+
+    def on_packet_encode(self, cl, pk):
+        self.seen += 1
+        return pk
+
+    def on_packet_sent(self, cl, pk, b):
+        self.seen += 1
 
 
 class Harness:

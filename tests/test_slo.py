@@ -366,13 +366,13 @@ class TestDeliverySLI:
         rows = tele.delivery_summary()
         assert rows["delivery_remote"]["count"] == 1
 
-    def test_bench_block_carries_delivery_stage_rows(self):
+    def test_delivery_summary_folds_tenants_per_path(self):
         tele = Telemetry(sample=1)
         tele.observe_delivery(0.001, "", 0, "local")
         tele.observe_delivery(0.3, "acme", 1, "remote")
-        stages = tele.bench_block()["stages"]
-        assert stages["delivery_local"]["count"] == 1
-        assert stages["delivery_remote"]["p99_ms"] >= 300
+        rows = tele.delivery_summary()
+        assert rows["delivery_local"]["count"] == 1
+        assert rows["delivery_remote"]["p99_ms"] >= 300
 
 
 # -- /healthz ----------------------------------------------------------------

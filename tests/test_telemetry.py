@@ -30,6 +30,7 @@ from mqtt_tpu.topics import SYS_PREFIX
 
 from tests.test_server import (
     Harness,
+    ObservingHook,
     pub_packet,
     read_wire_packet,
     run,
@@ -616,19 +617,14 @@ class TestStagedPipelineTelemetry:
         run(scenario())
 
     def test_outbound_queue_wait_sampling(self):
-        """The legacy (non-batched) fan-out delivers through the
-        bounded outbound queue, so sampled enqueues observe a queue
-        wait — the path the batched flush deliberately skips for idle
-        sockets (ISSUE 13)."""
+        """The per-subscriber fan-out (a hook observes sends) delivers
+        through the bounded outbound queue, so sampled enqueues observe
+        a queue wait — the path the batched flush deliberately skips
+        for idle sockets (ISSUE 13)."""
 
         async def scenario():
-            h = Harness(
-                Options(
-                    inline_client=True,
-                    telemetry_sample=1,
-                    fanout_batch=False,
-                )
-            )
+            h = Harness(Options(inline_client=True, telemetry_sample=1))
+            h.server.add_hook(ObservingHook())
             await h.server.serve()
             tele = h.server.telemetry
             sub_r, sub_w, _ = await h.connect("sub")

@@ -222,10 +222,6 @@ class TestDeviceProfiler:
         assert p.overlap_ratio() == pytest.approx(0.2)
         assert p.idle_gap_hist.count == 1
         assert 6.0 <= p.idle_gap_hist.percentile(0.99) <= 10.0
-        block = p.bench_block()
-        assert block["batches"] == 3
-        assert block["duty_cycle"] == pytest.approx(0.4)
-        assert block["overlap_ratio"] == pytest.approx(0.2)
 
     def test_record_pairing_is_exact_out_of_order(self):
         """Concurrent/out-of-order resolution (the resilience guard

@@ -118,18 +118,26 @@ class TreeMesh:
     async def settle_summaries(self):
         """Wait until every edge's interest summary is stamped with the
         receiver's CURRENT epoch (the summary gate is live, not in
-        conservative pass-through)."""
+        conservative pass-through) AND holds what its sender would
+        advertise on that edge now: a subscription made just before the
+        call has then reached every bloom it feeds, however many hops
+        (one gossip tick each) away."""
         def _epoch_key(c):
             ep = c.topo.epoch
             return (ep.num, ep.boot, ep.proposer)
 
+        def _settled(c, p):
+            es = c._edge_summaries.get(p)
+            if es is None or es.ep_key != _epoch_key(c):
+                return False
+            want = self.clusters[p]._edge_summary_for(c.worker_id)
+            return (es.bits.data, es.bits.match_all) == (
+                want.data, want.match_all
+            )
+
         await wait_for(
             lambda: all(
-                all(
-                    p in c._edge_summaries
-                    and c._edge_summaries[p].ep_key == _epoch_key(c)
-                    for p in c.topo.neighbors()
-                )
+                all(_settled(c, p) for p in c.topo.neighbors())
                 for c in self.clusters
             ),
             msg="summaries settled",
