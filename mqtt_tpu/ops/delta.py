@@ -27,6 +27,18 @@ stage 5, hard part #2):
   ``rebuild_after`` filters (or on demand via :meth:`flush`); the overlay
   generation swaps atomically and carries over only the mutations that
   arrived while the walk ran.
+- A bulk load (``TopicsIndex.bulk_load``: the durable restore,
+  ``staging.bulk_register``) is ONE generation of the table. While one is
+  open on the trie the background thread rebuilds nothing, since a table
+  walked from a half-loaded trie is thrown away by the next chunk, and
+  the generation is *whole-dirty*: every topic is affected, so every
+  publish is walked on the host trie, which is exact, and the overlay
+  keeps neither a mirror nor a list of a million loaded filters. A
+  mutation that is not part of the load but arrives during it is covered
+  the same way. The instant the outermost load closes, per-entry
+  recording resumes and the thread is woken for the one full build; the
+  generation that replaces a whole-dirty one is whole-dirty itself if a
+  load's mutation raced its walk.
 
 Because the overlay mini-trie IS a ``TopicsIndex``, its walk applies every
 matching rule — including the parent-inline quirk (topics.go:615) — so the
@@ -38,6 +50,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Optional
 
 from ..packets import Subscription
@@ -76,15 +89,21 @@ def _sharded_snapshot_cls():
 
 class _Gen:
     """One snapshot generation: the compiled device index plus the overlay
-    of filters mutated since its build started."""
+    of filters mutated since its build started. ``held`` counts the
+    mutations taken in while a bulk load was open on the trie: they are
+    in no list and no mini-trie, and above 0 the generation is
+    whole-dirty (every topic affected) until a full build replaces it."""
 
-    __slots__ = ("snap", "delta_trie", "deltas", "seen")
+    __slots__ = ("snap", "delta_trie", "deltas", "seen", "held")
 
-    def __init__(self, snap: _Snapshot, deltas: list[tuple[str, str]]) -> None:
+    def __init__(
+        self, snap: _Snapshot, deltas: list[tuple[str, str]], held: int = 0
+    ) -> None:
         self.snap = snap
         self.delta_trie = TopicsIndex()
         self.deltas: list[tuple[str, str]] = []
         self.seen: set[tuple[str, str]] = set()
+        self.held = held
         for f, kind in deltas:
             self.record(f, kind)
 
@@ -103,9 +122,16 @@ class _Gen:
             else:
                 self.delta_trie.subscribe(_DELTA_CLIENT, Subscription(filter=filter))
 
+    @property
+    def pending(self) -> int:
+        """Mutations since the snapshot, recorded or held."""
+        return len(self.deltas) + self.held
+
     def affected(self, topic: str) -> bool:
         """True when some mutation since the snapshot may change ``topic``'s
         subscriber set."""
+        if self.held:
+            return True
         if not self.deltas:
             return False
         s = self.delta_trie.subscribers(topic)
@@ -116,6 +142,8 @@ class _Gen:
         the resolver skip the per-topic predicate loop entirely when no
         mutations are pending — the common case for a broker whose
         subscriptions arrive at connect time."""
+        if self.held:
+            return [i for i, t in enumerate(topics) if t]
         if not self.deltas:
             return []
         affected = self.affected
@@ -125,6 +153,16 @@ class _Gen:
 class DeltaMatcher:
     """Drop-in for ``TopicsIndex.subscribers`` that serves device matches
     from a snapshot + host delta overlay and rebuilds off the hot path.
+
+    A bulk load open on the trie (``TopicsIndex.bulk_depth`` above 0)
+    holds the background rebuilds off: neither the ``rebuild_after``
+    threshold nor the interval tick builds from a half-loaded trie
+    (``stats.rebuilds_held`` counts the wake-ups put off), the overlay
+    goes whole-dirty instead of recording each loaded filter, and the
+    close of the outermost load (``stats.bulk_loads``) wakes the thread
+    for one full build; ``bulk_build_seconds`` is how long the newest
+    such build took. Nothing selects this: the trie says a load is open.
+    An explicit :meth:`flush` builds synchronously whenever it is called.
 
     Parameters
     ----------
@@ -177,6 +215,9 @@ class DeltaMatcher:
         # background rebuilds that raised (logged and retried; a smoke
         # that must not pass on a degraded matcher reads this)
         self.rebuild_errors = 0
+        # wall seconds of the newest build that replaced a whole-dirty
+        # generation, i.e. the one build a bulk load ends in
+        self.bulk_build_seconds = 0.0
         # ONE snapshot matcher reused across generations: both matcher kinds
         # swap their compiled state atomically, and the sharded one folds
         # deltas incrementally (per-shard) instead of recompiling the world
@@ -209,7 +250,7 @@ class DeltaMatcher:
         snap.rebuild()
         self._snap = snap
         self._gen = _Gen(snap, [])
-        topics.add_observer(self._on_mutation)
+        topics.add_observer(self._on_mutation, self._on_bulk_end)
         if background:
             self._thread = threading.Thread(
                 target=self._rebuild_loop, name="mqtt-tpu-csr-rebuild", daemon=True
@@ -224,17 +265,31 @@ class DeltaMatcher:
     # -- delta stream --------------------------------------------------------
 
     def _on_mutation(self, m: Mutation) -> None:
+        # called under the trie lock, which bulk_depth moves under too
+        loading = self.topics.bulk_depth > 0
         with self._lock:
             gen = self._gen
-            gen.record(m.filter, m.kind)
-            pending = len(gen.deltas)
+            if loading:
+                gen.held += 1
+            else:
+                gen.record(m.filter, m.kind)
+            pending = gen.pending
         if pending >= self.rebuild_after:
-            self._wake.set()
+            if not loading:
+                self._wake.set()
+            elif pending == self.rebuild_after:
+                self._snap.stats.rebuilds_held += 1
+
+    def _on_bulk_end(self) -> None:
+        """The outermost bulk load closed (under the trie lock): wake the
+        rebuild thread for the one build, with no caller's flush()."""
+        self._snap.stats.bulk_loads += 1
+        self._wake.set()
 
     @property
     def pending_deltas(self) -> int:
         with self._lock:
-            return len(self._gen.deltas)
+            return self._gen.pending
 
     # -- rebuild -------------------------------------------------------------
 
@@ -277,13 +332,22 @@ class DeltaMatcher:
             with self._lock:
                 old = self._gen
                 k = len(old.deltas)
-            if k == 0:
+                held = old.held
+            if k == 0 and held == 0:
                 return
-            self._rebuild_snapshot(filters={f for f, _ in old.deltas[:k]})
+            t0 = time.perf_counter()
+            # a whole-dirty generation has no filter set to offer fold():
+            # what ends a bulk load is a full rebuild
+            self._rebuild_snapshot(
+                filters=None if held else {f for f, _ in old.deltas[:k]}
+            )
             with self._lock:
-                # mutations that raced the walk (appended after index k)
-                # might be missing from the new snapshot: carry them over
-                self._gen = _Gen(self._snap, old.deltas[k:])
+                # mutations that raced the walk (recorded after index k,
+                # or held after the count read above) might be missing
+                # from the new snapshot: carry them over
+                self._gen = _Gen(self._snap, old.deltas[k:], old.held - held)
+            if held:
+                self.bulk_build_seconds = time.perf_counter() - t0
 
     def flush(self) -> None:
         """Synchronously fold all pending deltas into a fresh snapshot."""
@@ -297,6 +361,13 @@ class DeltaMatcher:
             self._wake.clear()
             if self._stop.is_set():
                 return
+            if self.topics.bulk_depth > 0:
+                # a load is open: whatever this walked would be replaced
+                # whole. Its close sets _wake (after the depth is back to
+                # 0 and after the clear above, so the wake is not lost)
+                if self._gen.pending:
+                    self._snap.stats.rebuilds_held += 1
+                continue
             try:
                 self._rebuild_once()
             except Exception:
@@ -308,7 +379,7 @@ class DeltaMatcher:
                 self._wake.set()
 
     def close(self) -> None:
-        self.topics.remove_observer(self._on_mutation)
+        self.topics.remove_observer(self._on_mutation, self._on_bulk_end)
         if hasattr(self._snap, "close"):
             self._snap.close()  # detach the sharded snapshot's own observer
         self._stop.set()

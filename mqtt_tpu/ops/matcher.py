@@ -349,6 +349,11 @@ class MatcherStats:
     rebuilds: int = 0
     rebuild_seconds: float = 0.0
     folds: int = 0  # incremental folds that avoided a full rebuild
+    # bulk loads of the trie (TopicsIndex.bulk_load) that closed under a
+    # delta overlay, and the background wake-ups (threshold or interval)
+    # that an open one put off: the load ends in ONE build (ops/delta.py)
+    bulk_loads: int = 0
+    rebuilds_held: int = 0
     # topics served by the exact-map host fast path (wildcard-free filter
     # sets answer from one dict probe; no device round trip)
     host_fast: int = 0
@@ -388,6 +393,8 @@ class MatcherStats:
             "rebuilds": self.rebuilds,
             "rebuild_seconds": round(self.rebuild_seconds, 3),
             "folds": self.folds,
+            "bulk_loads": self.bulk_loads,
+            "rebuilds_held": self.rebuilds_held,
             "host_fast": self.host_fast,
             "compact_batches": self.compact_batches,
             "compact_overflows": self.compact_overflows,

@@ -1046,6 +1046,9 @@ class Server:
         self._durable: dict = {
             "recovering": False,
             "recovery_seconds": 0.0,
+            # the one match-table build the restore's bulk load ended in
+            # (DeltaMatcher.bulk_build_seconds; 0 with no device matcher)
+            "restore_build_seconds": 0.0,
             "replayed_keys": 0,
             "restored_subscriptions": 0,
             "restored_retained": 0,
@@ -1384,15 +1387,28 @@ class Server:
         self.publish_sys_topics()
         self.hooks.on_started()
         if self._durable["recovering"]:
+            flush = getattr(self.matcher, "flush", None)
+            if flush is not None:
+                # the restore was one bulk load of the trie, and its
+                # close woke the matcher for ONE build of the restored
+                # table: wait for that build (flush() queues behind it,
+                # or does it if the thread has not yet) off the loop,
+                # which keeps answering from the host trie meanwhile
+                await asyncio.get_running_loop().run_in_executor(None, flush)
+                self._durable["restore_build_seconds"] = getattr(
+                    self.matcher, "bulk_build_seconds", 0.0
+                )
             # the restored maps are now actually served: flip healthz
             # from 503 `recovering` to ready and leave the recovery
             # numbers behind as retained $SYS/broker/durable/# rows
             self._durable["recovering"] = False
             self.publish_durable_sys()
             self.log.info(
-                "durable restore complete: seconds=%.3f replayed_keys=%d "
-                "subscriptions=%d retained=%d inflight=%d batches=%d",
+                "durable restore complete: seconds=%.3f build_seconds=%.3f "
+                "replayed_keys=%d subscriptions=%d retained=%d inflight=%d "
+                "batches=%d",
                 self._durable["recovery_seconds"],
+                self._durable["restore_build_seconds"],
                 self._durable["replayed_keys"],
                 self._durable["restored_subscriptions"],
                 self._durable["restored_retained"],
@@ -1583,6 +1599,8 @@ class Server:
             ("mqtt_tpu_matcher_overflows_total", "overflows"),
             ("mqtt_tpu_matcher_rebuilds_total", "rebuilds"),
             ("mqtt_tpu_matcher_folds_total", "folds"),
+            ("mqtt_tpu_matcher_bulk_loads_total", "bulk_loads"),
+            ("mqtt_tpu_matcher_rebuilds_held_total", "rebuilds_held"),
             ("mqtt_tpu_matcher_host_fast_total", "host_fast"),
             ("mqtt_tpu_matcher_compact_batches_total", "compact_batches"),
             ("mqtt_tpu_matcher_d2h_bytes_total", "d2h_bytes"),
@@ -1626,6 +1644,12 @@ class Server:
             "Wall seconds the last restart spent restoring persisted "
             "state (store replay + bulk re-registration)",
             fn=lambda: self._durable["recovery_seconds"],
+        )
+        r.gauge(
+            "mqtt_tpu_durable_restore_build_seconds",
+            "Wall seconds of the one match-table build the last restore's "
+            "bulk load ended in (0 with no device matcher)",
+            fn=lambda: self._durable["restore_build_seconds"],
         )
         r.counter(
             "mqtt_tpu_durable_replayed_keys_total",
@@ -1699,6 +1723,7 @@ class Server:
         rows = {
             "recovering": "1" if d["recovering"] else "0",
             "recovery_seconds": "%.6f" % d["recovery_seconds"],
+            "restore_build_seconds": "%.6f" % d["restore_build_seconds"],
             "replayed_keys": str(d["replayed_keys"]),
             "restored_subscriptions": str(d["restored_subscriptions"]),
             "restored_retained": str(d["restored_retained"]),
@@ -1761,6 +1786,9 @@ class Server:
                 "recovering": self._durable["recovering"],
                 "recovery_seconds": round(
                     self._durable["recovery_seconds"], 3
+                ),
+                "restore_build_seconds": round(
+                    self._durable["restore_build_seconds"], 3
                 ),
                 "replayed_keys": self._durable["replayed_keys"],
             }
@@ -5422,7 +5450,10 @@ class Server:
             entries.append((sub.client, sb))
         # batched re-registration (ISSUE 16): a million-session restart
         # must not pay a trie lock round-trip per subscription — chunks
-        # flow through the trie's bulk-insert path
+        # flow through the trie's bulk-insert path, as one bulk load of
+        # the trie (the device matcher builds once, when it closes; a
+        # caller that feeds this a stored batch at a time holds
+        # `self.topics.bulk_load()` open around its calls)
         from .staging import bulk_register
 
         new, batches = bulk_register(

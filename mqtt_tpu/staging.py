@@ -989,19 +989,27 @@ def bulk_register(topics, entries, batch: int = 4096) -> tuple[int, int]:
     ``subscribe`` round-trip, which is the difference between a bounded
     and an unbounded restart at a million sessions. Returns
     ``(new_subscriptions, batches)`` so recovery metrics can prove the
-    path was actually batched."""
+    path was actually batched.
+
+    The whole loop, not a chunk, is one bulk load of the trie
+    (``TopicsIndex.bulk_load``, re-entrant: a caller that restores a
+    stored batch a call holds one open around its calls): an observing
+    ``DeltaMatcher`` rebuilds nothing while it runs, answers publishes
+    from the host trie meanwhile, and builds its table once when the
+    outermost load closes, whether this returns or raises."""
     added = 0
     batches = 0
     chunk: list = []
-    for entry in entries:
-        chunk.append(entry)
-        if len(chunk) >= batch:
+    with topics.bulk_load():
+        for entry in entries:
+            chunk.append(entry)
+            if len(chunk) >= batch:
+                added += topics.subscribe_bulk(chunk)
+                batches += 1
+                chunk = []
+        if chunk:
             added += topics.subscribe_bulk(chunk)
             batches += 1
-            chunk = []
-    if chunk:
-        added += topics.subscribe_bulk(chunk)
-        batches += 1
     return added, batches
 
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import re
 import threading
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -508,16 +509,62 @@ class TopicsIndex:
         # else; the mesh-sharded matcher (mqtt_tpu.parallel) additionally
         # applies the mutation to the owning shard's replica trie.
         self._observers: list[Callable[[Mutation], None]] = []
+        # open bulk loads (bulk_load): above 0 a restart-sized run of
+        # inserts is in progress, and a table derived from the trie now
+        # would be thrown away by the next thousand entries. Moves under
+        # the trie lock, so an observer reads it consistently with the
+        # mutation it is handed.
+        self.bulk_depth = 0
+        self._bulk_end_observers: list[Callable[[], None]] = []
 
-    def add_observer(self, fn: Callable[[Mutation], None]) -> None:
-        """Register a subscription-mutation observer (delta stream consumer)."""
+    def add_observer(
+        self,
+        fn: Callable[[Mutation], None],
+        on_bulk_end: Optional[Callable[[], None]] = None,
+    ) -> None:
+        """Register a subscription-mutation observer (delta stream
+        consumer). ``on_bulk_end``, when given, is called under the trie
+        lock each time the last open :meth:`bulk_load` closes."""
         with self._lock:
             self._observers.append(fn)
+            if on_bulk_end is not None:
+                self._bulk_end_observers.append(on_bulk_end)
 
-    def remove_observer(self, fn: Callable[[Mutation], None]) -> None:
+    def remove_observer(
+        self,
+        fn: Callable[[Mutation], None],
+        on_bulk_end: Optional[Callable[[], None]] = None,
+    ) -> None:
         with self._lock:
             if fn in self._observers:
                 self._observers.remove(fn)
+            if on_bulk_end in self._bulk_end_observers:
+                self._bulk_end_observers.remove(on_bulk_end)
+
+    @contextmanager
+    def bulk_load(self):
+        """Mark a bulk load open on this trie for the length of the block
+        (``staging.bulk_register`` holds one around its whole loop, which
+        is the durable restore's route; a caller that loads in several
+        such calls holds one around them). Re-entrant and counted: only
+        the outermost close returns ``bulk_depth`` to 0 and tells the
+        observers that registered an ``on_bulk_end``, and an exception
+        inside the block closes it all the same. The mutation stream is
+        untouched (every entry still reaches every observer); what the
+        mark changes is what an observer may put off until the close:
+        the delta overlay (``mqtt_tpu.ops.delta``) rebuilds nothing
+        while a load is open and builds once when it ends."""
+        with self._lock:
+            self.bulk_depth += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self.bulk_depth -= 1
+                if self.bulk_depth == 0:
+                    for fn in self._bulk_end_observers:
+                        # brokerlint: ok=R5 intentional in-lock delivery, as _notify: the close must be atomic with the mutation stream (per-entry recording resumes with the first mutation after it); observers are contract-bound to O(1) work (a counter and an Event.set)
+                        fn()
 
     def _notify(self, mutation: Mutation) -> None:
         for fn in self._observers:

@@ -12,6 +12,7 @@ because recovery itself must be idempotent."""
 
 import asyncio
 import random
+import time
 import types
 
 import pytest
@@ -660,6 +661,108 @@ class TestBatchedRestore:
         assert len(srv.topics.retained) == 10
         names = srv._retained_engine.match("r/+")
         assert names is not None and len(names) == 10
+
+    @pytest.mark.parametrize("resilient", [True, False], ids=["breaker", "bare"])
+    def test_restored_server_leaves_recovering_after_the_one_build(self, resilient):
+        """The restore is one bulk load of the trie: the device matcher
+        builds nothing while it runs, once when it ends, and `recovering`
+        is left only after that build (healthz 503 until then)."""
+        from mqtt_tpu.hooks import STORED_SUBSCRIPTIONS, Hook
+        from mqtt_tpu.listeners import Config as LConfig
+        from mqtt_tpu.listeners.tcp import TCP
+
+        stored = [
+            types.SimpleNamespace(
+                client=f"c{i}",
+                filter=f"t/{i % 50}/+" if i % 10 == 0 else f"t/{i % 50}/{i}",
+                qos=1,
+                retain_handling=0,
+                retain_as_published=False,
+                no_local=False,
+                identifier=0,
+                predicates=(),
+            )
+            for i in range(3000)
+        ]
+
+        class Store(Hook):
+            def id(self):
+                return "stub-store"
+
+            def provides(self, b):
+                return b == STORED_SUBSCRIPTIONS
+
+            def stored_subscriptions(self):
+                return stored
+
+        async def scenario():
+            srv = Server(
+                Options(
+                    inline_client=False,
+                    device_matcher=True,
+                    matcher_resilience=resilient,
+                    matcher_opts={"rebuild_after": 8, "rebuild_interval": 0.01},
+                    durable_restore_batch=256,
+                )
+            )
+            srv.add_hook(Store(), None)
+            srv.add_listener(TCP(LConfig(type="tcp", id="t", address="127.0.0.1:0")))
+            stats = srv.matcher.stats
+            during, at_build = [], []
+            chunk = srv.topics.subscribe_bulk
+            srv.topics.subscribe_bulk = lambda entries: (
+                time.sleep(0.02),  # interval ticks fall inside the load
+                during.append((stats.rebuilds, srv.topics.bulk_depth)),
+                chunk(entries),
+            )[2]
+            snap = srv.matcher._snap
+            build = snap.rebuild
+
+            def rebuild():
+                build()
+                ok, detail = srv.health_report()
+                at_build.append((ok, list(detail["not_ready"])))
+
+            snap.rebuild = rebuild
+            try:
+                await srv.serve()  # read_store(), then the wait for the build
+                assert len(during) == 12 and all(d == (1, 1) for d in during), during
+                assert srv._durable["restored_subscriptions"] == 3000
+                # exactly the one build, made while healthz still said 503
+                assert at_build == [(False, ["recovering"])]
+                assert not srv._durable["recovering"]
+                assert srv.health_report()[0]
+                assert (stats.rebuilds, stats.folds, stats.bulk_loads) == (2, 0, 1)
+                assert stats.rebuilds_held >= 2
+                assert srv.matcher.pending_deltas == 0
+                assert srv._durable["restore_build_seconds"] > 0.0
+                for topic in ("t/0/0", "t/7/7", "t/40/x"):
+                    got = srv.matcher.subscribers(topic)
+                    assert sorted(got.subscriptions) == sorted(
+                        srv.topics.subscribers(topic).subscriptions
+                    )
+                    assert got.subscriptions
+                text = srv.telemetry.registry.exposition()
+                rows = dict(
+                    line.rsplit(" ", 1)
+                    for line in text.splitlines()
+                    if line and not line.startswith("#")
+                )
+                assert float(rows["mqtt_tpu_matcher_bulk_loads_total"]) == 1
+                assert float(rows["mqtt_tpu_matcher_rebuilds_held_total"]) >= 2
+                assert float(rows["mqtt_tpu_matcher_rebuilds_total"]) == 2
+                assert float(rows["mqtt_tpu_durable_restore_build_seconds"]) > 0
+                row = srv.topics.retained.get(
+                    "$SYS/broker/durable/restore_build_seconds"
+                )
+                assert row is not None and float(row.payload) > 0
+                assert int(
+                    srv.topics.retained.get("$SYS/broker/matcher/bulk_loads").payload
+                ) == 1
+            finally:
+                await srv.close()
+
+        run(scenario())
 
     def test_healthz_holds_503_while_recovering(self):
         srv = Server(Options(inline_client=False))

@@ -1056,3 +1056,97 @@ def test_handoff_fuzz_200_schedules():
     """The chaos-smoke acceptance sweep (ISSUE 19): >= 200 seeded
     cross-shard handoff schedules under the raising loop witness."""
     _run_handoff_sweep(range(200), deadline_s=540)
+
+
+def test_bulk_loads_race_live_churn_and_matching():
+    """Three loaders open overlapping bulk loads (``bulk_depth`` up to 3,
+    from different threads) while a writer churns live subscriptions and
+    this thread matches, all under a 100 us switch interval: the hold's
+    shared state (``_Gen.held``, the depth, the wake) must lose nothing.
+    When the last load has closed the overlay drains on its own and the
+    table equals the trie."""
+    from mqtt_tpu.staging import bulk_register
+
+    index = TopicsIndex()
+    faulthandler.dump_traceback_later(110, exit=True)
+    m = DeltaMatcher(
+        index, max_levels=4, rebuild_after=64, rebuild_interval=0.02, background=True
+    )
+    stop = threading.Event()
+    errors: list = []
+    loaded = [0, 0, 0]
+
+    def loader(k: int) -> None:
+        r = random.Random(100 + k)
+
+        def entries(n):
+            for i in range(n):
+                if i % 64 == 0:
+                    time.sleep(0.002)  # leave the lock to the other threads
+                yield f"l{k}_{loaded[k] + i}", Subscription(filter=_rand_filter(r), qos=i % 3)
+
+        try:
+            for _ in range(8):
+                n = r.randint(50, 300)
+                bulk_register(index, entries(n), batch=64)
+                loaded[k] += n
+                time.sleep(0.01 * r.random())
+        except Exception as e:  # pragma: no cover - the assertion target
+            errors.append(e)
+
+    def writer() -> None:
+        r = random.Random(9)
+        i = 0
+        try:
+            while not stop.is_set():
+                flt = _rand_filter(r)
+                if r.random() < 0.6:
+                    index.subscribe(f"w{i}", Subscription(filter=flt, qos=1))
+                else:
+                    index.unsubscribe(flt, f"w{r.randint(0, max(1, i))}")
+                i += 1
+                time.sleep(0.0005)
+        except Exception as e:  # pragma: no cover - the assertion target
+            errors.append(e)
+
+    threads = [threading.Thread(target=loader, args=(k,), daemon=True) for k in range(3)]
+    threads.append(threading.Thread(target=writer, daemon=True))
+    r = random.Random(42)
+    batches = 0
+    try:
+        with switch_interval(1e-4):
+            for t in threads:
+                t.start()
+            t_end = time.time() + 30.0
+            while any(t.is_alive() for t in threads[:3]) and time.time() < t_end:
+                topics = [_rand_topic(r) for _ in range(64)]
+                assert len(m.match_topics(topics)) == len(topics)
+                batches += 1
+            stop.set()
+            for t in threads:
+                t.join(30)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        assert batches >= 2 and sum(loaded) > 0
+        assert index.bulk_depth == 0
+        # nobody flushes: the last close (or the tick after the churn's
+        # last mutation) wakes the thread, and the overlay drains
+        deadline = time.time() + 30
+        while m.pending_deltas and time.time() < deadline:
+            time.sleep(0.02)
+        assert m.pending_deltas == 0 and m._gen.held == 0
+        assert m.rebuild_errors == 0
+        assert m.stats.bulk_loads >= 1
+        # every loaded client is in the trie exactly once
+        r2 = random.Random(7)
+        topics = [_rand_topic(r2) for _ in range(512)]
+        st = m.stats
+        before = (st.host_fallbacks, st.overflows)
+        for topic, got in zip(topics, m.match_topics(topics)):
+            assert canon(got) == canon(index.subscribers(topic)), topic
+        # answered from the table: no topic was routed by the overlay
+        assert st.host_fallbacks - before[0] == st.overflows - before[1]
+    finally:
+        stop.set()
+        faulthandler.cancel_dump_traceback_later()
+        m.close()
