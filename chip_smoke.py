@@ -292,9 +292,13 @@ class Smoke:
         out["build_s"] = round(stats.build_seconds, 3)
         out["upload_s"] = round(stats.upload_seconds, 3)
         out["rebuilds_during_load"] = stats.rebuilds
+        # what the loaded trie costs (read, not gated)
+        out["particles"] = self.srv.topics.particles
+        out["particle_maps"] = self.srv.topics.particle_maps
         self.note(
             f"loaded {len(subs)} subs in {out['load_s']}s, flush "
-            f"{out['flush_s']}s ({stats.rebuilds} rebuilds)"
+            f"{out['flush_s']}s ({stats.rebuilds} rebuilds), "
+            f"{out['particles']} particles, {out['particle_maps']} maps"
         )
 
     async def _attach_real_subscribers(self, real: list, oracle) -> None:

@@ -2470,9 +2470,14 @@ class Cluster:
         try:
             for path, node in _walk_terminals(self.server.topics):
                 base = "/".join(path)
-                for group in list(node.shared.internal):
-                    out.append(f"{SHARE_PREFIX}/{group}/{base}")
-                if node.subscriptions.internal or node.inline_subscriptions.internal:
+                shared = node.shared
+                if shared is not None:
+                    for group in list(shared.internal):
+                        out.append(f"{SHARE_PREFIX}/{group}/{base}")
+                if (
+                    node.subscriptions is not None
+                    or node.inline_subscriptions is not None
+                ):
                     out.append(base)
         except (RuntimeError, KeyError):
             pass  # racing mutations re-enter via the observer anyway
@@ -2486,10 +2491,8 @@ class Cluster:
                 node = self.server.topics._seek(f, 2 if share_rooted else 0)
                 if node is None:
                     return False, False
-                has_cli = bool(node.subscriptions.internal) or bool(
-                    node.shared.internal
-                )
-                has_inl = bool(node.inline_subscriptions.internal)
+                has_cli = node.subscriptions is not None or node.shared is not None
+                has_inl = node.inline_subscriptions is not None
                 return has_cli or has_inl, has_inl and not has_cli
             except (RuntimeError, KeyError):
                 continue
@@ -2513,10 +2516,14 @@ class Cluster:
                     return False, False, frozenset()
                 plain = False
                 sfx = set()
-                subs: list = list(node.subscriptions.internal.values())
-                subs.extend(node.inline_subscriptions.internal.values())
-                for group in node.shared.internal.values():
-                    subs.extend(group.values())
+                subs: list = []
+                if node.subscriptions is not None:
+                    subs.extend(node.subscriptions.internal.values())
+                if node.inline_subscriptions is not None:
+                    subs.extend(node.inline_subscriptions.internal.values())
+                if node.shared is not None:
+                    for group in node.shared.internal.values():
+                        subs.extend(group.values())
                 for sub in subs:
                     preds = getattr(sub, "predicates", ()) or ()
                     if preds:

@@ -500,23 +500,25 @@ def _node_snap(node) -> tuple:
     tuple ``(clients, shared, inline)`` — the unit both the sid table and
     the exact-map fast path serve from. Reads the live maps without the
     lock (tears retry, same contract as ``_walk_terminals``)."""
-    cli = tuple(node.subscriptions.internal.items())
+    subs, shared, inline = node.subscriptions, node.shared, node.inline_subscriptions
+    cli = tuple(subs.internal.items()) if subs is not None else ()
     shr = (
         tuple(
             (c, s)
-            for group in node.shared.internal.values()
+            for group in shared.internal.values()
             for c, s in group.items()
         )
-        if node.shared.internal
+        if shared is not None
         else ()
     )
-    inl = tuple(node.inline_subscriptions.internal.values())
+    inl = tuple(inline.internal.values()) if inline is not None else ()
     return (cli, shr, inl)
 
 
 def _walk_terminals(index: TopicsIndex):
     """Yield (path_levels, particle) for every trie node carrying
-    subscriptions. Iterative (deep tries must not recurse) and lock-free:
+    subscriptions: a particle names a map only while the map holds
+    something. Iterative (deep tries must not recurse) and lock-free:
     it reads the live maps without copying, so a concurrent structural
     mutation can tear the walk with RuntimeError/KeyError — callers retry
     (the same contract the sharded rebuild documents)."""
@@ -524,9 +526,9 @@ def _walk_terminals(index: TopicsIndex):
     while stack:
         p, path = stack.pop()
         if (
-            p.subscriptions.internal
-            or p.shared.internal
-            or p.inline_subscriptions.internal
+            p.subscriptions is not None
+            or p.shared is not None
+            or p.inline_subscriptions is not None
         ):
             yield path, p
         for key, child in p.particles.items():

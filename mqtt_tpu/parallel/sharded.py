@@ -46,6 +46,7 @@ from ..ops.flat import (
     KIND_SHARED,
     SubEntry,
     _bucket,
+    _node_snap,
     _pad_to,
     _walk_terminals,
     build_flat_index,
@@ -350,14 +351,14 @@ class ShardedTpuMatcher:
         walk (RuntimeError/KeyError from dict iteration) — callers retry."""
         replicas = [TopicsIndex() for _ in range(self.n_shards)]
         for _path, node in _walk_terminals(self.topics):
-            for client, sub in node.subscriptions.get_all().items():
+            cli, shr, inl = _node_snap(node)
+            for client, sub in cli:
                 s = shard_of(KIND_CLIENT, client, sub.filter, 0, self.n_shards)
                 replicas[s].subscribe(client, sub)
-            for group in node.shared.get_all().values():
-                for client, sub in group.items():
-                    s = shard_of(KIND_SHARED, client, sub.filter, 0, self.n_shards)
-                    replicas[s].subscribe(client, sub)
-            for isub in node.inline_subscriptions.get_all().values():
+            for client, sub in shr:
+                s = shard_of(KIND_SHARED, client, sub.filter, 0, self.n_shards)
+                replicas[s].subscribe(client, sub)
+            for isub in inl:
                 s = shard_of(
                     KIND_INLINE, "", isub.filter, isub.identifier, self.n_shards
                 )

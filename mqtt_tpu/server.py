@@ -1463,12 +1463,17 @@ class Server:
         """The broker's running counts a profiler slice's snapshots take
         (``tracing.DeviceProfiler.counters``): fallbacks the stage held
         in their publisher's order, frames handed to subscribers'
-        sockets, and calls that reached a socket."""
+        sockets, calls that reached a socket, and what the trie holds
+        (``TopicsIndex``'s three counts)."""
         stage = self._stage
+        trie = self.topics
         return {
             "order_held": 0 if stage is None else stage.order_held,
             "deliveries": self.telemetry.fanout_deliveries.value,
             "socket_sends": self._ops.socket_sends,
+            "particles": trie.particles,
+            "particle_maps": trie.particle_maps,
+            "held": trie.held,
         }
 
     def _register_core_gauges(self) -> None:
@@ -1499,6 +1504,27 @@ class Server:
             ("mqtt_tpu_inflight_messages", "inflight"),
         ):
             r.gauge(name, f"$SYS mirror of Info.{attr}", fn=lambda a=attr: getattr(info, a))
+        for name, attr, what in (
+            (
+                "mqtt_tpu_topics_particles",
+                "particles",
+                "Live nodes of the topic trie (the root is one)",
+            ),
+            (
+                "mqtt_tpu_topics_particle_maps",
+                "particle_maps",
+                "Containers alive across the trie's nodes: children dicts "
+                "and subscription, shared and inline maps (a node makes one "
+                "with its first entry of the kind and drops it with the last)",
+            ),
+            (
+                "mqtt_tpu_topics_held",
+                "held",
+                "Subscriptions of all three kinds the trie holds, bulk-loaded "
+                "ones included (mqtt_tpu_subscriptions counts live clients')",
+            ),
+        ):
+            r.gauge(name, what, fn=lambda a=attr: getattr(self.topics, a))
         r.gauge(
             "mqtt_tpu_uptime_seconds",
             "Monotonic seconds since broker start (clock-step immune)",
@@ -5072,6 +5098,13 @@ class Server:
             SYS_PREFIX + "/broker/subscriptions": str(info.subscriptions),
             SYS_PREFIX + "/broker/system/memory": str(info.memory_alloc),
             SYS_PREFIX + "/broker/system/threads": str(info.threads),
+            # what the trie costs (TopicsIndex's three counts): nodes,
+            # containers across them, subscriptions held
+            SYS_PREFIX + "/broker/topics/particles": str(self.topics.particles),
+            SYS_PREFIX + "/broker/topics/particle_maps": str(
+                self.topics.particle_maps
+            ),
+            SYS_PREFIX + "/broker/topics/held": str(self.topics.held),
         }
         if self.matcher is not None:
             # device-matcher observability (MatcherStats.as_dict): batches,

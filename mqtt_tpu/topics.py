@@ -461,7 +461,20 @@ class Subscribers:
 
 
 class _Particle:
-    """One trie node (reference 'particle', topics.go:748-769)."""
+    """One trie node (reference 'particle', topics.go:748-769).
+
+    A particle allocates only what it holds. ``subscriptions``,
+    ``shared`` and ``inline_subscriptions`` are ``None`` until the first
+    entry of that kind arrives here and ``None`` again once the last one
+    leaves: a path's interior particles, which hold nothing, own one
+    container (their children) and no lock. A map that is not ``None``
+    is never empty once the mutation that touched it is over.
+
+    Mutations run under the trie lock, readers (``subscribers()``, the
+    lock-free walks of ``ops.flat``) under none, so a map is made whole
+    and filled BEFORE the particle's attribute names it and emptied
+    before the attribute lets it go: a reader finds ``None`` or a map
+    its own lock guards, never a half-made one."""
 
     __slots__ = (
         "key",
@@ -477,9 +490,9 @@ class _Particle:
         self.key = key
         self.parent = parent
         self.particles: dict[str, _Particle] = {}
-        self.subscriptions = Subscriptions()
-        self.shared = SharedSubscriptions()
-        self.inline_subscriptions = InlineSubscriptions()
+        self.subscriptions: Subscriptions | None = None
+        self.shared: SharedSubscriptions | None = None
+        self.inline_subscriptions: InlineSubscriptions | None = None
         self.retain_path = ""
 
 
@@ -516,6 +529,14 @@ class TopicsIndex:
         # mutation it is handed.
         self.bulk_depth = 0
         self._bulk_end_observers: list[Callable[[], None]] = []
+        # what the trie costs, as three plain counts that move under the
+        # trie lock: live nodes (the root is one), containers alive
+        # across them (children dicts and the three kinds of map), and
+        # subscriptions of all three kinds the trie holds, bulk-loaded
+        # ones included
+        self.particles = 1
+        self.particle_maps = 1
+        self.held = 0
 
     def add_observer(
         self,
@@ -573,25 +594,47 @@ class TopicsIndex:
 
     # -- mutation ----------------------------------------------------------
 
+    def _insert(self, client: str, subscription: Subscription) -> bool:
+        """One subscription into its particle, under the trie lock; True
+        if it was new. The particle's map of that kind is made here when
+        this is its first entry."""
+        self.version += 1
+        prefix, _ = isolate_particle(subscription.filter, 0)
+        if prefix.upper() == SHARE_PREFIX:
+            group, _ = isolate_particle(subscription.filter, 1)
+            n = self._set(subscription.filter, 2)
+            shared = n.shared
+            if shared is None:
+                shared = SharedSubscriptions()
+                existed = False
+            else:
+                existed = shared.get(group, client) is not None
+            shared.add(group, client, subscription)
+            if n.shared is None:
+                n.shared = shared
+                self.particle_maps += 1
+        else:
+            n = self._set(subscription.filter, 0)
+            subs = n.subscriptions
+            if subs is None:
+                subs = Subscriptions()
+                existed = False
+            else:
+                existed = subs.get(client) is not None
+            subs.add(client, subscription)
+            if n.subscriptions is None:
+                n.subscriptions = subs
+                self.particle_maps += 1
+        if not existed:
+            self.held += 1
+        self._notify(Mutation(subscription.filter, "sub", "add", client, subscription))
+        return not existed
+
     def subscribe(self, client: str, subscription: Subscription) -> bool:
         """Add a subscription; returns True if it was new (topics.go:401-419).
         ``$SHARE/<group>/<filter>`` roots the subtree at depth 2."""
         with self._lock:
-            self.version += 1
-            prefix, _ = isolate_particle(subscription.filter, 0)
-            if prefix.upper() == SHARE_PREFIX:
-                group, _ = isolate_particle(subscription.filter, 1)
-                n = self._set(subscription.filter, 2)
-                existed = n.shared.get(group, client) is not None
-                n.shared.add(group, client, subscription)
-            else:
-                n = self._set(subscription.filter, 0)
-                existed = n.subscriptions.get(client) is not None
-                n.subscriptions.add(client, subscription)
-            self._notify(
-                Mutation(subscription.filter, "sub", "add", client, subscription)
-            )
-            return not existed
+            return self._insert(client, subscription)
 
     def subscribe_bulk(self, entries: list[tuple[str, Subscription]]) -> int:
         """Batched :meth:`subscribe`: one lock acquisition inserts a whole
@@ -603,23 +646,10 @@ class TopicsIndex:
         observers fire for every entry, so device-matcher overlays see
         the same mutation stream either way."""
         added = 0
+        insert = self._insert
         with self._lock:
             for client, subscription in entries:
-                self.version += 1
-                prefix, _ = isolate_particle(subscription.filter, 0)
-                if prefix.upper() == SHARE_PREFIX:
-                    group, _ = isolate_particle(subscription.filter, 1)
-                    n = self._set(subscription.filter, 2)
-                    existed = n.shared.get(group, client) is not None
-                    n.shared.add(group, client, subscription)
-                else:
-                    n = self._set(subscription.filter, 0)
-                    existed = n.subscriptions.get(client) is not None
-                    n.subscriptions.add(client, subscription)
-                self._notify(
-                    Mutation(subscription.filter, "sub", "add", client, subscription)
-                )
-                if not existed:
+                if insert(client, subscription):
                     added += 1
         return added
 
@@ -638,9 +668,21 @@ class TopicsIndex:
             self.version += 1
             if share_sub:
                 group, _ = isolate_particle(filter, 1)
-                particle.shared.delete(group, client)
+                shared = particle.shared
+                if shared is not None and shared.get(group, client) is not None:
+                    shared.delete(group, client)
+                    self.held -= 1
+                    if not shared.internal:
+                        particle.shared = None
+                        self.particle_maps -= 1
             else:
-                particle.subscriptions.delete(client)
+                subs = particle.subscriptions
+                if subs is not None and subs.get(client) is not None:
+                    subs.delete(client)
+                    self.held -= 1
+                    if not subs.internal:
+                        particle.subscriptions = None
+                        self.particle_maps -= 1
             self._trim(particle)
             self._notify(Mutation(filter, "sub", "del", client))
             return True
@@ -651,8 +693,18 @@ class TopicsIndex:
         with self._lock:
             self.version += 1
             n = self._set(subscription.filter, 0)
-            existed = n.inline_subscriptions.get(subscription.identifier) is not None
-            n.inline_subscriptions.add_inline(subscription)
+            inline = n.inline_subscriptions
+            if inline is None:
+                inline = InlineSubscriptions()
+                existed = False
+            else:
+                existed = inline.get(subscription.identifier) is not None
+            inline.add_inline(subscription)
+            if n.inline_subscriptions is None:
+                n.inline_subscriptions = inline
+                self.particle_maps += 1
+            if not existed:
+                self.held += 1
             self._notify(
                 Mutation(
                     subscription.filter,
@@ -670,7 +722,7 @@ class TopicsIndex:
         rule refcounts track the subscription actually stored."""
         with self._lock:
             particle = self._seek(filter, 0)
-            if particle is None:
+            if particle is None or particle.inline_subscriptions is None:
                 return None
             return particle.inline_subscriptions.get(id_)
 
@@ -680,8 +732,14 @@ class TopicsIndex:
             if particle is None:
                 return False
             self.version += 1
-            particle.inline_subscriptions.delete(id_)
-            if len(particle.inline_subscriptions) == 0:
+            inline = particle.inline_subscriptions
+            if inline is not None and inline.get(id_) is not None:
+                inline.delete(id_)
+                self.held -= 1
+                if not inline.internal:
+                    particle.inline_subscriptions = None
+                    self.particle_maps -= 1
+            if particle.inline_subscriptions is None:
                 self._trim(particle)
             self._notify(Mutation(filter, "inline", "del", identifier=id_))
             return True
@@ -733,6 +791,8 @@ class TopicsIndex:
             if p is None:
                 p = _Particle(key, n)
                 n.particles[key] = p
+                self.particles += 1
+                self.particle_maps += 1
             n = p
         return n
 
@@ -750,11 +810,16 @@ class TopicsIndex:
         while (
             n.parent is not None
             and n.retain_path == ""
-            and len(n.particles) + len(n.subscriptions) + len(n.shared) + len(n.inline_subscriptions) == 0
+            and not n.particles
+            and n.subscriptions is None
+            and n.shared is None
+            and n.inline_subscriptions is None
         ):
             key = n.key
             n = n.parent
-            n.particles.pop(key, None)
+            if n.particles.pop(key, None) is not None:
+                self.particles -= 1
+                self.particle_maps -= 1
 
     # -- scans -------------------------------------------------------------
 
@@ -815,7 +880,10 @@ class TopicsIndex:
         """Merge a particle's subscriptions into the result set, excluding
         top-level-wildcard filters for $-topics [MQTT-4.7.1-1/2]
         (topics.go:631-648)."""
-        for client, sub in particle.subscriptions.get_all().items():
+        held = particle.subscriptions
+        if held is None:
+            return
+        for client, sub in held.get_all().items():
             if sub.filter and topic[0] == "$" and sub.filter[0] in "+#":
                 continue
             if self._ns_excluded(topic, sub.filter):
@@ -824,7 +892,10 @@ class TopicsIndex:
             subs.subscriptions[client] = cls.merge(sub)
 
     def _gather_shared(self, topic: str, particle: _Particle, subs: Subscribers) -> None:
-        for shares in particle.shared.get_all().values():
+        held = particle.shared
+        if held is None:
+            return
+        for shares in held.get_all().values():
             for client, sub in shares.items():
                 if topic[:1] == NS_CHAR:
                     # the namespace guard applies to the INNER filter
@@ -836,12 +907,15 @@ class TopicsIndex:
                 subs.shared.setdefault(sub.filter, {})[client] = sub
 
     def _gather_inline(self, topic: str, particle: _Particle, subs: Subscribers) -> None:
+        held = particle.inline_subscriptions
+        if held is None:
+            return
         if topic[:1] == NS_CHAR:
-            for iid, isub in particle.inline_subscriptions.get_all().items():
+            for iid, isub in held.get_all().items():
                 if not self._ns_excluded(topic, isub.filter):
                     subs.inline_subscriptions[iid] = isub
             return
-        subs.inline_subscriptions.update(particle.inline_subscriptions.get_all())
+        subs.inline_subscriptions.update(held.get_all())
 
     def messages(self, filter: str) -> list[Packet]:
         """All retained messages matching ``filter`` (topics.go:525-579).

@@ -526,8 +526,12 @@ class LockedMap(Generic[K, V]):
     """RLock-protected dict with copy-on-iterate semantics. Pass a
     ``name`` to register the lock with the contention plane (the hot
     singletons — the client registry, the retained store); unnamed maps
-    (per-particle subscription containers, per-client state) keep the
-    bare RLock so the trie's millions of nodes cost nothing extra."""
+    (per-client state, a trie particle's subscription containers) keep
+    the bare RLock and stay off the plane. Bare is not free: a map is an
+    object, a dict and an ``RLock`` the collector tracks, so a trie
+    particle makes one only with its first entry of the kind and drops
+    it with the last (``topics._Particle``); a particle that holds
+    nothing has no map and no lock."""
 
     def __init__(self, name: Optional[str] = None) -> None:
         self._lock: Any = (
