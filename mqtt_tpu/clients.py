@@ -42,6 +42,11 @@ MINIMUM_KEEPALIVE = 5  # below this a warning is logged (clients.go:27)
 # a socket's cork (Client._cork) is written out early once it holds this
 # many bytes: large payloads are never joined into one buffer
 CORK_MAX_BYTES = 64 * 1024
+# the first bytes of the frames an ingest run takes (server.ingest_run):
+# a PUBLISH of QoS0 or QoS1 without RETAIN, and QoS1 with DUP, which
+# changes nothing inbound. The rest of the 0x3_ bytes are RETAIN, QoS2
+# and the two the fixed header refuses (QoS3, DUP at QoS0).
+RUN_FIRST_BYTES = frozenset((0x30, 0x32, 0x3A))
 
 
 class ConnectionClosedError(Exception):
@@ -430,19 +435,36 @@ class Client:
         if self.net.writer is None:
             return
         self._write(data)
-        self.ops.info.bytes_sent += len(data)
-        self.ops.info.packets_sent += 1
+        # io accounting only: the DELIVERY count for a shared frame is
+        # stamped by server._enqueue_frame, which still knows the topic
+        # (this pre-encoded frame does not) and so can keep $SYS
+        # housekeeping out of the amplification math
+        self._count_sent(len(data))
         self.ops.info.messages_sent += 1
+
+    def write_puback(self, packet_id: int) -> None:
+        """Write a v3.1.1 PUBACK as its four bytes, counted as
+        ``write_packet`` counts one: for a caller that has seen that no
+        hook takes the ack as a packet (server.ingest_run)."""
+        if self.closed:
+            raise ConnectionClosedError()
+        if self.net.writer is None:
+            return
+        self._write(bytes((0x40, 2, packet_id >> 8, packet_id & 0xFF)))
+        self._count_sent(4)
+
+    def _count_sent(self, n: int) -> None:
+        """One packet of ``n`` bytes went to the transport (or its
+        cork): ``info``, the connection's own and telemetry's counts."""
+        info = self.ops.info
+        info.bytes_sent += n
+        info.packets_sent += 1
         st = self.state
-        st.out_bytes += len(data)
+        st.out_bytes += n
         st.out_writes += 1
         tele = getattr(self.ops, "telemetry", None)
         if tele is not None:
-            # io accounting only here: the DELIVERY count for a shared
-            # frame is stamped by server._enqueue_frame, which still
-            # knows the topic (this pre-encoded frame does not) and so
-            # can keep $SYS housekeeping out of the amplification math
-            tele.outbound_bytes.inc(len(data))
+            tele.outbound_bytes.inc(n)
             tele.outbound_writes.inc()
 
     def _write(self, data: bytes) -> None:
@@ -592,6 +614,16 @@ class Client:
         which is what keeps the asyncio data plane within reach of the
         reference's goroutine throughput (SURVEY.md §7 hard-part #5).
 
+        A scan's frames are taken in by the run where they can be: every
+        stretch of PUBLISH frames of QoS0 or QoS1 without RETAIN
+        (``RUN_FIRST_BYTES``) goes to the server in ONE call
+        (``ops.ingest_run``, server.ingest_run), which decodes, checks,
+        acknowledges and parks as many of them as it can take as they
+        stand and says how many it took. The frame that ended the run,
+        the whole stretch where the run's gate is shut, and every other
+        packet go one at a time through ``packet_handler``, in the
+        frames' order always.
+
         ``packet_handler(cl, pk)`` is synchronous. A PUBLISH it parked
         with the staging loop (mqtt_tpu.staging) is still counted in
         ``_staged`` when it returns: every publish of a scan reaches the
@@ -611,6 +643,7 @@ class Client:
         caps = self.ops.options.capabilities
         fast_eligible = self.ops.fast_publish_eligible
         fast_publish = self.ops.fast_publish
+        ingest_run = self.ops.ingest_run
         telemetry = getattr(self.ops, "telemetry", None)
         # device pipeline profiler (mqtt_tpu.tracing): while a profiler
         # session is live, the loop time from a scan's frames in hand to
@@ -646,8 +679,36 @@ class Client:
                 n_in = self._pub_count
             start = 0
             self._cork = bytearray()  # this read's acks leave as one write
+            n = len(frames)
+            i = 0
+            solo = 0  # the frames below this index go one at a time
             try:
-                for f in frames:
+                while i < n:
+                    f = frames[i]
+                    if (
+                        i >= solo
+                        and f.first_byte in RUN_FIRST_BYTES
+                        and ingest_run is not None
+                    ):
+                        # a run of PUBLISH frames is taken in by one
+                        # call (server.ingest_run); the frame that ends
+                        # it goes down the path below, as does the whole
+                        # stretch when the run's gate is shut
+                        taken = ingest_run(self, rbuf, frames, i, start)
+                        if taken < 0:
+                            solo = i + 1
+                            while solo < n and frames[solo].first_byte in RUN_FIRST_BYTES:
+                                solo += 1
+                            continue
+                        if taken:
+                            i += taken
+                            f = frames[i - 1]
+                            start = f.body_offset + f.remaining
+                            if self.closed:
+                                break
+                        solo = i + 1
+                        continue
+                    i += 1
                     fstart = start
                     fend = f.body_offset + f.remaining
                     self.ops.info.bytes_received += (f.body_offset - start) + f.remaining
@@ -939,18 +1000,10 @@ class Client:
             put_buffer(buf)
 
         self._write(data)
-
-        self.ops.info.bytes_sent += len(data)
-        self.ops.info.packets_sent += 1
-        st = self.state
-        st.out_bytes += len(data)
-        st.out_writes += 1
-        tele = getattr(self.ops, "telemetry", None)
-        if tele is not None:
-            tele.outbound_bytes.inc(len(data))
-            tele.outbound_writes.inc()
+        self._count_sent(len(data))
         if pk.fixed_header.type == pkts.PUBLISH:
             self.ops.info.messages_sent += 1
+            tele = getattr(self.ops, "telemetry", None)
             if tele is not None and not pk.topic_name.startswith("$SYS"):
                 # a per-subscriber encode: the amplification numerator
                 # (ROADMAP item 3's encode-once rewrite drives this to

@@ -211,6 +211,14 @@ class Subscription:
 # A SUBSCRIBE/UNSUBSCRIBE packet's ordered filter list.
 Subscriptions = list  # list[Subscription]; a list to retain order (packets.go:169)
 
+# Packet's fields with a default factory, for ``Packet.__getattr__``
+_MADE_ON_FIRST_TOUCH = {
+    "connect": ConnectParams,
+    "properties": Properties,
+    "filters": list,
+    "mods": Mods,
+}
+
 
 @dataclass
 class Packet:
@@ -235,53 +243,98 @@ class Packet:
     reserved_bit: int = 0
     ignore: bool = False  # if True, skip message forwarding
 
+    # the sampled stage clock a publish may carry (mqtt_tpu.telemetry):
+    # a rider, not a field, so it never touches the wire or equality.
+    # The class default keeps ``getattr(pk, "_tclock", None)`` an
+    # ordinary attribute read on the packets that carry none.
+    _tclock = None
+
     # -- lifecycle ---------------------------------------------------------
+
+    @classmethod
+    def inbound_publish(
+        cls,
+        fixed_header: FixedHeader,
+        topic_name: str,
+        payload: bytes,
+        packet_id: int,
+        protocol_version: int,
+    ) -> "Packet":
+        """A PUBLISH as ``Packet()`` + ``publish_decode`` leaves it,
+        holding only what a PUBLISH without properties has: the ingest
+        run's packet (server.ingest_run). The scalar fields read their
+        class defaults; ``connect``, ``properties``, ``filters`` and
+        ``mods`` are made on first touch (``__getattr__``), so ``copy``,
+        ``==``, ``repr`` and every reader see field for field the packet
+        the constructor gives, and a publish nobody asks for them never
+        pays for them (ten allocations, six collector-tracked)."""
+        pk = cls.__new__(cls)
+        pk.fixed_header = fixed_header
+        pk.topic_name = topic_name
+        pk.payload = payload
+        pk.packet_id = packet_id
+        pk.protocol_version = protocol_version
+        return pk
+
+    def __getattr__(self, name: str):
+        # reached only for an attribute the instance lacks: a field
+        # ``inbound_publish`` left for its first touch
+        make = _MADE_ON_FIRST_TOUCH.get(name)
+        if make is None:
+            raise AttributeError(
+                f"{type(self).__name__!r} object has no attribute {name!r}"
+            )
+        value = self.__dict__[name] = make()
+        return value
 
     def copy(self, allow_transfer: bool) -> "Packet":
         """Deep copy with a reset DUP flag [MQTT-4.3.1-1] [MQTT-4.3.2-2] and
-        an optional transfer of packet id / topic alias (packets.go:185-250)."""
-        p = Packet(
-            fixed_header=FixedHeader(
-                remaining=self.fixed_header.remaining,
-                type=self.fixed_header.type,
-                retain=self.fixed_header.retain,
-                dup=False,
-                qos=self.fixed_header.qos,
-            ),
-            mods=Mods(max_size=self.mods.max_size),
-            reserved_bit=self.reserved_bit,
-            protocol_version=self.protocol_version,
-            connect=ConnectParams(
-                client_identifier=self.connect.client_identifier,
-                keepalive=self.connect.keepalive,
-                will_qos=self.connect.will_qos,
-                will_topic=self.connect.will_topic,
-                will_flag=self.connect.will_flag,
-                will_retain=self.connect.will_retain,
-                will_properties=self.connect.will_properties.copy(allow_transfer),
-                clean=self.connect.clean,
-            ),
-            topic_name=self.topic_name,
-            properties=self.properties.copy(allow_transfer),
-            session_present=self.session_present,
-            reason_code=self.reason_code,
-            filters=self.filters,
-            created=self.created,
-            expiry=self.expiry,
-            origin=self.origin,
-        )
+        an optional transfer of packet id / topic alias (packets.go:185-250).
+        A member the source has not made yet (``inbound_publish``) is
+        left for the copy's own first touch: its copy would equal the
+        default."""
+        d = self.__dict__
+        fh = self.fixed_header
+        p = Packet.__new__(Packet)
+        p.fixed_header = FixedHeader(fh.type, False, fh.qos, fh.retain, fh.remaining)
+        if "mods" in d:
+            p.mods = Mods(max_size=self.mods.max_size)
+        p.reserved_bit = self.reserved_bit
+        p.protocol_version = self.protocol_version
+        if "connect" in d:
+            c = self.connect
+            pc = p.connect = ConnectParams(
+                client_identifier=c.client_identifier,
+                keepalive=c.keepalive,
+                will_qos=c.will_qos,
+                will_topic=c.will_topic,
+                will_flag=c.will_flag,
+                will_retain=c.will_retain,
+                will_properties=c.will_properties.copy(allow_transfer),
+                clean=c.clean,
+            )
+            if c.protocol_name:
+                pc.protocol_name = bytes(c.protocol_name)
+            if c.password:
+                pc.password_flag = True
+                pc.password = bytes(c.password)
+            if c.username:
+                pc.username_flag = True
+                pc.username = bytes(c.username)
+            if c.will_payload:
+                pc.will_payload = bytes(c.will_payload)
+        p.topic_name = self.topic_name
+        if "properties" in d:
+            p.properties = self.properties.copy(allow_transfer)
+        p.session_present = self.session_present
+        p.reason_code = self.reason_code
+        if "filters" in d:
+            p.filters = self.filters
+        p.created = self.created
+        p.expiry = self.expiry
+        p.origin = self.origin
         if allow_transfer:
             p.packet_id = self.packet_id
-        if self.connect.protocol_name:
-            p.connect.protocol_name = bytes(self.connect.protocol_name)
-        if self.connect.password:
-            p.connect.password_flag = True
-            p.connect.password = bytes(self.connect.password)
-        if self.connect.username:
-            p.connect.username_flag = True
-            p.connect.username = bytes(self.connect.username)
-        if self.connect.will_payload:
-            p.connect.will_payload = bytes(self.connect.will_payload)
         if self.payload:
             p.payload = bytes(self.payload)
         if self.reason_codes:

@@ -195,6 +195,15 @@ class Properties:
         )  # [MQTT-3.3.2-17]
         return pr
 
+    def value(self) -> "Properties":
+        """The reference's struct copy: a new ``Properties`` with these
+        values, its lists and buffers shared with this one. For a packet
+        that inherits another's properties (an ack its publish's) and
+        then sets fields of its own."""
+        pr = Properties.__new__(Properties)
+        pr.__dict__ = self.__dict__.copy()
+        return pr
+
     def _can_encode(self, pkt: int, k: int) -> bool:
         return pkt in VALID_PACKET_PROPERTIES.get(k, ())
 

@@ -19,7 +19,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Optional
 
 from . import packets as pkts
-from .clients import Client, Clients, ConnectionClosedError, Will
+from .clients import RUN_FIRST_BYTES, Client, Clients, ConnectionClosedError, Will
 from .hooks import (
     ON_PACKET_ENCODE,
     ON_PACKET_PROCESSED,
@@ -27,6 +27,8 @@ from .hooks import (
     ON_PACKET_SENT,
     ON_PUBLISH,
     ON_PUBLISHED,
+    ON_QOS_COMPLETE,
+    ON_QOS_PUBLISH,
     STORED_CLIENTS,
     STORED_INFLIGHT_MESSAGES,
     STORED_RETAINED_MESSAGES,
@@ -761,6 +763,30 @@ def publish_frame_topic(frame: bytes):
         return None
 
 
+# a hook that provides one of these takes the packet a QoS0 frame
+# passthrough never builds (Server.fast_publish_eligible)
+_FAST_PUBLISH_EVENTS = (
+    ON_PACKET_READ,
+    ON_PUBLISH,
+    ON_PACKET_ENCODE,
+    ON_PACKET_SENT,
+    ON_PUBLISHED,
+    ON_PACKET_PROCESSED,
+)
+# a hook that provides one of these sees, per frame, what the ingest run
+# does once a run or not at all: the packet as it was read, the publish
+# before it is taken, the inflight bookkeeping around a QoS1 ack, the
+# ack as a packet (Server.ingest_run)
+_INGEST_RUN_EVENTS = (
+    ON_PACKET_READ,
+    ON_PUBLISH,
+    ON_QOS_PUBLISH,
+    ON_QOS_COMPLETE,
+    ON_PACKET_ENCODE,
+    ON_PACKET_SENT,
+)
+
+
 class _Ops:
     """Server values propagated to clients (server.go:159-164).
     ``fast_publish`` is the server's QoS0 frame-passthrough entry point
@@ -792,6 +818,13 @@ class _Ops:
         # slice's deliveries) and each send of the native fan-out flush.
         # A plain add on the writing loop.
         self.socket_sends = 0
+        # the server's entry point for a scan's run of PUBLISH frames
+        # (Server.ingest_run; None until the server wires it), the runs
+        # that took at least one publish and the publishes they took in.
+        # Plain adds on the reading loop.
+        self.ingest_run: Optional[Callable[..., int]] = None
+        self.ingest_runs = 0
+        self.ingest_run_publishes = 0
 
 
 class Server:
@@ -817,8 +850,10 @@ class Server:
         self._ops = _Ops(opts, self.info, self.hooks, self.log)
         self._ops.fast_publish = self.try_fast_publish
         self._ops.fast_publish_eligible = self.fast_publish_eligible
-        self._fastpub_gate_gen = -1  # hooks generation the gate was cached at
-        self._fastpub_gate_ok = False
+        self._ops.ingest_run = self.ingest_run
+        # "no hook provides any of these events", by gate name, as of a
+        # hooks generation: (generation, verdict) (_no_hook_provides)
+        self._hook_gates: dict = {}
         if opts.scan_coalesce:
             # read-side decode batching: frame scans from read loops that
             # wake in the same event-loop tick coalesce into one native
@@ -1463,7 +1498,8 @@ class Server:
         """The broker's running counts a profiler slice's snapshots take
         (``tracing.DeviceProfiler.counters``): fallbacks the stage held
         in their publisher's order, frames handed to subscribers'
-        sockets, calls that reached a socket, and what the trie holds
+        sockets, calls that reached a socket, the ingest runs and the
+        publishes they took in, and what the trie holds
         (``TopicsIndex``'s three counts)."""
         stage = self._stage
         trie = self.topics
@@ -1471,6 +1507,8 @@ class Server:
             "order_held": 0 if stage is None else stage.order_held,
             "deliveries": self.telemetry.fanout_deliveries.value,
             "socket_sends": self._ops.socket_sends,
+            "ingest_runs": self._ops.ingest_runs,
+            "ingest_run_publishes": self._ops.ingest_run_publishes,
             "particles": trie.particles,
             "particle_maps": trie.particle_maps,
             "held": trie.held,
@@ -1576,6 +1614,21 @@ class Server:
             "publisher's order as held members instead of completing at once",
             fn=lambda: 0 if self._stage is None else self._stage.order_held,
         )
+        for name, attr, what in (
+            (
+                "mqtt_tpu_ingest_runs_total",
+                "ingest_runs",
+                "Runs of PUBLISH frames a read loop handed to the ingest "
+                "run in one call that took at least one publish",
+            ),
+            (
+                "mqtt_tpu_ingest_run_publishes_total",
+                "ingest_run_publishes",
+                "Publishes taken in by ingest runs (the rest took the "
+                "per-frame path: mqtt_tpu_messages_received_total has both)",
+            ),
+        ):
+            r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
         r.gauge(
             "mqtt_tpu_staging_pipeline_depth",
             "Device batches in flight across the staging pipeline legs",
@@ -2708,14 +2761,7 @@ class Server:
             return False
 
         if not cl.net.inline and not self.hooks.on_acl_check(cl, pk.topic_name, True):
-            if pk.fixed_header.qos == 0:
-                return False
-            if cl.properties.protocol_version != 5:
-                self.disconnect_client(cl, ERR_NOT_AUTHORIZED)
-                return False
-            ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
-            ack = self.build_ack(pk.packet_id, ack_type, 0, pk.properties, ERR_NOT_AUTHORIZED)
-            cl.write_packet(ack)
+            self._deny_publish(cl, pk)
             return False
 
         pk.origin = cl.id
@@ -2760,19 +2806,7 @@ class Server:
             and self.overload is not None
             and not self.overload.admit(cl)
         ):
-            self.info.messages_dropped += 1
-            if cl.tenant is not None:
-                # per-tenant shed accounting: quota classes must be
-                # visibly shaping who sheds (mqtt_tpu.tenancy)
-                cl.tenant.messages_dropped += 1
-            if pk.fixed_header.qos == 0:
-                return False
-            ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
-            cl.write_packet(
-                self.build_ack(
-                    pk.packet_id, ack_type, 0, pk.properties, ERR_QUOTA_EXCEEDED
-                )
-            )
+            self._shed_publish(cl, pk)
             return False
 
         # telemetry stage clock (attached by the read loop on sampled
@@ -2790,24 +2824,7 @@ class Server:
             # one empty-list check
             clock = tele.adopt_trace(pk)
         if clock is not None:
-            clock.stamp("admission")
-            if self.topic_sketch is not None:
-                # topic-cardinality sketch rides the sampling verdict:
-                # the same 1-in-N publishes that carry a clock feed the
-                # top-K/avg-hits estimate (mqtt_tpu.profiling)
-                self.topic_sketch.observe(pk.topic_name)
-            trace_id = getattr(clock, "trace_id", None)
-            if trace_id is not None and self.options.trace_user_property:
-                # client-visible traces: subscribers (and peers on the
-                # packet leg) see the trace id as a v5 user property
-                from .telemetry import TRACE_USER_PROPERTY
-
-                if not any(
-                    u.key == TRACE_USER_PROPERTY for u in pk.properties.user
-                ):
-                    pk.properties.user.append(
-                        UserProperty(TRACE_USER_PROPERTY, trace_id)
-                    )
+            self._stamp_admission(pk, clock)
 
         try:
             pk = self.hooks.on_publish(cl, pk)
@@ -2838,17 +2855,7 @@ class Server:
             # retention would leave the publisher believing the topic is
             # retained. Same graceful posture as overload: QoS0 drops
             # (counted), QoS1/2 ack 0x97 Quota Exceeded.
-            self.info.messages_dropped += 1
-            if cl.tenant is not None:
-                cl.tenant.messages_dropped += 1
-            if pk.fixed_header.qos == 0:
-                return False
-            ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
-            cl.write_packet(
-                self.build_ack(
-                    pk.packet_id, ack_type, 0, pk.properties, ERR_QUOTA_EXCEEDED
-                )
-            )
+            self._shed_publish(cl, pk)
             return False
 
         if pk.fixed_header.retain:  # [MQTT-3.3.1-5]
@@ -2890,6 +2897,61 @@ class Server:
         self._finish_publish_clock(pk)
         self.hooks.on_published(cl, pk)
         return False
+
+    def _deny_publish(self, cl: Client, pk: Packet) -> None:
+        """The write ACL refused this publish: QoS0 is dropped in
+        silence; a v3/v4 client is disconnected (the raise is
+        ``disconnect_client``'s); a v5 client gets its ack with 0x87."""
+        if pk.fixed_header.qos == 0:
+            return
+        if cl.properties.protocol_version != 5:
+            self.disconnect_client(cl, ERR_NOT_AUTHORIZED)
+            return
+        ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
+        cl.write_packet(
+            self.build_ack(pk.packet_id, ack_type, 0, pk.properties, ERR_NOT_AUTHORIZED)
+        )
+
+    def _shed_publish(self, cl: Client, pk: Packet) -> None:
+        """Refuse one publish gracefully, counted (the overload
+        governor's shed, a tenant's retained cap): QoS0 drops, QoS1/2
+        ack 0x97 Quota Exceeded (a v3/v4 ack carries no reason code: the
+        publish is simply not fanned out)."""
+        self.info.messages_dropped += 1
+        if cl.tenant is not None:
+            # per-tenant shed accounting: quota classes must be
+            # visibly shaping who sheds (mqtt_tpu.tenancy)
+            cl.tenant.messages_dropped += 1
+        if pk.fixed_header.qos == 0:
+            return
+        ack_type = pkts.PUBREC if pk.fixed_header.qos == 2 else pkts.PUBACK
+        cl.write_packet(
+            self.build_ack(
+                pk.packet_id, ack_type, 0, pk.properties, ERR_QUOTA_EXCEEDED
+            )
+        )
+
+    def _stamp_admission(self, pk: Packet, clock) -> None:
+        """A sampled publish is past admission: the clock's stamp and
+        what rides the sampling verdict with it."""
+        clock.stamp("admission")
+        if self.topic_sketch is not None:
+            # topic-cardinality sketch rides the sampling verdict:
+            # the same 1-in-N publishes that carry a clock feed the
+            # top-K/avg-hits estimate (mqtt_tpu.profiling)
+            self.topic_sketch.observe(pk.topic_name)
+        trace_id = getattr(clock, "trace_id", None)
+        if trace_id is not None and self.options.trace_user_property:
+            # client-visible traces: subscribers (and peers on the
+            # packet leg) see the trace id as a v5 user property
+            from .telemetry import TRACE_USER_PROPERTY
+
+            if not any(
+                u.key == TRACE_USER_PROPERTY for u in pk.properties.user
+            ):
+                pk.properties.user.append(
+                    UserProperty(TRACE_USER_PROPERTY, trace_id)
+                )
 
     def _finish_publish_clock(self, pk: Packet) -> None:
         """Close out a sampled publish's stage clock after fan-out: the
@@ -2990,6 +3052,206 @@ class Server:
             cl._staged += 1
         self._stage.park(pk.topic_name, entry)
         return True
+
+    def ingest_run(
+        self, cl: Client, rbuf: bytearray, frames: list, i: int, start: int
+    ) -> int:
+        """Take in a run of one scan's PUBLISH frames in one call: from
+        ``frames[i]`` on, every frame in turn that is a v3.1.1 QoS0 or
+        QoS1 PUBLISH without RETAIN (``clients.RUN_FIRST_BYTES``) and
+        that nothing below refuses, each decoded straight from ``rbuf``,
+        checked, acknowledged and parked as ``process_publish`` would
+        have it, the whole run parked at once (``MatchStage.park_many``).
+        ``start`` is where ``frames[i]`` begins. Returns how many frames
+        it took (their counters advanced as the per-frame path advances
+        them: ``info``, the connection's publish count, the 1-in-N clock
+        draw), or -1 when the run's gate is shut for this connection.
+
+        The gate, on what the code can see and nothing else: a staging
+        loop to park with; no hook that takes the packet as read, the
+        publish before it is taken, the inflight bookkeeping around an
+        ack or the ack as a packet (``_INGEST_RUN_EVENTS``, cached per
+        hooks generation); a network client speaking v3.1.1 outside any
+        tenant (so no namespace, no re-encryption) and no live payload
+        predicates. The frame that ends a run is not touched: the caller
+        hands it to the per-frame path, the one owner of every error,
+        drop and edge case (a malformed or empty topic, a wildcard or
+        ``$``-topic, bad UTF-8, a packet id of 0 or one in the inflight
+        map, receive-maximum exhausted), and the next eligible frame
+        opens a new run.
+
+        What a taken frame skips is what the gate proved nobody can see:
+        the ``Packet``'s unused members (``Packet.inbound_publish``), the
+        inflight lookup of QoS0's id 0 (never stored), and around a QoS1
+        ack the ``inflight.set`` / ``delete`` and quota down-and-up that
+        net to nothing; the ack leaves as its four bytes
+        (``Client.write_puback``). The write ACL and the overload
+        governor are asked once a publish, with ``process_publish``'s
+        outcomes for a refusal (``_deny_publish``, ``_shed_publish``)."""
+        stage = self._stage
+        eng = self._predicates
+        if (
+            stage is None
+            or cl.net.inline
+            or cl.tenant is not None
+            or cl.properties.protocol_version != 4
+            or (eng is not None and eng.active)
+            or not self._no_hook_provides("ingest_run", _INGEST_RUN_EVENTS)
+        ):
+            return -1
+        caps = self.options.capabilities
+        qos1_ok = caps.maximum_qos >= 1
+        state = cl.state
+        inflight = state.inflight
+        acl = self.hooks.on_acl_check
+        overload = self.overload
+        tele = self.telemetry
+        complete = self._staged_completion
+        origin = cl.id
+        created = int(time.time())  # brokerlint: ok=R3 packet creation stamp is wall-clock (persists/expires across restarts)
+        # a v3.1.1 publish has no expiry interval of its own
+        expiry = caps.maximum_message_expiry_interval
+        expiry = created + expiry if expiry > 0 else 0
+        try:
+            here = asyncio.get_running_loop()
+        except RuntimeError:
+            here = None  # no loop on this thread: the stage's completes them
+        # parked from the connection's own loop: its read loop does not
+        # read on before they have fanned out (_park_publish)
+        counted = here is not None and here is cl.net.loop
+        n = len(frames)
+        # the clock draws of the frames ahead that are sure to be None
+        # are added once, at the run's end; ``sampled`` is the taken
+        # frame whose draw is made for real
+        sampled = n if tele is None else tele.quiet_draws(n - i)
+        drawn = 0
+        items: list = []
+        taken = 0
+        k = i
+        try:
+            while k < n:
+                f = frames[k]
+                fb = f.first_byte
+                if fb not in RUN_FIRST_BYTES:
+                    break
+                due = taken == sampled
+                if due:
+                    t_frame = time.perf_counter()
+                off = f.body_offset
+                end = off + f.remaining
+                qos = (fb >> 1) & 1
+                if f.remaining < 2:
+                    break
+                t0 = off + 2
+                t1 = t0 + ((rbuf[off] << 8) | rbuf[off + 1])
+                if t1 == t0 or t1 + 2 * qos > end:
+                    break  # no topic, or the frame ends inside it
+                try:
+                    topic = rbuf[t0:t1].decode("utf-8")
+                except UnicodeDecodeError:
+                    break
+                if (
+                    topic[0] == "$"
+                    or "+" in topic
+                    or "#" in topic
+                    or "\x00" in topic
+                ):
+                    break  # publish_validate's and is_valid_filter's
+                pid = 0
+                if qos:
+                    pid = (rbuf[t1] << 8) | rbuf[t1 + 1]
+                    t1 += 2
+                    if not qos1_ok or pid == 0 or inflight.get(pid) is not None:
+                        break  # [MQTT-2.2.1-3] [MQTT-4.3.2-5]
+                if inflight.receive_quota == 0 or not state.open:
+                    break
+                # the frame is taken
+                k += 1
+                taken += 1
+                pk = Packet.inbound_publish(
+                    FixedHeader(pkts.PUBLISH, fb == 0x3A, qos, False, f.remaining),
+                    topic,
+                    bytes(rbuf[t1:end]),
+                    pid,
+                    4,
+                )
+                clock = None
+                if due:
+                    tele.skip_draws(taken - 1 - drawn)
+                    clock = tele.publish_clock()
+                    drawn = taken
+                    sampled = taken + tele.quiet_draws(n - k)
+                    if clock is not None:
+                        # the decode leg runs from the frame's first
+                        # instant, as the per-frame path's does
+                        clock.t0 = clock.last = t_frame
+                        clock.stamp("decode")
+                        pk._tclock = clock
+                if not acl(cl, topic, True):
+                    self._park_run(cl, items, counted)
+                    self._unparked(cl, pk, self._deny_publish)
+                    continue
+                pk.origin = origin
+                pk.created = created
+                if expiry:
+                    pk.expiry = expiry
+                if overload is not None and not overload.admit(cl):
+                    self._park_run(cl, items, counted)
+                    self._unparked(cl, pk, self._shed_publish)
+                    continue
+                if clock is not None:
+                    self._stamp_admission(pk, clock)
+                if qos:
+                    cl.write_puback(pid)  # [MQTT-4.3.2-4]
+                entry = Parked(complete, clock, None, None, cl, pk)
+                entry.loop = here
+                entry.counted = counted
+                items.append((topic, entry))
+        except Code as code:
+            self._packet_error(cl, code)
+            raise
+        finally:
+            self._park_run(cl, items, counted)
+            if taken:
+                last = frames[i + taken - 1]
+                info = self.info
+                info.bytes_received += last.body_offset + last.remaining - start
+                info.packets_received += taken
+                info.messages_received += taken
+                cl._pub_count += taken
+                if tele is not None:
+                    tele.skip_draws(taken - drawn)
+                ops = self._ops
+                ops.ingest_runs += 1
+                ops.ingest_run_publishes += taken
+        return taken
+
+    def _park_run(self, cl: Client, items: list, counted: bool) -> None:
+        """Park what an ingest run has gathered, in order, before
+        anything else happens for its connection, and empty the list.
+        The first entry alone may say that nothing of this connection is
+        in the stage (``Parked.alone``)."""
+        if items:
+            if counted:
+                items[0][1].alone = not cl._staged
+                cl._staged += len(items)
+            self._stage.park_many(items)
+            del items[:]
+
+    def _unparked(self, cl: Client, pk: Packet, outcome) -> None:
+        """``outcome(cl, pk)`` for a publish an ingest run took and does
+        not park, and after it what ``process_packet`` does for a packet
+        that was not parked: ``on_packet_processed`` with the error the
+        outcome raised, if any, and the quota drain."""
+        err: Optional[Exception] = None
+        try:
+            outcome(cl, pk)
+        except Exception as e:
+            err = e
+            raise
+        finally:
+            self.hooks.on_packet_processed(cl, pk, err)
+        self._drain_quota_starved(cl)
 
     def _complete_staged(self, entries, results, t_set_ns: int = 0) -> None:
         """Complete one slice of a staged batch (``staging.Parked``): fan
@@ -3248,26 +3510,24 @@ class Server:
             # tenant publishes need namespace scoping (and possibly the
             # re-encryption leg) — the decode path owns both
             return False
+        return self._no_hook_provides("fast_publish", _FAST_PUBLISH_EVENTS)
+
+    def _no_hook_provides(self, gate: str, events: tuple) -> bool:
+        """True when no attached hook provides any of ``events``: the
+        provides() scan, cached under ``gate`` per hooks generation."""
         gen = self.hooks.generation
-        if gen != self._fastpub_gate_gen:
-            ok = not self.hooks.provides(
-                ON_PACKET_READ,
-                ON_PUBLISH,
-                ON_PACKET_ENCODE,
-                ON_PACKET_SENT,
-                ON_PUBLISHED,
-                ON_PACKET_PROCESSED,
-            )
-            # only cache when no add_hook raced the scan: Hooks.add bumps
-            # the generation on BOTH sides of the list publish, so a scan
-            # that saw a mid-add list can never be cached as current (it
-            # still decides this one frame — the same one-frame window the
-            # reference's lock-free hook swap has, hooks.go:150-170)
-            if self.hooks.generation == gen:
-                self._fastpub_gate_ok = ok
-                self._fastpub_gate_gen = gen
-            return ok
-        return self._fastpub_gate_ok
+        cached = self._hook_gates.get(gate)
+        if cached is not None and cached[0] == gen:
+            return cached[1]
+        ok = not self.hooks.provides(*events)
+        # only cache when no add_hook raced the scan: Hooks.add bumps
+        # the generation on BOTH sides of the list publish, so a scan
+        # that saw a mid-add list can never be cached as current (it
+        # still decides this one frame or run — the same window the
+        # reference's lock-free hook swap has, hooks.go:150-170)
+        if self.hooks.generation == gen:
+            self._hook_gates[gate] = (gen, ok)
+        return ok
 
     @staticmethod
     def _shared_frame_ok(props: "ClientProperties", sub: Subscription) -> bool:
@@ -4743,6 +5003,11 @@ class Server:
         (server.go:1136-1157)."""
         if self.options.capabilities.compatibilities.no_inherited_properties_on_ack:
             properties = Properties()
+        else:
+            # by value, as the reference passes them: what the ack sets
+            # here and at write time (the reason string, its own expiry
+            # interval) never lands on the publish it answers
+            properties = properties.value()
         if reason.code >= ERR_UNSPECIFIED_ERROR.code:
             properties.reason_string = reason.reason
         now = int(time.time())  # brokerlint: ok=R3 ack created/expiry stamps are wall-clock (message-expiry contract)
@@ -5105,6 +5370,11 @@ class Server:
                 self.topics.particle_maps
             ),
             SYS_PREFIX + "/broker/topics/held": str(self.topics.held),
+            # publishes the read loops took in by the run, and the runs
+            SYS_PREFIX + "/broker/ingest/runs": str(self._ops.ingest_runs),
+            SYS_PREFIX + "/broker/ingest/run_publishes": str(
+                self._ops.ingest_run_publishes
+            ),
         }
         if self.matcher is not None:
             # device-matcher observability (MatcherStats.as_dict): batches,

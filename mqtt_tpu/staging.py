@@ -14,8 +14,10 @@ pipelined batch engine:
   under one read of the client registry). The publishing connection
   waits once a socket read for its own publishes (clients.read), so
   *that* client blocks while every other client keeps being served.
-  ``submit(topic)`` is the same path for callers that want a future:
-  an entry whose completion sets it.
+  ``park_many(items)`` parks a run of publishes (a socket read's
+  PUBLISH frames, server.ingest_run) as ``park()`` would one by one,
+  at one lock pair and one wake-up. ``submit(topic)`` is the same path
+  for callers that want a future: an entry whose completion sets it.
 - A collector task gathers everything submitted within the accumulation
   window (or up to the batch cap) and issues ONE ``match_topics_async``
   dispatch. The issue leg (host tokenize + H2D + async device dispatch)
@@ -423,18 +425,41 @@ class MatchStage:
         parkers: a connection's read loop does not read on while a
         publish of its last socket read is in the stage (clients.read),
         so it holds at most one read's frames; a ``submit()`` caller
-        holds what it has futures for."""
-        loop = entry.loop
+        holds what it has futures for.
+
+        A run of one (:meth:`park_many`)."""
+        self.park_many([(topic, entry)])
+
+    def park_many(self, items: list) -> None:
+        """Park a run of publishes, ``(topic, entry)`` in submit order,
+        all parked from the calling thread's loop: what :meth:`park`
+        called once an item does (the same admission verdict an item,
+        reckoned with the depth as it stands when the item's turn comes;
+        the same held members, ``alone`` completions and counts), at one
+        acquisition of the park lock while every item is admitted, one
+        wake-up of the collector and one ``submit_ns`` stamp a run. From
+        the first item admission refuses, the rest of the run goes one
+        item at a time, each behind the one before it (a held member's
+        host walk runs outside the lock and must be done before a later
+        item may be parked behind it)."""
+        if not items:
+            return
+        loop = items[0][1].loop
         if loop is None:
             try:
-                loop = entry.loop = asyncio.get_running_loop()
+                loop = asyncio.get_running_loop()
             except RuntimeError:
                 pass  # no loop on this thread: completes on the stage's
         prof = self.profiler
-        if prof is not None and prof.armed:
-            # a live profiler session: the park instant rides on the
-            # entry, for the batch's mqtt/stage.wait (tracing)
-            entry.submit_ns = time.perf_counter_ns()
+        # a live profiler session: the park instant rides on the
+        # entries, for the batch's mqtt/stage.wait (tracing)
+        submit_ns = (
+            time.perf_counter_ns() if prof is not None and prof.armed else 0
+        )
+        for _, entry in items:
+            if entry.loop is None:
+                entry.loop = loop
+            entry.submit_ns = submit_ns
         if _LOOP_PLANE.active:
             w = _LOOP_PLANE.witness
             if w is not None:
@@ -443,33 +468,20 @@ class MatchStage:
                 )
         wake = self._wake
         if self._stopping or wake is None:
-            self._fallback_all([(topic, entry)], klass=None)
+            self._fallback_all(items, klass=None)
             return
+        taken = 0
         with self._plock:
-            parked = len(self._pending) - self._held_pending
-            if parked >= self.max_pending or self._past_deadline():
-                admitted = False
-            else:
-                admitted = True
-                self._pending.append((topic, entry))
-                if parked >= self.peak_pending:
-                    self.peak_pending = parked + 1
-        if not admitted:
-            self.admission_fallbacks += 1
-            if entry.alone:
-                self._fallback_all([(topic, entry)], klass="admission")
-                return
-            self._hold([(topic, entry)], klass="admission")
-            with self._plock:
-                # stop() takes _pending under this lock, after it set
-                # _stopping: a held member is never left behind it
-                stopping = self._stopping
-                if not stopping:
-                    self._pending.append((topic, entry))
-                    self._held_pending += 1
-            if stopping:
-                self._fallback_all([(topic, entry)], klass=None)
-                return
+            for item in items:
+                if not self._admit(item):
+                    break
+                taken += 1
+        queued = items
+        if taken < len(items):
+            queued = items[:taken]
+            queued += [item for item in items[taken:] if self._park_one(item)]
+        if not queued:
+            return
         # the wake Event is loop-affine: shard-loop submitters marshal
         # the set() onto the stage's loop (mqtt_tpu.shards). A never-
         # started stage (_loop None: unit harnesses that drive the
@@ -481,15 +493,57 @@ class MatchStage:
                 self._loop.call_soon_threadsafe(wake.set)
             except RuntimeError:
                 # stage loop gone mid-shutdown: serve the host walk now,
-                # unless stop() has already taken the entry
+                # but for what stop() has already taken
+                mine = []
                 with self._plock:
-                    try:
-                        self._pending.remove((topic, entry))
-                    except ValueError:
-                        return
-                    if entry.held is not None:
-                        self._held_pending -= 1
-                self._fallback_all([(topic, entry)], klass=None)
+                    for item in queued:
+                        try:
+                            self._pending.remove(item)
+                        except ValueError:
+                            continue
+                        if item[1].held is not None:
+                            self._held_pending -= 1
+                        mine.append(item)
+                self._fallback_all(mine, klass=None)
+
+    def _admit(self, item: tuple) -> bool:
+        """Under the park lock: park ``item`` for the device unless the
+        backlog is at its cap or the projected wait is past the deadline
+        (False: the caller serves it by the host walk)."""
+        parked = len(self._pending) - self._held_pending
+        if parked >= self.max_pending or self._past_deadline():
+            return False
+        self._pending.append(item)
+        if parked >= self.peak_pending:
+            self.peak_pending = parked + 1
+        return True
+
+    def _park_one(self, item: tuple) -> bool:
+        """One item's admission, by itself under the park lock: the slow
+        leg of :meth:`park_many`, from the first item it could not admit.
+        True: the item is in ``_pending`` (admitted, or held behind what
+        is there); False: it has completed inside this call (alone, or
+        the stage is stopping)."""
+        with self._plock:
+            admitted = self._admit(item)
+        if admitted:
+            return True
+        self.admission_fallbacks += 1
+        if item[1].alone:
+            self._fallback_all([item], klass="admission")
+            return False
+        self._hold([item], klass="admission")
+        with self._plock:
+            # stop() takes _pending under this lock, after it set
+            # _stopping: a held member is never left behind it
+            stopping = self._stopping
+            if not stopping:
+                self._pending.append(item)
+                self._held_pending += 1
+        if stopping:
+            self._fallback_all([item], klass=None)
+            return False
+        return True
 
     def _hold(self, items, klass: str) -> None:
         """Walk ``(topic, entry)`` items on the host now and leave each

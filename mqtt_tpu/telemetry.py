@@ -1007,6 +1007,23 @@ class Telemetry:
             return None
         return StageClock()
 
+    def quiet_draws(self, limit: int) -> int:
+        """How many of the next ``limit`` :meth:`publish_clock` draws are
+        sure to return None: a caller that takes publishes in by the run
+        (server.ingest_run) makes only the draw after them for real and
+        adds the quiet ones at once (:meth:`skip_draws`)."""
+        n = self._n
+        tracer = self.tracer
+        if tracer is not None and tracer.sample:
+            limit = min(limit, tracer.sample - 1 - n % tracer.sample)
+        if self.sample:
+            limit = min(limit, self.sample - 1 - n % self.sample)
+        return limit
+
+    def skip_draws(self, n: int) -> None:
+        """``n`` draws that :meth:`quiet_draws` said return None."""
+        self._n += n
+
     def adopt_trace(self, pk: Any) -> Optional[StageClock]:
         """Adopt a client-supplied trace id: an inbound v5 PUBLISH whose
         user properties carry ``trace-id`` gets a trace context with

@@ -523,13 +523,14 @@ async def subscriber(h, client_id, flt, qos=0):
     return r, w
 
 
-def run_echo(seed, n_clients, chunk, rounds, **options):
+def run_echo(seed, n_clients, chunk, rounds, hooks=(), **options):
     """stresser's echo loop (``benchmark/deployments/stresser.py``) over
     loopback TCP: ``n_clients`` connections, each writing ``rounds``
     chunks of ``chunk`` frames to its own topic and reading each chunk
-    back before the next. Returns the plain reference's verdict on what
-    the sockets saw (``benchmark/reference.py``), the count of
-    deliveries, and the broker's counts read before it closed."""
+    back before the next, on a broker with ``hooks`` added. Returns the
+    plain reference's verdict on what the sockets saw
+    (``benchmark/reference.py``), the count of deliveries, and the
+    broker's counts read before it closed."""
     reference = load_benchmark_module("reference")
     stresser = load_benchmark_module("deployments/stresser")
     params = {
@@ -571,6 +572,8 @@ def run_echo(seed, n_clients, chunk, rounds, **options):
 
         h = Harness(staged_options(matcher_stage_latency_budget_ms=0, **options))
         srv = h.server
+        for hook in hooks:
+            srv.add_hook(hook)
         srv.add_listener(TCP(LConfig(type="tcp", id="t", address="127.0.0.1:0")))
         await srv.serve()
         port = int(srv.listeners.get("t").address().rsplit(":", 1)[1])
@@ -590,6 +593,7 @@ def run_echo(seed, n_clients, chunk, rounds, **options):
             "held": stage.order_held, "fallbacks": stage.admission_fallbacks,
             "peak": stage.peak_pending, "completed": stage.batch_completed,
             "sends": srv._ops.socket_sends - sends,
+            "took": srv._ops.ingest_run_publishes,
             "loops": len({
                 srv.clients.get(subs[row][0]).net.loop for row in plan["live"]
             }),

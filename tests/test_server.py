@@ -562,13 +562,16 @@ class TestSysTopics:
                 # device observatory rows scale with the device count
                 and not t.startswith("$SYS/broker/devices/")
             }
-            # the 20 of the reference's tree and the trie's three counts
+            # the 20 of the reference's tree, the trie's three counts
+            # and the ingest run's two
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
                 "$SYS/broker/topics/held",
+                "$SYS/broker/ingest/runs",
+                "$SYS/broker/ingest/run_publishes",
             } <= base
-            assert len(base) == 23
+            assert len(base) == 25
             await h.shutdown()
 
         run(scenario())

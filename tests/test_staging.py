@@ -505,3 +505,115 @@ class TestSingleConnectionPipelining:
             await h.shutdown()
 
         run(scenario())
+
+
+def _parks(many, n, cap, parked_before, first_alone, second_loop):
+    """Park ``n`` entries behind ``parked_before`` on a stage of
+    ``max_pending`` ``cap`` whose collector does not run, one ``park()``
+    each or in one ``park_many()``, from the stage's loop or from a
+    second one; return all that can be seen of it afterwards."""
+    from mqtt_tpu.staging import Parked
+
+    hits, done = [], []
+
+    def host(topic):
+        hits.append(topic)
+        return Subscribers()
+
+    def complete(entries, results, t_set_ns=0):
+        done.extend(e.pk for e in entries)
+
+    def park_them(stage):
+        items = []
+        for i in range(n):
+            entry = Parked(complete, pk=f"t/{i}")
+            entry.alone = first_alone and i == 0
+            items.append((f"t/{i}", entry))
+        if many:
+            stage.park_many(items)
+        else:
+            for topic, entry in items:
+                stage.park(topic, entry)
+        return [entry for _, entry in items]
+
+    async def scenario():
+        stage = MatchStage(None, host, max_pending=cap)
+        stage._wake = asyncio.Event()  # armed, no collector: entries stay
+        stage._loop = asyncio.get_running_loop()
+        for i in range(parked_before):
+            stage.park(f"before/{i}", Parked(complete, pk=f"before/{i}"))
+        stage._wake.clear()
+        if second_loop:
+            loop_b = asyncio.new_event_loop()
+            t = threading.Thread(target=loop_b.run_forever, daemon=True)
+            t.start()
+
+            async def on_b():
+                return park_them(stage)
+
+            entries = await asyncio.wrap_future(
+                asyncio.run_coroutine_threadsafe(on_b(), loop_b)
+            )
+            assert all(e.loop is loop_b for e in entries)
+            await asyncio.sleep(0.01)  # the marshalled wake-up
+        else:
+            entries = park_them(stage)
+        seen = {
+            "pending": [
+                (topic, e.held is not None) for topic, e in stage._pending
+            ],
+            "held_pending": stage._held_pending,
+            "peak": stage.peak_pending,
+            "fallbacks": stage.admission_fallbacks,
+            "order_held": stage.order_held,
+            "walked": list(hits),
+            "done_inside": list(done),
+            "woken": stage._wake.is_set(),
+        }
+        await stage.stop()
+        if second_loop:
+            done_on_b = asyncio.run_coroutine_threadsafe(asyncio.sleep(0), loop_b)
+            await asyncio.wrap_future(done_on_b)
+            loop_b.call_soon_threadsafe(loop_b.stop)
+            t.join(5)
+            loop_b.close()
+        seen["done"] = sorted(done, key=lambda pk: pk.startswith("t/"))
+        seen["walked_by_stop"] = hits[len(seen["walked"]):]
+        return seen
+
+    return run(scenario())
+
+
+PARK_RUNS = {
+    # name: (n, cap, parked before, the first entry is alone)
+    "one": (1, 8192, 0, True),
+    "one_behind_others": (1, 8192, 3, False),
+    "one_refused_alone": (1, 4, 4, True),
+    "one_refused_held": (1, 4, 4, False),
+    "sixty_four": (64, 8192, 0, True),
+    "straddles_the_cap": (64, 40, 0, True),
+    "straddles_the_cap_behind_others": (64, 40, 10, False),
+    "all_refused_first_alone": (5, 2, 2, True),
+}
+
+
+@pytest.mark.parametrize("second_loop", [False, True], ids=["stage_loop", "second_loop"])
+@pytest.mark.parametrize("name", sorted(PARK_RUNS))
+def test_park_many_is_park_once_an_item(name, second_loop):
+    """``park_many(items)`` leaves what ``park()`` called once an item
+    leaves: the same ``_pending`` (admitted members, then held ones, in
+    order), ``peak_pending``, ``admission_fallbacks``, ``order_held``,
+    ``alone`` completions inside the call, the collector woken, and the
+    same completions in the same order when the stage stops."""
+    n, cap, before, alone = PARK_RUNS[name]
+    one_by_one = _parks(False, n, cap, before, alone, second_loop)
+    at_once = _parks(True, n, cap, before, alone, second_loop)
+    assert at_once == one_by_one
+    room = max(0, cap - before)
+    assert at_once["fallbacks"] == max(0, n - room)
+    assert [held for _, held in at_once["pending"][before:]] == (
+        [False] * min(n, room)
+        + [True] * (max(0, n - room) - (alone and room == 0))
+    )
+    assert at_once["woken"] == bool(at_once["pending"][before:])
+    assert len(at_once["done"]) == before + n

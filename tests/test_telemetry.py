@@ -664,3 +664,58 @@ class TestStagedPipelineTelemetry:
             await h.shutdown()
 
         run(scenario())
+
+
+class TestIngestRunCounts:
+    def test_slice_counters_metrics_and_sys(self):
+        """The runs and the publishes they took in ride the profiler
+        slice's snapshots, /metrics and $SYS, and count what the read
+        loop handed to ``ingest_run`` and nothing else: a retained
+        publish and a SUBSCRIBE take the per-frame path."""
+
+        async def scenario():
+            h = Harness(
+                Options(
+                    inline_client=True, device_matcher=True,
+                    matcher_stage_window_ms=2.0,
+                    matcher_opts={"max_levels": 4, "background": False},
+                )
+            )
+            srv = h.server
+            await srv.serve()
+            counts = srv._slice_counters()
+            assert counts["ingest_runs"] == counts["ingest_run_publishes"] == 0
+            sub_r, sub_w, _ = await h.connect("sub")
+            sub_w.write(sub_packet(1, [Subscription(filter="t/#", qos=0)]))
+            assert (await read_wire_packet(sub_r)).fixed_header.type == SUBACK
+            srv.matcher.flush()
+            _r, w, _ = await h.connect("pub")
+            w.write(
+                b"".join(pub_packet(f"t/{i}", b"m") for i in range(5))
+                + pub_packet("t/kept", b"m", retain=True)
+                + b"".join(pub_packet(f"t/{i}", b"m") for i in range(5, 8))
+            )
+            for _ in range(9):
+                assert (await read_wire_packet(sub_r)).fixed_header.type == PUBLISH
+            counts = srv._slice_counters()
+            assert counts["ingest_run_publishes"] == 8
+            # one run before the retained frame, one after it, unless
+            # the socket handed the bytes over in more reads
+            assert 2 <= counts["ingest_runs"] <= 8
+            assert srv.info.messages_received == 9
+            text = srv.telemetry.registry.exposition()
+            assert "mqtt_tpu_ingest_run_publishes_total 8" in text
+            assert f"mqtt_tpu_ingest_runs_total {counts['ingest_runs']}" in text
+            srv.publish_sys_topics()
+            got = {
+                p.topic_name: bytes(p.payload)
+                for p in srv.topics.messages("$SYS/broker/ingest/#")
+            }
+            assert got == {
+                "$SYS/broker/ingest/run_publishes": b"8",
+                "$SYS/broker/ingest/runs": str(counts["ingest_runs"]).encode(),
+            }
+            await srv.close()
+            await h.shutdown()
+
+        run(scenario())

@@ -539,6 +539,12 @@ class TestLoopCounters:
         assert sl.b["deliveries"] - sl.a["deliveries"] == 12
         assert 1 <= sl.b["socket_sends"] - sl.a["socket_sends"] <= 12
         assert sl.b["order_held"] == sl.a["order_held"] == 0
+        # every publish came in by the run (a v4 client, QoS0, no hook
+        # that takes the packet): a run a socket read, and one read as a
+        # rule; the six before the session are in both snapshots
+        assert sl.a["ingest_run_publishes"] == 6
+        assert sl.b["ingest_run_publishes"] - sl.a["ingest_run_publishes"] == 12
+        assert 1 <= sl.b["ingest_runs"] - sl.a["ingest_runs"] <= 12
         roots = [e for e in doc["traceEvents"] if e["name"] == "publish"]
         assert len(roots) == 24
         kept = {r.seq for r in sl.batches}
