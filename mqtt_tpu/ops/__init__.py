@@ -13,7 +13,7 @@ PUBLISH — onto the TPU as a multi-probe flat-hash join:
                  background rebuild, for live brokers under churn
 
 The host trie in ``mqtt_tpu.topics`` remains the bit-identical oracle and
-the fallback path (spill/saturation routes, in-flight delta windows).
+the fallback path (saturation and over-deep routes, in-flight delta windows).
 """
 
 from .delta import DeltaMatcher

@@ -29,15 +29,28 @@ Encoding (reference semantics: topics.go:583-628):
   exempt (topics.go:637) — a per-entry top_wild flag plus a per-id exempt
   bit.
 - Anything the device cannot prove is routed to the bit-identical host
-  trie: probes of saturated buckets, entries whose id list exceeds the
-  window, topics deeper than the compiled level cap, and (for the packed
-  transfer path) topics matching more ids than the transfer prefix.
+  trie: probes of saturated buckets and topics deeper than the compiled
+  level cap (the mesh-sharded slot form also routes a topic whose ids
+  outnumber its ``out_slots``).
 
-Table layout: `table[S, 16]` u32 = 4 entries/bucket x [key1, key2, meta,
+Table layout: `table[S, 16]` u32 = 4 slots/bucket x [key1, key2, meta,
 base]. Sub ids are SYNTHETIC — entry ordinal x window + slot — so the
 kernel computes them from the bucket row alone: matching costs exactly ONE
 64-byte row gather per probe shape, and the host maps ids back to
-subscriptions lazily (sid // window -> entry snapshot).
+subscriptions lazily (sid // window -> snapshot).
+
+An entry of at most ``window`` ids takes one slot and one ordinal, its
+three counts packed into the meta word. A WIDE entry (more ids than the
+window: a broadcast filter a thousand clients hold) takes two adjacent
+slots of its bucket and ``ceil(n / window)`` CONSECUTIVE ordinals: the
+meta word carries the wide flag and zero counts, the slot after it is
+``[ncli, nreg, 0, ninl]`` in full 32-bit words, and the id list (clients,
+then shared, then inline) is cut into window-sized snapshots, one an
+ordinal, each a ``(clients, shared, inline)`` tuple of its own. Its sids
+are therefore one contiguous range like any other entry's, the probe
+still reads one row, and ``sid // window -> snapshot`` stays two integer
+operations for the resolvers (native/accelmod.c ``merge_sid`` and
+``_LazySubTable.__getitem__`` are unchanged).
 """
 
 from __future__ import annotations
@@ -64,19 +77,24 @@ PLUS2 = 0xC2B2AE3D  # sentinel level-hash for '+' (lane 2)
 KIND_EXACT = 0x165667B1
 KIND_HASH = 0x27D4EB2F
 
-# meta word bit layout (one per entry). Counts are window-bounded, so six
-# bits each: ncli (the $-exempt boundary: slots >= ncli are shared/inline),
-# nreg (clients+shared — the id count when a '#' entry matches its exact
-# depth, which excludes inline), ninl (inline tail).
+# meta word bit layout (one per entry). A narrow entry's counts are
+# window-bounded, so six bits each: ncli (the $-exempt boundary: slots >=
+# ncli are shared/inline), nreg (clients+shared — the id count when a '#'
+# entry matches its exact depth, which excludes inline), ninl (inline
+# tail). A WIDE entry (more ids than the window) sets _WIDE_SHIFT, leaves
+# the three fields 0 and keeps its counts as whole words in the bucket's
+# NEXT slot, `[ncli, nreg, 0, ninl]` (_EXT_*): word 2 stays 0 so that slot
+# never reads as wide itself, and the probe masks it out of the key match.
 _CNT_BITS = 6
 _NCLI_SHIFT = 0
 _NREG_SHIFT = 6
 _NINL_SHIFT = 12
 _TOPWILD_SHIFT = 18
 _LASTPLUS_SHIFT = 19
-_SPILL_SHIFT = 20
+_WIDE_SHIFT = 20
 _SAT_SHIFT = 21  # entry-0 meta only: whole bucket saturated at build
 MAX_WINDOW = (1 << _CNT_BITS) - 1
+_EXT_NCLI, _EXT_NREG, _EXT_NINL = 0, 1, 3  # words of a wide entry's second slot
 
 ENTRY_INTS = 4
 BUCKET_ENTRIES = 4
@@ -123,14 +141,15 @@ class FlatIndex:
     n_entries: int = 0
     n_subs: int = 0  # actual subscriptions indexed (sid space is larger)
     n_sat: int = 0  # build-saturated buckets (probes host-route)
-    n_spill: int = 0  # entries with more ids than the window (host-route)
+    n_wide: int = 0  # entries with more ids than the window (two slots each)
+    max_width: int = 0  # most ids any entry has held since the last build
     n_orphans: int = 0  # sid windows abandoned by in-place folds
     # Wildcard-free fast path (SURVEY §7 hard part 4: "host fast-path for
     # exact-match-only tries"): when the filter set has NO '+'/'#' anywhere,
     # matching degenerates to one dict probe — path string -> snapshot
     # tuple — and the device round trip is pure loss. ``exact_map``
-    # covers ALL terminal paths, including
-    # over-deep and spilled entries the device table cannot serve, so the
+    # covers ALL terminal paths, including the over-deep ones and those
+    # in saturated buckets that the device table cannot serve, so the
     # fast path has no fallback classes at all. None when the filter set
     # has wildcards (or after a fold introduces one).
     exact_map: Any = None
@@ -184,11 +203,18 @@ class FlatIndex:
         seconds of host build plus a full-table H2D upload, while a fold
         touches one bucket row per distinct filter path (~KB).
 
+        An entry that crosses the window boundary folds in place, either
+        way: it keeps its ordinals when the new id list needs no more of
+        them than it holds (the spare ones are orphaned), takes a fresh
+        run of consecutive ordinals otherwise, and its bucket row is
+        re-packed around the one or two slots it now needs.
+
         Full-rebuild (``None``) cases: a new wildcard SHAPE with no free
         pad slot in the pattern arrays, a token hashing to the ``+``
         sentinel pair under the current salt, a torn trie read that
         persists across retries, or degradation beyond the compaction
-        thresholds (orphaned sid windows, fold-saturated buckets).
+        thresholds (orphaned sid windows; a bucket whose entries, a wide
+        one counting two, no longer fit its four slots).
         Residual risk: a new filter whose 64-bit path key collides with a
         different live filter folds into the wrong entry (p ~ 2^-64 x n;
         the same order as the kernel's own topic-key match); the periodic
@@ -204,17 +230,12 @@ class FlatIndex:
         # where every unsubscribe is a large fraction) never thrash
         if self.n_orphans * self.window > max(4096, len(self.subs) // 4):
             return None
-        # a fold appends at most one fresh window per filter: re-check the
-        # sid-space int32 bound build_flat_index enforces (conservative
-        # upper estimate; a None forces the rebuild that re-packs sids)
-        if len(self.subs) + len(filters) * self.window >= 1 << 30:
-            return None
-
         seen_paths = set()
         touched: set = set()
         pats_changed = False
         empty_snap = ((), (), ())
-        cnt_mask = (1 << _CNT_BITS) - 1
+        window = self.window
+        subs = self.subs
         # exact-map maintenance is STAGED and applied only when the whole
         # fold succeeds: the dict is shared with the live instance
         # (clone_for_fold does not copy it — a 1M-entry dict copy would
@@ -283,122 +304,110 @@ class FlatIndex:
                             return None  # sentinel collision: needs a re-salt
                     h1 = _mix_np(h1, t1)
                     h2 = _mix_np(h2, t2)
-            h1 = np.uint32(h1)
-            h2 = np.uint32(h2)
+            h1 = int(h1)
+            h2 = int(h2)
 
             n_cli, n_shr, n_inl = len(snap[0]), len(snap[1]), len(snap[2])
             total = n_cli + n_shr + n_inl
 
-            slot = int(h1 & np.uint32(S - 1))
+            slot = h1 & (S - 1)
             row = tbl[slot]
             if (int(row[0, 2]) >> _SAT_SHIFT) & 1:
                 continue  # saturated bucket: already fully host-routed
-            found = -1
-            free = -1
-            for e in range(BUCKET_ENTRIES):
-                if row[e, 0] == h1 and row[e, 1] == h2 and row[e].any():
-                    found = e
+            entries = _bucket_entries(row)
+            found = None
+            for entry in entries:
+                if entry[0] == h1 and entry[1] == h2:
+                    found = entry
                     break
-                if free < 0 and not row[e].any():
-                    free = e
+            if found is None and total == 0:
+                continue  # deleted before we ever indexed it
 
             top_wild = bool(parts) and parts[0] in ("+", "#")
             last_plus = is_hash and depth > 0 and ((mask >> (depth - 1)) & 1) == 1
-            spill_new = (
-                total > self.window
-                or (n_cli + n_shr) > MAX_WINDOW
-                or n_inl > MAX_WINDOW
+            flags = (int(top_wild) << _TOPWILD_SHIFT) | (
+                int(last_plus) << _LASTPLUS_SHIFT
             )
-
-            def meta_word(ncli, nreg, ninl, spill):
-                return np.uint32(
-                    (ncli << _NCLI_SHIFT)
-                    | (nreg << _NREG_SHIFT)
-                    | (ninl << _NINL_SHIFT)
-                    | (int(top_wild) << _TOPWILD_SHIFT)
-                    | (int(last_plus) << _LASTPLUS_SHIFT)
-                    | (int(spill) << _SPILL_SHIFT)
-                )
-
-            if found >= 0:
-                old_meta = int(row[found, 2])
-                old_spill = bool((old_meta >> _SPILL_SHIFT) & 1)
-                # spilled entries carry zeroed counts, so this is 0 for them
-                self.n_subs -= ((old_meta >> _NREG_SHIFT) & cnt_mask) + (
-                    (old_meta >> _NINL_SHIFT) & cnt_mask
-                )
-                if not spill_new:
-                    self.n_subs += total
-                if total == 0:
-                    if not old_spill:
-                        self.subs.replace(int(row[found, 3]) // self.window, empty_snap)
-                        self.n_orphans += 1
-                    else:
-                        self.n_spill -= 1
-                    row[found] = 0
-                    self.n_entries -= 1
-                elif spill_new:
-                    if not old_spill:
-                        self.subs.replace(int(row[found, 3]) // self.window, empty_snap)
-                        self.n_orphans += 1
-                        self.n_spill += 1
-                    row[found, 2] = meta_word(0, 0, 0, True)
-                    row[found, 3] = 0
-                else:
-                    if old_spill:
-                        ordinal = self.subs.append(snap)
-                        self.n_spill -= 1
-                    else:
-                        ordinal = int(row[found, 3]) // self.window
-                        self.subs.replace(ordinal, snap)
-                    row[found, 2] = meta_word(n_cli, n_cli + n_shr, n_inl, False)
-                    row[found, 3] = np.uint32(ordinal * self.window)
-                touched.add(slot)
+            if total > window:
+                meta = flags | (1 << _WIDE_SHIFT)
+                counts = (n_cli, n_cli + n_shr, n_inl)
             else:
+                meta = (
+                    flags
+                    | (n_cli << _NCLI_SHIFT)
+                    | ((n_cli + n_shr) << _NREG_SHIFT)
+                    | (n_inl << _NINL_SHIFT)
+                )
+                counts = None
+            chunks = _chunk_snaps(snap, window) if total else []
+
+            if found is not None:
+                _ncli, old_nreg, old_ninl = _entry_counts(found)
+                old_k = _ordinals_for(old_nreg + old_ninl, window)
+                ordinal = found[3] // window
+                if len(chunks) > old_k:
+                    # more windows than it holds: the old run is orphaned
+                    # whole and the entry moves to a fresh consecutive one
+                    for j in range(old_k):
+                        subs.replace(ordinal + j, empty_snap)
+                    self.n_orphans += old_k
+                    if len(subs) + len(chunks) * window >= 1 << 30:
+                        return None  # sid space: the rebuild re-packs it
+                    ordinal = subs.extend(chunks)
+                else:
+                    for j, chunk in enumerate(chunks):
+                        subs.replace(ordinal + j, chunk)
+                    for j in range(len(chunks), old_k):
+                        subs.replace(ordinal + j, empty_snap)
+                    self.n_orphans += old_k - len(chunks)
+                self.n_subs += total - old_nreg - old_ninl
+                self.n_wide += int(counts is not None) - int(found[4] is not None)
                 if total == 0:
-                    continue  # deleted before we ever indexed it
-                if free < 0:
-                    # fold-time saturation would orphan the bucket's OTHER
-                    # entries — filters that are NOT in the delta overlay,
-                    # so in-flight batches could still decode their sids
-                    # against emptied snapshots. Only the full rebuild
-                    # (which swaps a fresh FlatIndex wholesale, leaving
-                    # captured snapshots intact) can absorb this safely.
-                    return None
+                    entries.remove(found)
+                    self.n_entries -= 1
+                else:
+                    found[2:] = [meta, ordinal * window, counts]
+            else:
                 # the shape must already be compiled (or claim a pad slot)
-                shape_ok = False
-                pad_free = -1
+                claim = -1
                 for p in range(len(self.pat_depth)):
                     if (
                         self.pat_kind[p] == np.uint32(kind)
                         and self.pat_depth[p] == depth
                         and self.pat_mask[p] == np.uint32(mask)
                     ):
-                        shape_ok = True
+                        claim = -1
                         break
-                    if pad_free < 0 and self.pat_depth[p] < 0:
-                        pad_free = p
-                if not shape_ok:
-                    if pad_free < 0:
-                        return None  # pads exhausted: recompile needed
-                    self.pat_kind[pad_free] = np.uint32(kind)
-                    self.pat_depth[pad_free] = np.int32(depth)
-                    self.pat_mask[pad_free] = np.uint32(mask)
-                    pats_changed = True
-                if spill_new:
-                    row[free] = (h1, h2, meta_word(0, 0, 0, True), 0)
-                    self.n_spill += 1
+                    if claim < 0 and self.pat_depth[p] < 0:
+                        claim = p
                 else:
-                    ordinal = self.subs.append(snap)
-                    row[free] = (
-                        h1,
-                        h2,
-                        meta_word(n_cli, n_cli + n_shr, n_inl, False),
-                        np.uint32(ordinal * self.window),
-                    )
-                    self.n_subs += total
+                    if claim < 0:
+                        return None  # pads exhausted: recompile needed
+                if len(subs) + len(chunks) * window >= 1 << 30:
+                    return None  # sid space: the rebuild re-packs it
+                entries.append(
+                    [h1, h2, meta, subs.extend(chunks) * window, counts]
+                )
+                self.n_subs += total
+                self.n_wide += int(counts is not None)
                 self.n_entries += 1
-                touched.add(slot)
+            if sum(1 if e[4] is None else 2 for e in entries) > BUCKET_ENTRIES:
+                # fold-time saturation would orphan the bucket's OTHER
+                # entries — filters that are NOT in the delta overlay,
+                # so in-flight batches could still decode their sids
+                # against emptied snapshots. Only the full rebuild
+                # (which swaps a fresh FlatIndex wholesale, leaving
+                # captured snapshots intact) can absorb this safely.
+                return None
+            if found is None and claim >= 0:
+                self.pat_kind[claim] = np.uint32(kind)
+                self.pat_depth[claim] = np.int32(depth)
+                self.pat_mask[claim] = np.uint32(mask)
+                pats_changed = True
+            _write_bucket(row, entries)
+            if total > self.max_width:
+                self.max_width = total
+            touched.add(slot)
 
         # the fold succeeded: apply the staged exact-map maintenance. The
         # dict is shared with the live instance; mutating it here (before
@@ -419,6 +428,81 @@ class FlatIndex:
         return updates, pats_changed
 
 
+def _ordinals_for(total: int, window: int) -> int:
+    """Consecutive ordinals an entry of ``total`` ids is laid over."""
+    return max(1, -(-total // window))
+
+
+def _chunk_snaps(snap: tuple, window: int) -> list:
+    """Cut one ``(clients, shared, inline)`` snapshot into the
+    window-sized snapshots a wide entry's consecutive ordinals hold: the
+    id list is clients, then shared, then inline, and chunk ``j`` is its
+    ids ``[j * window, (j + 1) * window)``, again as a 3-tuple, so
+    ``sid // window`` finds the chunk and ``sid % window`` the id in it
+    by the same clients-shared-inline arithmetic a narrow entry uses."""
+    cli, shr, inl = snap
+    nc, ns = len(cli), len(shr)
+    total = nc + ns + len(inl)
+    if total <= window:
+        return [snap]
+    return [
+        (
+            cli[lo : lo + window],
+            shr[max(0, lo - nc) : max(0, lo + window - nc)],
+            inl[max(0, lo - nc - ns) : max(0, lo + window - nc - ns)],
+        )
+        for lo in range(0, total, window)
+    ]
+
+
+def _bucket_entries(row: np.ndarray) -> list:
+    """One bucket row ``[4, 4]`` as its live entries, in slot order:
+    ``[k1, k2, meta, base, counts]`` with ``counts`` the
+    ``(ncli, nreg, ninl)`` of a wide entry's second slot, None for a
+    narrow one (its counts are in ``meta``)."""
+    out = []
+    words = row.tolist()
+    e = 0
+    while e < BUCKET_ENTRIES:
+        k1, k2, meta, base = words[e]
+        e += 1
+        if not (k1 or k2 or meta or base):
+            continue
+        counts = None
+        if (meta >> _WIDE_SHIFT) & 1:
+            ext = words[e]
+            counts = (ext[_EXT_NCLI], ext[_EXT_NREG], ext[_EXT_NINL])
+            e += 1
+        out.append([k1, k2, meta, base, counts])
+    return out
+
+
+def _entry_counts(entry: list) -> tuple:
+    """``(ncli, nreg, ninl)`` of one parsed entry, wide or narrow."""
+    if entry[4] is not None:
+        return entry[4]
+    meta = entry[2]
+    return (
+        (meta >> _NCLI_SHIFT) & MAX_WINDOW,
+        (meta >> _NREG_SHIFT) & MAX_WINDOW,
+        (meta >> _NINL_SHIFT) & MAX_WINDOW,
+    )
+
+
+def _write_bucket(row: np.ndarray, entries: list) -> None:
+    """Re-pack a bucket row from its parsed entries (at most four slots'
+    worth: the caller checked), a wide entry's counts in the slot after
+    it."""
+    row[:] = 0
+    e = 0
+    for k1, k2, meta, base, counts in entries:
+        row[e] = (k1, k2, meta, base)
+        e += 1
+        if counts is not None:
+            row[e, _EXT_NCLI], row[e, _EXT_NREG], row[e, _EXT_NINL] = counts
+            e += 1
+
+
 def _mix_np(h: np.ndarray, t: np.ndarray) -> np.ndarray:
     h = (h ^ t).astype(np.uint32)
     h = ((h << np.uint32(13)) | (h >> np.uint32(19))).astype(np.uint32)
@@ -426,10 +510,12 @@ def _mix_np(h: np.ndarray, t: np.ndarray) -> np.ndarray:
 
 
 class _LazySubTable:
-    """sid -> SubEntry, materialized on demand from per-entry snapshot
-    tuples (clients, shared, inline) captured at build time. Sub ids are
-    synthetic — entry ordinal x window + slot — so the mapping is two
-    integer ops. Memoized: hot topics resolve to dict hits."""
+    """sid -> SubEntry, materialized on demand from per-ordinal snapshot
+    tuples (clients, shared, inline) captured at build time: a narrow
+    entry's one, a wide entry's window-sized chunks on consecutive
+    ordinals (``_chunk_snaps``). Sub ids are synthetic — ordinal x
+    window + slot — so the mapping is two integer ops. Memoized: hot
+    topics resolve to dict hits."""
 
     __slots__ = ("_window", "_snaps", "_n", "memo")
 
@@ -487,11 +573,13 @@ class _LazySubTable:
         for sid in range(ordinal * w, ordinal * w + w):
             memo_pop(sid, None)
 
-    def append(self, snap) -> int:
-        """Allocate a fresh ordinal for a new entry (fold clones only)."""
-        self._snaps.append(snap)
-        ordinal = len(self._snaps) - 1
-        self._n += self._window
+    def extend(self, snaps: list) -> int:
+        """Allocate a fresh run of consecutive ordinals, one a snapshot
+        (a narrow entry's one, a wide entry's chunks), and return the
+        first (fold clones only)."""
+        ordinal = len(self._snaps)
+        self._snaps.extend(snaps)
+        self._n += self._window * len(snaps)
         return ordinal
 
 
@@ -651,7 +739,6 @@ def build_flat_index(
     n_cli = np.zeros(n_all, dtype=np.int64)
     n_shr = np.zeros(n_all, dtype=np.int64)
     n_inl = np.zeros(n_all, dtype=np.int64)
-    spills = np.zeros(n_all, dtype=bool)
     top_wilds = np.zeros(n_all, dtype=bool)
     for k, i in enumerate(sel):
         node = nodes[i]
@@ -670,49 +757,50 @@ def build_flat_index(
             f"window must be <= {MAX_WINDOW} (meta packs counts in "
             f"{_CNT_BITS}-bit fields); got {window}"
         )
-    spills = (
-        (total_ids > window)
-        | ((n_cli + n_shr) > MAX_WINDOW)
-        | (n_inl > MAX_WINDOW)
-    )
-    n_spill = int(spills[sel].sum())
-    # synthetic sid space: entry ordinal (over kept, non-spill entries) x
-    # window + slot; nothing is stored — the kernel computes ids from the
-    # bucket row and the host divides them back out
-    ordinal = np.full(n_all, -1, dtype=np.int64)
-    alive = np.zeros(n_all, dtype=bool)
-    alive[sel] = True
-    alive &= ~spills
-    ordinal[alive] = np.arange(int(alive.sum()))
-    n_sids = int(alive.sum()) * window
+    # an entry of more ids than the window is WIDE: it is laid over
+    # ceil(n / window) consecutive ordinals, a window-sized snapshot each,
+    # and takes two slots of its bucket (its counts do not fit the meta
+    # word). Narrow entries keep ordinals 0..n_narrow-1 in walk order; the
+    # wide ones' runs follow, so a table without any is built as before
+    wide = total_ids > window
+    n = len(sel)
+    sel_wide = wide[sel]
+    wide_idx = sel[sel_wide]
+    n_wide = len(wide_idx)
+    n_narrow = n - n_wide
+    runs = -(-total_ids[wide_idx] // window)  # ordinals a wide entry
+    n_ordinals = n_narrow + int(runs.sum())
+    n_sids = n_ordinals * window
     if n_sids >= 1 << 30:
         # sid arithmetic is int32 end to end; leave sign-bit headroom
         raise RuntimeError(
             f"flat index sid space must stay < {1 << 30}, got {n_sids}"
         )
-    bases = np.where(alive, ordinal * window, 0).astype(np.uint32)
-    starts = bases  # the table's per-entry 4th word
-    nclis = np.where(spills, 0, np.minimum(n_cli, MAX_WINDOW)).astype(np.uint32)
-    nregs = np.where(spills, 0, np.minimum(n_cli + n_shr, MAX_WINDOW)).astype(np.uint32)
-    ninls = np.where(spills, 0, np.minimum(n_inl, MAX_WINDOW)).astype(np.uint32)
-    n_subs_total = int(total_ids[alive].sum())
-    subs = _LazySubTable(
-        window,
-        [snaps[i] for i in range(n_all) if alive[i]],
-        n_sids,
-    )
+    ordinal = np.zeros(n_all, dtype=np.int64)
+    ordinal[sel[~sel_wide]] = np.arange(n_narrow)
+    ordinal[wide_idx] = n_narrow + np.cumsum(runs) - runs
+    starts = (ordinal * window).astype(np.uint32)  # the per-entry 4th word
+    nclis = np.where(wide, 0, n_cli).astype(np.uint32)
+    nregs = np.where(wide, 0, n_cli + n_shr).astype(np.uint32)
+    ninls = np.where(wide, 0, n_inl).astype(np.uint32)
+    n_subs_total = int(total_ids[sel].sum())
+    flat_snaps = [snaps[i] for i in sel[~sel_wide].tolist()]
+    for i in wide_idx.tolist():
+        flat_snaps.extend(_chunk_snaps(snaps[i], window))
+    subs = _LazySubTable(window, flat_snaps, n_sids)
 
-    # size for ~0.6 entries per 4-slot bucket: P(bucket > 4 | Poisson 0.6)
+    # size for ~0.6 slots per 4-slot bucket: P(bucket > 4 | Poisson 0.6)
     # ~ 3e-4, so saturation host-routes a negligible probe fraction
-    n = len(sel)
-    S = _bucket(max(min_buckets, int(n / 0.6) + 1), minimum=1024)
+    need = 1 + sel_wide.astype(np.int64)  # slots an entry takes
+    S = _bucket(max(min_buckets, int(need.sum() / 0.6) + 1), minimum=1024)
     slot = (h1[sel] & np.uint32(S - 1)).astype(np.int64)
     order = np.argsort(slot, kind="stable")
     sslot = slot[order]
     first = np.searchsorted(sslot, sslot, side="left")
-    rank = np.arange(n) - first  # occupancy rank within each bucket
-    counts = np.bincount(slot, minlength=S)
-    sat = counts > BUCKET_ENTRIES
+    before = np.cumsum(need[order]) - need[order]
+    rank = before - before[first]  # an entry's first slot within its bucket
+    used = np.bincount(slot, weights=need, minlength=S)
+    sat = used > BUCKET_ENTRIES
     n_sat = int(sat.sum())
 
     meta = (
@@ -724,13 +812,21 @@ def build_flat_index(
             (is_hash[sel] & (depths[sel] > 0) & (((masks[sel] >> (depths[sel] - 1).astype(np.uint32)) & 1) == 1)).astype(np.uint32)
             << np.uint32(_LASTPLUS_SHIFT)
         )
-        | (spills[sel].astype(np.uint32) << np.uint32(_SPILL_SHIFT))
+        | (sel_wide.astype(np.uint32) << np.uint32(_WIDE_SHIFT))
     )
     table = np.zeros((S, BUCKET_ENTRIES, ENTRY_INTS), dtype=np.uint32)
     ok = ~sat[slot[order]]
     o = order[ok]
     cols = np.stack([h1[sel][o], h2[sel][o], meta[o], starts[sel][o]], axis=1)
     table[slot[o], rank[ok]] = cols
+    if n_wide:
+        ow = ok & sel_wide[order]
+        w = sel[order[ow]]
+        ext = np.zeros((len(w), ENTRY_INTS), dtype=np.uint32)
+        ext[:, _EXT_NCLI] = n_cli[w]
+        ext[:, _EXT_NREG] = n_cli[w] + n_shr[w]
+        ext[:, _EXT_NINL] = n_inl[w]
+        table[slot[order[ow]], rank[ow] + 1] = ext
     table[np.nonzero(sat)[0], 0, 2] = np.uint32(1 << _SAT_SHIFT)
     table = table.reshape(S, ROW_INTS)
 
@@ -752,8 +848,8 @@ def build_flat_index(
         pat_depth = _pad_to(pat_depth, pb, np.int32(-1))
         pat_mask = _pad_to(pat_mask, pb, np.uint32(0))
 
-    # wildcard-free fast path: every terminal path (kept, spilled, and
-    # over-deep alike) keyed by its literal path string — one dict probe
+    # wildcard-free fast path: every terminal path (kept and over-deep
+    # alike) keyed by its literal path string — one dict probe
     # replaces the whole device round trip (FlatIndex.exact_map)
     exact_map = None
     if not any_wild:
@@ -775,7 +871,8 @@ def build_flat_index(
         n_entries=n,
         n_subs=n_subs_total,
         n_sat=n_sat,
-        n_spill=n_spill,
+        n_wide=n_wide,
+        max_width=int(total_ids[sel].max()) if n else 0,
         exact_map=exact_map,
     )
 
@@ -792,8 +889,12 @@ def _probe_head(
     """The shared probe stage: whole-path hashes, ONE bucket row gather per
     probe, hit/meta decode, and the per-probe surviving id range
     ``[base+lo, base+lo+cnt)`` (synthetic ids make every probe's result a
-    contiguous range; the $-mask drops exactly the client prefix).
-    Returns ``(start[B,P] i32, cnt[B,P] i32, overflow[B] bool)``."""
+    contiguous range; the $-mask drops exactly the client prefix). A
+    wide entry's three counts come from the slot after it in the SAME
+    row, so its ``cnt`` runs past the window and nothing else differs.
+    Returns ``(start[B,P] i32, cnt[B,P] i32, overflow[B] bool,
+    wide[B] bool)``: ``overflow`` is a probe of a saturated bucket,
+    ``wide`` a topic whose answer holds a wide entry's hit."""
     import jax.numpy as jnp
 
     B, L = tok1.shape
@@ -826,19 +927,32 @@ def _probe_head(
     rows = table[slot].reshape(B, P, BUCKET_ENTRIES, ENTRY_INTS)
 
     hit = (rows[..., 0] == h1[..., None]) & (rows[..., 1] == h2[..., None])
-    hit = hit & active[..., None]  # [B, P, 4]; at most one per probe
+    # the slot after a wide entry holds its counts, not a key
+    counts_slot = ((rows[..., :-1, 2] >> _WIDE_SHIFT) & 1) == 1  # [B, P, 3]
+    keyed = jnp.concatenate(
+        [jnp.ones((B, P, 1), bool), ~counts_slot], axis=-1
+    )
+    hit = hit & keyed & active[..., None]  # [B, P, 4]; at most one per probe
     meta = jnp.where(hit, rows[..., 2], 0).max(axis=-1)
     base = jnp.where(hit, rows[..., 3], 0).max(axis=-1)
     hit_any = hit.any(axis=-1)
     sat_probe = ((rows[:, :, 0, 2] >> _SAT_SHIFT) & 1) == 1
 
     cnt_mask = (1 << _CNT_BITS) - 1
-    ncli = ((meta >> _NCLI_SHIFT) & cnt_mask).astype(jnp.int32)
-    nreg = ((meta >> _NREG_SHIFT) & cnt_mask).astype(jnp.int32)
-    ninl = ((meta >> _NINL_SHIFT) & cnt_mask).astype(jnp.int32)
+    wide = ((meta >> _WIDE_SHIFT) & 1) == 1
+    # a wide entry never sits in the bucket's last slot: its counts follow
+    hit_w = hit[..., :-1]
+
+    def count(shift, word):
+        in_meta = (meta >> shift) & cnt_mask
+        in_next = jnp.where(hit_w, rows[..., 1:, word], 0).max(axis=-1)
+        return jnp.where(wide, in_next, in_meta).astype(jnp.int32)
+
+    ncli = count(_NCLI_SHIFT, _EXT_NCLI)
+    nreg = count(_NREG_SHIFT, _EXT_NREG)
+    ninl = count(_NINL_SHIFT, _EXT_NINL)
     top_wild = (meta >> _TOPWILD_SHIFT) & 1
     last_plus = (meta >> _LASTPLUS_SHIFT) & 1
-    spill = ((meta >> _SPILL_SHIFT) & 1) == 1
 
     # 'filter/#' matching the exact-length topic: only via a literal last
     # level (topics.go:612), and without inline subs (topics.go:615)
@@ -853,8 +967,8 @@ def _probe_head(
     lo = jnp.where(dollar, jnp.minimum(ncli, count), 0)  # [B, P]
     cnt = count - lo
     start = base.astype(jnp.int32) + lo
-    overflow = (sat_probe & active).any(axis=1) | (spill & valid_hit).any(axis=1)
-    return start, cnt, overflow
+    overflow = (sat_probe & active).any(axis=1)
+    return start, cnt, overflow, (wide & (cnt > 0)).any(axis=1)
 
 
 def flat_match_core(
@@ -877,10 +991,12 @@ def flat_match_core(
 
     Returns ``(sub_ids[B, out_slots] int32 (-1 padded), totals[B] int32,
     overflow[B] bool)`` — ``overflow`` marks topics the host must re-walk
-    (saturated-bucket probe, spilled entry hit, or more matches than
-    ``overflow_slots``/``out_slots``). Pure jnp; jit/shard_map-able
-    (mqtt_tpu.parallel shards the table's bucket axis across a device
-    mesh)."""
+    (saturated-bucket probe, or more matches than
+    ``overflow_slots``/``out_slots``: a wide entry's hit is slotted like
+    any other while the topic's ids fit, so on this form a filter that
+    more subscribers hold than a shard has slots still takes the host
+    route). Pure jnp; jit/shard_map-able (mqtt_tpu.parallel shards the
+    table's bucket axis across a device mesh)."""
     import jax.numpy as jnp
 
     B, L = tok1.shape
@@ -891,7 +1007,7 @@ def flat_match_core(
             jnp.zeros((B,), jnp.int32),
             jnp.zeros((B,), bool),
         )
-    start, cnt, overflow = _probe_head(
+    start, cnt, overflow, _wide = _probe_head(
         table, pat_kind, pat_depth, pat_mask, tok1, tok2, lengths, is_dollar,
         max_levels=max_levels,
     )
@@ -931,9 +1047,30 @@ def flat_match_ranges_core(
     This is the single-device production form: synthetic ids make every
     probe's surviving result one contiguous range, so ranges carry the
     COMPLETE result in 2P ints/topic — no transfer-prefix cap (and no
-    host fallback class for it), no device-side compaction, and totals
-    are naturally bounded by P x window. ``overflow`` = saturated-bucket
-    probe or spilled-entry hit only."""
+    host fallback class for it), no device-side compaction, whatever the
+    width of the entries hit. ``overflow`` = saturated-bucket probe
+    only."""
+    start, cnt, totals, flags = _ranges_flags(
+        table, pat_kind, pat_depth, pat_mask, tok1, tok2, lengths, is_dollar,
+        max_levels=max_levels,
+    )
+    return start, cnt, totals, (flags & FLAG_OVERFLOW) != 0
+
+
+# the served programs' per-topic flags word: the host re-walks a topic
+# with FLAG_OVERFLOW; FLAG_WIDE only says its answer holds a wide entry's
+# hit (MatcherStats.wide_topics) and routes nothing
+FLAG_OVERFLOW = 1
+FLAG_WIDE = 2
+
+
+def _ranges_flags(
+    table, pat_kind, pat_depth, pat_mask, tok1, tok2, lengths, is_dollar,
+    *, max_levels
+):
+    """``flat_match_ranges_core`` with the two per-topic facts of the
+    probe in one i32 word, as the packed and compacted programs carry
+    them: ``(start, cnt, totals, flags[B])``."""
     import jax.numpy as jnp
 
     B, L = tok1.shape
@@ -943,13 +1080,14 @@ def flat_match_ranges_core(
             jnp.zeros((B, 0), jnp.int32),
             jnp.zeros((B, 0), jnp.int32),
             jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), bool),
+            jnp.zeros((B,), jnp.int32),
         )
-    start, cnt, overflow = _probe_head(
+    start, cnt, overflow, wide = _probe_head(
         table, pat_kind, pat_depth, pat_mask, tok1, tok2, lengths, is_dollar,
         max_levels=max_levels,
     )
-    return start, cnt, cnt.sum(axis=1), overflow
+    flags = overflow * jnp.int32(FLAG_OVERFLOW) + wide * jnp.int32(FLAG_WIDE)
+    return start, cnt, cnt.sum(axis=1), flags
 
 
 def _jit_core():
@@ -1021,7 +1159,8 @@ def _packed_core(
 ):
     """The production single-device form: ONE packed input transfer and
     ONE packed RANGES output transfer. In ``[B, 2L+2]`` i32, out
-    ``[B, 2P+2]`` i32 = (range starts | range counts | total | overflow).
+    ``[B, 2P+2]`` i32 = (range starts | range counts | total | flags:
+    FLAG_OVERFLOW, FLAG_WIDE).
     Ranges carry the complete result (flat_match_ranges_core), so there is
     no transfer-prefix host-fallback class and no device-side compaction;
     2P ints/topic also transfer less than any useful slot prefix."""
@@ -1033,7 +1172,7 @@ def _packed_core(
     tok2 = jax.lax.bitcast_convert_type(packed_tokens[:, L : 2 * L], jnp.uint32)
     lengths = packed_tokens[:, 2 * L]
     is_dollar = packed_tokens[:, 2 * L + 1].astype(bool)
-    start, cnt, totals, overflow = flat_match_ranges_core(
+    start, cnt, totals, flags = _ranges_flags(
         table,
         pat_kind,
         pat_depth,
@@ -1045,13 +1184,7 @@ def _packed_core(
         max_levels=max_levels,
     )
     return jnp.concatenate(
-        [
-            start,
-            cnt,
-            totals[:, None],
-            overflow[:, None].astype(jnp.int32),
-        ],
-        axis=1,
+        [start, cnt, totals[:, None], flags[:, None]], axis=1
     )
 
 
@@ -1080,8 +1213,8 @@ def _compact_core(
     expansion, no per-topic padding.
 
     Output: ONE int32 vector ``[2 + 2B + capacity]`` =
-    ``(n_hits, batch_overflow | totals[B] | overflow[B] |
-    pair_sid[capacity])`` (-1-padded). The pair stream is TOPIC-MAJOR,
+    ``(n_hits, batch_overflow | totals[B] | flags[B] (FLAG_OVERFLOW,
+    FLAG_WIDE) | pair_sid[capacity])`` (-1-padded). The pair stream is TOPIC-MAJOR,
     so each pair's topic_idx is reconstructed for free on the host by
     walking the per-topic totals — the logical ``(topic_idx, sid)``
     pair moves 4 bytes, not 8. ``n_hits`` is the TRUE hit count even
@@ -1110,7 +1243,7 @@ def _compact_core(
                 jnp.full((capacity,), -1, jnp.int32),
             ]
         )
-    start, cnt, totals, overflow = flat_match_ranges_core(
+    start, cnt, totals, flags = _ranges_flags(
         table,
         pat_kind,
         pat_depth,
@@ -1136,7 +1269,7 @@ def _compact_core(
         [
             header,
             totals,
-            overflow.astype(jnp.int32),
+            flags,
             jnp.where(valid, sid, -1),
         ]
     )

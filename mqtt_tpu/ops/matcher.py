@@ -25,6 +25,8 @@ from ..packets import Subscription
 from ..topics import Subscribers, TopicsIndex
 from ..tracing import span
 from .flat import (
+    FLAG_OVERFLOW,
+    FLAG_WIDE,
     KIND_CLIENT,
     KIND_INLINE,
     KIND_SHARED,
@@ -337,8 +339,8 @@ class MatcherStats:
     server's $SYS loop when a device matcher is active (server.py).
 
     ``host_fallbacks`` counts topics re-walked on the host for any reason;
-    ``overflows`` counts the subset caused by device-side routing (spilled
-    entries, saturated buckets, over-deep topics) rather than delta-overlay
+    ``overflows`` counts the subset caused by device-side routing
+    (saturated buckets, over-deep topics) rather than delta-overlay
     routes.
     """
 
@@ -364,6 +366,12 @@ class MatcherStats:
     compact_batches: int = 0
     compact_overflows: int = 0
     d2h_bytes: int = 0
+    # wide entries (ops/flat.py: a filter more subscribers hold than the
+    # window, laid over consecutive ordinals): how many the served index
+    # holds, and the topics whose DEVICE answer, served, held the hit of
+    # one — counted once a batch from the flags word the program returns
+    wide_entries: int = 0
+    wide_topics: int = 0
     # the LAST full rebuild, split into its host and device halves: the
     # flat-index build, the (completed) H2D upload, and the host bytes
     # of the arrays uploaded — what chip_smoke.py holds HBM in use to
@@ -399,6 +407,8 @@ class MatcherStats:
             "compact_batches": self.compact_batches,
             "compact_overflows": self.compact_overflows,
             "d2h_bytes": self.d2h_bytes,
+            "wide_entries": self.wide_entries,
+            "wide_topics": self.wide_topics,
         }
         out["fallback_ratio"] = (
             round(self.host_fallbacks / self.topics, 6) if self.topics else 0.0
@@ -411,9 +421,11 @@ class TpuMatcher:
 
     Wildcard-shape fan-out is a build-time property of the filter set
     (ops/flat.py). ``out_slots`` caps the per-topic device result on the
-    slot-expanding core (the mesh-sharded form); ``window`` caps ids per
-    filter path. The packed path transfers per-probe RANGES, which carry
-    the complete result in 2P+2 ints per topic.
+    slot-expanding core (the mesh-sharded form); ``window`` is the ids an
+    ordinal holds: a filter path with more is a wide entry over several
+    consecutive ones, answered from the device like any other. The
+    packed path transfers per-probe RANGES, which carry the complete
+    result in 2P+2 ints per topic.
     """
 
     def __init__(
@@ -496,6 +508,7 @@ class TpuMatcher:
         self._fold_poisoned = False
         stats = self.stats
         stats.rebuilds += 1
+        stats.wide_entries = flat.n_wide
         stats.table_bytes = sum(int(a.nbytes) for a in host_arrays)
         stats.build_seconds = t_built - t0
         stats.upload_seconds = t_up - t_built
@@ -558,6 +571,7 @@ class TpuMatcher:
         self._state = (flat, (new_table, *new_pats), version)
         self._fold_poisoned = False
         self.stats.folds += 1
+        self.stats.wide_entries = flat.n_wide
         self.stats.note_rebuild(time.perf_counter() - t0)
         return True
 
@@ -778,7 +792,8 @@ class TpuMatcher:
             stats.compact_batches += 1
             stats.d2h_bytes += int(out.nbytes)
             totals = out[2 : 2 + bp]
-            true_overflow = out[2 + bp : 2 + 2 * bp].astype(bool) | len_overflow
+            flags = out[2 + bp : 2 + 2 * bp]
+            true_overflow = ((flags & FLAG_OVERFLOW) != 0) | len_overflow
             pair_sid = out[2 + 2 * bp : 2 + 2 * bp + capacity]
             if batch_pred is not None:
                 routed = batch_pred(topics)
@@ -789,6 +804,7 @@ class TpuMatcher:
             host_route = true_overflow.copy()
             if len(routed):
                 host_route[np.asarray(routed, dtype=np.int64)] = True
+            self._count_wide(flags[:b], host_route[:b])
             return self._materialize_pairs(
                 pair_sid, None, totals, host_route, n_hits, topics, flat,
                 true_overflow,
@@ -815,9 +831,11 @@ class TpuMatcher:
 
     def _compact_capacity_for(self, b_padded: int, flat) -> int:
         """The pair-buffer capacity for one batch (pick_compact_capacity:
-        pinned-or-adaptive with sticky pow2 buckets), capped at the
-        theoretical hit bound (P probes x window ids per topic)."""
-        max_hits = b_padded * int(flat.pat_depth.shape[0]) * flat.window
+        pinned-or-adaptive with sticky pow2 buckets), capped at the hits
+        a batch of this index can hold: P probes a topic, each of at
+        most the widest entry's ids (the window where no entry is wide)."""
+        width = max(flat.window, flat.max_width)
+        max_hits = b_padded * int(flat.pat_depth.shape[0]) * width
         return pick_compact_capacity(
             self.compact_capacity, self._hits_ewma, b_padded, max_hits,
             self._caps,
@@ -826,6 +844,14 @@ class TpuMatcher:
     def _observe_hits(self, n_hits: int, b: int) -> None:
         """Feed one batch's true hit count into the capacity EWMA."""
         self._hits_ewma = fold_hits_ewma(self._hits_ewma, n_hits, b)
+
+    def _count_wide(self, flags: np.ndarray, host_route) -> None:
+        """Count the batch's topics whose device answer held a wide
+        entry's hit and was served (not re-walked): one pass over the
+        flags word in hand, nothing a topic in Python."""
+        wide = (flags & FLAG_WIDE) != 0
+        if wide.any():
+            self.stats.wide_topics += int(np.count_nonzero(wide & ~host_route))
 
     @staticmethod
     def _note_sync(prof, rec) -> None:
@@ -885,12 +911,19 @@ class TpuMatcher:
                 acc, packed, topics, flat, P, len_overflow, pred, batch_pred
             )
         stats = self.stats
-        # the ONLY host-route class left: device overflow (sat/spill)
-        # or >max_levels topics — ranges carry the COMPLETE result,
-        # so every fallback is also an overflow
-        overflow = (
-            packed[:, 2 * P + 1].astype(bool) | len_overflow
-        ).tolist()
+        # the ONLY host-route class left: device overflow (a saturated
+        # bucket) or >max_levels topics — ranges carry the COMPLETE
+        # result, so every fallback is also an overflow
+        flags = packed[:, 2 * P + 1]
+        overflow = ((flags & FLAG_OVERFLOW) != 0) | len_overflow
+        route = overflow
+        if pred is not None:
+            route = overflow | np.fromiter(
+                (bool(t) and pred(t) for t in topics), bool, len(topics)
+            )
+        self._count_wide(flags, route)
+        overflow = overflow.tolist()
+        route = route.tolist()
         # one bulk C conversion: per-row numpy slicing costs ~10us of
         # fixed overhead per topic, plain list walks are ~10x cheaper
         out_rows = packed[:, : 2 * P].tolist()
@@ -900,7 +933,7 @@ class TpuMatcher:
         for i, topic in enumerate(topics):
             if not topic:
                 results_append(Subscribers())  # empty topic never matches
-            elif overflow[i] or (pred is not None and pred(topic)):
+            elif route[i]:
                 stats.host_fallbacks += 1
                 stats.overflows += int(overflow[i])
                 results_append(self.topics.subscribers(topic))  # host fallback
@@ -918,8 +951,8 @@ class TpuMatcher:
     def _match_exact_fast(self, topics: list[str], flat, route_to_host):
         """Serve a batch from the exact-map (wildcard-free filter sets):
         every topic is one dict probe + one snapshot expansion, covering
-        spilled and over-deep entries too — no fallback classes, no device
-        dispatch. Results are bit-identical to the host walk: in an
+        over-deep paths and saturated buckets too — no fallback classes,
+        no device dispatch. Results are bit-identical to the host walk: in an
         exact-only trie the walk gathers exactly the literal path's node.
 
         The work happens when the RESOLVER runs, not at issue time: the
@@ -1010,18 +1043,24 @@ class TpuMatcher:
         # delta-routed topics — is merged into the overflow column BEFORE
         # the C call, so routed rows are never materialized just to be
         # thrown away by a patch-up loop
-        true_overflow = (packed[:, col] != 0) | len_overflow
+        flags = packed[:, col]
+        true_overflow = ((flags & FLAG_OVERFLOW) != 0) | len_overflow
         if batch_pred is not None:
             routed = batch_pred(topics)
         elif pred is not None:
             routed = [i for i, t in enumerate(topics) if t and pred(t)]
         else:
             routed = ()
-        if len_overflow.any() or len(routed):
+        host_route = true_overflow
+        if len(routed):
+            host_route = true_overflow.copy()
+            host_route[np.asarray(routed, dtype=np.int64)] = True
+        self._count_wide(flags, host_route)
+        if (flags != host_route).any():
+            # the C materializer reads the column as "re-walk on the
+            # host": hand it the routes alone, without the wide flag
             packed = packed.copy()
-            packed[:, col] |= len_overflow
-            if len(routed):
-                packed[np.asarray(routed, dtype=np.int64), col] = 1
+            packed[:, col] = host_route
         if self.lazy and hasattr(acc, "resolve_batch_views"):
             # lazy ranges views (ISSUE 13): the packed row itself is the
             # result; per-hit objects build on demand at fan-out. The

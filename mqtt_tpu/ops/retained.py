@@ -55,7 +55,7 @@ import numpy as np
 from ..packets import Subscription
 from ..resilience import CircuitBreaker
 from ..topics import NS_CHAR, TopicsIndex, ns_local, ns_tenant
-from .flat import build_flat_index, flat_match_packed
+from .flat import FLAG_OVERFLOW, build_flat_index, flat_match_packed
 from .hashing import tokenize_topics
 
 # host-fallback classes (counted; mirrors flat.py's fallback accounting)
@@ -270,7 +270,7 @@ class RetainedMatchEngine:
         )
         p = fidx.pat_kind.shape[0]
         totals = out[: len(names), 2 * p]
-        if bool(out[: len(names), 2 * p + 1].any()):
+        if bool((out[: len(names), 2 * p + 1] & FLAG_OVERFLOW).any()):
             self.fallbacks["overflow"] += 1
             return None
         hits = [i for i in range(len(names)) if names[i] is not None and totals[i] > 0]

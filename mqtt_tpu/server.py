@@ -825,6 +825,9 @@ class _Ops:
         self.ingest_run: Optional[Callable[..., int]] = None
         self.ingest_runs = 0
         self.ingest_run_publishes = 0
+        # the most target ids one completion slice has looked up at once
+        # (Server._complete_staged: its ``ids`` list): one compare a slice
+        self.slice_targets_max = 0
 
 
 class Server:
@@ -1499,16 +1502,22 @@ class Server:
         (``tracing.DeviceProfiler.counters``): fallbacks the stage held
         in their publisher's order, frames handed to subscribers'
         sockets, calls that reached a socket, the ingest runs and the
-        publishes they took in, and what the trie holds
+        publishes they took in, the widest completion slice so far (a
+        high-water mark, not a sum), the matcher's wide entries and the
+        topics they answered, and what the trie holds
         (``TopicsIndex``'s three counts)."""
         stage = self._stage
         trie = self.topics
+        stats = None if self.matcher is None else self.matcher.stats
         return {
             "order_held": 0 if stage is None else stage.order_held,
             "deliveries": self.telemetry.fanout_deliveries.value,
             "socket_sends": self._ops.socket_sends,
             "ingest_runs": self._ops.ingest_runs,
             "ingest_run_publishes": self._ops.ingest_run_publishes,
+            "slice_targets_max": self._ops.slice_targets_max,
+            "wide_entries": getattr(stats, "wide_entries", 0),
+            "wide_topics": getattr(stats, "wide_topics", 0),
             "particles": trie.particles,
             "particle_maps": trie.particle_maps,
             "held": trie.held,
@@ -1630,6 +1639,12 @@ class Server:
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
         r.gauge(
+            "mqtt_tpu_stage_slice_targets_max",
+            "Most target ids one completion slice of a staged batch has "
+            "looked up at once (high-water mark since start)",
+            fn=lambda: self._ops.slice_targets_max,
+        )
+        r.gauge(
             "mqtt_tpu_staging_pipeline_depth",
             "Device batches in flight across the staging pipeline legs",
             fn=lambda: (
@@ -1683,6 +1698,7 @@ class Server:
             ("mqtt_tpu_matcher_host_fast_total", "host_fast"),
             ("mqtt_tpu_matcher_compact_batches_total", "compact_batches"),
             ("mqtt_tpu_matcher_d2h_bytes_total", "d2h_bytes"),
+            ("mqtt_tpu_matcher_wide_topics_total", "wide_topics"),
         ):
             r.counter(
                 name,
@@ -1693,6 +1709,16 @@ class Server:
                     else getattr(self.matcher.stats, f, 0)
                 ),
             )
+        r.gauge(
+            "mqtt_tpu_matcher_wide_entries",
+            "Entries of the served index that more subscribers hold than "
+            "the window (MatcherStats.wide_entries; 0 when no device matcher)",
+            fn=lambda: (
+                0
+                if self.matcher is None
+                else getattr(self.matcher.stats, "wide_entries", 0)
+            ),
+        )
 
     def _durable_store_stats(self) -> dict:
         """Merge ``durable_stats()`` across storage hooks that expose one
@@ -3313,6 +3339,8 @@ class Server:
                 for group in subs.shared.values():
                     ids += group  # every candidate, before selection
             work.append((entry, subs, targets))
+        if len(ids) > self._ops.slice_targets_max:
+            self._ops.slice_targets_max = len(ids)
         present = self.clients.present(ids)
         lookup = present.get
         corked = self._cork_repeated(ids, present)
@@ -5439,6 +5467,9 @@ class Server:
                 topics[
                     SYS_PREFIX + "/broker/overload/stage_order_held"
                 ] = str(st.order_held)
+                topics[
+                    SYS_PREFIX + "/broker/overload/stage_slice_targets_max"
+                ] = str(self._ops.slice_targets_max)
         if self.telemetry is not None:
             # telemetry-plane observability (mqtt_tpu.telemetry): stage
             # histogram percentiles, batch occupancy, fallback classes,

@@ -199,7 +199,7 @@ def test_server_option_wires_delta_matcher():
 def test_incremental_fold_parity_over_many_rounds():
     """Folds (in-place bucket edits + device scatter) must keep the
     snapshot bit-identical to a from-scratch rebuild across adds,
-    removals, spill transitions, and brand-new wildcard shapes."""
+    removals, narrow/wide transitions, and brand-new wildcard shapes."""
     rng = random.Random(11)
     v = [f"t{i}" for i in range(12)]
     index = TopicsIndex()
@@ -254,16 +254,16 @@ def test_fold_new_wildcard_shape_claims_pad_slot():
     assert m.stats.rebuilds == r0  # pad slot claimed, no recompile-rebuild
 
 
-def test_fold_spill_and_unspill_transitions():
+def test_fold_wide_and_narrow_transitions():
     index = TopicsIndex()
     index.subscribe("seed", Subscription(filter="s/t", qos=0))
     m = DeltaMatcher(index, background=False, max_levels=4, window=16)
-    # spill: push one path over the window
+    # wide: push one path over the window
     for i in range(40):
         index.subscribe(f"sp{i}", Subscription(filter="s/t", qos=0))
     m.flush()
     assert canon(m.subscribers("s/t")) == canon(index.subscribers("s/t"))
-    # unspill: back under the window
+    # narrow again: back under the window
     for i in range(40):
         index.unsubscribe("s/t", f"sp{i}")
     m.flush()

@@ -330,9 +330,9 @@ class TestExactMapFastPath:
         assert matcher.stats.host_fast == 5  # all but the empty topic
         assert matcher.stats.host_fallbacks == 0
 
-    def test_spilled_entry_served_from_map(self):
+    def test_wide_entry_served_from_map(self):
         index = TopicsIndex()
-        for i in range(40):  # >> window: device entry would spill
+        for i in range(40):  # >> window: a wide entry on the device table
             index.subscribe(f"c{i}", Subscription(filter="hot/topic", qos=1))
         matcher = TpuMatcher(index, max_levels=4, window=8)
         matcher.rebuild()
