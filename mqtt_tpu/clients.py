@@ -47,6 +47,11 @@ CORK_MAX_BYTES = 64 * 1024
 # changes nothing inbound. The rest of the 0x3_ bytes are RETAIN, QoS2
 # and the two the fixed header refuses (QoS3, DUP at QoS0).
 RUN_FIRST_BYTES = frozenset((0x30, 0x32, 0x3A))
+# the frame an ack run takes (server.ack_run): a PUBACK that is its
+# packet id and nothing else, v3.1.1's only form and v5's short one
+# (reason 0, no properties)
+ACK_FIRST_BYTE = 0x40
+ACK_REMAINING = 2
 
 
 class ConnectionClosedError(Exception):
@@ -622,7 +627,10 @@ class Client:
         stand and says how many it took. The frame that ended the run,
         the whole stretch where the run's gate is shut, and every other
         packet go one at a time through ``packet_handler``, in the
-        frames' order always.
+        frames' order always. A stretch of PUBACK frames that hold a
+        packet id and nothing else goes the same way to
+        ``ops.ack_run`` (server.ack_run), which takes all of it or, its
+        gate shut, none, and says how long it is either way.
 
         ``packet_handler(cl, pk)`` is synchronous. A PUBLISH it parked
         with the staging loop (mqtt_tpu.staging) is still counted in
@@ -644,12 +652,14 @@ class Client:
         fast_eligible = self.ops.fast_publish_eligible
         fast_publish = self.ops.fast_publish
         ingest_run = self.ops.ingest_run
+        ack_run = self.ops.ack_run
         telemetry = getattr(self.ops, "telemetry", None)
         # device pipeline profiler (mqtt_tpu.tracing): while a profiler
         # session is live, the loop time from a scan's frames in hand to
         # their handlers returned (decode, admission, acks, the publish
         # parked with the stage) is counted as ingest, once a scan, over
-        # the publishes in it
+        # the publishes in it; a scan without a publish books it over
+        # its PUBACK frames
         prof = getattr(self.ops, "profiler", None)
         # the shard's own gate wins (per-shard decode batching is
         # default-on inside the fabric); the server-wide gate serves the
@@ -706,7 +716,24 @@ class Client:
                             start = f.body_offset + f.remaining
                             if self.closed:
                                 break
-                        solo = i + 1
+                        if i < n and frames[i].first_byte in RUN_FIRST_BYTES:
+                            solo = i + 1  # a PUBLISH the run refused
+                        continue
+                    if (
+                        i >= solo
+                        and f.first_byte == ACK_FIRST_BYTE
+                        and f.remaining == ACK_REMAINING
+                        and ack_run is not None
+                    ):
+                        # a stretch of bare PUBACK frames is taken in by
+                        # one call (server.ack_run), all of it or none
+                        taken = ack_run(self, rbuf, frames, i, start)
+                        if taken < 0:
+                            solo = i - taken  # the stretch, a frame at a time
+                            continue
+                        i += taken
+                        f = frames[i - 1]
+                        start = f.body_offset + f.remaining
                         continue
                     i += 1
                     fstart = start
@@ -752,10 +779,16 @@ class Client:
                         break
             finally:
                 self._uncork()
-            if armed and self._pub_count != n_in:
-                prof.note_ingest(
-                    time.perf_counter_ns() - t_in, self._pub_count - n_in
-                )
+            if armed:
+                busy_ns = time.perf_counter_ns() - t_in
+                if self._pub_count != n_in:
+                    prof.note_ingest(busy_ns, self._pub_count - n_in)
+                else:
+                    acks = sum(
+                        (f.first_byte >> 4) == pkts.PUBACK for f in frames[:i]
+                    )
+                    if acks:
+                        prof.note_acks(busy_ns, acks)
             if self._staged:
                 # publishes of this scan are still in the stage: one
                 # pipelining client fills device batches instead of

@@ -77,6 +77,34 @@ class Inflight:
         with self._lock:
             return self.internal.pop(id_, None) is not None
 
+    def acknowledge(self, ids: list[int]) -> int:
+        """A run of acknowledged packet ids under ONE acquisition of the
+        lock: every id that is in flight leaves the map (an unknown or a
+        repeated one is passed over) and the send quota rises by their
+        number within its maximum, which is what ``get``, ``delete``
+        and ``increase_send_quota`` an id come to. Returns how many
+        left.
+
+        -1, and nothing touched, while an entry waits for send quota
+        (``expiry < 0``) and the quota is or can become positive: each
+        single ack would then be followed by that entry's resend (the
+        server's quota drain), so the ids go one at a time."""
+        with self._lock:
+            internal = self.internal
+            if self.send_quota > 0 or self.maximum_send_quota > 0:
+                for m in internal.values():
+                    if m.expiry < 0:
+                        return -1
+            removed = 0
+            for id_ in ids:
+                if internal.pop(id_, None) is not None:
+                    removed += 1
+            if self.send_quota < self.maximum_send_quota:
+                self.send_quota = min(
+                    self.send_quota + removed, self.maximum_send_quota
+                )
+            return removed
+
     # -- flow-control quotas (inflight.go:119-156) -------------------------
 
     def decrease_receive_quota(self) -> None:

@@ -19,7 +19,15 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Optional
 
 from . import packets as pkts
-from .clients import RUN_FIRST_BYTES, Client, Clients, ConnectionClosedError, Will
+from .clients import (
+    ACK_FIRST_BYTE,
+    ACK_REMAINING,
+    RUN_FIRST_BYTES,
+    Client,
+    Clients,
+    ConnectionClosedError,
+    Will,
+)
 from .hooks import (
     ON_PACKET_ENCODE,
     ON_PACKET_PROCESSED,
@@ -785,6 +793,10 @@ _INGEST_RUN_EVENTS = (
     ON_PACKET_ENCODE,
     ON_PACKET_SENT,
 )
+# a hook that provides one of these is shown every PUBACK: the packet
+# as it was read, the completion of its delivery (a storage hook
+# persists it), the packet once processed (Server.ack_run)
+_ACK_RUN_EVENTS = (ON_PACKET_READ, ON_QOS_COMPLETE, ON_PACKET_PROCESSED)
 
 
 class _Ops:
@@ -825,6 +837,11 @@ class _Ops:
         self.ingest_run: Optional[Callable[..., int]] = None
         self.ingest_runs = 0
         self.ingest_run_publishes = 0
+        # the same for a scan's stretch of bare PUBACK frames
+        # (Server.ack_run): the runs, and the frames they took in
+        self.ack_run: Optional[Callable[..., int]] = None
+        self.ack_runs = 0
+        self.ack_run_acks = 0
         # the most target ids one completion slice has looked up at once
         # (Server._complete_staged: its ``ids`` list): one compare a slice
         self.slice_targets_max = 0
@@ -854,6 +871,7 @@ class Server:
         self._ops.fast_publish = self.try_fast_publish
         self._ops.fast_publish_eligible = self.fast_publish_eligible
         self._ops.ingest_run = self.ingest_run
+        self._ops.ack_run = self.ack_run
         # "no hook provides any of these events", by gate name, as of a
         # hooks generation: (generation, verdict) (_no_hook_provides)
         self._hook_gates: dict = {}
@@ -1502,10 +1520,10 @@ class Server:
         (``tracing.DeviceProfiler.counters``): fallbacks the stage held
         in their publisher's order, frames handed to subscribers'
         sockets, calls that reached a socket, the ingest runs and the
-        publishes they took in, the widest completion slice so far (a
-        high-water mark, not a sum), the matcher's wide entries and the
-        topics they answered, and what the trie holds
-        (``TopicsIndex``'s three counts)."""
+        publishes they took in, the ack runs and their PUBACK frames,
+        the widest completion slice so far (a high-water mark, not a
+        sum), the matcher's wide entries and the topics they answered,
+        and what the trie holds (``TopicsIndex``'s three counts)."""
         stage = self._stage
         trie = self.topics
         stats = None if self.matcher is None else self.matcher.stats
@@ -1515,6 +1533,8 @@ class Server:
             "socket_sends": self._ops.socket_sends,
             "ingest_runs": self._ops.ingest_runs,
             "ingest_run_publishes": self._ops.ingest_run_publishes,
+            "ack_runs": self._ops.ack_runs,
+            "ack_run_acks": self._ops.ack_run_acks,
             "slice_targets_max": self._ops.slice_targets_max,
             "wide_entries": getattr(stats, "wide_entries", 0),
             "wide_topics": getattr(stats, "wide_topics", 0),
@@ -1635,6 +1655,18 @@ class Server:
                 "ingest_run_publishes",
                 "Publishes taken in by ingest runs (the rest took the "
                 "per-frame path: mqtt_tpu_messages_received_total has both)",
+            ),
+            (
+                "mqtt_tpu_ack_runs_total",
+                "ack_runs",
+                "Stretches of bare PUBACK frames a read loop handed to the "
+                "ack run in one call that took them",
+            ),
+            (
+                "mqtt_tpu_ack_run_acks_total",
+                "ack_run_acks",
+                "PUBACK frames taken in by ack runs (the rest took the "
+                "per-frame path)",
             ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
@@ -3250,6 +3282,67 @@ class Server:
                 ops = self._ops
                 ops.ingest_runs += 1
                 ops.ingest_run_publishes += taken
+        return taken
+
+    def ack_run(
+        self, cl: Client, rbuf: bytearray, frames: list, i: int, start: int
+    ) -> int:
+        """Take in a stretch of one scan's PUBACK frames in one call:
+        from ``frames[i]`` on, every frame in turn that is a PUBACK of a
+        packet id and nothing else (``clients.ACK_FIRST_BYTE``,
+        ``ACK_REMAINING``: v3.1.1's form, v5's without reason or
+        properties), each id read straight from ``rbuf``. ``start`` is
+        where ``frames[i]`` begins. For the stretch as a whole it does
+        what ``process_puback`` and ``process_packet``'s epilogue do a
+        frame: every id that is in flight leaves the map
+        [MQTT-4.3.2-5], an unknown one is passed over, the send quota
+        rises by as many within its maximum and ``info.inflight`` falls
+        by them (``Inflight.acknowledge``: one lock pair a run), and
+        ``info.bytes_received`` / ``packets_received`` advance as the
+        per-frame path advances them. Returns how many frames the
+        stretch holds: positive when it took them, all of them;
+        negative when the gate is shut and it took none, so that the
+        caller sends that many down the per-frame path.
+
+        The gate, on what the code can see and nothing else: a network
+        client that is open; no hook that is shown a PUBACK as read, as
+        processed or as its delivery's completion (``_ACK_RUN_EVENTS``,
+        cached per hooks generation: a storage hook persists
+        completions and keeps the per-frame path); no entry of the
+        session waiting for send quota, which the quota drain would
+        resend between two acks (``Inflight.acknowledge``). With it
+        open the drain after each frame finds nothing to do, no frame
+        can stop the client, and a PUBACK draws no telemetry clock and
+        counts for no tenant: nothing else sees a frame. The frame that
+        ends the stretch is not touched."""
+        n = len(frames)
+        ids = []
+        k = i
+        while k < n:
+            f = frames[k]
+            if f.first_byte != ACK_FIRST_BYTE or f.remaining != ACK_REMAINING:
+                break
+            off = f.body_offset
+            ids.append((rbuf[off] << 8) | rbuf[off + 1])
+            k += 1
+        taken = k - i
+        if (
+            cl.net.inline
+            or cl.closed
+            or not self._no_hook_provides("ack_run", _ACK_RUN_EVENTS)
+        ):
+            return -taken
+        removed = cl.state.inflight.acknowledge(ids)
+        if removed < 0:
+            return -taken
+        last = frames[k - 1]
+        info = self.info
+        info.inflight -= removed
+        info.bytes_received += last.body_offset + last.remaining - start
+        info.packets_received += taken
+        ops = self._ops
+        ops.ack_runs += 1
+        ops.ack_run_acks += taken
         return taken
 
     def _park_run(self, cl: Client, items: list, counted: bool) -> None:
@@ -5402,6 +5495,10 @@ class Server:
             SYS_PREFIX + "/broker/ingest/runs": str(self._ops.ingest_runs),
             SYS_PREFIX + "/broker/ingest/run_publishes": str(
                 self._ops.ingest_run_publishes
+            ),
+            SYS_PREFIX + "/broker/ingest/ack_runs": str(self._ops.ack_runs),
+            SYS_PREFIX + "/broker/ingest/ack_run_acks": str(
+                self._ops.ack_run_acks
             ),
         }
         if self.matcher is not None:

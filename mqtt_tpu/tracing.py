@@ -698,8 +698,8 @@ class DeviceProfiler:
     ``armed``. Off -> on: snapshot A, keep every record from now on
     (and those in flight), start the loop heartbeat. On -> off:
     snapshot B, freeze a :class:`TraceSlice` (``last_slice()``). The
-    per-publish loop counters (``note_ingest`` / ``note_fanout``) count
-    only while armed."""
+    per-publish loop counters (``note_ingest`` / ``note_acks`` /
+    ``note_fanout``) count only while armed."""
 
     def __init__(self, registry: Any = None) -> None:
         self._lock = threading.Lock()
@@ -736,6 +736,10 @@ class DeviceProfiler:
         # (fanout_wait), fan-out start -> flush done (fanout_busy)
         self.ingest_busy_ns = 0
         self.ingest_n = 0
+        # the same stretch of a scan that held no publish, over its
+        # PUBACK frames
+        self.ack_busy_ns = 0
+        self.ack_n = 0
         self.fanout_wait_ns = 0
         self.fanout_busy_ns = 0
         self.fanout_n = 0
@@ -837,6 +841,8 @@ class DeviceProfiler:
             "gc2_recent": list(GC2.recent),
             "ingest_busy_ns": self.ingest_busy_ns,
             "ingest_n": self.ingest_n,
+            "ack_busy_ns": self.ack_busy_ns,
+            "ack_n": self.ack_n,
             "fanout_wait_ns": self.fanout_wait_ns,
             "fanout_busy_ns": self.fanout_busy_ns,
             "fanout_n": self.fanout_n,
@@ -896,6 +902,15 @@ class DeviceProfiler:
         the stage (``clients.read``, once a scan)."""
         self.ingest_busy_ns += busy_ns
         self.ingest_n += n
+
+    def note_acks(self, busy_ns: int, n: int) -> None:
+        """Loop time of the frame loop of one socket read that held no
+        publish (armed only), over its ``n`` PUBACK frames, whether an
+        ack run took them or they went a frame at a time: what a
+        subscriber's acknowledgements cost the loop (``clients.read``,
+        once a scan)."""
+        self.ack_busy_ns += busy_ns
+        self.ack_n += n
 
     def note_fanout(self, set_ns: int, start_ns: int, done_ns: int) -> None:
         """One publish's fan-out in its batch's completion (a kept batch

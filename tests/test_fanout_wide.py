@@ -458,6 +458,7 @@ def serve_fanout(seed, publishes=12):
             "received": received, "expected": expected, "due": due,
             "delta": {k: c1[k] - c0[k] for k in c0}, "after": c1,
             "pubacks": pubacks, "acked": acked[0], "inflight_left": inflight_left,
+            "info_inflight": srv.info.inflight,
             "metrics": metrics, "sys_topics": sys_topics, "sent": len(sent),
         }
 

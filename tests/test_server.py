@@ -563,15 +563,17 @@ class TestSysTopics:
                 and not t.startswith("$SYS/broker/devices/")
             }
             # the 20 of the reference's tree, the trie's three counts
-            # and the ingest run's two
+            # and the two each of the ingest run and the ack run
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
                 "$SYS/broker/topics/held",
                 "$SYS/broker/ingest/runs",
                 "$SYS/broker/ingest/run_publishes",
+                "$SYS/broker/ingest/ack_runs",
+                "$SYS/broker/ingest/ack_run_acks",
             } <= base
-            assert len(base) == 25
+            assert len(base) == 27
             await h.shutdown()
 
         run(scenario())

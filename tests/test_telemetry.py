@@ -714,6 +714,9 @@ class TestIngestRunCounts:
             assert got == {
                 "$SYS/broker/ingest/run_publishes": b"8",
                 "$SYS/broker/ingest/runs": str(counts["ingest_runs"]).encode(),
+                # no PUBACK came in (tests/test_ack_run.py counts them)
+                "$SYS/broker/ingest/ack_runs": b"0",
+                "$SYS/broker/ingest/ack_run_acks": b"0",
             }
             await srv.close()
             await h.shutdown()
