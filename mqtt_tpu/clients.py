@@ -479,8 +479,7 @@ class Client:
         ``CORK_MAX_BYTES`` it is written out early and stays open."""
         cork = self._cork
         if cork is None:
-            self.net.writer.write(data)
-            self.ops.socket_sends += 1
+            self._send(data)
             return
         cork += data
         if len(cork) >= CORK_MAX_BYTES:
@@ -493,8 +492,22 @@ class Client:
         completion slice) and for the teardown."""
         cork, self._cork = self._cork, None
         if cork and self.net.writer is not None:
-            self.net.writer.write(bytes(cork))
-            self.ops.socket_sends += 1
+            self._send(bytes(cork))
+
+    def _send(self, data: bytes) -> None:
+        """One transport write: a call that reaches the socket (the
+        transport sends at once while its buffer is empty), counted, and
+        timed while a profiler session is live (``send_busy_ns``: what a
+        send costs the loop in this run, on this host)."""
+        ops = self.ops
+        prof = getattr(ops, "profiler", None)
+        if prof is not None and prof.armed:
+            t0 = time.perf_counter_ns()
+            self.net.writer.write(data)
+            prof.send_busy_ns += time.perf_counter_ns() - t0
+        else:
+            self.net.writer.write(data)
+        ops.socket_sends += 1
 
     def parse_connect(self, lid: str, pk: Packet) -> None:
         """Absorb CONNECT parameters into client state (clients.go:208-257)."""
@@ -659,7 +672,8 @@ class Client:
         # their handlers returned (decode, admission, acks, the publish
         # parked with the stage) is counted as ingest, once a scan, over
         # the publishes in it; a scan without a publish books it over
-        # its PUBACK frames
+        # its PUBACK frames. Either stretch is also an annotation on the
+        # profiler's own clock (mqtt/loop.ingest, mqtt/loop.acks)
         prof = getattr(self.ops, "profiler", None)
         # the shard's own gate wins (per-shard decode batching is
         # default-on inside the fabric); the server-wide gate serves the
@@ -684,9 +698,19 @@ class Client:
                 )
             # account for and process every complete packet
             armed = prof is not None and prof.armed
+            span = None
             if armed:
-                t_in = time.perf_counter_ns()
+                # the annotation lies around the counted stretch, its
+                # own cost outside it
+                kinds = [f.first_byte >> 4 for f in frames]
+                name, n_kind = "mqtt/loop.ingest", kinds.count(pkts.PUBLISH)
+                if not n_kind:
+                    name, n_kind = "mqtt/loop.acks", kinds.count(pkts.PUBACK)
+                if n_kind:
+                    span = prof.annotation(name, n=n_kind)
+                    span.__enter__()
                 n_in = self._pub_count
+                t_in = time.perf_counter_ns()
             start = 0
             self._cork = bytearray()  # this read's acks leave as one write
             n = len(frames)
@@ -779,8 +803,12 @@ class Client:
                         break
             finally:
                 self._uncork()
+                if armed:
+                    t_out = time.perf_counter_ns()
+                    if span is not None:
+                        span.__exit__(None, None, None)
             if armed:
-                busy_ns = time.perf_counter_ns() - t_in
+                busy_ns = t_out - t_in
                 if self._pub_count != n_in:
                     prof.note_ingest(busy_ns, self._pub_count - n_in)
                 else:
@@ -869,12 +897,18 @@ class Client:
         else:
             coro = self.net.reader.read(65536)
         if self._deadline is None:
-            return await coro
-        timeout = self._deadline - time.monotonic()
-        if timeout <= 0:
-            coro.close()
-            raise asyncio.TimeoutError()
-        return await asyncio.wait_for(coro, timeout)
+            data = await coro
+        else:
+            timeout = self._deadline - time.monotonic()
+            if timeout <= 0:
+                coro.close()
+                raise asyncio.TimeoutError()
+            data = await asyncio.wait_for(coro, timeout)
+        if data:
+            # one wake-up of this read loop on one recv (the stream may
+            # have joined two)
+            self.ops.socket_reads += 1
+        return data
 
     def stop(self, err: Optional[Exception] = None) -> None:
         """Idempotently end the client: close the transport, cancel the

@@ -830,6 +830,9 @@ class _Ops:
         # slice's deliveries) and each send of the native fan-out flush.
         # A plain add on the writing loop.
         self.socket_sends = 0
+        # wake-ups of a read loop on data (Client._read_more: one recv
+        # each, or two the stream joined): a plain add on the reading loop
+        self.socket_reads = 0
         # the server's entry point for a scan's run of PUBLISH frames
         # (Server.ingest_run; None until the server wires it), the runs
         # that took at least one publish and the publishes they took in.
@@ -1519,11 +1522,14 @@ class Server:
         """The broker's running counts a profiler slice's snapshots take
         (``tracing.DeviceProfiler.counters``): fallbacks the stage held
         in their publisher's order, frames handed to subscribers'
-        sockets, calls that reached a socket, the ingest runs and the
+        sockets, calls that reached a socket, the read loops' wake-ups
+        on data, the ingest runs and the
         publishes they took in, the ack runs and their PUBACK frames,
         the widest completion slice so far (a high-water mark, not a
         sum), the matcher's wide entries and the topics they answered,
-        and what the trie holds (``TopicsIndex``'s three counts)."""
+        what the trie holds (``TopicsIndex``'s three counts), and what
+        set-up's load cost, as values at the snapshot: the bulk loads'
+        open-to-close wall and the build that ended the newest one."""
         stage = self._stage
         trie = self.topics
         stats = None if self.matcher is None else self.matcher.stats
@@ -1531,6 +1537,7 @@ class Server:
             "order_held": 0 if stage is None else stage.order_held,
             "deliveries": self.telemetry.fanout_deliveries.value,
             "socket_sends": self._ops.socket_sends,
+            "socket_reads": self._ops.socket_reads,
             "ingest_runs": self._ops.ingest_runs,
             "ingest_run_publishes": self._ops.ingest_run_publishes,
             "ack_runs": self._ops.ack_runs,
@@ -1541,6 +1548,8 @@ class Server:
             "particles": trie.particles,
             "particle_maps": trie.particle_maps,
             "held": trie.held,
+            "bulk_load_seconds": trie.bulk_load_seconds,
+            "bulk_build_seconds": getattr(self.matcher, "bulk_build_seconds", 0.0),
         }
 
     def _register_core_gauges(self) -> None:
@@ -1667,6 +1676,19 @@ class Server:
                 "ack_run_acks",
                 "PUBACK frames taken in by ack runs (the rest took the "
                 "per-frame path)",
+            ),
+            (
+                "mqtt_tpu_socket_sends_total",
+                "socket_sends",
+                "Calls that reached a client's socket: transport writes "
+                "(one a packet, or one a cork) and the native fan-out "
+                "flush's sends",
+            ),
+            (
+                "mqtt_tpu_socket_reads_total",
+                "socket_reads",
+                "Wake-ups of a connection's read loop on data: one recv "
+                "each (the stream may join two)",
             ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
@@ -3480,6 +3502,8 @@ class Server:
         finally:
             if corked:
                 if prof is not None:
+                    span = prof.annotation("mqtt/loop.flush", sends=len(corked))
+                    span.__enter__()
                     t_run = time.perf_counter_ns()
                 for cl in corked:
                     try:
@@ -3490,6 +3514,7 @@ class Server:
                         )
                 if prof is not None:
                     prof.note_slice_flush(time.perf_counter_ns() - t_run)
+                    span.__exit__(None, None, None)
 
     def _cork_repeated(self, ids: list, present: dict) -> list:
         """Open the cork of every socket a completion slice targets more
@@ -4294,12 +4319,18 @@ class Server:
             self._note_tenant_out(cl, dpk)
         if not flush:
             return
+        prof = self.profiler
+        armed = prof is not None and prof.armed
+        if armed:
+            t_send = time.perf_counter_ns()
         sent = fan_flush(
             [fd for _, fd, _ in flush],
             data,
             id_off,
             [pid for _, _, pid in flush] if id_off >= 0 else None,
         )
+        if armed:  # the sends of all of the variant's sockets
+            prof.send_busy_ns += time.perf_counter_ns() - t_send
         if self.telemetry is not None:
             self.telemetry.fanout_writev_batches.inc()
         if sent is None:

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import re
 import threading
+import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -529,6 +530,10 @@ class TopicsIndex:
         # mutation it is handed.
         self.bulk_depth = 0
         self._bulk_end_observers: list[Callable[[], None]] = []
+        # the outermost loads' open-to-close wall, summed (one clock read
+        # each end): set-up's load half, as a profiler slice reads it
+        self.bulk_load_seconds = 0.0
+        self._bulk_t0 = 0.0
         # what the trie costs, as three plain counts that move under the
         # trie lock: live nodes (the root is one), containers alive
         # across them (children dicts and the three kinds of map), and
@@ -577,12 +582,15 @@ class TopicsIndex:
         while a load is open and builds once when it ends."""
         with self._lock:
             self.bulk_depth += 1
+            if self.bulk_depth == 1:
+                self._bulk_t0 = time.perf_counter()
         try:
             yield
         finally:
             with self._lock:
                 self.bulk_depth -= 1
                 if self.bulk_depth == 0:
+                    self.bulk_load_seconds += time.perf_counter() - self._bulk_t0
                     for fn in self._bulk_end_observers:
                         # brokerlint: ok=R5 intentional in-lock delivery, as _notify: the close must be atomic with the mutation stream (per-entry recording resumes with the first mutation after it); observers are contract-bound to O(1) work (a counter and an Event.set)
                         fn()

@@ -36,7 +36,10 @@ comparison axis (PAPERS.md). This module adds:
   batch records, enters the busy spans as ``TraceAnnotation`` blocks (so
   they lie on the device trace's own clock in the ``.xplane.pb``), and
   brackets the session with two snapshots (per-thread CPU, matcher
-  topics, gen-2 collections, loop counters, loop heartbeat). When the
+  topics, collections by generation, loop counters, loop heartbeat).
+  Between them the loop's own time is booked whole (``_LoopFrame``: a
+  timing proxy around the loop's selector): every iteration, every
+  ``select()``, the longest iteration with what was inside it. When the
   session ends the slice freezes; ``last_slice()`` returns the newest.
 - ``check_trace_events``: a ~20-line pure-Python validator for the
   exported JSON (the /traces analog of ``telemetry.check_exposition``),
@@ -470,22 +473,40 @@ def span(rec: Optional[BatchProfile], slot: str):
         setattr(rec, slot, (t0, time.perf_counter_ns()))
 
 
-# -- gen-2 collections ---------------------------------------------------------
+# -- collections ----------------------------------------------------------------
+
+YOUNG_PAUSE_NS = 1_000_000  # a young collection worth keeping: over 1 ms
 
 
 class Gen2Pauses:
-    """The interpreter's full (generation-2) collections and how long
-    each held the process: a ``gc.callbacks`` hook that returns at once
-    for the young generations. Over a heap of a million subscriptions a
-    full collection is the first suspect for a whole-broker stall; this
-    is the counter that can convict it (``/metrics``:
+    """The interpreter's collections and how long each held the process:
+    the one ``gc.callbacks`` hook, for all three generations. Over a heap
+    of a million subscriptions a full collection is the first suspect for
+    a whole-broker stall, and a young one for a stall of the loop; these
+    are the counters that can convict or clear them. Full collections
+    keep what they had (``recent``, ``hist``: ``/metrics``
     ``mqtt_tpu_gc_gen2_pause_seconds``; ``/traces``: the pauses inside
-    the newest profiler slice)."""
+    the newest profiler slice). Every generation adds to
+    ``gc_pause_ns``; a young collection of over 1 ms is kept in
+    ``young_recent``. While a profiler session is live (``annotate``,
+    set by the armed ``DeviceProfiler``) each collection is a
+    ``mqtt/gc`` annotation too."""
 
     def __init__(self) -> None:
-        # (end_ns, duration_ns) of the newest pauses, perf_counter_ns
+        # (end_ns, duration_ns) of the newest full pauses, perf_counter_ns
         self.recent: collections.deque = collections.deque(maxlen=64)
         self.hist = Histogram()
+        # by generation: cumulative pause, and when the newest one
+        # ended; and the pauses' sum over all three
+        self.gc_pause_ns = [0, 0, 0]
+        self.last_end_ns = [0, 0, 0]
+        self.pause_ns_total = 0
+        # (end_ns, duration_ns, generation) of the newest collections
+        # of generations 0 and 1 that took over YOUNG_PAUSE_NS
+        self.young_recent: collections.deque = collections.deque(maxlen=64)
+        # jax.profiler.TraceAnnotation while a session is live, else None
+        self.annotate: Any = None
+        self._span: Any = None
         self._t0 = 0
         self._installed = False
 
@@ -495,15 +516,27 @@ class Gen2Pauses:
             gc.callbacks.append(self._on_gc)
 
     def _on_gc(self, phase: str, info: dict) -> None:
-        if info["generation"] != 2:
+        gen = info["generation"]
+        if phase == "start":
+            annotate = self.annotate
+            if annotate is not None:
+                self._span = annotate("mqtt/gc", gen=gen)
+                self._span.__enter__()
+            self._t0 = time.perf_counter_ns()
             return
         now = time.perf_counter_ns()
-        if phase == "start":
-            self._t0 = now
-            return
         dt = now - self._t0
-        self.recent.append((now, dt))
-        self.hist.observe(dt / 1e9)
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+        self.gc_pause_ns[gen] += dt
+        self.last_end_ns[gen] = now
+        self.pause_ns_total += dt
+        if gen == 2:
+            self.recent.append((now, dt))
+            self.hist.observe(dt / 1e9)
+        elif dt > YOUNG_PAUSE_NS:
+            self.young_recent.append((now, dt, gen))
 
 
 # process-wide, as the collector is (ops/devicestats.LEDGER's posture);
@@ -515,6 +548,7 @@ GC2 = Gen2Pauses()
 
 MAX_SLICE_BATCHES = 16_384
 BEAT_NS = 5_000_000  # the loop heartbeat's interval while armed
+CLOCK_NS = 1_000_000_000  # a mqtt/clock mark at the loop's first turn this long after the last
 TRACES_BATCHES = 256  # a slice's newest batches, as /traces serves them
 
 
@@ -590,6 +624,15 @@ class TraceSlice:
             if self.a["t_ns"] < p[0] <= self.b["t_ns"]
         ]
 
+    def young_pauses(self) -> list:
+        """``(end_ns, duration_ns, generation)`` of the collections of
+        generations 0 and 1 that took over ``YOUNG_PAUSE_NS`` and ended
+        between ``a`` and ``b``."""
+        return [
+            p for p in self.b.get("young_recent", ())
+            if self.a["t_ns"] < p[0] <= self.b["t_ns"]
+        ]
+
 
 _LAST_SLICE: Optional[TraceSlice] = None
 
@@ -603,7 +646,9 @@ def slice_events(sl: TraceSlice, anchor: float, pid: int) -> list:
     """The newest slice as Chrome trace events for ``/traces``: the span
     tree of its newest ``TRACES_BATCHES`` batches, one track a batch
     (``args.batch`` is the number a sampled publish's root span names),
-    and the full collections that ended inside it."""
+    the collections that ended inside it (every full one, the young
+    ones of over a millisecond), and the loop's longest iteration with
+    its parts (``stall``: ``_LoopFrame``)."""
     events = []
 
     def add(name, cat, t0_ns, t1_ns, tid, args):
@@ -619,7 +664,110 @@ def slice_events(sl: TraceSlice, anchor: float, pid: int) -> list:
             add(name, "batch", t0, t1, 1_000_000 + rec.seq % 1_000_000, args)
     for end, dur in sl.gen2_pauses():
         add("gc/gen2", "gc", end - dur, end, 999_999, {})
+    for end, dur, gen in sl.young_pauses():
+        add(f"gc/gen{gen}", "gc", end - dur, end, 999_999, {})
+    stall = sl.b.get("stall")
+    if stall is not None:
+        t0 = stall["t0_ns"]
+        add("loop/stall", "loop", t0, t0 + stall["busy_ns"], 999_998,
+            {k: v for k, v in stall.items() if k != "t0_ns"})
     return events
+
+
+class _LoopFrame:
+    """The frame around an event loop's iterations: a proxy of the
+    loop's selector that forwards everything and times ``select()``. It
+    stands in ``loop._selector`` only while its profiler is armed
+    (``DeviceProfiler._frame_in`` / ``_frame_out``, on the loop's own
+    thread). Every ``select()`` closes one iteration (from the previous
+    ``select()``'s return to this call: the loop's working time) and is
+    itself booked as a poll that could block (``pollw``: the loop's idle
+    time, plus the call) or one that could not (``poll0``: the ready
+    queue was not empty, so the pure cost of the system call). The three
+    add up to the time the frame stood. The longest iteration is kept
+    with what was inside it: the deltas across it of the phase counters
+    (``_phases``), against their values at its start: it is the loop's
+    longest hold (``loop_stall_max_ns``), so no heartbeat runs through a
+    loop that is framed, and the turns of the loop are its beats. Two
+    clock reads and a dozen plain adds a ``select()``, on the profiler's
+    counters; once a second a ``mqtt/clock`` mark."""
+
+    def __init__(
+        self, selector: Any, prof: "DeviceProfiler", began_ns: int, phases: tuple
+    ) -> None:
+        self._sel = selector
+        self._select = selector.select
+        self._prof = prof
+        self._idle = prof.annotation
+        # the calls the loop makes on its selector besides select()
+        for name in ("register", "unregister", "modify", "get_key", "get_map", "close"):
+            setattr(self, name, getattr(selector, name))
+        # > 0: in an iteration since then; < 0: inside select() since
+        # then (one field, so a snapshot off the loop reads it whole).
+        # The first iteration is booked from snapshot A: the frame is
+        # stood by a callback that the arming queued, so but for one
+        # select() that could not block the loop worked all the while
+        self.mark = began_ns
+        self._saved = phases
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self._sel, name)
+
+    def _phases(self) -> tuple:
+        prof = self._prof
+        return (
+            prof.ingest_busy_ns, prof.ack_busy_ns, prof.fanout_busy_ns,
+            prof.slice_flush_ns, prof.send_busy_ns, GC2.pause_ns_total,
+        )
+
+    def select(self, timeout: Optional[float] = None) -> list:
+        prof = self._prof
+        t0 = time.perf_counter_ns()
+        began = self.mark
+        if began > 0:
+            busy = t0 - began
+            prof.iter_busy_ns += busy
+            if busy > prof.stall_busy_ns:
+                self._note_stall(began, busy)
+        self.mark = -t0
+        if timeout is None or timeout > 0:
+            with self._idle("mqtt/loop.idle"):
+                events = self._select(timeout)
+            t1 = time.perf_counter_ns()
+            prof.pollw_n += 1
+            prof.pollw_ns += t1 - t0
+        else:
+            events = self._select(timeout)
+            t1 = time.perf_counter_ns()
+            prof.poll0_n += 1
+            prof.poll0_ns += t1 - t0
+        prof.poll_ready_n += len(events)
+        self._saved = self._phases()
+        self.mark = t1
+        if t1 - prof._clock_ns >= CLOCK_NS:
+            prof._clock_mark()
+        return events
+
+    def _note_stall(self, began: int, busy: int) -> None:
+        """The iteration that just closed is the slice's longest."""
+        prof = self._prof
+        prof.stall_busy_ns = busy
+        ingest, ack, fanout, flush, send, gc_ns = (
+            now - was for now, was in zip(self._phases(), self._saved)
+        )
+        prof.stall = {
+            "t0_ns": began, "busy_ns": busy,
+            "ingest_ns": ingest, "ack_ns": ack,
+            # the slice's joined writes are inside fan-out, the sends
+            # and the collections inside whichever phase made them
+            "fanout_ns": fanout, "flush_ns": flush,
+            "send_ns": send, "gc_ns": gc_ns,
+            # the oldest generation collected inside it, -1 where none
+            "gc_gen": max(
+                (g for g, end in enumerate(GC2.last_end_ns) if end > began),
+                default=-1,
+            ),
+        }
 
 
 # D2H transfer sizes: single compact rows (~tens of bytes) up to the
@@ -699,7 +847,17 @@ class DeviceProfiler:
     (and those in flight), start the loop heartbeat. On -> off:
     snapshot B, freeze a :class:`TraceSlice` (``last_slice()``). The
     per-publish loop counters (``note_ingest`` / ``note_acks`` /
-    ``note_fanout``) count only while armed."""
+    ``note_fanout``) count only while armed, and only then does a
+    :class:`_LoopFrame` stand around the selector of ``loop``: the
+    loop's ledger (``poll0_*``, ``pollw_*``, ``poll_ready_n``,
+    ``iter_busy_ns``, ``stall``), whose longest iteration is
+    ``loop_stall_max_ns`` and whose turns are ``loop_beats``. A loop
+    without a ``_selector`` (uvloop, proactor) leaves the ledger out of
+    the snapshots and gets the 5 ms heartbeat for those two instead.
+    ``mqtt/clock`` annotations (at arming, then one a second, from the
+    frame or the heartbeat) carry ``perf_counter_ns`` into the
+    profiler's own file: the offset between its clock and every
+    boundary stamped here."""
 
     def __init__(self, registry: Any = None) -> None:
         self._lock = threading.Lock()
@@ -723,6 +881,7 @@ class DeviceProfiler:
         self._kept: list = []
         self._snap_a: Optional[dict] = None
         self._is_enabled: Any = None  # TraceAnnotation.is_enabled, on first poll
+        self._annotation: Any = None  # TraceAnnotation, on first use
         # set by whoever owns them: the served matcher's MatcherStats
         # (server), the loop the stage runs on (MatchStage.start), and
         # the broker's own running counts for the snapshots (server:
@@ -743,10 +902,32 @@ class DeviceProfiler:
         self.fanout_wait_ns = 0
         self.fanout_busy_ns = 0
         self.fanout_n = 0
-        # the heartbeat's longest missed interval since arming
+        # of fanout_busy_ns, the joined writes at the slices' ends
+        self.slice_flush_ns = 0
+        # around the calls that reach a socket and count
+        # ``_Ops.socket_sends`` (clients._send, the native flush):
+        # inside ingest, fan-out or neither
+        self.send_busy_ns = 0
+        # the loop's ledger (_LoopFrame adds to it, on the loop's own
+        # thread): select() calls that could not block and that could,
+        # events they returned, the summed iterations, and the longest
+        # iteration since arming with its parts
+        self.poll0_n = 0
+        self.poll0_ns = 0
+        self.pollw_n = 0
+        self.pollw_ns = 0
+        self.poll_ready_n = 0
+        self.iter_busy_ns = 0
+        self.stall: Optional[dict] = None
+        self.stall_busy_ns = 0
+        self._frame: Optional[_LoopFrame] = None  # around loop's selector, while armed
+        self._framed = False  # this slice has a ledger (loop has a selector)
+        # the heartbeat's longest missed interval since arming, where
+        # no frame stands (a framed loop's is its longest iteration)
         self.loop_stall_max_ns = 0
         self._beat_ns = 0
         self._beats = 0
+        self._clock_ns = 0  # the newest mqtt/clock mark
         GC2.install()
         if registry is not None:
             self.issue_hist = registry.histogram(
@@ -829,16 +1010,17 @@ class DeviceProfiler:
     def _snapshot(self) -> dict:
         """One edge of a slice: the instant, CPU (process and per
         thread), topics the matcher took in, the in-flight union so far
-        (``duty_cycle``'s numerator), the newest full collections, the
-        armed-only loop counters, and the owner's ``counters()``."""
-        stats = self.matcher_stats
-        return {
+        (``duty_cycle``'s numerator), the collections (the newest full
+        ones, the pause by generation, the newest long young ones), the
+        armed-only loop counters, the loop's longest hold and its beats
+        (the ledger's where a frame stands, else the heartbeat's), the
+        loop's ledger, and the owner's ``counters()``."""
+        # what the loop moves, first and together: a snapshot is taken
+        # off the loop, which goes on while the slow parts below are
+        # read. The phases before the ledger, so that the iterations it
+        # holds are no shorter than the phases inside them
+        snap = {
             "t_ns": time.perf_counter_ns(),
-            "process_cpu_ns": time.process_time_ns(),
-            "thread_cpu_ns": thread_cpu_ns(),
-            "topics": stats.topics if stats is not None else 0,
-            "inflight_s": self._busy_s,
-            "gc2_recent": list(GC2.recent),
             "ingest_busy_ns": self.ingest_busy_ns,
             "ingest_n": self.ingest_n,
             "ack_busy_ns": self.ack_busy_ns,
@@ -846,36 +1028,136 @@ class DeviceProfiler:
             "fanout_wait_ns": self.fanout_wait_ns,
             "fanout_busy_ns": self.fanout_busy_ns,
             "fanout_n": self.fanout_n,
+            "slice_flush_ns": self.slice_flush_ns,
+            "send_busy_ns": self.send_busy_ns,
+            "gc_pause_ns": list(GC2.gc_pause_ns),
             "loop_beats": self._beats,
             "loop_stall_max_ns": self.loop_stall_max_ns,
+            **self._ledger(),
+        }
+        stats = self.matcher_stats
+        snap.update({
+            "process_cpu_ns": time.process_time_ns(),
+            "thread_cpu_ns": thread_cpu_ns(),
+            "topics": stats.topics if stats is not None else 0,
+            "inflight_s": self._busy_s,
+            "gc2_recent": list(GC2.recent),
+            "young_recent": list(GC2.young_recent),
             **(self.counters() if self.counters is not None else {}),
+        })
+        return snap
+
+    def _ledger(self) -> dict:
+        """The loop's ledger as a snapshot takes it (nothing where the
+        loop has no selector to frame). A snapshot is taken off the
+        loop: what the iteration or the ``select()`` in progress has run
+        so far is added from the frame's ``mark``, so the parts come to
+        the time the frame stood. The loop's longest hold and its beats
+        are the ledger's own: the longest iteration (the one in
+        progress counts) and the turns of the loop."""
+        if not self._framed:
+            return {}
+        busy, waited, held = self.iter_busy_ns, self.pollw_ns, self.stall_busy_ns
+        frame = self._frame
+        if frame is not None:
+            now = time.perf_counter_ns()
+            mark = frame.mark
+            if mark > 0:
+                busy += max(0, now - mark)
+                held = max(held, now - mark)
+            else:
+                waited += max(0, now + mark)
+        return {
+            "poll0_n": self.poll0_n, "poll0_ns": self.poll0_ns,
+            "pollw_n": self.pollw_n, "pollw_ns": waited,
+            "poll_ready_n": self.poll_ready_n,
+            "iter_busy_ns": busy,
+            "stall": self.stall,
+            "loop_beats": self.poll0_n + self.pollw_n,
+            "loop_stall_max_ns": held,
         }
 
+    def _trace_annotation(self) -> Any:
+        cls = self._annotation
+        if cls is None:
+            from jax.profiler import TraceAnnotation
+
+            cls = self._annotation = TraceAnnotation
+        return cls
+
+    def annotation(self, name: str, **args: Any) -> Any:
+        """A ``jax.profiler.TraceAnnotation`` block, for the armed
+        callers on the loop (``mqtt/loop.*``)."""
+        return self._trace_annotation()(name, **args)
+
+    def _clock_mark(self) -> None:
+        """``perf_counter_ns`` as the argument of an annotation: the
+        profiler's file then holds the offset between its own clock and
+        the clock of every boundary stamped here."""
+        now = self._clock_ns = time.perf_counter_ns()
+        with self.annotation("mqtt/clock", perf_ns=now):
+            pass
+
     def _arm(self) -> None:
+        # the class itself, and before snapshot A: the collector's hook
+        # must never import (a collection inside an import in progress
+        # would import again)
+        annotate = self._trace_annotation()
         inflight = [r for r in self._recent if r.deliver is None]
         for r in inflight:
             r.kept = True
         self._kept = inflight
         self.loop_stall_max_ns = 0
         self._beat_ns = 0
+        self.stall = None
+        self.stall_busy_ns = 0
+        loop = self.loop
+        self._framed = getattr(loop, "_selector", None) is not None
         self._snap_a = self._snapshot()
         self.armed = True
-        loop = self.loop
+        GC2.annotate = annotate
+        self._clock_mark()
         if loop is not None:
-            try:
-                loop.call_soon_threadsafe(self._beat)
-            except RuntimeError:
-                pass  # the loop is closed: no heartbeat, nothing to stall
+            self._on_loop(self._frame_in if self._framed else self._beat)
+
+    def _on_loop(self, fn: Any, *args: Any) -> None:
+        try:
+            self.loop.call_soon_threadsafe(fn, *args)
+        except RuntimeError:
+            pass  # the loop is closed: nothing left to time or to stall
+
+    def _frame_in(self) -> None:
+        """Stand a frame around the loop's selector (on its own thread)."""
+        loop = self.loop
+        selector = getattr(loop, "_selector", None)
+        a = self._snap_a
+        if (
+            self.armed and a is not None and selector is not None
+            and not isinstance(selector, _LoopFrame)
+        ):
+            self._frame = loop._selector = _LoopFrame(
+                selector, self, a["t_ns"],
+                (a["ingest_busy_ns"], a["ack_busy_ns"], a["fanout_busy_ns"],
+                 a["slice_flush_ns"], a["send_busy_ns"], sum(a["gc_pause_ns"])),
+            )
+
+    def _frame_out(self, frame: _LoopFrame) -> None:
+        if getattr(self.loop, "_selector", None) is frame:
+            self.loop._selector = frame._sel
 
     def _disarm(self) -> None:
         global _LAST_SLICE
         self.armed = False
+        GC2.annotate = None
         if self._beat_ns:  # a stall still in progress counts
             self._note_beat(time.perf_counter_ns())
         kept, self._kept = self._kept, []
         a, self._snap_a = self._snap_a, None
         if a is not None:
             _LAST_SLICE = TraceSlice(a, self._snapshot(), kept)
+        frame, self._frame = self._frame, None
+        if frame is not None:
+            self._on_loop(self._frame_out, frame)
 
     def _note_beat(self, now: int) -> None:
         late = now - self._beat_ns - BEAT_NS
@@ -893,6 +1175,8 @@ class DeviceProfiler:
             self._note_beat(now)
         self._beat_ns = now
         self._beats += 1
+        if now - self._clock_ns >= CLOCK_NS:
+            self._clock_mark()
         self.loop.call_later(BEAT_NS / 1e9, self._beat)
 
     def note_ingest(self, busy_ns: int, n: int) -> None:
@@ -926,8 +1210,10 @@ class DeviceProfiler:
     def note_slice_flush(self, busy_ns: int) -> None:
         """The joined writes at a completion slice's end (the sockets the
         slice corked, ``server._complete_staged``): fan-out time of the
-        slice's publishes, and no publish of its own."""
+        slice's publishes, and no publish of its own; ``slice_flush_ns``
+        is the "of which"."""
         self.fanout_busy_ns += busy_ns
+        self.slice_flush_ns += busy_ns
 
     def ensure_device(self, did: int) -> _DevWindow:
         """The window replica for one device id, creating it (and its

@@ -215,7 +215,10 @@ class TestSlice:
                 for ev in line.events:
                     if ev.name.startswith("mqtt/"):
                         stats = dict(ev.stats)
-                        seen.setdefault(ev.name, set()).add(int(stats["batch"]))
+                        # the loop's own annotations (mqtt/loop.*,
+                        # mqtt/clock, mqtt/gc) belong to no batch
+                        if "batch" in stats:
+                            seen.setdefault(ev.name, set()).add(int(stats["batch"]))
         kept = {r.seq for r in sl.batches}
         for name in BUSY_SPANS.values():
             assert seen.get(name), name
@@ -325,9 +328,19 @@ class TestSlice:
         doc = tracing.Tracer(seed=1).export()
         assert tracing.check_trace_events(doc) == len(doc["traceEvents"])
         by_batch: dict = {}
+        others = []
         for ev in doc["traceEvents"]:
-            assert ev["cat"] == "batch"
-            by_batch.setdefault(ev["args"]["batch"], []).append(ev)
+            if ev["cat"] == "batch":
+                by_batch.setdefault(ev["args"]["batch"], []).append(ev)
+            else:
+                others.append(ev)
+        # beside the batches: the loop's longest iteration with its
+        # parts, and the collections that ended inside the slice
+        assert [e["name"] for e in others if e["cat"] == "loop"] == ["loop/stall"]
+        assert {e["cat"] for e in others} <= {"loop", "gc"}
+        stall = next(e for e in others if e["cat"] == "loop")
+        assert stall["dur"] == pytest.approx(sl.b["stall"]["busy_ns"] / 1e3, abs=0.01)
+        assert stall["args"]["busy_ns"] >= stall["args"]["ingest_ns"] >= 0
         assert set(by_batch) == {r.seq for r in sl.batches}
         for rec in sl.batches:
             events = by_batch[rec.seq]
@@ -349,9 +362,9 @@ class TestSlice:
         one_session(tmp_path)
         sl = tracing.last_slice()
         doc = tracing.Tracer(seed=1).export()
-        assert {e["args"]["batch"] for e in doc["traceEvents"]} == {
-            r.seq for r in sl.batches[-2:]
-        }
+        assert {
+            e["args"]["batch"] for e in doc["traceEvents"] if e["cat"] == "batch"
+        } == {r.seq for r in sl.batches[-2:]}
 
 
 class TestUndispatchedPaths:
