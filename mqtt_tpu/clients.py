@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import logging
 import threading
 import time
@@ -233,6 +234,293 @@ class ScanGate:
                 fut.set_result(res)
 
 
+# the most one read of the stream feeder brings (asyncio's stream
+# reader's own limit), and what a gated or throttled connection may
+# buffer before its transport stops reading: the stream reader pauses at
+# twice its limit
+READ_SIZE = 65536
+READ_HIGH_WATER = 2 * READ_SIZE
+# where ``_DirectFeed._pump`` takes up its turn
+_SCAN, _SETTLE, _READ = range(3)
+
+
+class _DirectFeed(asyncio.BufferedProtocol):
+    """The direct feeder of ``Client.read``: the broker's own protocol on
+    a connection's transport, once the CONNECT handshake is through.
+
+    The transport receives into one buffer a thread (``get_buffer``: no
+    allocation a read, where a plain protocol's transport allocates
+    256 KiB for every ``recv`` and the allocator maps, shrinks and unmaps
+    it: three system calls beside the one that reads).
+    ``buffer_updated`` appends what came to the connection's buffer and
+    runs the scan, the frame loop and what follows them
+    (``Client._take_frames``, ``_settle_scan``: the stream feeder's own)
+    inside the transport's callback. The three waits of the read side
+    are a handle each at most, and none is made for a read that needs
+    none:
+
+    - **the gate**: while ``cl._staged`` is non-zero no frame of the
+      connection is handled; bytes that come meanwhile are buffered
+      (past ``READ_HIGH_WATER`` the transport stops reading). The
+      completion that takes ``_staged`` to zero calls
+      ``cl._staged_waiter``, which schedules the rest of the turn with
+      ``call_soon``: a turn of the loop of its own, never inside a
+      completion slice.
+    - **the keepalive**: one ``call_later`` handle a connection, armed
+      for ``cl._deadline`` and re-armed when it fires and the deadline
+      has moved ([MQTT-3.1.2-24]).
+    - **the THROTTLE lever**: a positive ``read_delay`` stops the
+      transport reading until a ``call_later`` resumes it.
+
+    ``run`` resolves on a clean end and fails with the exception the
+    stream feeder would have raised; after that no byte of the
+    connection is scanned. ``pause_writing``, ``resume_writing``,
+    ``eof_received`` and ``connection_lost`` are passed on to the
+    ``StreamReaderProtocol`` the transport had, which the connection's
+    ``StreamWriter`` still hangs on (``drain``, ``wait_closed``)."""
+
+    # the receive buffer of the thread's connections (an event loop runs
+    # in one thread): the transport fills it and ``buffer_updated`` has
+    # copied out of it before it returns
+    _received = threading.local()
+
+    def __init__(self, cl: "Client", transport: asyncio.Transport, packet_handler) -> None:
+        from .native import MAX_FRAMES_PER_SCAN, frame_scan, varint_decode
+
+        view = getattr(self._received, "view", None)
+        if view is None:
+            view = self._received.view = memoryview(bytearray(READ_SIZE))
+        self._view = view
+        self._cl = cl
+        self._transport = transport
+        self._stream = transport.get_protocol()
+        self._handler = packet_handler
+        self._frame_scan = frame_scan
+        self._varint_decode = varint_decode
+        self._max_frames = MAX_FRAMES_PER_SCAN
+        self._caps = cl.ops.options.capabilities
+        self._loop = asyncio.get_running_loop()
+        self._done: asyncio.Future = self._loop.create_future()
+        self._rbuf = bytearray()
+        # the scan whose frames are handled and whose publishes are still
+        # in the stage: (frames, full, consumed, err), for _settle_scan
+        self._held: tuple = ()
+        # stopped at the gate or by the THROTTLE lever: bytes are only
+        # buffered, and ``_fresh`` says some were
+        self._blocked = False
+        self._fresh = False
+        self._paused = False  # this feeder stopped the transport reading
+        # bytes still to come of the partial packet at the buffer's head
+        # (0 = unknown): they are not worth a scan
+        self._need = 0
+        # why no more bytes will come: the peer's EOF, the transport lost
+        self._end: Optional[BaseException] = None
+        self._on_gate = self._gate_opened  # one bound method a connection
+        self._turn: Optional[asyncio.Handle] = None  # the gate's or the lever's
+        self._timer: Optional[asyncio.TimerHandle] = None  # the keepalive's
+
+    async def run(self) -> None:
+        """Take the transport over from the stream reader, with what it
+        still buffers (bytes that followed CONNECT in one segment), its
+        EOF or exception and a transport it had paused; then serve the
+        connection to its end."""
+        reader = self._cl.net.reader
+        self._transport.set_protocol(self)
+        buffered = reader._buffer
+        if buffered:
+            self._rbuf += buffered
+            del buffered[:]
+            self._fresh = True
+        reader._maybe_resume_transport()
+        exc = reader.exception()
+        if exc is not None:
+            self._end = exc
+        elif reader.at_eof():
+            self._end = ConnectionClosedError()
+        try:
+            self._pump(_SCAN)
+            await self._done
+        finally:
+            for handle in (self._turn, self._timer):
+                if handle is not None:
+                    handle.cancel()
+            if not self._done.cancel() and not self._done.cancelled():
+                self._done.exception()  # seen, if the await never was
+            if self._cl._staged_waiter is self._on_gate:
+                self._cl._staged_waiter = None
+
+    # -- the transport's callbacks -----------------------------------------
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        return self._view
+
+    def buffer_updated(self, n: int) -> None:
+        if self._done.done():
+            return
+        rbuf = self._rbuf
+        rbuf += self._view[:n]
+        if self._blocked:
+            self._fresh = True
+            if len(rbuf) > READ_HIGH_WATER:
+                self._pause()
+            return
+        if n < self._need:
+            self._need -= n
+            return
+        ops = self._cl.ops
+        ops.socket_reads += 1
+        ops.direct_reads += 1
+        self._pump(_SCAN)
+
+    def eof_received(self) -> Optional[bool]:
+        self._ended(None)
+        return self._stream.eof_received()
+
+    def connection_lost(self, exc: Optional[Exception]) -> None:
+        self._ended(exc)
+        self._stream.connection_lost(exc)
+
+    def pause_writing(self) -> None:
+        self._stream.pause_writing()
+
+    def resume_writing(self) -> None:
+        self._stream.resume_writing()
+
+    # -- the read side's turn ----------------------------------------------
+
+    def _pump(self, stage: int) -> None:
+        """One turn of the read side from ``stage`` on: scan and handle
+        what is buffered (``_SCAN``), do what follows a scan
+        (``_SETTLE``), see to the next read (``_READ``), around again
+        while complete packets may be buffered; until the next packet
+        needs bytes that have not come, the gate or the lever stops the
+        turn, or the connection ends."""
+        cl = self._cl
+        rbuf = self._rbuf
+        held = self._held
+        try:
+            while True:
+                if stage == _SCAN:
+                    if cl.closed:
+                        self._finish(None)
+                        return
+                    frames, consumed, err = self._frame_scan(
+                        rbuf, max_frames=self._max_frames,
+                        max_packet_size=self._caps.maximum_packet_size,
+                    )
+                    cl._take_frames(rbuf, frames, self._handler)
+                    n = len(frames)
+                    held = (n, n == self._max_frames, consumed, err)
+                    if cl._staged:
+                        # publishes of this scan are still in the stage:
+                        # the completion of the last of them takes the
+                        # turn up again (_gate_opened)
+                        self._held = held
+                        self._blocked = True
+                        cl._staged_waiter = self._on_gate
+                        return
+                if stage != _READ:
+                    if cl._settle_scan(rbuf, *held):
+                        stage = _SCAN
+                        continue
+                    delay = cl._read_delay()
+                    if delay > 0:
+                        self._blocked = True
+                        self._pause()
+                        self._turn = self._loop.call_later(delay, self._unthrottled)
+                        return
+                deadline = cl._deadline
+                if deadline is not None and self._timer is None:
+                    # ticking and not armed: the first read, or the
+                    # timer fired while the gate or the lever held
+                    left = deadline - time.monotonic()
+                    if left <= 0:
+                        raise asyncio.TimeoutError()
+                    self._timer = self._loop.call_later(left, self._deadline_due)
+                if self._fresh:
+                    # bytes that came while the gate or the lever held
+                    # (or behind CONNECT): one wake-up on data, as the
+                    # stream feeder's read of what its reader buffered
+                    self._fresh = False
+                    cl.ops.socket_reads += 1
+                    cl.ops.direct_reads += 1
+                    stage = _SCAN
+                    continue
+                if self._end is not None:
+                    raise self._end
+                if self._paused:
+                    self._paused = False
+                    self._transport.resume_reading()
+                self._need = cl._missing_bytes(rbuf, self._varint_decode)
+                return
+        except Exception as e:
+            self._finish(e)
+
+    def _gate_opened(self) -> None:
+        """``cl._staged`` is back at zero (server._complete_staged, inside
+        a completion slice): the rest of the turn runs in a turn of the
+        loop of its own."""
+        self._cl._staged_waiter = None
+        self._turn = self._loop.call_soon(self._resume)
+
+    def _resume(self) -> None:
+        self._turn = None
+        if self._done.done():
+            return
+        cl = self._cl
+        if cl._staged:
+            # a publish of this connection was staged from outside its
+            # read since (an injected packet): its completion calls again
+            cl._staged_waiter = self._on_gate
+            return
+        self._blocked = False
+        self._pump(_SETTLE)
+
+    def _unthrottled(self) -> None:
+        self._turn = None
+        if self._done.done():
+            return
+        self._blocked = False
+        self._pump(_READ)
+
+    def _deadline_due(self) -> None:
+        """The keepalive's timer fired: the connection is over if its
+        deadline has not moved, else the timer is armed for where it
+        moved to. While the gate or the lever holds, the turn that
+        follows looks at the deadline itself."""
+        self._timer = None
+        deadline = self._cl._deadline
+        if self._done.done() or self._blocked or deadline is None:
+            return
+        left = deadline - time.monotonic()
+        if left > 0:
+            self._timer = self._loop.call_later(left, self._deadline_due)
+        else:
+            self._finish(asyncio.TimeoutError())
+
+    def _pause(self) -> None:
+        if not self._paused:
+            self._paused = True
+            self._transport.pause_reading()
+
+    def _ended(self, exc: Optional[BaseException]) -> None:
+        """No more bytes will come. What is buffered is still served
+        (the gate or the lever may hold it); the turn that next needs
+        bytes ends the connection, as does this call where one waits."""
+        if self._end is None:
+            self._end = exc if exc is not None else ConnectionClosedError()
+        if not self._blocked:
+            self._finish(self._end)
+
+    def _finish(self, exc: Optional[BaseException]) -> None:
+        if self._done.done():
+            return
+        if exc is None:
+            self._done.set_result(None)
+        else:
+            self._done.set_exception(exc)
+
+
 @dataclass
 class Will:
     """Last will and testament details (clients.go:132-140)."""
@@ -361,11 +649,14 @@ class Client:
         # this connection's publishes in the staging loop
         # (mqtt_tpu.staging): counted up where one is parked from the
         # connection's own loop and down by its batch's completion
-        # (server._park_publish / _complete_staged). The read loop waits
-        # ONCE a scan on ``_staged_waiter`` until the count is back at
-        # zero, and raises there the first error a completion recorded.
+        # (server._park_publish / _complete_staged). The read side stops
+        # ONCE a scan until the count is back at zero, and raises there
+        # the first error a completion recorded. ``_staged_waiter`` is
+        # what it left at the gate, or None: the completion that takes
+        # the count to zero calls it (the stream feeder's future's
+        # wake-up; the direct feeder's ``call_soon`` of its next turn).
         self._staged = 0
-        self._staged_waiter: Optional[asyncio.Future] = None
+        self._staged_waiter: Optional[Callable[[], None]] = None
         self._staged_err: Optional[BaseException] = None
         # the encoded packets held back for this socket, joined in order,
         # or None while nothing is: they leave as ONE transport write when
@@ -622,66 +913,76 @@ class Client:
         return deleted
 
     async def read(self, packet_handler: Callable[["Client", Packet], Optional[Awaitable]]) -> None:
-        """The blocking per-packet read loop (clients.go:363-388); raises on
-        connection error, keepalive timeout, or a handler error.
+        """Take this connection's packets in until it ends (clients.go:363-388);
+        raises on connection error, keepalive timeout, or a handler error,
+        and returns on a clean DISCONNECT or a stop from inside a handler.
 
-        Packets are framed in bulk: each socket read drains everything
-        available, the native frame scanner (mqtt_tpu/native) splits it
-        into complete packets, and each is decoded straight from the
-        buffer — one await per socket read instead of one per header byte,
-        which is what keeps the asyncio data plane within reach of the
-        reference's goroutine throughput (SURVEY.md §7 hard-part #5).
+        Packets are framed in bulk: everything a socket read brought is
+        scanned at once by the native frame scanner (mqtt_tpu/native) and
+        each complete packet is decoded straight from the buffer
+        (SURVEY.md §7 hard-part #5). One frame loop (``_take_frames``)
+        and one set of rules after it (``_settle_scan``) serve two
+        feeders, chosen by what the connection is:
 
-        A scan's frames are taken in by the run where they can be: every
-        stretch of PUBLISH frames of QoS0 or QoS1 without RETAIN
-        (``RUN_FIRST_BYTES``) goes to the server in ONE call
-        (``ops.ingest_run``, server.ingest_run), which decodes, checks,
-        acknowledges and parks as many of them as it can take as they
-        stand and says how many it took. The frame that ended the run,
-        the whole stretch where the run's gate is shut, and every other
-        packet go one at a time through ``packet_handler``, in the
-        frames' order always. A stretch of PUBACK frames that hold a
-        packet id and nothing else goes the same way to
-        ``ops.ack_run`` (server.ack_run), which takes all of it or, its
-        gate shut, none, and says how long it is either way.
+        - **direct** (``_DirectFeed``): the connection's reader is fed by
+          its own transport (TCP, TLS, a unix socket, a socket a loop
+          shard wrapped) and no scan coalescer stands before it. The
+          broker's own protocol takes the transport over and the scan
+          runs inside the transport's read callback (``buffer_updated``):
+          no future, task step, timer or allocation a socket read. This coroutine awaits one future
+          a connection.
+        - **stream** (``_read_stream``): everything else: a reader some
+          pump feeds (the WebSocket leg), inline and mock clients, and a
+          connection behind a ``ScanGate`` (loop shards,
+          ``Options.scan_coalesce``: that coalescer awaits across
+          connections). One ``reader.read`` coroutine a socket read.
 
         ``packet_handler(cl, pk)`` is synchronous. A PUBLISH it parked
         with the staging loop (mqtt_tpu.staging) is still counted in
         ``_staged`` when it returns: every publish of a scan reaches the
-        staging batch before this loop blocks, and it blocks ONCE a scan,
-        on one future, until the last of them has fanned out — no further
-        read before that (the back-pressure and the per-connection order
-        depend on it). An error a completion recorded for this
-        connection is raised here. What the handlers write to this
-        connection during one read (an ack a QoS>0 frame) is corked and
-        leaves as one transport write when the read's frames are done
-        (``_cork``): one socket send a read, not one a frame. The read is
-        one of the cork's two openers; a delivery that reaches this socket
-        while it is open (a publisher that hears its own topic) joins it.
+        staging batch before the read side stops, and it stops ONCE a
+        scan, at one gate, until the last of them has fanned out: no
+        frame of this connection is handled before that (the
+        back-pressure and the per-connection order depend on it). An
+        error a completion recorded for this connection is raised here.
         """
-        from .native import MAX_FRAMES_PER_SCAN, frame_scan, varint_decode
-
-        caps = self.ops.options.capabilities
-        fast_eligible = self.ops.fast_publish_eligible
-        fast_publish = self.ops.fast_publish
-        ingest_run = self.ops.ingest_run
-        ack_run = self.ops.ack_run
-        telemetry = getattr(self.ops, "telemetry", None)
-        # device pipeline profiler (mqtt_tpu.tracing): while a profiler
-        # session is live, the loop time from a scan's frames in hand to
-        # their handlers returned (decode, admission, acks, the publish
-        # parked with the stage) is counted as ingest, once a scan, over
-        # the publishes in it; a scan without a publish books it over
-        # its PUBACK frames. Either stretch is also an annotation on the
-        # profiler's own clock (mqtt/loop.ingest, mqtt/loop.acks)
-        prof = getattr(self.ops, "profiler", None)
         # the shard's own gate wins (per-shard decode batching is
         # default-on inside the fabric); the server-wide gate serves the
         # single-loop opt-in (Options.scan_coalesce)
         scan_gate = self.scan_gate or getattr(self.ops, "scan_gate", None)
+        self.refresh_deadline(self.state.keepalive)
+        transport = self._fed_transport() if scan_gate is None else None
+        if transport is not None:
+            await _DirectFeed(self, transport, packet_handler).run()
+        else:
+            await self._read_stream(packet_handler, scan_gate)
+
+    def _fed_transport(self) -> Optional[asyncio.Transport]:
+        """The transport whose ``StreamReaderProtocol`` feeds this
+        connection's reader, or None where something else does (a pump,
+        a test) or nothing (an inline client)."""
+        reader = self.net.reader
+        transport = getattr(self.net.writer, "transport", None)
+        get_protocol = getattr(transport, "get_protocol", None)
+        if get_protocol is None or not isinstance(reader, asyncio.StreamReader):
+            return None
+        protocol = get_protocol()
+        if (
+            isinstance(protocol, asyncio.StreamReaderProtocol)
+            and protocol._stream_reader is reader
+        ):
+            return transport
+        return None
+
+    async def _read_stream(self, packet_handler, scan_gate: Optional[ScanGate]) -> None:
+        """The stream feeder of ``read``: one ``reader.read`` under the
+        keepalive's ``wait_for`` a socket read, one future a gated
+        scan."""
+        from .native import MAX_FRAMES_PER_SCAN, frame_scan, varint_decode
+
+        caps = self.ops.options.capabilities
         rbuf = bytearray()
         loop = asyncio.get_running_loop()
-        self.refresh_deadline(self.state.keepalive)
         while True:
             if self.closed:
                 return
@@ -696,173 +997,234 @@ class Client:
                     rbuf, max_frames=MAX_FRAMES_PER_SCAN,
                     max_packet_size=caps.maximum_packet_size,
                 )
-            # account for and process every complete packet
-            armed = prof is not None and prof.armed
-            span = None
-            if armed:
-                # the annotation lies around the counted stretch, its
-                # own cost outside it
-                kinds = [f.first_byte >> 4 for f in frames]
-                name, n_kind = "mqtt/loop.ingest", kinds.count(pkts.PUBLISH)
-                if not n_kind:
-                    name, n_kind = "mqtt/loop.acks", kinds.count(pkts.PUBACK)
-                if n_kind:
-                    span = prof.annotation(name, n=n_kind)
-                    span.__enter__()
-                n_in = self._pub_count
-                t_in = time.perf_counter_ns()
-            start = 0
-            self._cork = bytearray()  # this read's acks leave as one write
-            n = len(frames)
-            i = 0
-            solo = 0  # the frames below this index go one at a time
-            try:
-                while i < n:
-                    f = frames[i]
-                    if (
-                        i >= solo
-                        and f.first_byte in RUN_FIRST_BYTES
-                        and ingest_run is not None
-                    ):
-                        # a run of PUBLISH frames is taken in by one
-                        # call (server.ingest_run); the frame that ends
-                        # it goes down the path below, as does the whole
-                        # stretch when the run's gate is shut
-                        taken = ingest_run(self, rbuf, frames, i, start)
-                        if taken < 0:
-                            solo = i + 1
-                            while solo < n and frames[solo].first_byte in RUN_FIRST_BYTES:
-                                solo += 1
-                            continue
-                        if taken:
-                            i += taken
-                            f = frames[i - 1]
-                            start = f.body_offset + f.remaining
-                            if self.closed:
-                                break
-                        if i < n and frames[i].first_byte in RUN_FIRST_BYTES:
-                            solo = i + 1  # a PUBLISH the run refused
-                        continue
-                    if (
-                        i >= solo
-                        and f.first_byte == ACK_FIRST_BYTE
-                        and f.remaining == ACK_REMAINING
-                        and ack_run is not None
-                    ):
-                        # a stretch of bare PUBACK frames is taken in by
-                        # one call (server.ack_run), all of it or none
-                        taken = ack_run(self, rbuf, frames, i, start)
-                        if taken < 0:
-                            solo = i - taken  # the stretch, a frame at a time
-                            continue
-                        i += taken
-                        f = frames[i - 1]
-                        start = f.body_offset + f.remaining
-                        continue
-                    i += 1
-                    fstart = start
-                    fend = f.body_offset + f.remaining
-                    self.ops.info.bytes_received += (f.body_offset - start) + f.remaining
-                    start = fend
-                    if (f.first_byte >> 4) == pkts.PUBLISH:
-                        # overload-governor accounting: publishes this window
-                        # (both the fast-path and decode legs land here)
-                        self._pub_count += 1
-                    # QoS0 v4 PUBLISH passthrough (flags all zero): deliver the
-                    # frame bytes without materializing a Packet when the
-                    # server proves nothing can observe the difference. The
-                    # session gate runs BEFORE any bytes are copied.
-                    if (
-                        f.first_byte == 0x30
-                        and fast_publish is not None
-                        and fast_eligible(self)
-                    ):
-                        frame = bytes(rbuf[fstart:fend])
-                        if fast_publish(self, frame, f.body_offset - fstart):
-                            continue
-                        body = frame[f.body_offset - fstart :]
-                    else:
-                        body = bytes(rbuf[f.body_offset : fend])
-                    # telemetry stage clock: 1-in-N publishes get stamped
-                    # through decode -> admission -> staging -> fanout
-                    # (mqtt_tpu.telemetry); the clock rides on the packet
-                    clock = None
-                    if telemetry is not None and (f.first_byte >> 4) == pkts.PUBLISH:
-                        clock = telemetry.publish_clock()
-                    fh = FixedHeader()
-                    fh.decode(f.first_byte)
-                    fh.remaining = f.remaining
-                    pk = self._decode_body(fh, body)
-                    if clock is not None:
-                        clock.stamp("decode")
-                        # dynamic rider, not a Packet field: the clock never
-                        # touches the wire or dataclass equality
-                        setattr(pk, "_tclock", clock)
-                    packet_handler(self, pk)
-                    if self.closed:
-                        break
-            finally:
-                self._uncork()
-                if armed:
-                    t_out = time.perf_counter_ns()
-                    if span is not None:
-                        span.__exit__(None, None, None)
-            if armed:
-                busy_ns = t_out - t_in
-                if self._pub_count != n_in:
-                    prof.note_ingest(busy_ns, self._pub_count - n_in)
-                else:
-                    acks = sum(
-                        (f.first_byte >> 4) == pkts.PUBACK for f in frames[:i]
-                    )
-                    if acks:
-                        prof.note_acks(busy_ns, acks)
+            self._take_frames(rbuf, frames, packet_handler)
             if self._staged:
                 # publishes of this scan are still in the stage: one
                 # pipelining client fills device batches instead of
                 # paying a round trip each, and waits here for all of
                 # them at once
-                waiter = self._staged_waiter = loop.create_future()
+                waiter = loop.create_future()
+                self._staged_waiter = functools.partial(OutboundQueue._wake, waiter)
                 try:
                     await waiter
                 finally:
                     self._staged_waiter = None
-            if self._staged_err is not None:
-                err0, self._staged_err = self._staged_err, None
-                raise err0
-            if self.closed:
-                return
-            del rbuf[:consumed]
-            if err == -2:
-                raise ERR_PACKET_TOO_LARGE()  # [MQTT-3.2.2-15]
-            if err == -1:
-                # replay the per-byte path for the precise reason code
-                FixedHeader().decode(rbuf[0])  # raises for bad header bytes
-                raise pkts.ERR_MALFORMED_VARIABLE_BYTE_INTEGER()
-            if len(frames) == MAX_FRAMES_PER_SCAN:
-                continue  # more complete packets may still be buffered
-            if frames:
-                # progress made — extend the keepalive deadline. A trickle
-                # of partial-packet bytes deliberately does NOT extend it.
-                self.refresh_deadline(self.state.keepalive)
-            overload = self.ops.overload
-            if overload is not None and not self.net.inline:
-                # THROTTLE lever: an over-quota publisher's next socket
-                # read is delayed, so the kernel's TCP window pushes
-                # back on it — the QoS0 analog of v5 receive-maximum
-                delay = overload.read_delay(self)
-                if delay > 0:
-                    await asyncio.sleep(delay)
+            n = len(frames)
+            if self._settle_scan(rbuf, n, n == MAX_FRAMES_PER_SCAN, consumed, err):
+                continue
+            delay = self._read_delay()
+            if delay > 0:
+                await asyncio.sleep(delay)
             data = await self._read_more(self._missing_bytes(rbuf, varint_decode))
             if not data:
                 raise ConnectionClosedError()
             rbuf += data
 
+    def _take_frames(self, rbuf: bytearray, frames: list, packet_handler) -> None:
+        """Handle one scan's complete packets, in the frames' order: the
+        one frame loop of both feeders.
+
+        A scan's frames are taken in by the run where they can be: every
+        stretch of PUBLISH frames of QoS0 or QoS1 without RETAIN
+        (``RUN_FIRST_BYTES``) goes to the server in ONE call
+        (``ops.ingest_run``, server.ingest_run), which decodes, checks,
+        acknowledges and parks as many of them as it can take as they
+        stand and says how many it took. The frame that ended the run,
+        the whole stretch where the run's gate is shut, and every other
+        packet go one at a time through ``packet_handler``. A stretch of
+        PUBACK frames that hold a packet id and nothing else goes the
+        same way to ``ops.ack_run`` (server.ack_run), which takes all of
+        it or, its gate shut, none, and says how long it is either way.
+
+        What the handlers write to this connection during one scan (an
+        ack a QoS>0 frame) is corked and leaves as one transport write
+        when the scan's frames are done (``_cork``): one socket send a
+        read, not one a frame. The read is one of the cork's two openers;
+        a delivery that reaches this socket while it is open (a publisher
+        that hears its own topic) joins it.
+
+        While a profiler session is live (mqtt_tpu.tracing), the loop
+        time from the frames in hand to their handlers returned (decode,
+        admission, acks, the publish parked with the stage) is counted as
+        ingest, once a scan, over the publishes in it; a scan without a
+        publish books it over its PUBACK frames. Either stretch is also
+        an annotation on the profiler's own clock (mqtt/loop.ingest,
+        mqtt/loop.acks)."""
+        ops = self.ops
+        fast_eligible = ops.fast_publish_eligible
+        fast_publish = ops.fast_publish
+        ingest_run = ops.ingest_run
+        ack_run = ops.ack_run
+        telemetry = getattr(ops, "telemetry", None)
+        prof = getattr(ops, "profiler", None)
+        armed = prof is not None and prof.armed
+        span = None
+        if armed:
+            # the annotation lies around the counted stretch, its
+            # own cost outside it
+            kinds = [f.first_byte >> 4 for f in frames]
+            name, n_kind = "mqtt/loop.ingest", kinds.count(pkts.PUBLISH)
+            if not n_kind:
+                name, n_kind = "mqtt/loop.acks", kinds.count(pkts.PUBACK)
+            if n_kind:
+                span = prof.annotation(name, n=n_kind)
+                span.__enter__()
+            n_in = self._pub_count
+            t_in = time.perf_counter_ns()
+        start = 0
+        self._cork = bytearray()  # this read's acks leave as one write
+        n = len(frames)
+        i = 0
+        solo = 0  # the frames below this index go one at a time
+        try:
+            while i < n:
+                f = frames[i]
+                if (
+                    i >= solo
+                    and f.first_byte in RUN_FIRST_BYTES
+                    and ingest_run is not None
+                ):
+                    # a run of PUBLISH frames is taken in by one
+                    # call (server.ingest_run); the frame that ends
+                    # it goes down the path below, as does the whole
+                    # stretch when the run's gate is shut
+                    taken = ingest_run(self, rbuf, frames, i, start)
+                    if taken < 0:
+                        solo = i + 1
+                        while solo < n and frames[solo].first_byte in RUN_FIRST_BYTES:
+                            solo += 1
+                        continue
+                    if taken:
+                        i += taken
+                        f = frames[i - 1]
+                        start = f.body_offset + f.remaining
+                        if self.closed:
+                            break
+                    if i < n and frames[i].first_byte in RUN_FIRST_BYTES:
+                        solo = i + 1  # a PUBLISH the run refused
+                    continue
+                if (
+                    i >= solo
+                    and f.first_byte == ACK_FIRST_BYTE
+                    and f.remaining == ACK_REMAINING
+                    and ack_run is not None
+                ):
+                    # a stretch of bare PUBACK frames is taken in by
+                    # one call (server.ack_run), all of it or none
+                    taken = ack_run(self, rbuf, frames, i, start)
+                    if taken < 0:
+                        solo = i - taken  # the stretch, a frame at a time
+                        continue
+                    i += taken
+                    f = frames[i - 1]
+                    start = f.body_offset + f.remaining
+                    continue
+                i += 1
+                fstart = start
+                fend = f.body_offset + f.remaining
+                ops.info.bytes_received += (f.body_offset - start) + f.remaining
+                start = fend
+                if (f.first_byte >> 4) == pkts.PUBLISH:
+                    # overload-governor accounting: publishes this window
+                    # (both the fast-path and decode legs land here)
+                    self._pub_count += 1
+                # QoS0 v4 PUBLISH passthrough (flags all zero): deliver the
+                # frame bytes without materializing a Packet when the
+                # server proves nothing can observe the difference. The
+                # session gate runs BEFORE any bytes are copied.
+                if (
+                    f.first_byte == 0x30
+                    and fast_publish is not None
+                    and fast_eligible(self)
+                ):
+                    frame = bytes(rbuf[fstart:fend])
+                    if fast_publish(self, frame, f.body_offset - fstart):
+                        continue
+                    body = frame[f.body_offset - fstart :]
+                else:
+                    body = bytes(rbuf[f.body_offset : fend])
+                # telemetry stage clock: 1-in-N publishes get stamped
+                # through decode -> admission -> staging -> fanout
+                # (mqtt_tpu.telemetry); the clock rides on the packet
+                clock = None
+                if telemetry is not None and (f.first_byte >> 4) == pkts.PUBLISH:
+                    clock = telemetry.publish_clock()
+                fh = FixedHeader()
+                fh.decode(f.first_byte)
+                fh.remaining = f.remaining
+                pk = self._decode_body(fh, body)
+                if clock is not None:
+                    clock.stamp("decode")
+                    # dynamic rider, not a Packet field: the clock never
+                    # touches the wire or dataclass equality
+                    setattr(pk, "_tclock", clock)
+                packet_handler(self, pk)
+                if self.closed:
+                    break
+        finally:
+            self._uncork()
+            if armed:
+                t_out = time.perf_counter_ns()
+                if span is not None:
+                    span.__exit__(None, None, None)
+        if armed:
+            busy_ns = t_out - t_in
+            if self._pub_count != n_in:
+                prof.note_ingest(busy_ns, self._pub_count - n_in)
+            else:
+                acks = sum(
+                    (f.first_byte >> 4) == pkts.PUBACK for f in frames[:i]
+                )
+                if acks:
+                    prof.note_acks(busy_ns, acks)
+
+    def _settle_scan(
+        self, rbuf: bytearray, n_frames: int, full: bool, consumed: int, err: int
+    ) -> bool:
+        """What follows a scan's frames once nothing of this connection
+        is staged, for both feeders: raise the first error a completion
+        recorded, drop the scanned prefix, raise the scan's own error
+        (an oversize packet, a malformed length), extend the keepalive.
+        True: scan again before reading on (the connection was stopped,
+        which the feeder's next turn sees, or the scan was ``full`` and
+        more complete packets may be buffered)."""
+        if self._staged_err is not None:
+            err0, self._staged_err = self._staged_err, None
+            raise err0
+        if self.closed:
+            return True
+        del rbuf[:consumed]
+        if err == -2:
+            raise ERR_PACKET_TOO_LARGE()  # [MQTT-3.2.2-15]
+        if err == -1:
+            # replay the per-byte path for the precise reason code
+            FixedHeader().decode(rbuf[0])  # raises for bad header bytes
+            raise pkts.ERR_MALFORMED_VARIABLE_BYTE_INTEGER()
+        if full:
+            return True
+        if n_frames:
+            # progress made — extend the keepalive deadline. A trickle
+            # of partial-packet bytes deliberately does NOT extend it.
+            self.refresh_deadline(self.state.keepalive)
+        return False
+
+    def _read_delay(self) -> float:
+        """THROTTLE lever: the seconds an over-quota publisher's next
+        socket read is put off, so the kernel's TCP window pushes back
+        on it (the QoS0 analog of v5 receive-maximum); 0 otherwise."""
+        overload = self.ops.overload
+        if overload is None or self.net.inline:
+            return 0.0
+        return overload.read_delay(self)
+
     @staticmethod
     def _missing_bytes(rbuf: bytearray, varint_decode) -> int:
         """How many more bytes complete the partial packet at the head of
-        the buffer (0 = unknown): lets a huge body arrive in one readexactly
-        instead of 64 KiB nibbles that would rescan the buffer each time."""
+        the buffer, where that is more than one read brings (``READ_SIZE``);
+        else 0: lets a huge body arrive whole (the stream feeder's one
+        ``readexactly``, the direct feeder's chunks buffered unscanned)
+        instead of in nibbles that would wake the scan each time. A
+        smaller rest comes with the next read and whatever follows it."""
         if len(rbuf) < 2:
             return 0
         try:
@@ -871,7 +1233,8 @@ class Client:
             return 0
         if vb == 0:
             return 0
-        return max(0, 1 + vb + remaining - len(rbuf))
+        missing = 1 + vb + remaining - len(rbuf)
+        return missing if missing > READ_SIZE else 0
 
     def _decode_body(self, fh: FixedHeader, body: bytes) -> Packet:
         """Decode one framed packet body and run the on_packet_read chain
@@ -887,15 +1250,19 @@ class Client:
         return self.ops.hooks.on_packet_read(self, pk)
 
     async def _read_more(self, need: int = 0) -> bytes:
-        """One bulk socket read honoring the keepalive deadline. ``need``>0
-        waits for exactly that many bytes (completing a known partial
-        packet); otherwise reads whatever is available up to 64 KiB."""
+        """One bulk socket read of the stream feeder, honoring the
+        keepalive deadline: a ``reader.read`` coroutine under
+        ``wait_for`` (a future, two task steps and a timer a read; the
+        direct feeder, ``_DirectFeed``, makes none). ``need``>0 waits for
+        exactly that many bytes (the rest of a large body,
+        ``_missing_bytes``); otherwise reads whatever is available up to
+        ``READ_SIZE``."""
         if self.net.reader is None:
             raise ConnectionClosedError()
         if need > 0:
             coro = self.net.reader.readexactly(need)
         else:
-            coro = self.net.reader.read(65536)
+            coro = self.net.reader.read(READ_SIZE)
         if self._deadline is None:
             data = await coro
         else:
@@ -905,8 +1272,8 @@ class Client:
                 raise asyncio.TimeoutError()
             data = await asyncio.wait_for(coro, timeout)
         if data:
-            # one wake-up of this read loop on one recv (the stream may
-            # have joined two)
+            # one wake-up of the frame scan on data (the stream may
+            # have joined two recvs)
             self.ops.socket_reads += 1
         return data
 

@@ -376,6 +376,26 @@ class Frame:
         self.remaining = remaining
 
 
+# frame_scan's output arrays, one set a thread (the scan is synchronous
+# and every event loop runs in one thread): made once, where every call
+# used to allocate three numpy arrays and five ctypes objects
+_scan_out = threading.local()
+
+
+def _scan_scratch(max_frames: int) -> tuple:
+    out = getattr(_scan_out, "held", None)
+    if out is None or out[0] < max_frames:
+        consumed, err = ctypes.c_int64(), ctypes.c_int32()
+        out = _scan_out.held = (
+            max_frames,
+            (ctypes.c_int64 * max_frames)(),
+            (ctypes.c_uint8 * max_frames)(),
+            (ctypes.c_uint32 * max_frames)(),
+            consumed, err, ctypes.byref(consumed), ctypes.byref(err),
+        )
+    return out
+
+
 def frame_scan(
     buf: bytes, max_frames: int = 1024, max_packet_size: int = 0
 ) -> tuple[list[Frame], int, int]:
@@ -389,25 +409,23 @@ def frame_scan(
     l = lib()
     if l is None:
         return _frame_scan_py(buf, max_frames, max_packet_size)
-    body_offsets = np.zeros(max_frames, dtype=np.int64)
-    first_bytes = np.zeros(max_frames, dtype=np.uint8)
-    remainings = np.zeros(max_frames, dtype=np.uint32)
-    consumed = ctypes.c_int64()
-    err = ctypes.c_int32()
+    size = len(buf)
+    if not size:
+        return [], 0, 0
+    _, body_offsets, first_bytes, remainings, consumed, err, consumed_ref, err_ref = (
+        _scan_scratch(max_frames)
+    )
     if isinstance(buf, (bytearray, memoryview)):
         # zero-copy view of the mutable read buffer
-        holder = (ctypes.c_char * len(buf)).from_buffer(buf) if len(buf) else b""
-        ptr = ctypes.addressof(holder) if len(buf) else None
+        holder = ctypes.c_char.from_buffer(buf)
+        ptr = ctypes.addressof(holder)
     else:
         holder = buf
-        ptr = ctypes.cast(ctypes.c_char_p(buf), ctypes.c_void_p).value if buf else None
+        ptr = ctypes.cast(ctypes.c_char_p(buf), ctypes.c_void_p).value
     try:
         n = l.mqtt_frame_scan(
-            ptr, len(buf), max_frames, max_packet_size,
-            body_offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            first_bytes.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            remainings.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            ctypes.byref(consumed), ctypes.byref(err),
+            ptr, size, max_frames, max_packet_size,
+            body_offsets, first_bytes, remainings, consumed_ref, err_ref,
         )
     finally:
         # release the from_buffer export DETERMINISTICALLY: anything that
@@ -417,10 +435,7 @@ def frame_scan(
         # alive and make the caller's `del rbuf[:consumed]` raise
         # BufferError("Existing exports of data") mid-read-loop
         del holder
-    frames = [
-        Frame(int(first_bytes[i]), int(body_offsets[i]), int(remainings[i]))
-        for i in range(n)
-    ]
+    frames = list(map(Frame, first_bytes[:n], body_offsets[:n], remainings[:n]))
     return frames, consumed.value, err.value
 
 

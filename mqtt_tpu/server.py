@@ -830,9 +830,17 @@ class _Ops:
         # slice's deliveries) and each send of the native fan-out flush.
         # A plain add on the writing loop.
         self.socket_sends = 0
-        # wake-ups of a read loop on data (Client._read_more: one recv
-        # each, or two the stream joined): a plain add on the reading loop
+        # wake-ups of a connection's frame scan on data, by either
+        # feeder of Client.read: the stream feeder's ``_read_more`` that
+        # returned bytes (one recv, or two the stream joined; one for a
+        # large body read to its end), the direct feeder's
+        # ``buffer_updated`` that ran the scan (one recv; the chunks of a
+        # known partial packet count once, and the bytes buffered while
+        # the gate held count once, when its turn takes them up). Of
+        # them ``direct_reads`` are the direct feeder's. Plain adds on
+        # the reading loop.
         self.socket_reads = 0
+        self.direct_reads = 0
         # the server's entry point for a scan's run of PUBLISH frames
         # (Server.ingest_run; None until the server wires it), the runs
         # that took at least one publish and the publishes they took in.
@@ -1538,6 +1546,7 @@ class Server:
             "deliveries": self.telemetry.fanout_deliveries.value,
             "socket_sends": self._ops.socket_sends,
             "socket_reads": self._ops.socket_reads,
+            "direct_reads": self._ops.direct_reads,
             "ingest_runs": self._ops.ingest_runs,
             "ingest_run_publishes": self._ops.ingest_run_publishes,
             "ack_runs": self._ops.ack_runs,
@@ -1689,6 +1698,13 @@ class Server:
                 "socket_reads",
                 "Wake-ups of a connection's read loop on data: one recv "
                 "each (the stream may join two)",
+            ),
+            (
+                "mqtt_tpu_direct_reads_total",
+                "direct_reads",
+                "Of the socket reads, those taken in inside the "
+                "transport's read callback by the broker's own protocol "
+                "(the rest came through a stream reader)",
             ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
@@ -3496,9 +3512,11 @@ class Server:
                 if entry.counted:
                     cl._staged -= 1
                     if not cl._staged:
-                        waiter = cl._staged_waiter
-                        if waiter is not None and not waiter.done():
-                            waiter.set_result(None)  # brokerlint: ok=R12 a counted entry was parked from cl.net.loop and completes on it: the read loop's own
+                        # a counted entry was parked from cl.net.loop
+                        # and completes on it: the read side's own loop
+                        wake = cl._staged_waiter
+                        if wake is not None:
+                            wake()
         finally:
             if corked:
                 if prof is not None:
@@ -5530,6 +5548,10 @@ class Server:
             SYS_PREFIX + "/broker/ingest/ack_runs": str(self._ops.ack_runs),
             SYS_PREFIX + "/broker/ingest/ack_run_acks": str(
                 self._ops.ack_run_acks
+            ),
+            # socket reads taken in inside the transport's callback
+            SYS_PREFIX + "/broker/ingest/direct_reads": str(
+                self._ops.direct_reads
             ),
         }
         if self.matcher is not None:

@@ -188,7 +188,9 @@ CASES = {
     ),
     "ack_split_across_reads": Case(
         [acks([1, 2]) + acks([3])[:3], acks([3])[3:] + acks([4])],
-        fill(range(1, 7)), took=4, runs=3,  # the missing byte is read alone
+        # the missing byte comes with what follows it (a small rest is
+        # not read alone: Client._missing_bytes)
+        fill(range(1, 7)), took=4, runs=2,
     ),
 }
 

@@ -6,6 +6,7 @@ The native library is optional; when it can't be built these tests skip
 
 import hashlib
 import random
+import sys
 
 import numpy as np
 import pytest
@@ -204,6 +205,50 @@ class TestFrameScan:
         # PUBLISH DUP=1 QoS=0 violates [MQTT-3.3.1-2]
         frames, consumed, err = self._scan_both(b"\x38\x00")
         assert err == -1
+
+    @pytest.mark.parametrize("kind", [bytes, bytearray, memoryview])
+    def test_one_scans_results_do_not_outlive_it_in_the_next(self, kind):
+        """The output arrays are kept from call to call (one set a
+        thread): a later scan, of fewer frames or asked for more than the
+        arrays hold, leaves an earlier scan's frames as they were."""
+        ping, pk = bytes.fromhex("c000"), bytes.fromhex("30080003612f62706179")
+        wrap = (lambda b: memoryview(bytearray(b))) if kind is memoryview else kind
+        first, _, _ = self._scan_both(wrap(pk * 5), max_frames=8)
+        second, consumed, err = self._scan_both(wrap(ping * 3), max_frames=8)
+        third, _, _ = self._scan_both(wrap(ping * 40 + pk), max_frames=64)  # grows
+        assert [(f.first_byte, f.remaining) for f in first] == [(0x30, 8)] * 5
+        assert [f.body_offset for f in first] == [2, 12, 22, 32, 42]
+        assert [(f.first_byte, f.body_offset) for f in second] == [(0xC0, 2), (0xC0, 4), (0xC0, 6)]
+        assert (consumed, err) == (6, 0) and len(third) == 41 and third[-1].remaining == 8
+
+    def test_threads_scan_side_by_side(self):
+        """Each thread has its own output arrays: scans on two event
+        loops' threads at once do not write into each other's."""
+        import threading
+
+        streams = [bytes.fromhex("c000") * 200, bytes.fromhex("30080003612f62706179") * 200]
+        want = [_frame_scan_py(s, 256, 0) for s in streams]
+        wrong = []
+
+        def scan(k):
+            for _ in range(300):
+                got = frame_scan(bytearray(streams[k]), max_frames=256)
+                if [(f.first_byte, f.body_offset, f.remaining) for f in got[0]] != [
+                    (f.first_byte, f.body_offset, f.remaining) for f in want[k][0]
+                ] or got[1:] != want[k][1:]:
+                    wrong.append(k)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=scan, args=(k % 2,), daemon=True) for k in range(6)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(30)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads) and not wrong
 
 
 class TestUtf8:

@@ -563,7 +563,8 @@ class TestSysTopics:
                 and not t.startswith("$SYS/broker/devices/")
             }
             # the 20 of the reference's tree, the trie's three counts
-            # and the two each of the ingest run and the ack run
+            # and the two each of the ingest run and the ack run, and
+            # the direct feeder's reads
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
@@ -572,8 +573,9 @@ class TestSysTopics:
                 "$SYS/broker/ingest/run_publishes",
                 "$SYS/broker/ingest/ack_runs",
                 "$SYS/broker/ingest/ack_run_acks",
+                "$SYS/broker/ingest/direct_reads",
             } <= base
-            assert len(base) == 27
+            assert len(base) == 28
             await h.shutdown()
 
         run(scenario())

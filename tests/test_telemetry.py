@@ -699,6 +699,8 @@ class TestIngestRunCounts:
                 assert (await read_wire_packet(sub_r)).fixed_header.type == PUBLISH
             counts = srv._slice_counters()
             assert counts["ingest_run_publishes"] == 8
+            # both connections sit on socketpairs: the direct feeder's
+            assert counts["direct_reads"] == counts["socket_reads"] > 0
             # one run before the retained frame, one after it, unless
             # the socket handed the bytes over in more reads
             assert 2 <= counts["ingest_runs"] <= 8
@@ -717,6 +719,9 @@ class TestIngestRunCounts:
                 # no PUBACK came in (tests/test_ack_run.py counts them)
                 "$SYS/broker/ingest/ack_runs": b"0",
                 "$SYS/broker/ingest/ack_run_acks": b"0",
+                "$SYS/broker/ingest/direct_reads": str(
+                    counts["direct_reads"]
+                ).encode(),
             }
             await srv.close()
             await h.shutdown()
