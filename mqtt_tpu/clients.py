@@ -773,16 +773,22 @@ class Client:
             self._send(data)
             return
         cork += data
+        self.ops.cork_frames += 1
         if len(cork) >= CORK_MAX_BYTES:
+            self.ops.cork_early_writes += 1
             self._uncork()
             self._cork = bytearray()
 
     def _uncork(self) -> None:
         """Write what this socket's cork holds, as one transport write,
         and stop corking. For the opener (the read in hand, or the
-        completion slice) and for the teardown."""
+        completion slice) and for the teardown. Counted (``_Ops``): a
+        cork written (``cork_writes``), beside the packets that joined
+        one (``cork_frames``) and the writes its byte bound forced
+        (``cork_early_writes``)."""
         cork, self._cork = self._cork, None
         if cork and self.net.writer is not None:
+            self.ops.cork_writes += 1
             self._send(bytes(cork))
 
     def _send(self, data: bytes) -> None:

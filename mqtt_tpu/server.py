@@ -797,6 +797,13 @@ _INGEST_RUN_EVENTS = (
 # as it was read, the completion of its delivery (a storage hook
 # persists it), the packet once processed (Server.ack_run)
 _ACK_RUN_EVENTS = (ON_PACKET_READ, ON_QOS_COMPLETE, ON_PACKET_PROCESSED)
+# ``_Ops``' counts of the way out, as the profiler's slice snapshots and
+# ``$SYS/broker/egress/*`` carry them (``/metrics`` has a help text each)
+_EGRESS_COUNTERS = (
+    "deliveries_flush", "deliveries_cork", "deliveries_queue",
+    "deliveries_dropped_full", "cork_writes", "cork_frames",
+    "cork_early_writes",
+)
 
 
 class _Ops:
@@ -856,6 +863,23 @@ class _Ops:
         # the most target ids one completion slice has looked up at once
         # (Server._complete_staged: its ``ids`` list): one compare a slice
         self.slice_targets_max = 0
+        # the three ways a delivery of the encode-once fan-out leaves
+        # (Server._flush_variant): the native flush (a ready socket), an
+        # open cork (its read's, or the completion slice's), the bounded
+        # outbound queue (a socket with a backlog: one write a frame, by
+        # its write loop); and the deliveries that queue refused, full
+        # (each is also one of ``info.messages_dropped``). Plain adds on
+        # the writing loop, once a variant.
+        self.deliveries_flush = 0
+        self.deliveries_cork = 0
+        self.deliveries_queue = 0
+        self.deliveries_dropped_full = 0
+        # a socket's cork (clients.Client._write / _uncork): corks
+        # written (one transport write each), the packets they held, and
+        # of the writes those a cork cut at CORK_MAX_BYTES forced early
+        self.cork_writes = 0
+        self.cork_frames = 0
+        self.cork_early_writes = 0
 
 
 class Server:
@@ -1534,7 +1558,10 @@ class Server:
         on data, the ingest runs and the
         publishes they took in, the ack runs and their PUBACK frames,
         the widest completion slice so far (a high-water mark, not a
-        sum), the matcher's wide entries and the topics they answered,
+        sum), the deliveries by the way they left (native flush, cork,
+        outbound queue; and those the full queue refused), the corks
+        written with their packets and early writes, the matcher's wide
+        entries and the topics they answered,
         what the trie holds (``TopicsIndex``'s three counts), and what
         set-up's load cost, as values at the snapshot: the bulk loads'
         open-to-close wall and the build that ended the newest one."""
@@ -1552,6 +1579,7 @@ class Server:
             "ack_runs": self._ops.ack_runs,
             "ack_run_acks": self._ops.ack_run_acks,
             "slice_targets_max": self._ops.slice_targets_max,
+            **{key: getattr(self._ops, key) for key in _EGRESS_COUNTERS},
             "wide_entries": getattr(stats, "wide_entries", 0),
             "wide_topics": getattr(stats, "wide_topics", 0),
             "particles": trie.particles,
@@ -1705,6 +1733,47 @@ class Server:
                 "Of the socket reads, those taken in inside the "
                 "transport's read callback by the broker's own protocol "
                 "(the rest came through a stream reader)",
+            ),
+            (
+                "mqtt_tpu_deliveries_flush_total",
+                "deliveries_flush",
+                "Deliveries of the encode-once fan-out that left by the "
+                "native flush (a ready socket: idle transport, empty queue)",
+            ),
+            (
+                "mqtt_tpu_deliveries_cork_total",
+                "deliveries_cork",
+                "Deliveries of the encode-once fan-out that joined a "
+                "socket's open cork (its read's, or the completion slice's)",
+            ),
+            (
+                "mqtt_tpu_deliveries_queue_total",
+                "deliveries_queue",
+                "Deliveries of the encode-once fan-out that took a "
+                "socket's bounded outbound queue (one write a frame)",
+            ),
+            (
+                "mqtt_tpu_deliveries_dropped_full_total",
+                "deliveries_dropped_full",
+                "Deliveries of the encode-once fan-out the outbound queue "
+                "refused, full (also in mqtt_tpu_messages_dropped_total)",
+            ),
+            (
+                "mqtt_tpu_cork_writes_total",
+                "cork_writes",
+                "Corks written: one transport write for the packets a "
+                "socket's read or a completion slice held back",
+            ),
+            (
+                "mqtt_tpu_cork_frames_total",
+                "cork_frames",
+                "Packets that joined a socket's cork",
+            ),
+            (
+                "mqtt_tpu_cork_early_writes_total",
+                "cork_early_writes",
+                "Of the corks written, those a cork past its byte bound "
+                "forced before its opener closed it",
             ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
@@ -3475,6 +3544,7 @@ class Server:
         present = self.clients.present(ids)
         lookup = present.get
         corked = self._cork_repeated(ids, present)
+        frames0 = self._ops.cork_frames
         try:
             for entry, subs, targets in work:
                 cl, pk = entry.cl, entry.pk
@@ -3520,7 +3590,10 @@ class Server:
         finally:
             if corked:
                 if prof is not None:
-                    span = prof.annotation("mqtt/loop.flush", sends=len(corked))
+                    span = prof.annotation(
+                        "mqtt/loop.flush", sends=len(corked),
+                        frames=self._ops.cork_frames - frames0,
+                    )
                     span.__enter__()
                     t_run = time.perf_counter_ns()
                 for cl in corked:
@@ -4231,7 +4304,10 @@ class Server:
         (``Client._cork``: the completion slice in hand targets it again,
         or its own read is in hand) takes the frame into the cork, in
         order behind what it holds, and is written when the cork's
-        opener closes it.
+        opener closes it. The three ways out are counted, a delivery
+        each where it was accepted (``_Ops.deliveries_flush``, ``_cork``,
+        ``_queue``), and the deliveries a full queue refused
+        (``deliveries_dropped_full``).
 
         Under the shard fabric the group is split BY OWNING SHARD
         first: each remote shard receives its whole sub-group as one
@@ -4277,6 +4353,7 @@ class Server:
             topic = ns_local(topic)
         on_acl = self.hooks.on_acl_check
         flush: list = []
+        n_cork = n_queue = n_full = 0
         for cl, sub in group:
             try:
                 if not on_acl(cl, topic, False):
@@ -4306,6 +4383,7 @@ class Server:
                         if self._transport_write_frame(
                             cl, frame, count_delivery
                         ):
+                            n_cork += 1
                             self._note_tenant_out(cl, dpk)
                         continue
                     sock = writer.get_extra_info("socket")
@@ -4326,15 +4404,21 @@ class Server:
                     cl, frame, lambda: dpk,
                     count_delivery=count_delivery,
                 ):
+                    n_full += 1
                     if eff > 0:
                         self._rollback_qos_delivery(cl, pid)
                     continue
+                n_queue += 1
             except Exception as e:
                 self.log.debug(
                     "failed publishing packet: error=%s client=%s", e, cl.id
                 )
                 continue
             self._note_tenant_out(cl, dpk)
+        ops = self._ops
+        ops.deliveries_cork += n_cork
+        ops.deliveries_queue += n_queue
+        ops.deliveries_dropped_full += n_full
         if not flush:
             return
         prof = self.profiler
@@ -4359,12 +4443,13 @@ class Server:
                     data if id_off < 0 else self._patch_id(data, id_off, pid)
                 )
                 if self._transport_write_frame(cl, frame, count_delivery):
+                    ops.deliveries_flush += 1
                     self._note_tenant_out(cl, dpk)
             return
         n = len(data)
         for (cl, _fd, pid), wrote in zip(flush, sent.tolist()):
             if wrote == n:
-                self._ops.socket_sends += 1  # the native flush's send
+                ops.socket_sends += 1  # the native flush's send
                 self._note_direct_write(cl, n, count_delivery)
             elif wrote >= 0:
                 # short write (kernel buffer filled mid-frame): finish
@@ -4375,7 +4460,7 @@ class Server:
                 )
                 try:
                     cl.net.writer.write(frame[wrote:])
-                    self._ops.socket_sends += 2  # the flush's and the tail's
+                    ops.socket_sends += 2  # the flush's and the tail's
                 except Exception as e:
                     self.log.debug(
                         "fan-out flush tail failed: error=%s client=%s",
@@ -4395,6 +4480,7 @@ class Server:
                     continue
             # accounting only on a delivery that actually went out (the
             # legacy path counts after publish_to_client succeeds)
+            ops.deliveries_flush += 1
             self._note_tenant_out(cl, dpk)
 
     @staticmethod
@@ -5554,6 +5640,11 @@ class Server:
                 self._ops.direct_reads
             ),
         }
+        # the way out: deliveries by the way they left, and the corks
+        for key in _EGRESS_COUNTERS:
+            topics[SYS_PREFIX + "/broker/egress/" + key] = str(
+                getattr(self._ops, key)
+            )
         if self.matcher is not None:
             # device-matcher observability (MatcherStats.as_dict): batches,
             # topics, host_fallbacks, overflows, rebuilds, fallback_ratio

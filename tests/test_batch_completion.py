@@ -1424,7 +1424,8 @@ class TestSliceWrites:
                 self.flushes.append(busy_ns)
 
             def annotation(self, name, **args):
-                assert name == "mqtt/loop.flush" and args == {"sends": 1}
+                # one socket's cork, the slice's two deliveries in it
+                assert name == "mqtt/loop.flush" and args == {"sends": 1, "frames": 2}
                 return contextlib.nullcontext()
 
         async def scenario():

@@ -563,8 +563,8 @@ class TestSysTopics:
                 and not t.startswith("$SYS/broker/devices/")
             }
             # the 20 of the reference's tree, the trie's three counts
-            # and the two each of the ingest run and the ack run, and
-            # the direct feeder's reads
+            # and the two each of the ingest run and the ack run, the
+            # direct feeder's reads, and the seven of the way out
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
@@ -574,8 +574,15 @@ class TestSysTopics:
                 "$SYS/broker/ingest/ack_runs",
                 "$SYS/broker/ingest/ack_run_acks",
                 "$SYS/broker/ingest/direct_reads",
+                "$SYS/broker/egress/deliveries_flush",
+                "$SYS/broker/egress/deliveries_cork",
+                "$SYS/broker/egress/deliveries_queue",
+                "$SYS/broker/egress/deliveries_dropped_full",
+                "$SYS/broker/egress/cork_writes",
+                "$SYS/broker/egress/cork_frames",
+                "$SYS/broker/egress/cork_early_writes",
             } <= base
-            assert len(base) == 28
+            assert len(base) == 35
             await h.shutdown()
 
         run(scenario())
