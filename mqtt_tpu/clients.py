@@ -557,6 +557,73 @@ class ClientConnection:
                 self.remote = f"{peer[0]}:{peer[1]}" if isinstance(peer, tuple) else str(peer)
 
 
+class SliceSocket:
+    """What a completion slice keeps of ONE socket whose cork it opened
+    (``server._cork_repeated``: a socket of this loop that the slice
+    hits more than once), from the slice's start to its end: what
+    fan-out would otherwise read again at every delivery.
+
+    The socket was ready when the record was made (open, no TLS, an
+    empty transport buffer, an empty outbound queue) and its session
+    asks for no rewrite of a shared frame (no outbound aliases, no size
+    cap): a delivery is then ONE append of its variant's frame to
+    ``cork``, in submit order. That holds only while the record's own
+    appends (and other packets that join the same cork) are all that
+    touched the socket, so whatever else puts a frame on the client's
+    way out or takes the socket away clears ``Client._slice``: an early
+    cork write (``_uncork``), the outbound queue's two ways in
+    (``server._enqueue_frame``, ``publish_to_client``), ``stop``. The
+    next delivery then reads the socket afresh, as before.
+
+    ``n``, ``nbytes``, ``payload``: the deliveries the record took, their
+    frames' bytes and their payloads' (the tenant's count). The io
+    counts a delivery moves (``info``, the connection's, telemetry's
+    outbound pair, the tenant's) are added once, from these, when the
+    slice ends (``settle``): the slice does not yield, and nothing
+    reads them from another thread. The counts a profiler's snapshot
+    does read off the loop (deliveries, by route; the cork's frames)
+    move once a publish and variant, with the publish's ``fanout_n``
+    (``server._flush_variant``)."""
+
+    __slots__ = ("cl", "cork", "version", "n", "nbytes", "payload")
+
+    def __init__(self, cl: "Client") -> None:
+        self.cl = cl
+        self.cork = cl._cork
+        self.version = cl.properties.protocol_version
+        self.n = 0
+        self.nbytes = 0
+        self.payload = 0
+
+    def take(self, frame: bytes, payload: int) -> None:
+        """One delivery: ``frame`` behind what the cork holds
+        (``payload``: its payload's bytes). Past the cork's byte bound
+        the cork is written early, at the same frame as ``Client._write``
+        would, and the record ends."""
+        cork = self.cork
+        cork += frame
+        self.n += 1
+        self.nbytes += len(frame)
+        self.payload += payload
+        if len(cork) >= CORK_MAX_BYTES:
+            self.cl._cut_cork()
+
+    def settle(self) -> None:
+        """Add the io counts of ``n`` shared-frame deliveries into a
+        cork, as ``Client.write_frame`` and the tenant's note add them
+        a frame at a time."""
+        n = self.n
+        if not n:
+            return
+        cl = self.cl
+        cl._count_sent(self.nbytes, n)
+        cl.ops.info.messages_sent += n
+        tenant = cl.tenant
+        if tenant is not None:
+            tenant.messages_out += n
+            tenant.bytes_out += self.payload
+
+
 class ClientProperties:
     """Properties defining client behaviour (clients.go:123-129)."""
 
@@ -667,6 +734,9 @@ class Client:
         # return to the event loop. A socket send is the dearest thing
         # the loop does (a syscall against a handful of bytecodes).
         self._cork: Optional[bytearray] = None
+        # the completion slice's record of this socket while it holds
+        # (SliceSocket), else None
+        self._slice: Optional[SliceSocket] = None
         # priority-weighted shedding (mqtt_tpu.overload): the class and
         # its shed/publish-quota multiplier, resolved at CONNECT from
         # Options.overload_priority_users / overload_priority_classes
@@ -749,19 +819,20 @@ class Client:
         self._write(bytes((0x40, 2, packet_id >> 8, packet_id & 0xFF)))
         self._count_sent(4)
 
-    def _count_sent(self, n: int) -> None:
-        """One packet of ``n`` bytes went to the transport (or its
-        cork): ``info``, the connection's own and telemetry's counts."""
+    def _count_sent(self, n: int, packets: int = 1) -> None:
+        """``packets`` packets of ``n`` bytes together went to the
+        transport (or its cork): ``info``, the connection's own and
+        telemetry's counts."""
         info = self.ops.info
         info.bytes_sent += n
-        info.packets_sent += 1
+        info.packets_sent += packets
         st = self.state
         st.out_bytes += n
-        st.out_writes += 1
+        st.out_writes += packets
         tele = getattr(self.ops, "telemetry", None)
         if tele is not None:
             tele.outbound_bytes.inc(n)
-            tele.outbound_writes.inc()
+            tele.outbound_writes.inc(packets)
 
     def _write(self, data: bytes) -> None:
         """One encoded packet to the transport, in order: behind the
@@ -775,9 +846,14 @@ class Client:
         cork += data
         self.ops.cork_frames += 1
         if len(cork) >= CORK_MAX_BYTES:
-            self.ops.cork_early_writes += 1
-            self._uncork()
-            self._cork = bytearray()
+            self._cut_cork()
+
+    def _cut_cork(self) -> None:
+        """The cork is past its byte bound: write it out early, and go
+        on corking."""
+        self.ops.cork_early_writes += 1
+        self._uncork()
+        self._cork = bytearray()
 
     def _uncork(self) -> None:
         """Write what this socket's cork holds, as one transport write,
@@ -785,8 +861,10 @@ class Client:
         completion slice) and for the teardown. Counted (``_Ops``): a
         cork written (``cork_writes``), beside the packets that joined
         one (``cork_frames``) and the writes its byte bound forced
-        (``cork_early_writes``)."""
+        (``cork_early_writes``). The slice's record of the socket ends
+        with its cork: the transport may hold bytes now."""
         cork, self._cork = self._cork, None
+        self._slice = None
         if cork and self.net.writer is not None:
             self.ops.cork_writes += 1
             self._send(bytes(cork))
@@ -1295,6 +1373,7 @@ class Client:
         if not self.state.open:
             return
         self.state.open = False
+        self._slice = None  # a slice in hand reads the socket afresh
         if err is not None:
             self.state.stop_cause = err
         loop = self.net.loop

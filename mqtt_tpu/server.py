@@ -26,6 +26,7 @@ from .clients import (
     Client,
     Clients,
     ConnectionClosedError,
+    SliceSocket,
     Will,
 )
 from .hooks import (
@@ -802,7 +803,7 @@ _ACK_RUN_EVENTS = (ON_PACKET_READ, ON_QOS_COMPLETE, ON_PACKET_PROCESSED)
 _EGRESS_COUNTERS = (
     "deliveries_flush", "deliveries_cork", "deliveries_queue",
     "deliveries_dropped_full", "cork_writes", "cork_frames",
-    "cork_early_writes",
+    "cork_early_writes", "socket_checks",
 )
 
 
@@ -880,6 +881,11 @@ class _Ops:
         self.cork_writes = 0
         self.cork_frames = 0
         self.cork_early_writes = 0
+        # times the encode-once fan-out read a socket's readiness
+        # (closed, TLS, the transport's buffer, the outbound queue):
+        # once a delivery in Server._flush_variant, once a slice for a
+        # socket the slice keeps a record of (clients.SliceSocket)
+        self.socket_checks = 0
 
 
 class Server:
@@ -1560,7 +1566,8 @@ class Server:
         the widest completion slice so far (a high-water mark, not a
         sum), the deliveries by the way they left (native flush, cork,
         outbound queue; and those the full queue refused), the corks
-        written with their packets and early writes, the matcher's wide
+        written with their packets and early writes, the readiness reads
+        of fan-out (``socket_checks``), the matcher's wide
         entries and the topics they answered,
         what the trie holds (``TopicsIndex``'s three counts), and what
         set-up's load cost, as values at the snapshot: the bulk loads'
@@ -1774,6 +1781,13 @@ class Server:
                 "cork_early_writes",
                 "Of the corks written, those a cork past its byte bound "
                 "forced before its opener closed it",
+            ),
+            (
+                "mqtt_tpu_socket_checks_total",
+                "socket_checks",
+                "Times the encode-once fan-out read a socket's readiness: "
+                "once a delivery, or once a completion slice for a socket "
+                "the slice corked",
             ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
@@ -3505,7 +3519,10 @@ class Server:
         its cork opened here, its deliveries join it in submit order
         (``_flush_variant``) and leave as ONE write when the slice ends,
         before this method returns and so before any yield to the event
-        loop. A socket targeted once is written at its own publish."""
+        loop. A socket targeted once is written at its own publish.
+        What a corked socket is, is read once too and kept for the slice
+        (``clients.SliceSocket``): its deliveries are appends, and the
+        counters they move are added when the slice ends."""
         hooks = self.hooks
         observed = hooks.provides(ON_PACKET_ENCODE, ON_PACKET_SENT)
         on_published = (
@@ -3543,7 +3560,7 @@ class Server:
             self._ops.slice_targets_max = len(ids)
         present = self.clients.present(ids)
         lookup = present.get
-        corked = self._cork_repeated(ids, present)
+        corked, records = self._cork_repeated(ids, present)
         frames0 = self._ops.cork_frames
         try:
             for entry, subs, targets in work:
@@ -3596,6 +3613,10 @@ class Server:
                     )
                     span.__enter__()
                     t_run = time.perf_counter_ns()
+                # the io counts of the records' deliveries: fan-out's
+                # time, as when each delivery added its own
+                for rec in records:
+                    rec.settle()
                 for cl in corked:
                     try:
                         cl._uncork()
@@ -3607,20 +3628,29 @@ class Server:
                     prof.note_slice_flush(time.perf_counter_ns() - t_run)
                     span.__exit__(None, None, None)
 
-    def _cork_repeated(self, ids: list, present: dict) -> list:
+    def _cork_repeated(self, ids: list, present: dict) -> tuple[list, list]:
         """Open the cork of every socket a completion slice targets more
         than once (``ids``: the slice's target ids in submit order,
         ``present``: those of them that are connected) and return the
         clients whose cork this call opened: the slice closes them. A
         cork that is open already (the slice runs inside that
         connection's read) stays its opener's; a socket another shard's
-        loop owns is written there, outside any slice."""
+        loop owns is written there, outside any slice.
+
+        Those are the sockets whose deliveries leave when the slice
+        ends, so what each IS is read here, once: one that is ready, in
+        a session that takes shared frames, gets the slice's record
+        (``clients.SliceSocket``, on ``Client._slice``), and
+        ``_flush_variant`` appends to its cork without asking again.
+        The records are returned beside the clients: the slice settles
+        their counts, also of those that ended early."""
         hits = list(filter(present.__contains__, ids))
         if len(hits) == len(present):
-            return []  # every socket once: each is written at its publish
+            return [], []  # every socket once: each is written at its publish
         fabric = self._fabric is not None
         seen: set = set()
         corked = []
+        records = []
         for cid in hits:
             if cid not in seen:
                 seen.add(cid)
@@ -3631,7 +3661,17 @@ class Server:
             ):
                 cl._cork = bytearray()
                 corked.append(cl)
-        return corked
+                if not self._session_shares_frames(cl.properties):
+                    continue  # its deliveries are rewritten one by one
+                self._ops.socket_checks += 1
+                try:
+                    ready = self._socket_ready(cl)
+                except Exception:  # a transport that cannot say
+                    ready = False
+                if ready:
+                    cl._slice = SliceSocket(cl)
+                    records.append(cl._slice)
+        return corked, records
 
     def _staged_error(self, cl: Client, err: BaseException, counted: bool) -> None:
         """One staged publish failed in its completion: what
@@ -3783,11 +3823,40 @@ class Server:
         reconnect with different properties under a live plan — that
         split is the ONE remaining site that must track rule changes by
         hand."""
-        ids = sub.identifiers
+        return Server._session_shares_frames(
+            props
+        ) and Server._subscription_shares_frames(sub)
+
+    @staticmethod
+    def _session_shares_frames(props: "ClientProperties") -> bool:
+        """The session's half of ``_shared_frame_ok``: fixed at CONNECT,
+        so a completion slice reads it once a socket
+        (``_cork_repeated``)."""
         return (
             props.props.topic_alias_maximum == 0
             and props.props.maximum_packet_size == 0
-            and not (ids and any(v > 0 for v in ids.values()))
+        )
+
+    @staticmethod
+    def _subscription_shares_frames(sub: Subscription) -> bool:
+        """The subscription's half of ``_shared_frame_ok``."""
+        ids = sub.identifiers
+        return not ids or max(ids.values()) <= 0
+
+    @staticmethod
+    def _socket_ready(cl: Client) -> bool:
+        """Whether a frame written to this socket now goes out in order
+        and at once: the connection is open (a session that outlives its
+        socket stays in the registry, its dead writer with it), nothing
+        waits in its outbound queue or in the transport's buffer, and no
+        TLS layer stands between."""
+        writer = cl.net.writer
+        return (
+            writer is not None
+            and cl.state.open
+            and cl.state.outbound_qty == 0
+            and writer.get_extra_info("sslcontext") is None
+            and writer.transport.get_write_buffer_size() == 0
         )
 
     def _stamp_outbound(self, tcl: Client) -> None:
@@ -3810,6 +3879,7 @@ class Server:
         ``count_delivery`` keeps $SYS housekeeping fan-out out of the
         amplification accounting (the caller knows the topic; the
         pre-encoded frame does not)."""
+        tcl._slice = None  # frames wait in the queue now: not ready
         try:
             tcl.state.outbound.put_nowait(data)
             tcl.state.outbound_full_since = None
@@ -4198,78 +4268,63 @@ class Server:
         path. Per-socket backpressure (bounded outbound queues), the
         slow-consumer eviction clock and every drop/overload counter
         behave exactly as the legacy path — only the encode count and
-        the GIL profile change."""
+        the GIL profile change.
+
+        A target whose socket the completion slice in hand keeps a
+        record of (``Client._slice``: corked by the slice and read once
+        when the slice began) has the session's half of
+        ``_shared_frame_ok`` and its protocol version on the record;
+        ``_flush_variant`` appends its frame to the cork without reading
+        the socket again."""
         clock = getattr(pk, "_tclock", None)
         topic = dpk.topic_name
         sys_topic = topic.startswith("$SYS")
-        tele = self.telemetry
-        amp_tele = None if sys_topic else tele
-        caps = self.options.capabilities
+        amp_tele = None if sys_topic else self.telemetry
         origin = dpk.origin
-        groups: dict[tuple, list] = {}
+        header = dpk.fixed_header
+        retained = header.retain
+        qos = header.qos
+        if qos and qos > self.options.capabilities.maximum_qos:
+            qos = self.options.capabilities.maximum_qos  # [MQTT-3.2.2-9]
+        subscription_ok = self._subscription_shares_frames
+        # a variant's key: version | effective QoS << 3 | retain << 5
+        groups: dict[int, list] = {}
         slow: list = []
         for cid, sub in items:
             cl = lookup(cid)
             if cl is None or (sub.no_local and cid == origin):
                 continue  # [MQTT-3.8.3-3]
-            props = cl.properties
-            if not self._shared_frame_ok(props, sub):
+            rec = cl._slice
+            if rec is None:
+                props = cl.properties
+                if not self._shared_frame_ok(props, sub):
+                    slow.append((cl, sub))
+                    continue
+                key = props.protocol_version
+            elif subscription_ok(sub):
+                key = rec.version
+            else:
                 slow.append((cl, sub))
                 continue
-            eff = dpk.fixed_header.qos
-            if eff > sub.qos:
-                eff = sub.qos
-            if eff > caps.maximum_qos:
-                eff = caps.maximum_qos  # [MQTT-3.2.2-9]
-            pv = props.protocol_version
-            retain = dpk.fixed_header.retain and (
+            if retained and (
                 sub.fwd_retained_flag
-                or (pv == 5 and sub.retain_as_published)
-            )  # [MQTT-3.3.1-12] / [MQTT-3.3.1-13]
-            groups.setdefault((pv, eff, bool(retain)), []).append((cl, sub))
+                or (key == 5 and sub.retain_as_published)
+            ):  # [MQTT-3.3.1-12] / [MQTT-3.3.1-13]
+                key |= 32
+            key |= (qos if qos <= sub.qos else sub.qos) << 3
+            groups.setdefault(key, []).append((cl, sub))
 
-        variants = []
-        for (pv, eff, retain), group in groups.items():
-            out = dpk.copy(False)
-            out.fixed_header.qos = eff
-            out.fixed_header.retain = retain
-            out.protocol_version = pv
-            if eff > 0:
-                # nonzero placeholder (the encoder rejects pid 0 on
-                # QoS>0); every target's real id is patched at flush
-                out.packet_id = 1
-            if out.expiry > 0:
-                # the send-time expiry rewrite [MQTT-3.3.2-6], once per
-                # variant instead of per subscriber
-                out.properties.message_expiry_interval = max(
-                    1, out.expiry - int(time.time())  # brokerlint: ok=R3 message expiry is an absolute wall-clock stamp
-                )
-            buf = get_buffer()
-            try:
-                pkts.ENCODERS[pkts.PUBLISH](out, buf)
-                data = bytes(buf)
-            finally:
-                put_buffer(buf)
-            if amp_tele is not None:
-                amp_tele.publish_encodes.inc()
-                amp_tele.fanout_variants.inc()
-            id_off = -1
-            if eff > 0:
-                # packet id sits right after the topic in the variable
-                # header (no aliasing in this path, so the topic is
-                # always present)
-                id_off = (
-                    publish_frame_body_offset(data)
-                    + 2
-                    + len(topic.encode("utf-8"))
-                )
-            variants.append((pv, eff, retain, data, id_off, group))
+        variants = [
+            (key, self._encode_variant(dpk, key, amp_tele), group)
+            for key, group in groups.items()
+        ]
         if clock is not None:
             clock.stamp("encode")
 
-        for pv, eff, retain, data, id_off, group in variants:
-            self._flush_variant(dpk, eff, retain, data, id_off, group,
-                                sys_topic)
+        for key, (data, id_off), group in variants:
+            self._flush_variant(
+                dpk, key >> 3 & 3, key >= 32, data, id_off, group, sys_topic
+            )
         for cl, sub in slow:
             try:
                 delivered = self._deliver_to_client(
@@ -4285,6 +4340,46 @@ class Server:
                     cl.tenant.bytes_out += len(dpk.payload)
         if clock is not None:
             clock.stamp("flush")
+
+    def _encode_variant(self, dpk: Packet, key: int, amp_tele) -> tuple:
+        """One variant of a publish, encoded once for all its targets:
+        ``(frame, offset of the packet id in it or -1)``. ``key`` as
+        ``_fan_out_batched`` makes it."""
+        eff = key >> 3 & 3
+        out = dpk.copy(False)
+        out.fixed_header.qos = eff
+        out.fixed_header.retain = key >= 32
+        out.protocol_version = key & 7
+        if eff > 0:
+            # nonzero placeholder (the encoder rejects pid 0 on
+            # QoS>0); every target's real id is patched at flush
+            out.packet_id = 1
+        if out.expiry > 0:
+            # the send-time expiry rewrite [MQTT-3.3.2-6], once per
+            # variant instead of per subscriber
+            out.properties.message_expiry_interval = max(
+                1, out.expiry - int(time.time())  # brokerlint: ok=R3 message expiry is an absolute wall-clock stamp
+            )
+        buf = get_buffer()
+        try:
+            pkts.ENCODERS[pkts.PUBLISH](out, buf)
+            data = bytes(buf)
+        finally:
+            put_buffer(buf)
+        if amp_tele is not None:
+            amp_tele.publish_encodes.inc()
+            amp_tele.fanout_variants.inc()
+        id_off = -1
+        if eff > 0:
+            # packet id sits right after the topic in the variable
+            # header (no aliasing in this path, so the topic is
+            # always present)
+            id_off = (
+                publish_frame_body_offset(data)
+                + 2
+                + len(dpk.topic_name.encode("utf-8"))
+            )
+        return data, id_off
 
     def _flush_variant(
         self,
@@ -4304,7 +4399,13 @@ class Server:
         (``Client._cork``: the completion slice in hand targets it again,
         or its own read is in hand) takes the frame into the cork, in
         order behind what it holds, and is written when the cork's
-        opener closes it. The three ways out are counted, a delivery
+        opener closes it. A socket the completion slice in hand keeps a
+        record of (``Client._slice``, ``clients.SliceSocket``: corked by
+        the slice and found ready then) is not read again: past the ACL
+        call and the QoS bookkeeping its delivery is one append to that
+        cork. Whatever else touches the socket inside the slice ends the
+        record, a hook of this very delivery too, and the socket is read
+        as any other. The three ways out are counted, a delivery
         each where it was accepted (``_Ops.deliveries_flush``, ``_cork``,
         ``_queue``), and the deliveries a full queue refused
         (``deliveries_dropped_full``).
@@ -4318,8 +4419,6 @@ class Server:
         the encode-once write path). ``call_soon_threadsafe`` preserves
         per-publisher FIFO into each shard, so one publisher's
         deliveries to one subscriber stay in order."""
-        from .native import fan_flush
-
         if self._fabric is not None:
             try:
                 here: Optional[asyncio.AbstractEventLoop] = (
@@ -4352,26 +4451,36 @@ class Server:
         if topic[:1] == NS_CHAR:
             topic = ns_local(topic)
         on_acl = self.hooks.on_acl_check
+        payload_len = len(dpk.payload)
         flush: list = []
-        n_cork = n_queue = n_full = 0
+        n_checks = n_cork = n_queue = n_full = n_rec = 0
         for cl, sub in group:
             try:
                 if not on_acl(cl, topic, False):
                     continue
-                if cl.closed or cl.net.writer is None:
+                # the slice's record of this socket, if it holds still:
+                # open and ready, read when the slice corked it
+                rec = cl._slice
+                if rec is None and (cl.closed or cl.net.writer is None):
                     continue
                 pid = 0
                 if eff > 0:
                     pid = self._begin_qos_delivery(cl, dpk, eff, retain)
                     if pid < 0:
                         continue  # quota-refused or parked for resend
-                writer = cl.net.writer
+                if rec is not None and cl._slice is rec:
+                    # ONE append, behind what the cork holds; the io
+                    # counts wait for the slice's end (SliceSocket.settle)
+                    rec.take(
+                        data if id_off < 0
+                        else self._patch_id(data, id_off, pid),
+                        payload_len,
+                    )
+                    n_rec += 1
+                    continue
                 fd = -1
-                if (
-                    cl.state.outbound_qty == 0
-                    and writer.get_extra_info("sslcontext") is None
-                    and writer.transport.get_write_buffer_size() == 0
-                ):
+                n_checks += 1
+                if self._socket_ready(cl):
                     if cl._cork is not None:
                         # an open cork (the slice targets this socket
                         # again, or its own read is in hand): the frame
@@ -4386,7 +4495,7 @@ class Server:
                             n_cork += 1
                             self._note_tenant_out(cl, dpk)
                         continue
-                    sock = writer.get_extra_info("socket")
+                    sock = cl.net.writer.get_extra_info("socket")
                     if sock is not None:
                         try:
                             fd = sock.fileno()
@@ -4416,11 +4525,21 @@ class Server:
                 continue
             self._note_tenant_out(cl, dpk)
         ops = self._ops
+        if n_rec:
+            # what a profiler's snapshot reads off the loop moves with
+            # the publish, as its fanout_n does
+            n_cork += n_rec
+            ops.cork_frames += n_rec
+            if count_delivery and self.telemetry is not None:
+                self.telemetry.fanout_deliveries.inc(n_rec)
+        ops.socket_checks += n_checks
         ops.deliveries_cork += n_cork
         ops.deliveries_queue += n_queue
         ops.deliveries_dropped_full += n_full
         if not flush:
             return
+        from .native import fan_flush
+
         prof = self.profiler
         armed = prof is not None and prof.armed
         if armed:
@@ -5026,6 +5145,7 @@ class Server:
         if cl.net.writer is None or cl.closed:
             raise CODE_DISCONNECT()
 
+        cl._slice = None  # frames wait in the queue now: not ready
         try:
             cl.state.outbound.put_nowait(out)
             cl.state.outbound_full_since = None

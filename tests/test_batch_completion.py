@@ -1025,10 +1025,10 @@ class SliceRig:
     flush's fds, a transport write's bytes) and of every publish's end
     (``on_published``), in the order they happened."""
 
-    def __init__(self, monkeypatch, options=None):
+    def __init__(self, monkeypatch, options=None, allow=True):
         import mqtt_tpu.native as native
 
-        self.h = Harness(options)
+        self.h = Harness(options, allow)
         self.server = self.h.server
         self.log = []
         self.fd_owner = {}
@@ -1051,13 +1051,32 @@ class SliceRig:
 
         monkeypatch.setattr(native, "fan_flush", flush_spy)
 
-    async def join(self, client_id, *filters, version=4, qos=0, props=None):
-        """Connect, subscribe, and spy on the server side's transport
-        writes. ``filters``: a filter, or (filter, subscription id)."""
-        r, w, task = await self.h.connect(client_id, version=version)
+    async def join(
+        self, client_id, *filters, version=4, qos=0, props=None, clean=True
+    ):
+        """Connect (``props``: the CONNECT's properties), subscribe,
+        and spy on the server side's transport writes. ``filters``: a
+        filter, (filter, subscription id), or a Subscription."""
+        if props is None:
+            r, w, task = await self.h.connect(
+                client_id, version=version, clean=clean
+            )
+        else:
+            r, w, task = await self.h.attach()
+            cp = Packet(
+                fixed_header=FixedHeader(type=1), protocol_version=version,
+                properties=props,
+            )
+            cp.connect.protocol_name = b"MQTT"
+            cp.connect.clean = True
+            cp.connect.keepalive = 30
+            cp.connect.client_identifier = client_id
+            w.write(encode_packet(cp))
+            assert (await read_wire_packet(r, version)).fixed_header.type == CONNACK
         for n, flt in enumerate(filters):
             sub = (
-                Subscription(filter=flt[0], qos=qos, identifier=flt[1])
+                flt if isinstance(flt, Subscription)
+                else Subscription(filter=flt[0], qos=qos, identifier=flt[1])
                 if isinstance(flt, tuple)
                 else Subscription(filter=flt, qos=qos)
             )
@@ -1093,12 +1112,12 @@ class SliceRig:
 
     def slice_of(self, origin, publishes, qos=0):
         """One slice as the stage hands it over: ``(topic, payload)``
-        publishes of ``origin`` in submit order, each with the host
-        trie's answer."""
+        or ``(topic, payload, qos)`` publishes of ``origin`` in submit
+        order, each with the host trie's answer."""
         entries, results = [], []
-        for topic, payload in publishes:
+        for topic, payload, *own in publishes:
             pk = Packet(
-                fixed_header=FixedHeader(type=PUBLISH, qos=qos),
+                fixed_header=FixedHeader(type=PUBLISH, qos=own[0] if own else qos),
                 protocol_version=4, topic_name=topic, payload=payload,
                 origin=origin.id,
             )
@@ -1220,20 +1239,9 @@ class TestSliceWrites:
             if slow == "identifier":
                 sub, r, w, _ = await rig.join("sub", "a/#", ("b/#", 7), version=5)
             else:
-                r, w, _ = await rig.h.attach()
-                cp = Packet(
-                    fixed_header=FixedHeader(type=1), protocol_version=5,
-                    properties=Properties(topic_alias_maximum=8),
+                sub, r, w, _ = await rig.join(
+                    "sub", "+/#", version=5, props=Properties(topic_alias_maximum=8)
                 )
-                cp.connect.protocol_name = b"MQTT"
-                cp.connect.clean = True
-                cp.connect.keepalive = 30
-                cp.connect.client_identifier = "sub"
-                w.write(encode_packet(cp))
-                assert (await read_wire_packet(r, 5)).fixed_header.type == CONNACK
-                w.write(sub_packet(1, [Subscription(filter="+/#")], version=5))
-                assert (await read_wire_packet(r, 5)).fixed_header.type == SUBACK
-                sub = rig.server.clients.get("sub")
             pubs = [("a/1", b"1"), ("a/2", b"2"), ("b/1", b"3"), ("a/3", b"4")]
             rig.run_slice(pub, pubs)
             assert sub._cork is None
@@ -1482,6 +1490,412 @@ class TestSliceWrites:
                 assert rig.writes(cid) == [b"".join(frames(want))]
                 for _t, p in want:
                     assert bytes((await read_wire_packet(r)).payload) == p
+            await rig.h.shutdown()
+
+        run(scenario())
+
+
+# -- what a socket is, kept for the slice (clients.SliceSocket) --------------
+
+
+class AclGate(Hook):
+    """Allows everything but the ``(client id, topic)`` pairs in
+    ``denied``; ``during`` is called inside every subscriber-side check."""
+
+    def __init__(self, denied=()):
+        super().__init__()
+        self.denied = set(denied)
+        self.during = None
+
+    def id(self):
+        return "acl-gate"
+
+    def provides(self, b):
+        from mqtt_tpu.hooks import ON_ACL_CHECK, ON_CONNECT_AUTHENTICATE
+
+        return b in (ON_CONNECT_AUTHENTICATE, ON_ACL_CHECK)
+
+    def on_connect_authenticate(self, cl, pk):
+        return True
+
+    def on_acl_check(self, cl, topic, write):
+        if not write and self.during is not None:
+            self.during(cl, topic)
+        return (cl.id, topic) not in self.denied
+
+
+def counts(srv, clients):
+    """Every count a delivery moves, as it stands."""
+    from mqtt_tpu.server import _EGRESS_COUNTERS
+
+    ops, info = srv._ops, srv.info
+    c = {k: getattr(ops, k) for k in _EGRESS_COUNTERS + ("socket_sends",)}
+    c.update(
+        bytes_sent=info.bytes_sent, packets_sent=info.packets_sent,
+        messages_sent=info.messages_sent, inflight=info.inflight,
+        dropped=info.messages_dropped,
+        deliveries=srv.telemetry.fanout_deliveries.value,
+        outbound_bytes=srv.telemetry.outbound_bytes.value,
+        outbound_writes=srv.telemetry.outbound_writes.value,
+    )
+    for cl in clients:
+        c["out:" + cl.id] = (cl.state.out_bytes, cl.state.out_writes)
+        c["quota:" + cl.id] = cl.state.inflight.send_quota
+        c["ids:" + cl.id] = sorted(
+            (p.packet_id, bytes(p.payload)) for p in cl.state.inflight.get_all(False)
+        )
+    return c
+
+
+async def drained(srv, readers):
+    """Everything the sockets were sent, a socket: the outbound queues
+    first (their write loops need the event loop), then each socket
+    until it is quiet."""
+    for _ in range(200):
+        if not any(cl.state.outbound_qty for cl in srv.clients.get_all().values()):
+            break
+        await asyncio.sleep(0.005)
+
+    async def one(reader):
+        got = b""
+        while True:
+            try:
+                chunk = await asyncio.wait_for(reader.read(1 << 16), 0.05)
+            except asyncio.TimeoutError:
+                return got
+            if not chunk:
+                return got
+            got += chunk
+
+    return dict(zip(readers, await asyncio.gather(*map(one, readers.values()))))
+
+
+def delta(after, before):
+    """What moved between two ``counts``; the in-flight ids as they are."""
+    moved = {}
+    for k, v in after.items():
+        if isinstance(v, tuple):
+            moved[k] = tuple(x - y for x, y in zip(v, before[k]))
+        else:
+            moved[k] = v if isinstance(v, list) else v - before[k]
+    return moved
+
+
+async def served_mix(monkeypatch, seed, mode):
+    """One seeded mix of subscribers and publishes through a broker of
+    its own. ``mode``: ``slice`` (the publishes as ONE completion slice),
+    ``single`` (as one slice a publish, nothing between them), ``read``
+    (one slice, no socket given a record: every delivery reads its
+    socket, as before ISSUE 38). Returns the bytes each socket got, in
+    order, and the counts the publishes moved."""
+    from mqtt_tpu.packets import Properties
+
+    rng = random.Random(seed)
+    topics = ["m/a/x", "m/b/x", "m/a/y", "m/c/x", "q/none"]
+    gate = AclGate({(rng.choice(["a4", "c5", "e1"]), rng.choice(topics[:4]))})
+    rig = SliceRig(monkeypatch, allow=False)
+    srv = rig.server
+    srv.add_hook(gate)
+    if mode == "read":
+        # the slice corks as ever and keeps no record
+        monkeypatch.setattr(srv, "_session_shares_frames", lambda props: False)
+    q = lambda: rng.randrange(2)  # noqa: E731
+    roster = [
+        ("pub", 5, None, [Subscription(filter="m/#", qos=q(), no_local=True)]),
+        ("nl", 5, None, [Subscription(filter="m/#", qos=q(), no_local=True)]),
+        ("a4", 4, None, [Subscription(filter="m/#", qos=q())]),
+        ("b4", 4, None, [Subscription(filter="m/+/x", qos=1)]),
+        ("c5", 5, None, [Subscription(filter="m/#", qos=q())]),
+        # a plain subscription and one with an identifier, one socket
+        ("d5", 5, None, [
+            Subscription(filter="m/a/#", qos=q()),
+            Subscription(filter="m/b/#", qos=q(), identifier=7),
+        ]),
+        ("al", 5, Properties(topic_alias_maximum=8), [Subscription(filter="m/#", qos=q())]),
+        ("mx", 5, Properties(maximum_packet_size=4096), [Subscription(filter="m/+/x", qos=q())]),
+    ] + [
+        (f"e{k}", 4 + k % 2, None, [Subscription(filter=topics[k], qos=q())])
+        for k in range(4)
+    ]
+    clients, readers = [], {}
+    for cid, version, props, filters in roster:
+        cl, r, _w, _t = await rig.join(cid, *filters, version=version, props=props)
+        clients.append(cl)
+        readers[cid] = r
+    pubs = [
+        (rng.choice(topics), b"%03d" % i + bytes(rng.randrange(40)), rng.randrange(2))
+        for i in range(rng.randrange(24, 64))
+    ]
+    before = counts(srv, clients)
+    if mode == "single":
+        for one in pubs:
+            rig.run_slice(clients[0], [one])
+    else:
+        rig.run_slice(clients[0], pubs)
+    assert all(cl._cork is None and cl._slice is None for cl in clients)
+    got = await drained(srv, readers)
+    moved = delta(counts(srv, clients), before)
+    await rig.h.shutdown()
+    return got, moved
+
+
+class TestASliceReadsASocketOnce:
+    """ISSUE 38: a completion slice keeps a record of each ready socket
+    it corked, and a delivery to it is one append. Nothing a socket
+    receives and nothing that is counted may differ for it."""
+
+    @pytest.mark.parametrize("seed", [38, 3801, 380017, 2**31 + 38, 4099, 77])
+    def test_a_slice_of_n_equals_n_slices_of_one(self, monkeypatch, seed):
+        """The same publishes as one slice, as a slice each, and as one
+        slice that reads every socket at every delivery: every socket
+        gets the same bytes in the same order, and the counts agree
+        (between the first two all but the route, which is the point: a
+        socket hit once a slice is written at its publish)."""
+
+        async def scenario():
+            sliced, n_sliced = await served_mix(monkeypatch, seed, "slice")
+            single, n_single = await served_mix(monkeypatch, seed, "single")
+            read, n_read = await served_mix(monkeypatch, seed, "read")
+            assert sliced == single == read
+            assert sum(map(len, sliced.values())) > 2000
+            # against the slice that reads a socket a delivery: every
+            # count but the reads themselves
+            checks = n_sliced.pop("socket_checks"), n_read.pop("socket_checks")
+            assert n_sliced == n_read
+            assert checks[0] < checks[1]
+            # against a slice a publish: nothing is corked there, so
+            # the routes and the writes differ and nothing else
+            routes = ("deliveries_flush", "deliveries_cork", "deliveries_queue")
+            assert sum(n_sliced[k] for k in routes) == sum(n_single[k] for k in routes)
+            assert n_single["cork_writes"] == n_single["deliveries_cork"] == 0
+            assert n_sliced["cork_frames"] == n_sliced["deliveries_cork"] > 0
+            aside = routes + (
+                "cork_writes", "cork_frames", "cork_early_writes", "socket_sends",
+            )
+            for k in aside + ("socket_checks",):
+                n_sliced.pop(k, None), n_single.pop(k, None)
+            assert n_sliced == n_single
+
+        run(scenario())
+
+    def test_one_read_a_socket_a_slice(self, monkeypatch):
+        """``socket_checks``: one for 64 publishes to one socket, N for
+        N sockets hit once."""
+
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            ops = rig.server._ops
+            pub, *_ = await rig.join("pub")
+            sub, *_ = await rig.join("sub", "t/#")
+            for k in range(5):
+                await rig.join(f"s{k}", "one/shot")
+            at, wrote = ops.socket_checks, sub.state.out_writes
+            rig.run_slice(pub, [(f"t/{i}", b"%d" % i) for i in range(64)])
+            assert ops.socket_checks - at == 1
+            assert ops.deliveries_cork == 64 == sub.state.out_writes - wrote
+            at = ops.socket_checks
+            rig.run_slice(pub, [("one/shot", b"x")])
+            assert ops.socket_checks - at == 5 and ops.deliveries_flush == 5
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    @pytest.mark.parametrize(
+        "case",
+        ["byte_cap", "closed_by_a_hook", "closed_in_its_own_acl_call",
+         "queue_not_empty", "slow_path_between", "queued_in_its_own_acl_call"],
+    )
+    def test_what_ends_a_record_early(self, monkeypatch, case):
+        """Whatever else puts a frame on the socket's way out, or takes
+        the socket away, ends the record: the next delivery reads the
+        socket afresh. ``other``, beside it, always gets the whole
+        slice as one write."""
+        import mqtt_tpu.clients as clients_mod
+
+        async def scenario():
+            from mqtt_tpu.packets import Properties
+
+            if case == "byte_cap":
+                monkeypatch.setattr(clients_mod, "CORK_MAX_BYTES", 300)
+            gate = AclGate()
+            rig = SliceRig(monkeypatch, allow=False)
+            srv, ops = rig.server, rig.server._ops
+            srv.add_hook(gate)
+            pub, *_ = await rig.join("pub")
+            filters = ("t/#", ("s/#", 9)) if case == "slow_path_between" else ("t/#",)
+            sub, sub_r, *_ = await rig.join("sub", *filters, version=5)
+            other, other_r, *_ = await rig.join("other", "+/#")
+            readers = {"sub": sub_r, "other": other_r}
+            pubs = [(f"t/{i}", b"%02d" % i * 16) for i in range(12)]
+            ahead = b""
+            want = [p for _t, p in pubs]
+            if case == "closed_by_a_hook":
+                rig.after_publish = lambda p: p == pubs[4][1] and sub.stop()
+                want = want[:5]
+            elif case == "closed_in_its_own_acl_call":
+                gate.during = lambda cl, t: (cl, t) == (sub, "t/5") and sub.stop()
+                want = want[:5]
+            elif case == "queue_not_empty":
+                ahead = pub_packet("was/queued", b"first", version=5)
+                assert srv._enqueue_frame(sub, ahead, lambda: None)
+            elif case == "slow_path_between":
+                pubs[5] = ("s/5", pubs[5][1])
+            elif case == "queued_in_its_own_acl_call":
+                ahead = pub_packet("from/the/hook", b"hook", version=5)
+                gate.during = lambda cl, t: (cl, t) == (sub, "t/5") and (
+                    srv._enqueue_frame(sub, ahead, lambda: None)
+                )
+            before = counts(srv, [sub, other])
+            rig.run_slice(pub, pubs)
+            assert sub._slice is None and other._slice is None
+            assert b"".join(rig.writes("other")) == b"".join(frames(pubs))
+            assert case == "byte_cap" or len(rig.writes("other")) == 1
+            got = await drained(srv, readers)
+            n = delta(counts(srv, [sub, other]), before)
+            sent = b"".join(frames([(t, p) for t, p in pubs if p in want], 5))
+            if case == "slow_path_between":
+                sent = sent.replace(
+                    pub_packet("s/5", pubs[5][1], version=5),
+                    encode_packet(Packet(
+                        fixed_header=FixedHeader(type=PUBLISH), protocol_version=5,
+                        topic_name="s/5", payload=pubs[5][1],
+                        properties=Properties(subscription_identifier=[9]),
+                    )),
+                )
+            if case == "queue_not_empty":
+                assert got["sub"] == ahead + sent  # everything behind it
+                # one read as the slice corks it, one a delivery; one for other
+                assert n["deliveries_queue"] == 12 and n["socket_checks"] == 1 + 12 + 1
+            elif case == "queued_in_its_own_acl_call":
+                cut = len(b"".join(frames(pubs[:5], 5)))
+                assert got["sub"] == sent[:cut] + ahead + sent[cut:]
+                # five into the cork, seven behind the hook's frame
+                assert (n["deliveries_cork"], n["deliveries_queue"]) == (12 + 5, 7)
+            else:
+                assert got["sub"] == sent
+            if case == "byte_cap":
+                size = len(frames(pubs[:1], 5)[0])
+                early = len(rig.writes("sub")) - 1
+                assert early >= 1
+                assert n["cork_early_writes"] == early + len(rig.writes("other")) - 1
+                assert all(300 <= len(w) < 300 + size for w in rig.writes("sub")[:-1])
+                # one read each record, then one a delivery
+                assert 2 < n["socket_checks"] < 2 * 12
+            elif case.startswith("closed"):
+                # the five before went out as the socket closed; the
+                # seven after were dropped, as a closed socket's are
+                assert n["out:sub"] == (len(sent), 5)
+                assert n["deliveries_cork"] == 12 + 5 and n["dropped"] == 0
+            elif case == "slow_path_between":
+                # five into the cork, the sixth and all behind it queued
+                assert (n["deliveries_cork"], n["deliveries_queue"]) == (12 + 5, 6)
+                assert n["out:sub"] == (len(sent), 12)
+            assert n["out:other"] == (len(b"".join(frames(pubs))), 12)
+            assert n["messages_sent"] == n["out:sub"][1] + 12
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    @pytest.mark.parametrize(
+        "case",
+        ["offline_session_qos0", "offline_session_qos1",
+         "closed_in_its_own_acl_call", "queued_in_its_own_acl_call"],
+    )
+    def test_a_socket_that_is_gone_costs_what_it_did(self, monkeypatch, case, caplog):
+        """A session that outlives its socket stays in the registry, its
+        dead writer with it: hit twice a slice it is corked as ever, but
+        is no socket to keep a record of, and a delivery to it ends at
+        ``closed``, before any QoS bookkeeping. So does the delivery
+        whose own ACL call closes the socket. Against the same slice
+        with no records (every delivery reads its socket): the same
+        bytes, in-flight ids, send quota and counts, and no warning."""
+        import logging
+
+        qos = 0 if case.endswith("qos0") else 1
+
+        async def served(records):
+            gate = AclGate()
+            rig = SliceRig(monkeypatch, allow=False)
+            srv = rig.server
+            srv.add_hook(gate)
+            if not records:
+                monkeypatch.setattr(srv, "_session_shares_frames", lambda props: False)
+            pub, *_ = await rig.join("pub")
+            sub, sub_r, sub_w, sub_task = await rig.join(
+                "sub", "t/#", qos=1, clean=False
+            )
+            live, live_r, *_ = await rig.join("live", "t/#", qos=1)
+            readers = {"live": live_r}
+            if case.startswith("offline"):
+                sub_w.close()
+                await asyncio.wait_for(sub_task, TIMEOUT)
+                assert sub.closed and sub.net.writer is not None
+                assert srv.clients.get("sub") is sub  # the session stays
+            else:
+                readers["sub"] = sub_r
+                if case.startswith("closed"):
+                    gate.during = lambda cl, t: (cl, t) == (sub, "t/5") and sub.stop()
+                else:
+                    ahead = pub_packet("from/the/hook", b"hook")
+                    gate.during = lambda cl, t: (cl, t) == (sub, "t/5") and (
+                        srv._enqueue_frame(sub, ahead, lambda: None)
+                    )
+            pubs = [(f"t/{i}", b"%02d" % i) for i in range(12)]
+            before = counts(srv, [sub, live])
+            with caplog.at_level(logging.WARNING):
+                caplog.clear()
+                rig.run_slice(pub, pubs, qos=qos)
+                assert caplog.records == []
+            assert sub._slice is None and sub._cork is None
+            if case.startswith("offline"):
+                assert rig.writes("sub") == []  # nothing into a dead transport
+            got = await drained(srv, readers)
+            moved = delta(counts(srv, [sub, live]), before)
+            await rig.h.shutdown()
+            return got, moved
+
+        async def scenario():
+            kept, n_kept = await served(True)
+            read, n_read = await served(False)
+            assert kept == read and len(kept["live"]) > 12 * 6
+            checks = n_kept.pop("socket_checks"), n_read.pop("socket_checks")
+            assert n_kept == n_read
+            if case.startswith("offline"):
+                # one read a corked socket, against one a delivery to live
+                assert checks == (2, 12)
+                assert n_kept["ids:sub"] == [] and n_kept["quota:sub"] == 0
+                assert n_kept["out:sub"] == (0, 0)
+                assert n_kept["deliveries_cork"] == 12 == n_kept["messages_sent"]
+            elif case.startswith("closed"):
+                # five booked and sent, the sixth stopped at `closed`
+                assert len(n_kept["ids:sub"]) == 5 and n_kept["inflight"] == 5 + 12
+
+        run(scenario())
+
+    def test_another_shards_record_is_left_to_its_loop(self, monkeypatch):
+        """Under the shard fabric a socket's record belongs to the slice
+        of the loop that owns the socket: a fan-out on another loop goes
+        on marshalling its delivery there."""
+
+        async def scenario():
+            from mqtt_tpu.clients import SliceSocket
+
+            rig = SliceRig(monkeypatch)
+            srv = rig.server
+            pub, *_ = await rig.join("pub")
+            sub, *_ = await rig.join("sub", "t/#")
+            elsewhere = asyncio.new_event_loop()
+            try:
+                monkeypatch.setattr(srv, "_fabric", object())
+                sub.net.loop = elsewhere  # another shard's socket ...
+                sub._cork = bytearray()
+                rec = sub._slice = SliceSocket(sub)  # ... in its own slice
+                rig.run_slice(pub, [("t/1", b"a")])
+                assert rec.n == 0 and not rec.cork and sub._slice is rec
+                assert len(elsewhere._ready) == 1  # marshalled, as ever
+            finally:
+                sub._cork = sub._slice = None
+                elsewhere.close()
             await rig.h.shutdown()
 
         run(scenario())

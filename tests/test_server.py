@@ -581,8 +581,9 @@ class TestSysTopics:
                 "$SYS/broker/egress/cork_writes",
                 "$SYS/broker/egress/cork_frames",
                 "$SYS/broker/egress/cork_early_writes",
+                "$SYS/broker/egress/socket_checks",
             } <= base
-            assert len(base) == 35
+            assert len(base) == 36
             await h.shutdown()
 
         run(scenario())

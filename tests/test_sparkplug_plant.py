@@ -44,6 +44,7 @@ PARAMS, REHEARSE = CONFIG["params"], CONFIG["rehearse_params"]
 ROUTES = ("deliveries_flush", "deliveries_cork", "deliveries_queue")
 EGRESS = ROUTES + (
     "deliveries_dropped_full", "cork_writes", "cork_frames", "cork_early_writes",
+    "socket_checks",
 )
 
 
