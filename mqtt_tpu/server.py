@@ -805,6 +805,9 @@ _EGRESS_COUNTERS = (
     "deliveries_dropped_full", "cork_writes", "cork_frames",
     "cork_early_writes", "socket_checks",
 )
+# ``_Ops``' counts of shared-group selection (``Server._select_shared``),
+# as the slice snapshots and ``$SYS/broker/fanout/*`` carry them
+_SHARE_COUNTERS = ("share_groups", "share_picks_offline", "share_select_ns")
 
 
 class _Ops:
@@ -886,6 +889,15 @@ class _Ops:
         # once a delivery in Server._flush_variant, once a slice for a
         # socket the slice keeps a record of (clients.SliceSocket)
         self.socket_checks = 0
+        # shared-group selection (Server._select_shared): the groups
+        # selected, the picked members with no connected client, and,
+        # while a profiler session is live, the leg's time: the forced
+        # materialization of a lazy result that holds groups, the hooks'
+        # pick, select_shared and merge_shared_selected. Plain adds on
+        # the fan-out's loop.
+        self.share_groups = 0
+        self.share_picks_offline = 0
+        self.share_select_ns = 0
 
 
 class Server:
@@ -1567,7 +1579,9 @@ class Server:
         sum), the deliveries by the way they left (native flush, cork,
         outbound queue; and those the full queue refused), the corks
         written with their packets and early writes, the readiness reads
-        of fan-out (``socket_checks``), the matcher's wide
+        of fan-out (``socket_checks``), shared-group selection (groups,
+        offline picks, the leg's time) and the lazy results forced into
+        dicts (the C view module's count), the matcher's wide
         entries and the topics they answered,
         what the trie holds (``TopicsIndex``'s three counts), and what
         set-up's load cost, as values at the snapshot: the bulk loads'
@@ -1587,6 +1601,8 @@ class Server:
             "ack_run_acks": self._ops.ack_run_acks,
             "slice_targets_max": self._ops.slice_targets_max,
             **{key: getattr(self._ops, key) for key in _EGRESS_COUNTERS},
+            **{key: getattr(self._ops, key) for key in _SHARE_COUNTERS},
+            "view_materializations": self._view_materializations(),
             "wide_entries": getattr(stats, "wide_entries", 0),
             "wide_topics": getattr(stats, "wide_topics", 0),
             "particles": trie.particles,
@@ -1789,6 +1805,18 @@ class Server:
                 "once a delivery, or once a completion slice for a socket "
                 "the slice corked",
             ),
+            (
+                "mqtt_tpu_share_groups_total",
+                "share_groups",
+                "Shared subscription groups a publish's fan-out selected "
+                "a member of",
+            ),
+            (
+                "mqtt_tpu_share_picks_offline_total",
+                "share_picks_offline",
+                "Selected shared-group members with no connected client "
+                "(an offline session was chosen: nothing is written)",
+            ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
         r.gauge(
@@ -1813,6 +1841,12 @@ class Server:
             "Lazy fan-out views forced into materialized Subscribers "
             "dicts (the C view module's own count)",
             fn=self._view_materializations,
+        )
+        r.counter(
+            "mqtt_tpu_share_select_seconds_total",
+            "Time of shared-group selection while a profiler session was "
+            "live: forced materialization, the hooks' pick, select and merge",
+            fn=lambda: self._ops.share_select_ns / 1e9,
         )
         r.counter(
             "mqtt_tpu_staging_compact_overflow_total",
@@ -3549,6 +3583,11 @@ class Server:
                 if lazy and not subs.has_shared and not subs.has_inline:
                     targets = subs.targets()
                     ids += [cid for cid, _ in targets]
+                elif prof is not None and subs.has_shared:
+                    # the selection leg's first part (``_select_shared``)
+                    t_run = time.perf_counter_ns()
+                    subs = subs.materialize()
+                    self._ops.share_select_ns += time.perf_counter_ns() - t_run
                 else:
                     subs = subs.materialize()
             if targets is None and not isinstance(subs, BaseException):
@@ -4100,6 +4139,38 @@ class Server:
         self._stamp_publish_expiry(pk)
         return pk
 
+    def _select_shared(self, subscribers, pk: Packet, lookup):
+        """One member a shared group (server.go:1005-1012): the hooks'
+        pick, else ``Subscribers.select_shared``, folded into the plain
+        set. Counts the groups and the picked members that ``lookup``
+        finds no connected client for (``_Ops.share_groups``,
+        ``share_picks_offline``); while a profiler session is live the
+        leg is timed into ``share_select_ns`` (``_complete_staged`` adds
+        the forced materialization that precedes it) inside an
+        ``mqtt/share.select`` annotation."""
+        ops = self._ops
+        prof = self.profiler
+        span = None
+        if prof is not None and prof.armed:
+            span = prof.annotation(
+                "mqtt/share.select", groups=len(subscribers.shared)
+            )
+            span.__enter__()
+            t_run = time.perf_counter_ns()
+        subscribers = self.hooks.on_select_subscribers(subscribers, pk)
+        if not subscribers.shared_selected:
+            subscribers.select_shared()
+        subscribers.merge_shared_selected()
+        if span is not None:
+            ops.share_select_ns += time.perf_counter_ns() - t_run
+            span.__exit__(None, None, None)
+        ops.share_groups += len(subscribers.shared)
+        for client in subscribers.shared_selected:
+            cl = lookup(client)
+            if cl is None or cl.closed:
+                ops.share_picks_offline += 1
+        return subscribers
+
     def _fan_out(
         self, pk: Packet, subscribers, feats=None, rjob=None,
         lookup=None, targets=None, observed=None,
@@ -4170,12 +4241,7 @@ class Server:
                     subscribers, bytes(pk.payload), feats
                 )
             if subscribers.shared:
-                subscribers = self.hooks.on_select_subscribers(
-                    subscribers, pk
-                )
-                if not subscribers.shared_selected:
-                    subscribers.select_shared()
-                subscribers.merge_shared_selected()
+                subscribers = self._select_shared(subscribers, pk, lookup)
 
         # tenant namespace: deliveries carry the tenant-LOCAL topic
         # (clients never see the scope prefix); the scoped pk itself
@@ -5765,6 +5831,14 @@ class Server:
             topics[SYS_PREFIX + "/broker/egress/" + key] = str(
                 getattr(self._ops, key)
             )
+        # shared-group selection, and the lazy results forced into dicts
+        for key in _SHARE_COUNTERS:
+            topics[SYS_PREFIX + "/broker/fanout/" + key] = str(
+                getattr(self._ops, key)
+            )
+        topics[SYS_PREFIX + "/broker/fanout/view_materializations"] = str(
+            self._view_materializations()
+        )
         if self.matcher is not None:
             # device-matcher observability (MatcherStats.as_dict): batches,
             # topics, host_fallbacks, overflows, rebuilds, fallback_ratio

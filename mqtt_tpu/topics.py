@@ -440,9 +440,16 @@ class Subscribers:
 
     def select_shared(self) -> None:
         """Pick one subscriber per shared group. The reference picks the
-        first map-iteration entry (nondeterministic in Go, insertion-ordered
-        here); selection stays host-side and pluggable via the
-        on_select_subscribers hook."""
+        first map-iteration entry (nondeterministic in Go); here it is the
+        group's first member in insertion order: the member that
+        subscribed earliest and still holds the row. A SUBSCRIBE that
+        replaces a member's row keeps its place; a clean reconnect of a
+        session drops its rows and its SUBSCRIBE adds them again at the
+        group's end. Nothing here asks whether the member is connected, so
+        an offline session can be chosen while another member is online:
+        the group's copy then reaches no socket (``Server._select_shared``
+        counts such picks as ``share_picks_offline``). Selection stays
+        host-side and pluggable via the on_select_subscribers hook."""
         self.shared_selected = {}
         for subs in self.shared.values():
             for client, sub in subs.items():

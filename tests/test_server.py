@@ -564,7 +564,8 @@ class TestSysTopics:
             }
             # the 20 of the reference's tree, the trie's three counts
             # and the two each of the ingest run and the ack run, the
-            # direct feeder's reads, and the seven of the way out
+            # direct feeder's reads, the eight of the way out, and the
+            # four of shared-group selection
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
@@ -582,8 +583,12 @@ class TestSysTopics:
                 "$SYS/broker/egress/cork_frames",
                 "$SYS/broker/egress/cork_early_writes",
                 "$SYS/broker/egress/socket_checks",
+                "$SYS/broker/fanout/share_groups",
+                "$SYS/broker/fanout/share_picks_offline",
+                "$SYS/broker/fanout/share_select_ns",
+                "$SYS/broker/fanout/view_materializations",
             } <= base
-            assert len(base) == 36
+            assert len(base) == 40
             await h.shutdown()
 
         run(scenario())
