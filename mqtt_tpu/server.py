@@ -38,6 +38,7 @@ from .hooks import (
     ON_PUBLISHED,
     ON_QOS_COMPLETE,
     ON_QOS_PUBLISH,
+    ON_SELECT_SUBSCRIBERS,
     STORED_CLIENTS,
     STORED_INFLIGHT_MESSAGES,
     STORED_RETAINED_MESSAGES,
@@ -807,7 +808,10 @@ _EGRESS_COUNTERS = (
 )
 # ``_Ops``' counts of shared-group selection (``Server._select_shared``),
 # as the slice snapshots and ``$SYS/broker/fanout/*`` carry them
-_SHARE_COUNTERS = ("share_groups", "share_picks_offline", "share_select_ns")
+_SHARE_COUNTERS = (
+    "share_groups", "share_picks_offline", "share_select_ns",
+    "share_groups_early",
+)
 
 
 class _Ops:
@@ -893,11 +897,14 @@ class _Ops:
         # selected, the picked members with no connected client, and,
         # while a profiler session is live, the leg's time: the forced
         # materialization of a lazy result that holds groups, the hooks'
-        # pick, select_shared and merge_shared_selected. Plain adds on
-        # the fan-out's loop.
+        # pick, select_shared and merge_shared_selected. Of the groups,
+        # those a completion slice picked in its prologue, before it
+        # read its targets (Server._complete_staged). Plain adds on the
+        # fan-out's loop.
         self.share_groups = 0
         self.share_picks_offline = 0
         self.share_select_ns = 0
+        self.share_groups_early = 0
 
 
 class Server:
@@ -1580,7 +1587,8 @@ class Server:
         outbound queue; and those the full queue refused), the corks
         written with their packets and early writes, the readiness reads
         of fan-out (``socket_checks``), shared-group selection (groups,
-        offline picks, the leg's time) and the lazy results forced into
+        offline picks, the leg's time, the groups picked in a slice's
+        prologue) and the lazy results forced into
         dicts (the C view module's count), the matcher's wide
         entries and the topics they answered,
         what the trie holds (``TopicsIndex``'s three counts), and what
@@ -1816,6 +1824,12 @@ class Server:
                 "share_picks_offline",
                 "Selected shared-group members with no connected client "
                 "(an offline session was chosen: nothing is written)",
+            ),
+            (
+                "mqtt_tpu_share_groups_early_total",
+                "share_groups_early",
+                "Shared subscription groups a completion slice picked a "
+                "member of before it read its targets",
             ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
@@ -3556,7 +3570,18 @@ class Server:
         loop. A socket targeted once is written at its own publish.
         What a corked socket is, is read once too and kept for the slice
         (``clients.SliceSocket``): its deliveries are appends, and the
-        counters they move are added when the slice ends."""
+        counters they move are added when the slice ends.
+
+        A result that holds ``$share`` groups has each group's member
+        picked here, before the slice reads its targets, when nothing can
+        make the pick depend on the publish's own turn: no hook provides
+        ON_SELECT_SUBSCRIBERS and no predicate rule is live (rules drop
+        failing members before selection). The slice's targets are then
+        the members it writes to, not every candidate, and only those are
+        probed and corked; the work item's flag tells ``_fan_out`` not to
+        pick again. Otherwise every candidate is a target and the pick
+        waits for the publish's own turn in ``_fan_out``, in submit
+        order."""
         hooks = self.hooks
         observed = hooks.provides(ON_PACKET_ENCODE, ON_PACKET_SENT)
         on_published = (
@@ -3574,11 +3599,15 @@ class Server:
         # the slice's fan-out plans and, from them, its target clients
         vcls = _view_class()
         eng = self._predicates
-        lazy = vcls is not None and (eng is None or not eng.active)
+        unfiltered = eng is None or not eng.active
+        lazy = vcls is not None and unfiltered
+        pick_early = unfiltered and not hooks.provides(ON_SELECT_SUBSCRIBERS)
+        select_shared = self._select_shared
         ids: list = []
         work = []
         for entry, subs in zip(entries, results):
             targets = None
+            early = False
             if type(subs) is vcls:
                 if lazy and not subs.has_shared and not subs.has_inline:
                     targets = subs.targets()
@@ -3591,10 +3620,14 @@ class Server:
                 else:
                     subs = subs.materialize()
             if targets is None and not isinstance(subs, BaseException):
-                ids += subs.subscriptions
-                for group in subs.shared.values():
-                    ids += group  # every candidate, before selection
-            work.append((entry, subs, targets))
+                if pick_early and subs.shared:
+                    subs = select_shared(subs, entry.pk, hooked=False)
+                    early = True
+                ids += subs.subscriptions  # with the picked members
+                if not early:
+                    for group in subs.shared.values():
+                        ids += group  # every candidate, before selection
+            work.append((entry, subs, targets, early))
         if len(ids) > self._ops.slice_targets_max:
             self._ops.slice_targets_max = len(ids)
         present = self.clients.present(ids)
@@ -3602,7 +3635,7 @@ class Server:
         corked, records = self._cork_repeated(ids, present)
         frames0 = self._ops.cork_frames
         try:
-            for entry, subs, targets in work:
+            for entry, subs, targets, early in work:
                 cl, pk = entry.cl, entry.pk
                 err: Optional[BaseException] = None
                 try:
@@ -3612,7 +3645,7 @@ class Server:
                         t_run = time.perf_counter_ns()
                     fan_out(
                         pk, subs, entry.feats, entry.rjob,
-                        lookup, targets, observed,
+                        lookup, targets, observed, early,
                     )
                     if prof is not None:
                         prof.note_fanout(
@@ -4139,16 +4172,17 @@ class Server:
         self._stamp_publish_expiry(pk)
         return pk
 
-    def _select_shared(self, subscribers, pk: Packet, lookup):
+    def _select_shared(self, subscribers, pk: Packet, hooked: bool = True):
         """One member a shared group (server.go:1005-1012): the hooks'
         pick, else ``Subscribers.select_shared``, folded into the plain
-        set. Counts the groups and the picked members that ``lookup``
-        finds no connected client for (``_Ops.share_groups``,
-        ``share_picks_offline``); while a profiler session is live the
-        leg is timed into ``share_select_ns`` (``_complete_staged`` adds
-        the forced materialization that precedes it) inside an
-        ``mqtt/share.select`` annotation."""
-        ops = self._ops
+        set. ``hooked``: ask the ON_SELECT_SUBSCRIBERS hooks first; a
+        completion slice that knows none provides it picks in its
+        prologue without them (``_complete_staged``), and ``_fan_out``
+        picks, hooks first, at the publish's own turn otherwise. While a
+        profiler session is live the pick is timed into
+        ``share_select_ns`` (``_complete_staged`` adds the forced
+        materialization that precedes it) inside an ``mqtt/share.select``
+        annotation. ``_count_shared`` counts what was picked."""
         prof = self.profiler
         span = None
         if prof is not None and prof.armed:
@@ -4157,23 +4191,34 @@ class Server:
             )
             span.__enter__()
             t_run = time.perf_counter_ns()
-        subscribers = self.hooks.on_select_subscribers(subscribers, pk)
+        if hooked:
+            subscribers = self.hooks.on_select_subscribers(subscribers, pk)
         if not subscribers.shared_selected:
             subscribers.select_shared()
         subscribers.merge_shared_selected()
         if span is not None:
-            ops.share_select_ns += time.perf_counter_ns() - t_run
+            self._ops.share_select_ns += time.perf_counter_ns() - t_run
             span.__exit__(None, None, None)
-        ops.share_groups += len(subscribers.shared)
+        return subscribers
+
+    def _count_shared(self, subscribers, lookup, early: bool) -> None:
+        """Count one publish's picked groups (``_Ops.share_groups``; also
+        ``share_groups_early`` where the slice's prologue picked them)
+        and the picked members that ``lookup`` finds no connected client
+        for (``share_picks_offline``)."""
+        ops = self._ops
+        groups = len(subscribers.shared)
+        ops.share_groups += groups
+        if early:
+            ops.share_groups_early += groups
         for client in subscribers.shared_selected:
             cl = lookup(client)
             if cl is None or cl.closed:
                 ops.share_picks_offline += 1
-        return subscribers
 
     def _fan_out(
         self, pk: Packet, subscribers, feats=None, rjob=None,
-        lookup=None, targets=None, observed=None,
+        lookup=None, targets=None, observed=None, selected=False,
     ) -> None:
         """Deliver one matched publish: shared-group selection, inline
         handlers, per-subscriber delivery (server.go:1000-1021).
@@ -4184,7 +4229,12 @@ class Server:
         ``self.clients.get``, one lock pair a call); ``targets``, the
         lazy view's plan where it has already built it; ``observed``,
         whether a hook provides ON_PACKET_ENCODE / ON_PACKET_SENT
-        (default: asked here).
+        (default: asked here); ``selected``, the slice picked this
+        result's shared groups in its prologue (no selecting hook, no
+        live predicate rule), so they are counted here and not picked
+        again. Otherwise the groups are picked here, at the publish's own
+        turn: after the predicate rules, by the hooks, else by the
+        first-member rule.
 
         Which path serves, decided by what the code sees, never by an
         option: the encode-once batched flush (``_fan_out_batched``)
@@ -4241,7 +4291,9 @@ class Server:
                     subscribers, bytes(pk.payload), feats
                 )
             if subscribers.shared:
-                subscribers = self._select_shared(subscribers, pk, lookup)
+                if not selected:
+                    subscribers = self._select_shared(subscribers, pk)
+                self._count_shared(subscribers, lookup, selected)
 
         # tenant namespace: deliveries carry the tenant-LOCAL topic
         # (clients never see the scope prefix); the scoped pk itself

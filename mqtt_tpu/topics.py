@@ -449,7 +449,12 @@ class Subscribers:
         an offline session can be chosen while another member is online:
         the group's copy then reaches no socket (``Server._select_shared``
         counts such picks as ``share_picks_offline``). Selection stays
-        host-side and pluggable via the on_select_subscribers hook."""
+        host-side and pluggable via the on_select_subscribers hook. A
+        completion slice runs it in its prologue, before it reads its
+        target sockets, where no hook provides that event and no
+        predicate rule is live (``Server._complete_staged``); with either,
+        it waits for the publish's own turn in ``Server._fan_out``, after
+        the rules have dropped failing members and as the hooks decide."""
         self.shared_selected = {}
         for subs in self.shared.values():
             for client, sub in subs.items():

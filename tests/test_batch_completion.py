@@ -15,7 +15,12 @@ import time
 import pytest
 
 from mqtt_tpu import Options, staging
-from mqtt_tpu.hooks import ON_PACKET_PROCESSED, ON_PUBLISHED, Hook
+from mqtt_tpu.hooks import (
+    ON_PACKET_PROCESSED,
+    ON_PUBLISHED,
+    ON_SELECT_SUBSCRIBERS,
+    Hook,
+)
 from mqtt_tpu.packets import (
     CONNACK,
     ERR_UNSPECIFIED_ERROR,
@@ -1896,6 +1901,243 @@ class TestASliceReadsASocketOnce:
             finally:
                 sub._cork = sub._slice = None
                 elsewhere.close()
+            await rig.h.shutdown()
+
+        run(scenario())
+
+
+class Selector(Hook):
+    """Provides ON_SELECT_SUBSCRIBERS: logs each call's payload on
+    ``calls`` and returns its input (``pick`` None) or its input with each
+    group's member chosen by ``pick(members)``."""
+
+    def __init__(self, calls, pick=None):
+        super().__init__()
+        self.calls, self.pick = calls, pick
+
+    def id(self):
+        return "selector"
+
+    def provides(self, b):
+        return b == ON_SELECT_SUBSCRIBERS
+
+    def on_select_subscribers(self, subs, pk):
+        self.calls.append(("select", bytes(pk.payload)))
+        if self.pick is not None:
+            for members in subs.shared.values():
+                cid = self.pick(list(members))
+                subs.shared_selected[cid] = members[cid]
+        return subs
+
+
+def share_counts(srv):
+    ops = srv._ops
+    return {
+        k: getattr(ops, k)
+        for k in ("share_groups", "share_picks_offline", "share_groups_early",
+                  "socket_checks")
+    }
+
+
+async def served_shares(monkeypatch, seed, hooked):
+    """One seeded mix of ``$share`` groups and plain subscribers served
+    as ONE completion slice, in a broker of its own, with no selecting
+    hook (``hooked`` False: the slice picks in its prologue) or with one
+    that returns its input (the pick waits for each publish's turn).
+    Live groups; groups whose first member is an offline session; two
+    groups on one filter; a plain subscriber who is also a member.
+    Returns the bytes each socket got, in order, what the share counters
+    moved, and the selector's calls."""
+    rng = random.Random(seed)
+    rig = SliceRig(monkeypatch)
+    srv = rig.server
+    calls: list = []
+    if hooked:
+        srv.add_hook(Selector(calls))
+    q = lambda: rng.randrange(2)  # noqa: E731
+    # offline first members: sessions that subscribed and are not connected
+    staging.bulk_register(srv.topics, [
+        ("gone-b", Subscription(filter="$share/gb/s/+/x", qos=1)),
+        ("gone-d", Subscription(filter="$share/gd/s/#", qos=0)),
+    ])
+    roster = [
+        ("pub", []),
+        ("p", [Subscription(filter="$share/ga/s/#", qos=q()),
+               Subscription(filter="s/a/#", qos=q())]),
+        ("a1", [Subscription(filter="$share/ga/s/#", qos=q())]),
+        ("a2", [Subscription(filter="$share/ga/s/#", qos=q())]),
+        ("b1", [Subscription(filter="$share/gb/s/+/x", qos=q())]),
+        ("b2", [Subscription(filter="$share/gb/s/+/x", qos=q())]),
+        # two groups on one filter
+        ("c1", [Subscription(filter="$share/gc1/s/a/x", qos=q())]),
+        ("c2", [Subscription(filter="$share/gc2/s/a/x", qos=q())]),
+        ("c3", [Subscription(filter="$share/gc1/s/a/x", qos=q())]),
+        ("d1", [Subscription(filter="$share/gd/s/#", qos=q())]),
+        ("w", [Subscription(filter="s/+/x", qos=q())]),
+    ]
+    readers, clients = {}, []
+    for cid, filters in roster:
+        cl, r, _w, _t = await rig.join(cid, *filters)
+        readers[cid] = r
+        clients.append(cl)
+    topics = ["s/a/x", "s/b/x", "s/a/y", "s/c", "n/none"]
+    pubs = [
+        (rng.choice(topics), b"%03d" % i + bytes(rng.randrange(20)), rng.randrange(2))
+        for i in range(rng.randrange(24, 48))
+    ]
+    before = share_counts(srv)
+    rig.run_slice(clients[0], pubs)
+    assert all(cl._cork is None and cl._slice is None for cl in clients)
+    got = await drained(srv, readers)
+    moved = delta(share_counts(srv), before)
+    await rig.h.shutdown()
+    return got, moved, calls, pubs
+
+
+class TestTheSlicePicksGroupsFirst:
+    """A completion slice with no selecting hook and no live predicate
+    rule picks each ``$share`` group's member before it reads its
+    targets, so it probes and corks the member it writes to and not the
+    group's other candidates. With a selecting hook or live rules the
+    pick waits for each publish's own turn, as before."""
+
+    def test_one_group_named_three_times_corks_only_its_chosen_member(
+        self, monkeypatch
+    ):
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            srv = rig.server
+            pub, *_ = await rig.join("pub")
+            members = []
+            for k in range(16):
+                cl, r, *_ = await rig.join(f"m{k:02d}", "$share/g/t/#", qos=1)
+                members.append((cl, r))
+            chosen, rest = members[0][0], [cl for cl, _r in members[1:]]
+            seen = []
+
+            def after(payload):
+                seen.append((
+                    chosen._cork is not None and chosen._slice is not None,
+                    [(cl._cork, cl._slice) for cl in rest],
+                ))
+
+            rig.after_publish = after
+            before = share_counts(srv)
+            pubs = [("t/a", b"1"), ("t/b", b"2"), ("t/c", b"3")]
+            log = rig.run_slice(pub, pubs, qos=1)
+            moved = delta(share_counts(srv), before)
+            # the one chosen socket, named three times, is read once
+            assert moved["socket_checks"] == 1
+            assert seen == [(True, [(None, None)] * 15)] * 3
+            assert len(rig.writes("m00")) == 1
+            for cl in rest:
+                assert rig.writes(cl.id) == []
+            assert [e for e in log if e[0] == "flush"] == []
+            assert moved["share_groups"] == moved["share_groups_early"] == 3
+            assert moved["share_picks_offline"] == 0
+            got = [await read_wire_packet(members[0][1]) for _ in pubs]
+            assert [bytes(p.payload) for p in got] == [b"1", b"2", b"3"]
+            others = await drained(srv, {cl.id: r for cl, r in members[1:]})
+            assert set(others.values()) == {b""}
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    @pytest.mark.parametrize("seed", [41, 4101, 410017, 2**31 + 41])
+    def test_picked_early_equals_picked_at_each_publish(self, monkeypatch, seed):
+        """The same slice with no selecting hook and behind one that
+        returns its input: every socket gets the same bytes in the same
+        order, and the same groups and offline picks are counted; only
+        the first picked early, and it read fewer sockets."""
+
+        async def scenario():
+            early, n_early, calls_early, pubs = await served_shares(
+                monkeypatch, seed, False
+            )
+            late, n_late, calls_late, _ = await served_shares(
+                monkeypatch, seed, True
+            )
+            assert early == late
+            assert sum(map(len, early.values())) > 500
+            # the first member in insertion order serves its group: an
+            # offline session's group reaches no socket
+            assert all(early[c] for c in ("p", "c1", "c2", "w"))
+            assert not any(early[c] for c in ("a1", "a2", "b1", "b2", "c3", "d1"))
+            for key in ("share_groups", "share_picks_offline"):
+                assert n_early[key] == n_late[key], key
+            assert n_early["share_picks_offline"] > 0
+            assert n_early["share_groups_early"] == n_early["share_groups"] > len(pubs)
+            assert n_late["share_groups_early"] == 0
+            assert n_early["socket_checks"] < n_late["socket_checks"]
+            assert calls_early == []
+            # the hook was asked once a publish that holds a group
+            assert [p for _k, p in calls_late] == [
+                p for t, p, _q in pubs if t != "n/none"
+            ]
+
+        run(scenario())
+
+    def test_a_hook_that_picks_the_last_member_is_asked_at_each_turn(
+        self, monkeypatch
+    ):
+        async def scenario():
+            rig = SliceRig(monkeypatch)
+            srv = rig.server
+            srv.add_hook(Selector(rig.log, pick=lambda ids: ids[-1]))
+            pub, *_ = await rig.join("pub")
+            readers = {}
+            for k in range(4):
+                _cl, r, *_ = await rig.join(f"m{k}", "$share/g/t/#", qos=1)
+                readers[f"m{k}"] = r
+            before = share_counts(srv)
+            pubs = [("t/a", b"1"), ("t/b", b"2"), ("t/c", b"3"), ("t/d", b"4")]
+            log = rig.run_slice(pub, pubs, qos=1)
+            moved = delta(share_counts(srv), before)
+            # asked at each publish's own turn, in submit order
+            assert [e for e in log if e[0] in ("select", "published")] == [
+                (kind, p) for _t, p in pubs for kind in ("select", "published")
+            ]
+            got = [await read_wire_packet(readers["m3"]) for _ in pubs]
+            assert [bytes(p.payload) for p in got] == [p for _t, p in pubs]
+            del readers["m3"]
+            assert set((await drained(srv, readers)).values()) == {b""}
+            assert moved["share_groups"] == 4 and moved["share_groups_early"] == 0
+            await rig.h.shutdown()
+
+        run(scenario())
+
+    def test_a_live_rule_that_fails_the_first_member_serves_the_next(
+        self, monkeypatch
+    ):
+        async def scenario():
+            import json
+
+            rig = SliceRig(monkeypatch)
+            srv = rig.server
+            pub, *_ = await rig.join("pub")
+            readers = {}
+            for k, flt in enumerate(
+                ["$share/g/t$GT{v:5.0}", "$share/g/t", "$share/g/t"]
+            ):
+                _cl, r, *_ = await rig.join(f"m{k}", flt)
+                readers[f"m{k}"] = r
+            assert srv._predicates is not None and srv._predicates.active
+            before = share_counts(srv)
+            pubs = [("t", b'{"v": 1.0}'), ("t", b'{"v": 7.0}'), ("t", b'{"v": 2.0}')]
+            rig.run_slice(pub, pubs)
+            moved = delta(share_counts(srv), before)
+            got = await drained(srv, readers)
+            pkts = {}
+            for cid, raw in got.items():
+                r = asyncio.StreamReader()
+                r.feed_data(raw)
+                r.feed_eof()
+                pkts[cid] = [
+                    json.loads(bytes((await read_wire_packet(r)).payload))["v"]
+                    for _ in range(raw.count(b'{"v"'))
+                ]
+            assert pkts == {"m0": [7.0], "m1": [1.0, 2.0], "m2": []}
+            assert moved["share_groups"] == 3 and moved["share_groups_early"] == 0
             await rig.h.shutdown()
 
         run(scenario())

@@ -565,7 +565,7 @@ class TestSysTopics:
             # the 20 of the reference's tree, the trie's three counts
             # and the two each of the ingest run and the ack run, the
             # direct feeder's reads, the eight of the way out, and the
-            # four of shared-group selection
+            # five of shared-group selection
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
@@ -586,9 +586,10 @@ class TestSysTopics:
                 "$SYS/broker/fanout/share_groups",
                 "$SYS/broker/fanout/share_picks_offline",
                 "$SYS/broker/fanout/share_select_ns",
+                "$SYS/broker/fanout/share_groups_early",
                 "$SYS/broker/fanout/view_materializations",
             } <= base
-            assert len(base) == 40
+            assert len(base) == 41
             await h.shutdown()
 
         run(scenario())

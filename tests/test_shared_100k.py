@@ -6,7 +6,8 @@ against the plain reference (``benchmark/reference.py``), two groups on one
 filter as one wide entry, the served path over live sockets held to "one
 member a group", and the counters of group selection
 (``share_groups``, ``share_picks_offline``, ``share_select_ns``,
-``view_materializations``). Answers and counts, never a rate."""
+``share_groups_early``, ``view_materializations``). Answers and counts,
+never a rate."""
 
 import asyncio
 import collections
@@ -46,7 +47,7 @@ PARAMS, REHEARSE = CONFIG["params"], CONFIG["rehearse_params"]
 # the served path's size: 8 inner filters for 24 groups, so every live
 # group shares its filter with offline ones (a wide entry each)
 SERVED = {**REHEARSE, "groups": 24, "vocabulary": 2, "live_groups": 2, "publishers": 2}
-COUNTERS = ("share_groups", "share_picks_offline", "share_select_ns")
+COUNTERS = ("share_groups", "share_picks_offline", "share_select_ns", "share_groups_early")
 
 
 def answer_of(result) -> tuple:
@@ -416,11 +417,14 @@ class TestTheCounters:
         ``share_picks_offline`` by those with no live member (the first
         member in insertion order is then an offline session); the
         timing stays 0 with no profiler session live; a result that holds
-        groups is forced into dicts once (the C view module's count)."""
+        groups is forced into dicts once (the C view module's count).
+        With no selecting hook and no predicate rule every group is
+        picked in its completion slice's prologue."""
         d = served["delta"]
         hot = live_groups_of(served)
         groups = [f for _q, _t, gs in served["due"].values() for f, _m in gs]
         assert d["share_groups"] == len(groups) > len(served["sent"])
+        assert d["share_groups_early"] == d["share_groups"]
         assert d["share_picks_offline"] == sum(f not in hot for f in groups) > 0
         assert d["share_select_ns"] == 0
         assert d["topics"] == len(served["sent"]) and d["dropped"] == 0
@@ -429,7 +433,7 @@ class TestTheCounters:
 
     def test_metrics_and_sys_carry_the_counters(self, served):
         after, text, tree = served["after"], served["metrics"], served["sys_topics"]
-        for key in ("share_groups", "share_picks_offline"):
+        for key in ("share_groups", "share_picks_offline", "share_groups_early"):
             assert f"mqtt_tpu_{key}_total {after[key]}" in text, key
         assert "mqtt_tpu_share_select_seconds_total 0" in text
         assert (
