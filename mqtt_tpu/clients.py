@@ -943,22 +943,16 @@ class Client:
 
     def next_packet_id(self) -> int:
         """The next unused packet id; raises ERR_QUOTA_EXCEEDED when all ids
-        are inflight (clients.go:274-299)."""
-        i = self.state.packet_id
-        started = i
-        overflowed = False
-        maximum = self.ops.options.capabilities.maximum_packet_id
-        while True:
-            if overflowed and i == started:
-                raise ERR_QUOTA_EXCEEDED()
-            if i >= maximum:
-                overflowed = True
-                i = 0
-                continue
-            i += 1
-            if self.state.inflight.get(i & 0xFFFF) is None:
-                self.state.packet_id = i
-                return i
+        are inflight (clients.go:274-299). The walk is the inflight map's
+        own (``Inflight.next_id``, which ``Inflight.begin`` runs too)."""
+        st = self.state
+        i = st.inflight.next_id(
+            st.packet_id, self.ops.options.capabilities.maximum_packet_id
+        )
+        if i < 0:
+            raise ERR_QUOTA_EXCEEDED()
+        st.packet_id = i
+        return i
 
     def resend_inflight_messages(self, force: bool) -> None:
         """Resend pending inflight messages with DUP [MQTT-3.3.1-1/-3]

@@ -11,6 +11,12 @@ from typing import Optional
 from .packets import Packet
 from .utils.locked import InstrumentedLock
 
+# what Inflight.begin answers in place of a packet id (1..65535): an
+# entry was stored exactly where the answer is BEGIN_PARKED or above
+BEGIN_PARKED = -1  # stored, but the send quota is spent: nothing is written
+BEGIN_FULL = -2  # the session holds maximum_inflight entries: nothing stored
+BEGIN_EXHAUSTED = -3  # every packet id is in flight: nothing stored
+
 
 class Inflight:
     """Inflight packets keyed on packet id, with send/receive quota counters
@@ -42,6 +48,61 @@ class Inflight:
                     new += 1
                 self.internal[m.packet_id] = m
             return new
+
+    def _free_id(self, start: int, maximum: int) -> int:
+        """The packet-id walk (clients.go:274-299), the lock held by the
+        caller: the first id after ``start`` whose low 16 bits are not in
+        flight, wrapping to 1 past ``maximum``; -1 once the walk comes
+        round to ``start``."""
+        internal = self.internal
+        i = start
+        overflowed = False
+        while True:
+            if overflowed and i == start:
+                return -1
+            if i >= maximum:
+                overflowed = True
+                i = 0
+                continue
+            i += 1
+            if internal.get(i & 0xFFFF) is None:
+                return i
+
+    def next_id(self, start: int, maximum: int) -> int:
+        """:meth:`_free_id` under one acquisition of the lock."""
+        with self._lock:
+            return self._free_id(start, maximum)
+
+    def begin(self, state, pk: Packet, cap: int, maximum: int) -> int:
+        """One outbound QoS>0 delivery's bookkeeping under ONE acquisition
+        of the lock, in the order the reference takes it: the cap on
+        entries (``cap``: maximum_inflight), a packet id from the walk
+        of :meth:`_free_id` starting at ``state.packet_id`` (the
+        session's cursor, moved to the id), ``pk`` stored under it with
+        ``pk.packet_id`` set, and the send quota spent. The entry is
+        always new: the walk found its id free under the same hold.
+
+        Returns the packet id, or BEGIN_FULL / BEGIN_EXHAUSTED with
+        nothing stored, or BEGIN_PARKED where the quota was already 0
+        under a positive maximum: the entry waits for quota, and the
+        caller marks it for resend (``expiry = -1``) once its
+        ``on_qos_publish`` hooks have seen it."""
+        with self._lock:
+            internal = self.internal
+            if len(internal) >= cap:
+                return BEGIN_FULL
+            i = self._free_id(state.packet_id, maximum)
+            if i < 0:
+                return BEGIN_EXHAUSTED
+            state.packet_id = i
+            pid = pk.packet_id = i & 0xFFFF  # [MQTT-2.2.1-4]
+            internal[pid] = pk  # [MQTT-4.3.2-3] [MQTT-4.3.3-3]
+            quota = self.send_quota
+            if quota > 0:
+                self.send_quota = quota - 1
+            elif quota == 0 and self.maximum_send_quota > 0:
+                return BEGIN_PARKED
+            return pid
 
     def get(self, id_: int) -> Optional[Packet]:
         with self._lock:

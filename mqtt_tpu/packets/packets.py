@@ -211,6 +211,32 @@ class Subscription:
 # A SUBSCRIBE/UNSUBSCRIBE packet's ordered filter list.
 Subscriptions = list  # list[Subscription]; a list to retain order (packets.go:169)
 
+
+def _copy_connect(c: ConnectParams, allow_transfer: bool) -> ConnectParams:
+    """``Packet.copy``'s copy of the connect parameters."""
+    pc = ConnectParams(
+        client_identifier=c.client_identifier,
+        keepalive=c.keepalive,
+        will_qos=c.will_qos,
+        will_topic=c.will_topic,
+        will_flag=c.will_flag,
+        will_retain=c.will_retain,
+        will_properties=c.will_properties.copy(allow_transfer),
+        clean=c.clean,
+    )
+    if c.protocol_name:
+        pc.protocol_name = bytes(c.protocol_name)
+    if c.password:
+        pc.password_flag = True
+        pc.password = bytes(c.password)
+    if c.username:
+        pc.username_flag = True
+        pc.username = bytes(c.username)
+    if c.will_payload:
+        pc.will_payload = bytes(c.will_payload)
+    return pc
+
+
 # Packet's fields with a default factory, for ``Packet.__getattr__``
 _MADE_ON_FIRST_TOUCH = {
     "connect": ConnectParams,
@@ -302,27 +328,7 @@ class Packet:
         p.reserved_bit = self.reserved_bit
         p.protocol_version = self.protocol_version
         if "connect" in d:
-            c = self.connect
-            pc = p.connect = ConnectParams(
-                client_identifier=c.client_identifier,
-                keepalive=c.keepalive,
-                will_qos=c.will_qos,
-                will_topic=c.will_topic,
-                will_flag=c.will_flag,
-                will_retain=c.will_retain,
-                will_properties=c.will_properties.copy(allow_transfer),
-                clean=c.clean,
-            )
-            if c.protocol_name:
-                pc.protocol_name = bytes(c.protocol_name)
-            if c.password:
-                pc.password_flag = True
-                pc.password = bytes(c.password)
-            if c.username:
-                pc.username_flag = True
-                pc.username = bytes(c.username)
-            if c.will_payload:
-                pc.will_payload = bytes(c.will_payload)
+            p.connect = _copy_connect(self.connect, allow_transfer)
         p.topic_name = self.topic_name
         if "properties" in d:
             p.properties = self.properties.copy(allow_transfer)
@@ -339,6 +345,32 @@ class Packet:
             p.payload = bytes(self.payload)
         if self.reason_codes:
             p.reason_codes = bytes(self.reason_codes)
+        return p
+
+    def clone(self) -> "Packet":
+        """One delivery's own packet, made from a prototype that
+        ``copy(False)`` made and the caller then set up (an encode-once
+        variant's inflight entry: ``Server._flush_variant``). Every field
+        is the prototype's; the clone has its own ``FixedHeader`` (a
+        resend sets DUP on it) and its own copy of whichever of ``connect``,
+        ``properties`` and ``mods`` the prototype holds, as ``copy`` makes
+        them. Topic, payload and reason codes are immutable and shared,
+        as are the filters, which ``copy`` shares too. Equal, field for
+        field, to ``copy(False)`` of the prototype's source given the
+        same writes, at two dict copies."""
+        d = self.__dict__
+        p = Packet.__new__(Packet)
+        pd = d.copy()
+        fh = FixedHeader.__new__(FixedHeader)
+        fh.__dict__ = d["fixed_header"].__dict__.copy()
+        pd["fixed_header"] = fh
+        p.__dict__ = pd
+        if "properties" in d:
+            pd["properties"] = self.properties.copy(False)
+        if "mods" in d:
+            pd["mods"] = Mods(max_size=self.mods.max_size)
+        if "connect" in d:
+            pd["connect"] = _copy_connect(self.connect, False)
         return p
 
     def format_id(self) -> str:

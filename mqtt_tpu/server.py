@@ -48,6 +48,7 @@ from .hooks import (
     HookOptions,
     Hooks,
 )
+from .inflight import BEGIN_FULL, BEGIN_PARKED
 from .listeners import (
     TYPE_HEALTHCHECK,
     TYPE_MOCK,
@@ -812,6 +813,11 @@ _SHARE_COUNTERS = (
     "share_groups", "share_picks_offline", "share_select_ns",
     "share_groups_early",
 )
+# ``_Ops``' counts of the encode-once fan-out's QoS>0 bookkeeping
+# (``Server._flush_variant``), carried as the share counts are
+_QOS_COUNTERS = ("qos_begins", "qos_begins_hooked", "qos_begin_ns")
+# a hook that provides this is shown each new outbound inflight entry
+_QOS_PUBLISH_EVENTS = (ON_QOS_PUBLISH,)
 
 
 class _Ops:
@@ -905,6 +911,14 @@ class _Ops:
         self.share_picks_offline = 0
         self.share_select_ns = 0
         self.share_groups_early = 0
+        # the encode-once fan-out's QoS>0 deliveries (Server._flush_variant):
+        # those that reached the inflight bookkeeping, those of them that
+        # dispatched on_qos_publish (a hook provides it), and, while a
+        # profiler session is live, the bookkeeping's time (the entry's
+        # clone, Inflight.begin, the hooks). Plain adds, once a variant.
+        self.qos_begins = 0
+        self.qos_begins_hooked = 0
+        self.qos_begin_ns = 0
 
 
 class Server:
@@ -1588,7 +1602,9 @@ class Server:
         written with their packets and early writes, the readiness reads
         of fan-out (``socket_checks``), shared-group selection (groups,
         offline picks, the leg's time, the groups picked in a slice's
-        prologue) and the lazy results forced into
+        prologue), the QoS>0 bookkeeping of the encode-once fan-out
+        (begins, those that dispatched on_qos_publish, their time) and
+        the lazy results forced into
         dicts (the C view module's count), the matcher's wide
         entries and the topics they answered,
         what the trie holds (``TopicsIndex``'s three counts), and what
@@ -1610,6 +1626,7 @@ class Server:
             "slice_targets_max": self._ops.slice_targets_max,
             **{key: getattr(self._ops, key) for key in _EGRESS_COUNTERS},
             **{key: getattr(self._ops, key) for key in _SHARE_COUNTERS},
+            **{key: getattr(self._ops, key) for key in _QOS_COUNTERS},
             "view_materializations": self._view_materializations(),
             "wide_entries": getattr(stats, "wide_entries", 0),
             "wide_topics": getattr(stats, "wide_topics", 0),
@@ -1831,6 +1848,18 @@ class Server:
                 "Shared subscription groups a completion slice picked a "
                 "member of before it read its targets",
             ),
+            (
+                "mqtt_tpu_qos_begins_total",
+                "qos_begins",
+                "QoS>0 deliveries of the encode-once fan-out that reached "
+                "the inflight bookkeeping",
+            ),
+            (
+                "mqtt_tpu_qos_begins_hooked_total",
+                "qos_begins_hooked",
+                "Of those, the ones that dispatched on_qos_publish (a hook "
+                "provides it)",
+            ),
         ):
             r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
         r.gauge(
@@ -1861,6 +1890,13 @@ class Server:
             "Time of shared-group selection while a profiler session was "
             "live: forced materialization, the hooks' pick, select and merge",
             fn=lambda: self._ops.share_select_ns / 1e9,
+        )
+        r.counter(
+            "mqtt_tpu_qos_begin_seconds_total",
+            "Time of the encode-once fan-out's QoS>0 bookkeeping while a "
+            "profiler session was live: the entry's clone, the inflight "
+            "store, the hooks",
+            fn=lambda: self._ops.qos_begin_ns / 1e9,
         )
         r.counter(
             "mqtt_tpu_staging_compact_overflow_total",
@@ -4570,6 +4606,19 @@ class Server:
             topic = ns_local(topic)
         on_acl = self.hooks.on_acl_check
         payload_len = len(dpk.payload)
+        prof = self.profiler
+        armed = prof is not None and prof.armed
+        if eff > 0:
+            # what every QoS>0 delivery of the variant reads alike
+            caps = self.options.capabilities
+            cap, max_id = caps.maximum_inflight, caps.maximum_packet_id
+            hooked = not self._no_hook_provides(
+                "qos_publish", _QOS_PUBLISH_EVENTS
+            )
+            on_qos_publish = self.hooks.on_qos_publish
+            info = self.info
+            proto: Optional[Packet] = None
+            n_begins = n_hooked = begin_ns = 0
         flush: list = []
         n_checks = n_cork = n_queue = n_full = n_rec = 0
         for cl, sub in group:
@@ -4583,7 +4632,31 @@ class Server:
                     continue
                 pid = 0
                 if eff > 0:
-                    pid = self._begin_qos_delivery(cl, dpk, eff, retain)
+                    # the QoS>0 bookkeeping of publish_to_client: the
+                    # entry is a clone of the variant's prototype, and
+                    # the session's inflight lock is taken once
+                    if armed:
+                        t_begin = time.perf_counter_ns()
+                    if proto is None:
+                        proto = dpk.copy(False)
+                        proto.topic_name = topic
+                        proto.fixed_header.qos = eff
+                        proto.fixed_header.retain = retain
+                    out = proto.clone()
+                    st = cl.state
+                    pid = st.inflight.begin(st, out, cap, max_id)
+                    n_begins += 1
+                    if pid >= BEGIN_PARKED:  # a new entry
+                        info.inflight += 1
+                        if hooked:
+                            n_hooked += 1
+                            on_qos_publish(cl, out, out.created, 0)
+                        if pid == BEGIN_PARKED:
+                            out.expiry = -1  # resent once quota frees
+                    else:
+                        self._qos_refused(cl, dpk, pid)
+                    if armed:
+                        begin_ns += time.perf_counter_ns() - t_begin
                     if pid < 0:
                         continue  # quota-refused or parked for resend
                 if rec is not None and cl._slice is rec:
@@ -4654,12 +4727,14 @@ class Server:
         ops.deliveries_cork += n_cork
         ops.deliveries_queue += n_queue
         ops.deliveries_dropped_full += n_full
+        if eff > 0:
+            ops.qos_begins += n_begins
+            ops.qos_begins_hooked += n_hooked
+            ops.qos_begin_ns += begin_ns
         if not flush:
             return
         from .native import fan_flush
 
-        prof = self.profiler
-        armed = prof is not None and prof.armed
         if armed:
             t_send = time.perf_counter_ns()
         sent = fan_flush(
@@ -4728,55 +4803,28 @@ class Server:
         b[id_off + 1] = pid & 0xFF
         return bytes(b)
 
-    def _begin_qos_delivery(
-        self, cl: Client, dpk: Packet, eff: int, retain: bool
-    ) -> int:
-        """The QoS>0 per-target bookkeeping of publish_to_client —
-        inflight cap, packet-id allocation, inflight store, send quota —
-        WITHOUT the per-target encode. Returns the allocated packet id,
-        or -1 when nothing must be written now (quota refusal, or the
-        send-quota park that resends once quota frees)."""
-        caps = self.options.capabilities
-        if len(cl.state.inflight) >= caps.maximum_inflight:
+    def _qos_refused(self, cl: Client, pk: Packet, code: int) -> None:
+        """What a QoS>0 delivery that ``Inflight.begin`` refused (``code``:
+        BEGIN_FULL or BEGIN_EXHAUSTED) leaves behind: the drop counted,
+        a warning, and on exhausted packet ids the hooks told first."""
+        if code == BEGIN_FULL:
             self.info.inflight_dropped += 1
             self.log.warning(
                 "client store quota reached: client=%s listener=%s",
                 cl.id, cl.net.listener,
             )
-            return -1
-        try:
-            i = cl.next_packet_id()  # [MQTT-4.3.2-1] [MQTT-4.3.3-1]
-        except Code:
-            self.hooks.on_packet_id_exhausted(cl, dpk)
-            self.info.inflight_dropped += 1
-            self.log.warning(
-                "packet ids exhausted: client=%s listener=%s",
-                cl.id, cl.net.listener,
-            )
-            return -1
-        out = dpk.copy(False)
-        out.topic_name = (
-            ns_local(dpk.topic_name)
-            if dpk.topic_name[:1] == NS_CHAR
-            else dpk.topic_name
+            return
+        self.hooks.on_packet_id_exhausted(cl, pk)
+        self.info.inflight_dropped += 1
+        self.log.warning(
+            "packet ids exhausted: client=%s listener=%s",
+            cl.id, cl.net.listener,
         )
-        out.fixed_header.qos = eff
-        out.fixed_header.retain = retain
-        out.packet_id = i & 0xFFFF  # [MQTT-2.2.1-4]
-        sent_quota = cl.state.inflight.send_quota
-        if cl.state.inflight.set(out):  # [MQTT-4.3.2-3] [MQTT-4.3.3-3]
-            self.info.inflight += 1
-            self.hooks.on_qos_publish(cl, out, out.created, 0)
-            cl.state.inflight.decrease_send_quota()
-        if sent_quota == 0 and cl.state.inflight.maximum_send_quota > 0:
-            out.expiry = -1  # mark for immediate resend once quota frees
-            cl.state.inflight.set(out)
-            return -1
-        return out.packet_id
 
     def _rollback_qos_delivery(self, cl: Client, pid: int) -> None:
-        """Undo _begin_qos_delivery after a failed enqueue — the exact
-        rollback publish_to_client performs on a full outbound queue."""
+        """Undo a delivery's ``Inflight.begin`` after a failed enqueue —
+        the exact rollback publish_to_client performs on a full outbound
+        queue."""
         cl.state.inflight.delete(pid)
         cl.state.inflight.increase_send_quota()
 
@@ -5230,34 +5278,21 @@ class Server:
                     out.topic_name = ""
 
         if out.fixed_header.qos > 0:
+            # the inflight cap, a packet id [MQTT-4.3.2-1] [MQTT-4.3.3-1],
+            # the store and the send quota under one acquisition, as the
+            # encode-once fan-out takes them (_flush_variant)
             caps = self.options.capabilities
-            if len(cl.state.inflight) >= caps.maximum_inflight:
-                self.info.inflight_dropped += 1
-                self.log.warning(
-                    "client store quota reached: client=%s listener=%s", cl.id, cl.net.listener
-                )
+            st = cl.state
+            pid = st.inflight.begin(
+                st, out, caps.maximum_inflight, caps.maximum_packet_id
+            )
+            if pid < BEGIN_PARKED:
+                self._qos_refused(cl, pk, pid)
                 raise ERR_QUOTA_EXCEEDED()
-            try:
-                i = cl.next_packet_id()  # [MQTT-4.3.2-1] [MQTT-4.3.3-1]
-            except Code:
-                self.hooks.on_packet_id_exhausted(cl, pk)
-                self.info.inflight_dropped += 1
-                self.log.warning(
-                    "packet ids exhausted: client=%s listener=%s", cl.id, cl.net.listener
-                )
-                raise ERR_QUOTA_EXCEEDED() from None
-
-            out.packet_id = i & 0xFFFF  # [MQTT-2.2.1-4]
-            sent_quota = cl.state.inflight.send_quota
-
-            if cl.state.inflight.set(out):  # [MQTT-4.3.2-3] [MQTT-4.3.3-3]
-                self.info.inflight += 1
-                self.hooks.on_qos_publish(cl, out, out.created, 0)
-                cl.state.inflight.decrease_send_quota()
-
-            if sent_quota == 0 and cl.state.inflight.maximum_send_quota > 0:
+            self.info.inflight += 1
+            self.hooks.on_qos_publish(cl, out, out.created, 0)
+            if pid == BEGIN_PARKED:
                 out.expiry = -1  # mark for immediate resend once quota frees
-                cl.state.inflight.set(out)
                 return out
 
         if cl.net.writer is None or cl.closed:
@@ -5883,8 +5918,9 @@ class Server:
             topics[SYS_PREFIX + "/broker/egress/" + key] = str(
                 getattr(self._ops, key)
             )
-        # shared-group selection, and the lazy results forced into dicts
-        for key in _SHARE_COUNTERS:
+        # shared-group selection, the QoS>0 bookkeeping of the encode-once
+        # fan-out, and the lazy results forced into dicts
+        for key in _SHARE_COUNTERS + _QOS_COUNTERS:
             topics[SYS_PREFIX + "/broker/fanout/" + key] = str(
                 getattr(self._ops, key)
             )

@@ -564,8 +564,9 @@ class TestSysTopics:
             }
             # the 20 of the reference's tree, the trie's three counts
             # and the two each of the ingest run and the ack run, the
-            # direct feeder's reads, the eight of the way out, and the
-            # five of shared-group selection
+            # direct feeder's reads, the eight of the way out, the
+            # five of shared-group selection and the three of the QoS>0
+            # bookkeeping
             assert {
                 "$SYS/broker/topics/particles",
                 "$SYS/broker/topics/particle_maps",
@@ -588,8 +589,11 @@ class TestSysTopics:
                 "$SYS/broker/fanout/share_select_ns",
                 "$SYS/broker/fanout/share_groups_early",
                 "$SYS/broker/fanout/view_materializations",
+                "$SYS/broker/fanout/qos_begins",
+                "$SYS/broker/fanout/qos_begins_hooked",
+                "$SYS/broker/fanout/qos_begin_ns",
             } <= base
-            assert len(base) == 41
+            assert len(base) == 44
             await h.shutdown()
 
         run(scenario())
