@@ -1,5 +1,5 @@
 """Shared plumbing for the CI scrape gates (scrape_metrics /
-scrape_traces / scrape_profile): the raw HTTP/1.1 fetch against the
+scrape_traces): the raw HTTP/1.1 fetch against the
 broker's stats listener. One implementation so a fetch-path fix (the
 read-to-EOF rule, timeouts) lands in every gate at once."""
 

@@ -676,7 +676,7 @@ class ClientState:
         self.out_seq = 0
         self.out_deq = 0
         self.out_stamps: collections.deque = collections.deque(maxlen=64)
-        # write-path accounting (mqtt_tpu.profiling / ROADMAP item 3):
+        # write-path accounting (ROADMAP item 3):
         # bytes and socket-write calls this client's outbound legs have
         # issued — the per-client face of the aggregate
         # mqtt_tpu_outbound_{bytes,writes}_total counters

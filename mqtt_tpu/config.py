@@ -246,14 +246,8 @@ def from_bytes(b: bytes) -> Optional[Options]:
         "trace_user_property",
         "trace_adopt_max_per_s",
         "trace_jax_profiler_dir",
-        # host hot-path observatory: sampling wall profiler, lock
-        # contention plane, topic-cardinality sketch (mqtt_tpu.profiling
-        # + mqtt_tpu.utils.locked)
-        "profile",
-        "profile_hz",
-        "profile_ring",
+        # lock-contention plane (mqtt_tpu.utils.locked)
         "profile_locks",
-        "profile_topics",
         # cluster-wide SLO observatory: delivery-latency SLIs, the
         # burn-rate engine, and mesh metric federation (mqtt_tpu.slo +
         # mqtt_tpu.telemetry.ClusterMetrics)

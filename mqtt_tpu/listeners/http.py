@@ -117,10 +117,7 @@ class HTTPStats(_HttpListener):
     when a telemetry plane is attached (mqtt_tpu.telemetry), its
     Prometheus text exposition at ``GET /metrics``, the trace plane's
     Chrome trace-event export at ``GET /traces`` (mqtt_tpu.tracing;
-    load the body straight into Perfetto), and the host profiler's
-    exports at ``GET /profile`` (mqtt_tpu.profiling) — collapsed
-    flamegraph text by default, ``?format=trace`` for the
-    Perfetto-loadable flame chart — and the per-device observability
+    load the body straight into Perfetto), and the per-device observability
     snapshot at ``GET /devices`` (mqtt_tpu.ops.devicestats: HBM, duty
     cycles, shard skew, compile ledger).
 
@@ -141,9 +138,8 @@ class HTTPStats(_HttpListener):
         self.health = health
 
     def _respond(self, method: str, path: str):
-        # known paths match on the bare path; the query string only
-        # selects an export format (/profile?format=trace)
-        path, _, query = path.partition("?")
+        # known paths match on the bare path, without a query string
+        path = path.partition("?")[0]
         if path == "/healthz":
             if self.health is None:  # no server wired (bare listener)
                 return "404 Not Found", b"", "text/plain"
@@ -197,17 +193,6 @@ class HTTPStats(_HttpListener):
                 return self._method_not_allowed()
             body = json.dumps(plane.snapshot(), indent=1).encode()
             return "200 OK", body, "application/json", _NO_STORE
-        if path == "/profile":
-            profiler = getattr(self.telemetry, "host_profiler", None)
-            if profiler is None:  # telemetry off, or the profiler disabled
-                return "404 Not Found", b"", "text/plain"
-            if method != "GET":
-                return self._method_not_allowed()
-            if "format=trace" in query:
-                body = json.dumps(profiler.trace_events()).encode()
-                return "200 OK", body, "application/json", _NO_STORE
-            body = profiler.collapsed().encode()
-            return "200 OK", body, "text/plain; charset=utf-8", _NO_STORE
         if path == "/metrics":
             if self.telemetry is None:
                 return "404 Not Found", b"", "text/plain"
@@ -354,7 +339,7 @@ class Dashboard(_HttpListener):
                     "subscriptions": sorted(cl.state.subscriptions.get_all()),
                     "inflight": len(cl.state.inflight),
                     "done": cl.closed,
-                    # per-client write-path accounting (mqtt_tpu.profiling):
+                    # per-client write-path accounting:
                     # the client-level face of outbound_{bytes,writes}_total
                     "outbound_queue_depth": cl.state.outbound_qty,
                     "outbound_bytes": cl.state.out_bytes,

@@ -429,9 +429,8 @@ def frame_scan(
         )
     finally:
         # release the from_buffer export DETERMINISTICALLY: anything that
-        # pins this frame past return (the sampling wall profiler,
-        # mqtt_tpu.profiling, holds sys._current_frames() references
-        # briefly; a debugger does too) would otherwise keep the export
+        # pins this frame past return (a debugger, or anything else
+        # holding sys._current_frames() references) would keep the export
         # alive and make the caller's `del rbuf[:consumed]` raise
         # BufferError("Existing exports of data") mid-read-loop
         del holder
@@ -511,8 +510,8 @@ def frame_scan_multi(
         if isinstance(buf, (bytearray, memoryview)):
             # NOTE: the export must live ONLY in `holders` — a loop
             # local binding would survive the finally below and, with
-            # this frame pinned past return (the sampling wall
-            # profiler's sys._current_frames() references), keep the
+            # this frame pinned past return (a debugger's
+            # sys._current_frames() references), keep the
             # LAST buffer exported while its read loop resumes and
             # `del rbuf[:consumed]` raises BufferError — the exact
             # frame_scan hazard, multiplied by the shard fabric's

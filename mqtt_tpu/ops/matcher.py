@@ -450,8 +450,7 @@ class TpuMatcher:
         # device-resident hit compaction (ROADMAP item 1): results come
         # back as packed (topic_idx, sid) pairs sized for the hits that
         # exist. compact_capacity pins the pair buffer (0 = adaptive from
-        # the observed hits-per-topic EWMA, seeded by hits_estimate —
-        # the server wires TopicSketch's avg_hits_per_topic here).
+        # the observed hits-per-topic EWMA, seeded by hits_estimate).
         self.compact = compact
         self.compact_capacity = max(0, compact_capacity)
         # zero-materialization fan-out (ISSUE 13): results come back as

@@ -16,7 +16,7 @@ import logging
 import threading
 import time
 from dataclasses import dataclass, field, replace
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 from . import packets as pkts
 from .clients import (
@@ -202,8 +202,7 @@ class Options:
     matcher_stage_pipeline_depth: int = 3
     # device-resident hit compaction (ops/flat.flat_match_compact):
     # pinned pair-buffer capacity; 0 = adaptive from the observed
-    # hits-per-topic EWMA (seeded by the TopicSketch's avg_hits_per_topic
-    # when the host observatory is on)
+    # hits-per-topic EWMA
     matcher_compact_capacity: int = 0
     # read-side decode batching: coalesce frame scans from read loops
     # that wake in the same event-loop tick into one native multi-buffer
@@ -485,24 +484,10 @@ class Options:
     # and close() stops it — the deep-dive companion to the host-side
     # duty-cycle numbers ("" disables; requires a device matcher)
     trace_jax_profiler_dir: str = ""
-    # host hot-path observatory (mqtt_tpu.profiling): an always-on
-    # sampling wall profiler over every broker thread (sys._current_
-    # frames at profile_hz, zero per-call cost on the profiled paths),
-    # collapsed-stack + Perfetto exports at GET /profile and beside
-    # trigger dumps. Default on (requires telemetry).
-    profile: bool = True
-    # profiler sweep rate; each sweep walks every live thread's stack
-    profile_hz: float = 29.0
-    # raw samples retained for the /profile?format=trace flame chart
-    profile_ring: int = 2048
     # lock-contention plane (mqtt_tpu.utils.locked): arm the named-lock
     # wait/hold instrumentation and export it on /metrics. Opt-out knob
     # — a disarmed lock costs one bool test over the bare acquire.
     profile_locks: bool = True
-    # topic-cardinality space-saving sketch capacity (top-K hot topics
-    # + avg-hits-per-topic, observed on stage-clock-sampled publishes);
-    # 0 disables the sketch
-    profile_topics: int = 512
     # cluster-wide SLO observatory (ISSUE 14, mqtt_tpu.slo): the
     # per-tenant delivery-latency SLI (publish arrival at decode ->
     # frame flushed, riding the sampled stage clocks — the unsampled
@@ -716,12 +701,6 @@ class Options:
             self.loop_shard_accept = "handoff"
         else:
             self.loop_shard_accept = str(self.loop_shard_accept).lower()
-        if self.profile_hz <= 0:
-            self.profile_hz = 29.0
-        if self.profile_ring <= 0:
-            self.profile_ring = 2048
-        if self.profile_topics < 0:
-            self.profile_topics = 512
         if self.logger is None:
             self.logger = logging.getLogger("mqtt_tpu")
 
@@ -800,22 +779,6 @@ _INGEST_RUN_EVENTS = (
 # as it was read, the completion of its delivery (a storage hook
 # persists it), the packet once processed (Server.ack_run)
 _ACK_RUN_EVENTS = (ON_PACKET_READ, ON_QOS_COMPLETE, ON_PACKET_PROCESSED)
-# ``_Ops``' counts of the way out, as the profiler's slice snapshots and
-# ``$SYS/broker/egress/*`` carry them (``/metrics`` has a help text each)
-_EGRESS_COUNTERS = (
-    "deliveries_flush", "deliveries_cork", "deliveries_queue",
-    "deliveries_dropped_full", "cork_writes", "cork_frames",
-    "cork_early_writes", "socket_checks",
-)
-# ``_Ops``' counts of shared-group selection (``Server._select_shared``),
-# as the slice snapshots and ``$SYS/broker/fanout/*`` carry them
-_SHARE_COUNTERS = (
-    "share_groups", "share_picks_offline", "share_select_ns",
-    "share_groups_early",
-)
-# ``_Ops``' counts of the encode-once fan-out's QoS>0 bookkeeping
-# (``Server._flush_variant``), carried as the share counts are
-_QOS_COUNTERS = ("qos_begins", "qos_begins_hooked", "qos_begin_ns")
 # a hook that provides this is shown each new outbound inflight entry
 _QOS_PUBLISH_EVENTS = (ON_QOS_PUBLISH,)
 
@@ -921,6 +884,171 @@ class _Ops:
         self.qos_begin_ns = 0
 
 
+class _Counter(NamedTuple):
+    """How one ``_Ops`` count leaves the broker: as ``attr`` in the
+    profiler's slice snapshots (``Server._slice_counters``), as
+    ``metric`` with ``help`` on ``/metrics`` (a ``kind``: counter or
+    gauge; divided by ``scale``, the attribute's units a metric unit),
+    and as a retained ``$SYS`` topic at ``sys_path`` (None: none)."""
+
+    attr: str
+    metric: str
+    help: str
+    sys_path: Optional[str]
+    kind: str = "counter"
+    scale: int = 1
+
+
+# every ``_Ops`` count the broker exports, once: a new count is one
+# attribute on ``_Ops`` and one row here
+_OPS_COUNTERS = (
+    _Counter(
+        "socket_sends", "mqtt_tpu_socket_sends_total",
+        "Calls that reached a client's socket: transport writes "
+        "(one a packet, or one a cork) and the native fan-out "
+        "flush's sends",
+        None,
+    ),
+    _Counter(
+        "socket_reads", "mqtt_tpu_socket_reads_total",
+        "Wake-ups of a connection's read loop on data: one recv "
+        "each (the stream may join two)",
+        None,
+    ),
+    _Counter(
+        "direct_reads", "mqtt_tpu_direct_reads_total",
+        "Of the socket reads, those taken in inside the "
+        "transport's read callback by the broker's own protocol "
+        "(the rest came through a stream reader)",
+        "/broker/ingest/direct_reads",
+    ),
+    _Counter(
+        "ingest_runs", "mqtt_tpu_ingest_runs_total",
+        "Runs of PUBLISH frames a read loop handed to the ingest "
+        "run in one call that took at least one publish",
+        "/broker/ingest/runs",
+    ),
+    _Counter(
+        "ingest_run_publishes", "mqtt_tpu_ingest_run_publishes_total",
+        "Publishes taken in by ingest runs (the rest took the "
+        "per-frame path: mqtt_tpu_messages_received_total has both)",
+        "/broker/ingest/run_publishes",
+    ),
+    _Counter(
+        "ack_runs", "mqtt_tpu_ack_runs_total",
+        "Stretches of bare PUBACK frames a read loop handed to the "
+        "ack run in one call that took them",
+        "/broker/ingest/ack_runs",
+    ),
+    _Counter(
+        "ack_run_acks", "mqtt_tpu_ack_run_acks_total",
+        "PUBACK frames taken in by ack runs (the rest took the "
+        "per-frame path)",
+        "/broker/ingest/ack_run_acks",
+    ),
+    _Counter(
+        "slice_targets_max", "mqtt_tpu_stage_slice_targets_max",
+        "Most target ids one completion slice of a staged batch has "
+        "looked up at once (high-water mark since start)",
+        "/broker/overload/stage_slice_targets_max",
+        kind="gauge",
+    ),
+    _Counter(
+        "deliveries_flush", "mqtt_tpu_deliveries_flush_total",
+        "Deliveries of the encode-once fan-out that left by the "
+        "native flush (a ready socket: idle transport, empty queue)",
+        "/broker/egress/deliveries_flush",
+    ),
+    _Counter(
+        "deliveries_cork", "mqtt_tpu_deliveries_cork_total",
+        "Deliveries of the encode-once fan-out that joined a "
+        "socket's open cork (its read's, or the completion slice's)",
+        "/broker/egress/deliveries_cork",
+    ),
+    _Counter(
+        "deliveries_queue", "mqtt_tpu_deliveries_queue_total",
+        "Deliveries of the encode-once fan-out that took a "
+        "socket's bounded outbound queue (one write a frame)",
+        "/broker/egress/deliveries_queue",
+    ),
+    _Counter(
+        "deliveries_dropped_full", "mqtt_tpu_deliveries_dropped_full_total",
+        "Deliveries of the encode-once fan-out the outbound queue "
+        "refused, full (also in mqtt_tpu_messages_dropped_total)",
+        "/broker/egress/deliveries_dropped_full",
+    ),
+    _Counter(
+        "cork_writes", "mqtt_tpu_cork_writes_total",
+        "Corks written: one transport write for the packets a "
+        "socket's read or a completion slice held back",
+        "/broker/egress/cork_writes",
+    ),
+    _Counter(
+        "cork_frames", "mqtt_tpu_cork_frames_total",
+        "Packets that joined a socket's cork",
+        "/broker/egress/cork_frames",
+    ),
+    _Counter(
+        "cork_early_writes", "mqtt_tpu_cork_early_writes_total",
+        "Of the corks written, those a cork past its byte bound "
+        "forced before its opener closed it",
+        "/broker/egress/cork_early_writes",
+    ),
+    _Counter(
+        "socket_checks", "mqtt_tpu_socket_checks_total",
+        "Times the encode-once fan-out read a socket's readiness: "
+        "once a delivery, or once a completion slice for a socket "
+        "the slice corked",
+        "/broker/egress/socket_checks",
+    ),
+    _Counter(
+        "share_groups", "mqtt_tpu_share_groups_total",
+        "Shared subscription groups a publish's fan-out selected "
+        "a member of",
+        "/broker/fanout/share_groups",
+    ),
+    _Counter(
+        "share_picks_offline", "mqtt_tpu_share_picks_offline_total",
+        "Selected shared-group members with no connected client "
+        "(an offline session was chosen: nothing is written)",
+        "/broker/fanout/share_picks_offline",
+    ),
+    _Counter(
+        "share_select_ns", "mqtt_tpu_share_select_seconds_total",
+        "Time of shared-group selection while a profiler session was "
+        "live: forced materialization, the hooks' pick, select and merge",
+        "/broker/fanout/share_select_ns",
+        scale=1_000_000_000,
+    ),
+    _Counter(
+        "share_groups_early", "mqtt_tpu_share_groups_early_total",
+        "Shared subscription groups a completion slice picked a "
+        "member of before it read its targets",
+        "/broker/fanout/share_groups_early",
+    ),
+    _Counter(
+        "qos_begins", "mqtt_tpu_qos_begins_total",
+        "QoS>0 deliveries of the encode-once fan-out that reached "
+        "the inflight bookkeeping",
+        "/broker/fanout/qos_begins",
+    ),
+    _Counter(
+        "qos_begins_hooked", "mqtt_tpu_qos_begins_hooked_total",
+        "Of those, the ones that dispatched on_qos_publish (a hook "
+        "provides it)",
+        "/broker/fanout/qos_begins_hooked",
+    ),
+    _Counter(
+        "qos_begin_ns", "mqtt_tpu_qos_begin_seconds_total",
+        "Time of the encode-once fan-out's QoS>0 bookkeeping while a "
+        "profiler session was live: the entry's clone, the inflight "
+        "store, the hooks",
+        "/broker/fanout/qos_begin_ns",
+        scale=1_000_000_000,
+    ),
+)
+
+
 class Server:
     """An MQTT broker server; create via ``Server(options)``
     (server.go:135-205)."""
@@ -977,7 +1105,7 @@ class Server:
         self._draining = False
         # the optional planes below stay Any-typed deliberately: each is
         # a lazily imported subsystem (device matcher, staging loop,
-        # governor, telemetry/tracing/profiling) whose concrete class
+        # governor, telemetry/tracing) whose concrete class
         # never crosses this module's annotated signatures
         self.matcher: Optional[Any] = None  # device matcher; None = host walk
         self._stage: Optional[Any] = None  # publish staging loop (serve())
@@ -997,10 +1125,7 @@ class Server:
         # trace plane (mqtt_tpu.tracing): span ring + device profiler
         self.tracer: Optional[Any] = None
         self.profiler: Optional[Any] = None
-        # host hot-path observatory (mqtt_tpu.profiling): sampling wall
-        # profiler + topic-cardinality sketch; lock plane armed below
-        self.host_profiler: Optional[Any] = None
-        self.topic_sketch: Optional[Any] = None
+        # the lock-contention plane is armed in serve()
         self._lock_plane_armed = False
         if opts.telemetry:
             from .telemetry import Telemetry
@@ -1025,39 +1150,6 @@ class Server:
                 self.telemetry.attach_tracer(
                     self.tracer, exemplars=opts.trace_exemplars
                 )
-            if opts.profile:
-                # host hot-path observatory (mqtt_tpu.profiling): the
-                # sampling thread starts in serve(), so an embedder that
-                # builds but never serves a Server spawns no thread
-                from .profiling import SamplingProfiler, TopicSketch
-
-                self.host_profiler = SamplingProfiler(
-                    hz=opts.profile_hz,
-                    ring=opts.profile_ring,
-                    registry=self.telemetry.registry,
-                )
-                self.telemetry.attach_profiler(self.host_profiler)
-                if opts.profile_topics > 0:
-                    self.topic_sketch = TopicSketch(k=opts.profile_topics)
-                    sk = self.topic_sketch
-                    r = self.telemetry.registry
-                    r.gauge(
-                        "mqtt_tpu_topic_sketch_tracked",
-                        "Topics currently tracked by the space-saving sketch",
-                        fn=lambda: sk.tracked,
-                    )
-                    r.gauge(
-                        "mqtt_tpu_topic_sketch_avg_hits",
-                        "Observed average hits per admitted topic (device "
-                        "compaction-buffer sizing; sampled publishes)",
-                        fn=sk.avg_hits_per_topic,
-                    )
-                    r.counter(
-                        "mqtt_tpu_topic_sketch_evictions_total",
-                        "Space-saving evictions (sketch churn under high "
-                        "topic cardinality)",
-                        fn=lambda: sk.evictions,
-                    )
             if opts.profile_locks:
                 # export the per-lock wait/hold families now; ARMING
                 # waits for serve() so a constructed-but-never-served
@@ -1189,14 +1281,8 @@ class Server:
             from .ops.delta import DeltaMatcher
 
             # the pair-buffer capacity rides beside matcher_opts (which
-            # wins on conflict); the hits-per-topic capacity seed comes
-            # from the TopicSketch when the host observatory is on (its
-            # EWMA then keeps learning from every compacted batch)
+            # wins on conflict)
             mopts: dict = {"compact_capacity": opts.matcher_compact_capacity}
-            if self.topic_sketch is not None:
-                mopts["hits_estimate"] = max(
-                    2.0, self.topic_sketch.avg_hits_per_topic()
-                )
             mopts.update(opts.matcher_opts or {})
             self.matcher = DeltaMatcher(self.topics, **mopts)
             if opts.matcher_resilience:
@@ -1244,17 +1330,16 @@ class Server:
                     snap = getattr(self.matcher, "_snap", None)
                     if snap is not None and hasattr(snap, "profiler"):
                         snap.profiler = self.profiler
-                    # what its slice snapshots read, and who feeds and
-                    # polls it besides the staging loop: the read loops
-                    # and fan-out (per-publish loop counters while a
-                    # jax.profiler session is live), and the sampling
-                    # profiler's thread, so a session that ends after
-                    # the traffic stopped still closes the slice
+                    # what its slice snapshots read, and who feeds it
+                    # besides the staging loop: the read loops and
+                    # fan-out (per-publish loop counters while a
+                    # jax.profiler session is live); its own session
+                    # follower (started in serve()) polls it, so a
+                    # session that ends after the traffic stopped
+                    # still closes the slice
                     self.profiler.matcher_stats = stats
                     self.profiler.counters = self._slice_counters
                     self._ops.profiler = self.profiler
-                    if self.host_profiler is not None:
-                        self.host_profiler.on_sweep = self.profiler.poll
                 # mesh-sharded snapshot: per-shard compile times land in
                 # shard-local histograms on the rebuild path; the scrape
                 # merges them on demand (telemetry callback histogram)
@@ -1474,11 +1559,11 @@ class Server:
                 except Exception:
                     self.log.exception("jax.profiler trace failed to start")
 
-        if self.host_profiler is not None:
-            # the sampling thread is a daemon and samples off every
-            # broker lock path (it only reads sys._current_frames), so
-            # it starts before traffic and runs for the broker's life
-            self.host_profiler.start()
+        if self.profiler is not None:
+            # the session follower is a daemon that takes no broker
+            # lock, so it starts before traffic and runs for the
+            # broker's life
+            self.profiler.start()
         if (
             self.telemetry is not None
             and self.telemetry.lock_plane is not None
@@ -1593,22 +1678,11 @@ class Server:
         """The broker's running counts a profiler slice's snapshots take
         (``tracing.DeviceProfiler.counters``): fallbacks the stage held
         in their publisher's order, frames handed to subscribers'
-        sockets, calls that reached a socket, the read loops' wake-ups
-        on data, the ingest runs and the
-        publishes they took in, the ack runs and their PUBACK frames,
-        the widest completion slice so far (a high-water mark, not a
-        sum), the deliveries by the way they left (native flush, cork,
-        outbound queue; and those the full queue refused), the corks
-        written with their packets and early writes, the readiness reads
-        of fan-out (``socket_checks``), shared-group selection (groups,
-        offline picks, the leg's time, the groups picked in a slice's
-        prologue), the QoS>0 bookkeeping of the encode-once fan-out
-        (begins, those that dispatched on_qos_publish, their time) and
-        the lazy results forced into
-        dicts (the C view module's count), the matcher's wide
-        entries and the topics they answered,
-        what the trie holds (``TopicsIndex``'s three counts), and what
-        set-up's load cost, as values at the snapshot: the bulk loads'
+        sockets, every ``_Ops`` count (``_OPS_COUNTERS``), the lazy
+        results forced into dicts (the C view module's count), the
+        matcher's wide entries and the topics they answered, what the
+        trie holds (``TopicsIndex``'s three counts), and what set-up's
+        load cost, as values at the snapshot: the bulk loads'
         open-to-close wall and the build that ended the newest one."""
         stage = self._stage
         trie = self.topics
@@ -1616,17 +1690,7 @@ class Server:
         return {
             "order_held": 0 if stage is None else stage.order_held,
             "deliveries": self.telemetry.fanout_deliveries.value,
-            "socket_sends": self._ops.socket_sends,
-            "socket_reads": self._ops.socket_reads,
-            "direct_reads": self._ops.direct_reads,
-            "ingest_runs": self._ops.ingest_runs,
-            "ingest_run_publishes": self._ops.ingest_run_publishes,
-            "ack_runs": self._ops.ack_runs,
-            "ack_run_acks": self._ops.ack_run_acks,
-            "slice_targets_max": self._ops.slice_targets_max,
-            **{key: getattr(self._ops, key) for key in _EGRESS_COUNTERS},
-            **{key: getattr(self._ops, key) for key in _SHARE_COUNTERS},
-            **{key: getattr(self._ops, key) for key in _QOS_COUNTERS},
+            **{c.attr: getattr(self._ops, c.attr) for c in _OPS_COUNTERS},
             "view_materializations": self._view_materializations(),
             "wide_entries": getattr(stats, "wide_entries", 0),
             "wide_topics": getattr(stats, "wide_topics", 0),
@@ -1737,137 +1801,16 @@ class Server:
             "publisher's order as held members instead of completing at once",
             fn=lambda: 0 if self._stage is None else self._stage.order_held,
         )
-        for name, attr, what in (
-            (
-                "mqtt_tpu_ingest_runs_total",
-                "ingest_runs",
-                "Runs of PUBLISH frames a read loop handed to the ingest "
-                "run in one call that took at least one publish",
-            ),
-            (
-                "mqtt_tpu_ingest_run_publishes_total",
-                "ingest_run_publishes",
-                "Publishes taken in by ingest runs (the rest took the "
-                "per-frame path: mqtt_tpu_messages_received_total has both)",
-            ),
-            (
-                "mqtt_tpu_ack_runs_total",
-                "ack_runs",
-                "Stretches of bare PUBACK frames a read loop handed to the "
-                "ack run in one call that took them",
-            ),
-            (
-                "mqtt_tpu_ack_run_acks_total",
-                "ack_run_acks",
-                "PUBACK frames taken in by ack runs (the rest took the "
-                "per-frame path)",
-            ),
-            (
-                "mqtt_tpu_socket_sends_total",
-                "socket_sends",
-                "Calls that reached a client's socket: transport writes "
-                "(one a packet, or one a cork) and the native fan-out "
-                "flush's sends",
-            ),
-            (
-                "mqtt_tpu_socket_reads_total",
-                "socket_reads",
-                "Wake-ups of a connection's read loop on data: one recv "
-                "each (the stream may join two)",
-            ),
-            (
-                "mqtt_tpu_direct_reads_total",
-                "direct_reads",
-                "Of the socket reads, those taken in inside the "
-                "transport's read callback by the broker's own protocol "
-                "(the rest came through a stream reader)",
-            ),
-            (
-                "mqtt_tpu_deliveries_flush_total",
-                "deliveries_flush",
-                "Deliveries of the encode-once fan-out that left by the "
-                "native flush (a ready socket: idle transport, empty queue)",
-            ),
-            (
-                "mqtt_tpu_deliveries_cork_total",
-                "deliveries_cork",
-                "Deliveries of the encode-once fan-out that joined a "
-                "socket's open cork (its read's, or the completion slice's)",
-            ),
-            (
-                "mqtt_tpu_deliveries_queue_total",
-                "deliveries_queue",
-                "Deliveries of the encode-once fan-out that took a "
-                "socket's bounded outbound queue (one write a frame)",
-            ),
-            (
-                "mqtt_tpu_deliveries_dropped_full_total",
-                "deliveries_dropped_full",
-                "Deliveries of the encode-once fan-out the outbound queue "
-                "refused, full (also in mqtt_tpu_messages_dropped_total)",
-            ),
-            (
-                "mqtt_tpu_cork_writes_total",
-                "cork_writes",
-                "Corks written: one transport write for the packets a "
-                "socket's read or a completion slice held back",
-            ),
-            (
-                "mqtt_tpu_cork_frames_total",
-                "cork_frames",
-                "Packets that joined a socket's cork",
-            ),
-            (
-                "mqtt_tpu_cork_early_writes_total",
-                "cork_early_writes",
-                "Of the corks written, those a cork past its byte bound "
-                "forced before its opener closed it",
-            ),
-            (
-                "mqtt_tpu_socket_checks_total",
-                "socket_checks",
-                "Times the encode-once fan-out read a socket's readiness: "
-                "once a delivery, or once a completion slice for a socket "
-                "the slice corked",
-            ),
-            (
-                "mqtt_tpu_share_groups_total",
-                "share_groups",
-                "Shared subscription groups a publish's fan-out selected "
-                "a member of",
-            ),
-            (
-                "mqtt_tpu_share_picks_offline_total",
-                "share_picks_offline",
-                "Selected shared-group members with no connected client "
-                "(an offline session was chosen: nothing is written)",
-            ),
-            (
-                "mqtt_tpu_share_groups_early_total",
-                "share_groups_early",
-                "Shared subscription groups a completion slice picked a "
-                "member of before it read its targets",
-            ),
-            (
-                "mqtt_tpu_qos_begins_total",
-                "qos_begins",
-                "QoS>0 deliveries of the encode-once fan-out that reached "
-                "the inflight bookkeeping",
-            ),
-            (
-                "mqtt_tpu_qos_begins_hooked_total",
-                "qos_begins_hooked",
-                "Of those, the ones that dispatched on_qos_publish (a hook "
-                "provides it)",
-            ),
-        ):
-            r.counter(name, what, fn=lambda a=attr: getattr(self._ops, a))
-        r.gauge(
-            "mqtt_tpu_stage_slice_targets_max",
-            "Most target ids one completion slice of a staged batch has "
-            "looked up at once (high-water mark since start)",
-            fn=lambda: self._ops.slice_targets_max,
-        )
+        for c in _OPS_COUNTERS:
+            register = r.gauge if c.kind == "gauge" else r.counter
+            if c.scale == 1:
+                register(c.metric, c.help, fn=lambda a=c.attr: getattr(self._ops, a))
+            else:
+                register(
+                    c.metric,
+                    c.help,
+                    fn=lambda a=c.attr, k=c.scale: getattr(self._ops, a) / k,
+                )
         r.gauge(
             "mqtt_tpu_staging_pipeline_depth",
             "Device batches in flight across the staging pipeline legs",
@@ -1884,19 +1827,6 @@ class Server:
             "Lazy fan-out views forced into materialized Subscribers "
             "dicts (the C view module's own count)",
             fn=self._view_materializations,
-        )
-        r.counter(
-            "mqtt_tpu_share_select_seconds_total",
-            "Time of shared-group selection while a profiler session was "
-            "live: forced materialization, the hooks' pick, select and merge",
-            fn=lambda: self._ops.share_select_ns / 1e9,
-        )
-        r.counter(
-            "mqtt_tpu_qos_begin_seconds_total",
-            "Time of the encode-once fan-out's QoS>0 bookkeeping while a "
-            "profiler session was live: the entry's clone, the inflight "
-            "store, the hooks",
-            fn=lambda: self._ops.qos_begin_ns / 1e9,
         )
         r.counter(
             "mqtt_tpu_staging_compact_overflow_total",
@@ -3198,11 +3128,6 @@ class Server:
         """A sampled publish is past admission: the clock's stamp and
         what rides the sampling verdict with it."""
         clock.stamp("admission")
-        if self.topic_sketch is not None:
-            # topic-cardinality sketch rides the sampling verdict:
-            # the same 1-in-N publishes that carry a clock feed the
-            # top-K/avg-hits estimate (mqtt_tpu.profiling)
-            self.topic_sketch.observe(pk.topic_name)
         trace_id = getattr(clock, "trace_id", None)
         if trace_id is not None and self.options.trace_user_property:
             # client-visible traces: subscribers (and peers on the
@@ -4073,8 +3998,6 @@ class Server:
             return True  # QoS0 deny is a silent drop (server.go:879-881)
         if clock is not None:
             clock.stamp("admission")
-            if self.topic_sketch is not None:
-                self.topic_sketch.observe(topic)
 
         self._fast_fan_frame(plan, topic, frame, body_offset, cl.id)
         if self._cluster is not None:
@@ -5899,31 +5822,11 @@ class Server:
                 self.topics.particle_maps
             ),
             SYS_PREFIX + "/broker/topics/held": str(self.topics.held),
-            # publishes the read loops took in by the run, and the runs
-            SYS_PREFIX + "/broker/ingest/runs": str(self._ops.ingest_runs),
-            SYS_PREFIX + "/broker/ingest/run_publishes": str(
-                self._ops.ingest_run_publishes
-            ),
-            SYS_PREFIX + "/broker/ingest/ack_runs": str(self._ops.ack_runs),
-            SYS_PREFIX + "/broker/ingest/ack_run_acks": str(
-                self._ops.ack_run_acks
-            ),
-            # socket reads taken in inside the transport's callback
-            SYS_PREFIX + "/broker/ingest/direct_reads": str(
-                self._ops.direct_reads
-            ),
         }
-        # the way out: deliveries by the way they left, and the corks
-        for key in _EGRESS_COUNTERS:
-            topics[SYS_PREFIX + "/broker/egress/" + key] = str(
-                getattr(self._ops, key)
-            )
-        # shared-group selection, the QoS>0 bookkeeping of the encode-once
-        # fan-out, and the lazy results forced into dicts
-        for key in _SHARE_COUNTERS + _QOS_COUNTERS:
-            topics[SYS_PREFIX + "/broker/fanout/" + key] = str(
-                getattr(self._ops, key)
-            )
+        for c in _OPS_COUNTERS:
+            if c.sys_path is not None:
+                topics[SYS_PREFIX + c.sys_path] = str(getattr(self._ops, c.attr))
+        # the lazy results forced into dicts
         topics[SYS_PREFIX + "/broker/fanout/view_materializations"] = str(
             self._view_materializations()
         )
@@ -5990,9 +5893,6 @@ class Server:
                 topics[
                     SYS_PREFIX + "/broker/overload/stage_order_held"
                 ] = str(st.order_held)
-                topics[
-                    SYS_PREFIX + "/broker/overload/stage_slice_targets_max"
-                ] = str(self._ops.slice_targets_max)
         if self.telemetry is not None:
             # telemetry-plane observability (mqtt_tpu.telemetry): stage
             # histogram percentiles, batch occupancy, fallback classes,
@@ -6152,10 +6052,10 @@ class Server:
                 self.log.exception("jax.profiler trace failed to stop")
             if self.profiler is not None:
                 self.profiler.poll()  # the session ended: freeze its slice
+        if self.profiler is not None:
+            self.profiler.stop()
         if self.matcher is not None:
             self.matcher.close()
-        if self.host_profiler is not None:
-            self.host_profiler.stop()
         if self._lock_plane_armed:
             self._lock_plane_armed = False
             self.telemetry.lock_plane.disarm()

@@ -243,8 +243,8 @@ class MatchStage:
         self._queue: Optional[asyncio.Queue] = None
         self._tasks: list[asyncio.Task] = []
         # the resolve leg's dedicated executor: NAMED threads
-        # ("mqtt-tpu-resolve-N") so the host sampling profiler
-        # (mqtt_tpu.profiling) attributes the blocking D2H sync to the
+        # ("mqtt-tpu-resolve-N") so a slice's CPU by thread group
+        # (tracing.thread_group) attributes the blocking D2H sync to the
         # staging pipeline instead of an anonymous default-executor slot
         self._executor: Optional[ThreadPoolExecutor] = None
         # the issue leg's dedicated SINGLE dispatch thread

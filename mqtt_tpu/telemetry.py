@@ -723,10 +723,6 @@ class Telemetry:
         # the server via attach_tracer() — publish_clock consults it so
         # 1-in-trace_sample publishes carry a full trace context
         self.tracer: Any = None
-        # the host profiler (mqtt_tpu.profiling.SamplingProfiler) or
-        # None; attached by the server via attach_profiler() — serves
-        # GET /profile and rides trigger dumps
-        self.host_profiler: Any = None
         # the lock-contention plane (mqtt_tpu.utils.locked.LockPlane)
         # or None; attached via attach_lock_plane()
         self.lock_plane: Any = None
@@ -930,18 +926,11 @@ class Telemetry:
                 h.enable_exemplars()
             self.registry.emit_exemplars = True
 
-    def attach_profiler(self, profiler: Any) -> None:
-        """Attach the host sampling profiler
-        (mqtt_tpu.profiling.SamplingProfiler): GET /profile serves its
-        exports and trigger dumps grow a ``profile_*.txt`` sibling."""
-        self.host_profiler = profiler
-
     def attach_device_stats(self, plane: Any) -> None:
         """Attach the per-device observability plane
         (mqtt_tpu.ops.devicestats.DeviceStatsPlane): GET /devices and
         the $SYS devices tree serve its snapshot, and trigger dumps
-        write a ``devices_*.json`` sibling beside flight/traces/
-        profile."""
+        write a ``devices_*.json`` sibling beside flight/traces."""
         self.device_stats = plane
 
     def attach_lock_plane(self, plane: Any) -> None:
@@ -1147,27 +1136,20 @@ class Telemetry:
         thread. When the trace plane is attached, the same thread also
         writes a sibling ``traces_*.json`` (Perfetto-loadable) next to
         the flight dump — the dump's trace_ids point into it — and when
-        the host profiler is attached, a ``profile_*.txt`` collapsed
-        snapshot of where every broker thread was spending wall time
-        as the trigger fired. Use ``recorder.dump`` directly for a
-        synchronous dump."""
+        the device plane is attached, its snapshot. Use
+        ``recorder.dump`` directly for a synchronous dump."""
         after = (
             self._dump_siblings
-            if self.tracer is not None
-            or self.host_profiler is not None
-            or self.device_stats is not None
+            if self.tracer is not None or self.device_stats is not None
             else None
         )
         self.recorder.dump_async(reason, extra, after=after)
 
     def _dump_siblings(self, dump_path: str, reason: str) -> None:
-        """Write the trace ring, the profiler's collapsed stacks, and
-        the device-plane snapshot beside a just-written flight dump
-        (recorder writer thread)."""
+        """Write the trace ring and the device-plane snapshot beside a
+        just-written flight dump (recorder writer thread)."""
         if self.tracer is not None:
             self._dump_traces(dump_path, reason)
-        if self.host_profiler is not None:
-            self._dump_profile(dump_path, reason)
         if self.device_stats is not None:
             self._dump_devices(dump_path, reason)
 
@@ -1183,19 +1165,6 @@ class Telemetry:
             _log.exception("device-plane dump failed (path=%s)", path)
             return
         _log.warning("device snapshot dumped to %s (reason=%s)", path, reason)
-
-    def _dump_profile(self, dump_path: str, reason: str) -> None:
-        base = os.path.basename(dump_path)
-        stem = base[len("flight_"):] if base.startswith("flight_") else base
-        name = "profile_" + os.path.splitext(stem)[0] + ".txt"
-        path = os.path.join(os.path.dirname(dump_path), name)
-        try:
-            with open(path, "w") as f:
-                f.write(self.host_profiler.collapsed())
-        except OSError:
-            _log.exception("profile dump failed (path=%s)", path)
-            return
-        _log.warning("profiler stacks dumped to %s (reason=%s)", path, reason)
 
     def _dump_traces(self, dump_path: str, reason: str) -> None:
         """Write the trace ring beside a just-written flight dump (runs

@@ -56,6 +56,7 @@ import contextlib
 import gc
 import itertools
 import json
+import logging
 import os
 import random
 import threading
@@ -72,6 +73,8 @@ from .telemetry import (  # noqa: F401  (re-exports)
     Histogram,
     StageClock,
 )
+
+_log = logging.getLogger("mqtt_tpu.tracing")
 
 
 class PublishTrace(StageClock):
@@ -550,6 +553,9 @@ MAX_SLICE_BATCHES = 16_384
 BEAT_NS = 5_000_000  # the loop heartbeat's interval while armed
 CLOCK_NS = 1_000_000_000  # a mqtt/clock mark at the loop's first turn this long after the last
 TRACES_BATCHES = 256  # a slice's newest batches, as /traces serves them
+# the session follower's period: arming and freezing a slice lag the
+# session's edges by at most this when no batch forms
+FOLLOW_S = 1 / 29
 
 
 def thread_group(name: str) -> str:
@@ -558,7 +564,7 @@ def thread_group(name: str) -> str:
     the resolver threads and the resilience guard pool, which is where
     tokenize, dispatch, sync and resolve run when ``matcher_resilience``
     is on: the threads cannot be told apart by stage); or ``other``
-    (rebuild thread, sampler, breaker probe, flight writers)."""
+    (rebuild thread, session follower, breaker probe, flight writers)."""
     if name == "MainThread" or name.startswith("mqtt-tpu-shard-"):
         return "loop"
     if name.startswith(("mqtt-tpu-h2d", "mqtt-tpu-resolve", "mqtt-tpu-guard")):
@@ -841,9 +847,9 @@ class DeviceProfiler:
     held for arithmetic only.
 
     **The armed state.** ``poll()`` (once per batch from the staging
-    collector, once per sweep from the sampling profiler's thread)
-    compares ``jax.profiler.TraceAnnotation.is_enabled()`` with
-    ``armed``. Off -> on: snapshot A, keep every record from now on
+    collector, every ``FOLLOW_S`` from the session follower's thread
+    between ``start()`` and ``stop()``) compares
+    ``jax.profiler.TraceAnnotation.is_enabled()`` with ``armed``. Off -> on: snapshot A, keep every record from now on
     (and those in flight), start the loop heartbeat. On -> off:
     snapshot B, freeze a :class:`TraceSlice` (``last_slice()``). The
     per-publish loop counters (``note_ingest`` / ``note_acks`` /
@@ -928,6 +934,9 @@ class DeviceProfiler:
         self._beat_ns = 0
         self._beats = 0
         self._clock_ns = 0  # the newest mqtt/clock mark
+        # the session follower (start()): its thread and its stop signal
+        self._follower: Optional[threading.Thread] = None
+        self._follow_stop = threading.Event()
         GC2.install()
         if registry is not None:
             self.issue_hist = registry.histogram(
@@ -1006,6 +1015,32 @@ class DeviceProfiler:
                     else:
                         self._disarm()
         return self.armed
+
+    def start(self) -> None:
+        """Start the session follower: a daemon thread that polls every
+        ``FOLLOW_S``, so a session's edges are noticed when no batch
+        forms, and while the loop is held by a long completion slice."""
+        if self._follower is not None:
+            return
+        self._follow_stop = stop = threading.Event()
+        self._follower = threading.Thread(
+            target=self._follow, args=(stop,), daemon=True, name="mqtt-tpu-session"
+        )
+        self._follower.start()
+
+    def stop(self, timeout: float = 2.0) -> None:
+        """Stop the session follower and wait for its thread."""
+        follower, self._follower = self._follower, None
+        if follower is not None:
+            self._follow_stop.set()
+            follower.join(timeout)
+
+    def _follow(self, stop: threading.Event) -> None:
+        while not stop.wait(FOLLOW_S):
+            try:
+                self.poll()
+            except Exception:  # the follower outlives a failed poll
+                _log.exception("profiler session poll failed")
 
     def _snapshot(self) -> dict:
         """One edge of a slice: the instant, CPU (process and per

@@ -1529,12 +1529,18 @@ class AclGate(Hook):
         return (cl.id, topic) not in self.denied
 
 
+# the ``_Ops`` counts of the way out, and the socket sends
+EGRESS_COUNTS = (
+    "deliveries_flush", "deliveries_cork", "deliveries_queue",
+    "deliveries_dropped_full", "cork_writes", "cork_frames",
+    "cork_early_writes", "socket_checks", "socket_sends",
+)
+
+
 def counts(srv, clients):
     """Every count a delivery moves, as it stands."""
-    from mqtt_tpu.server import _EGRESS_COUNTERS
-
     ops, info = srv._ops, srv.info
-    c = {k: getattr(ops, k) for k in _EGRESS_COUNTERS + ("socket_sends",)}
+    c = {k: getattr(ops, k) for k in EGRESS_COUNTS}
     c.update(
         bytes_sent=info.bytes_sent, packets_sent=info.packets_sent,
         messages_sent=info.messages_sent, inflight=info.inflight,

@@ -478,6 +478,37 @@ def test_r6_quiet_on_catalog_globs_and_loop_registration(tmp_path):
     assert findings == []
 
 
+def test_r6_reads_rows_of_a_metric_table(tmp_path):
+    """A NamedTuple with ``metric`` and ``kind`` fields declares a metric
+    a row: its names are held to the scheme and the catalog as a
+    factory call's are."""
+    findings = run_r6(
+        tmp_path,
+        """
+        from typing import NamedTuple
+
+        class Row(NamedTuple):
+            attr: str
+            metric: str
+            kind: str = "counter"
+
+        ROWS = (
+            Row("a", "mqtt_tpu_a_total"),
+            Row("b", "mqtt_tpu_b"),
+            Row("c", "mqtt_tpu_c_total", kind="gauge"),
+            Row("d", "mqtt_tpu_undocumented_total"),
+        )
+        """,
+        ["a_total", "b", "c_total", "stale_total"],
+    )
+    msgs = sorted(f.msg for f in findings if f.rule == "R6")
+    assert len(msgs) == 4, msgs
+    assert any("'mqtt_tpu_b' must end in `_total`" in m for m in msgs)
+    assert any("gauge 'mqtt_tpu_c_total'" in m for m in msgs)
+    assert any("'mqtt_tpu_undocumented_total' missing" in m for m in msgs)
+    assert any("'mqtt_tpu_stale_total'" in m for m in msgs)
+
+
 # -- R7: thread daemon/tracking discipline ----------------------------------
 
 

@@ -1,27 +1,17 @@
-"""The host hot-path observatory (mqtt_tpu.profiling +
-mqtt_tpu.utils.locked): sampler determinism under a seeded synthetic
-thread workload, the collapsed-stack and trace-event exports + their
-validators, lock-plane wait/hold math, fan-out amplification accounting
-against a known fan-out, space-saving sketch accuracy bounds, and the
-GET /profile HTTP matrix.
+"""The host observability planes that stay on with telemetry
+(mqtt_tpu.utils.locked and the write paths' counters): lock-plane
+wait/hold math, fan-out amplification accounting against a known
+fan-out, and the lock families on /metrics.
 """
 
 import asyncio
-import json
 import threading
 import time
 
 import pytest
 
-from mqtt_tpu import Options, Server
-from mqtt_tpu.listeners import Config as LConfig, HTTPStats
+from mqtt_tpu import Options
 from mqtt_tpu.packets import SUBACK, Subscription
-from mqtt_tpu.profiling import (
-    SamplingProfiler,
-    TopicSketch,
-    check_collapsed,
-)
-from mqtt_tpu.tracing import check_trace_events
 from mqtt_tpu.utils.locked import (
     DEFAULT_PLANE,
     InstrumentedLock,
@@ -39,176 +29,6 @@ from tests.test_server import (
 )
 
 TIMEOUT = 5
-
-
-# -- deterministic sampler: synthetic frames ---------------------------------
-
-
-class _FakeCode:
-    def __init__(self, name, filename):
-        self.co_name = name
-        self.co_filename = filename
-
-
-class _FakeFrame:
-    """A minimal stand-in for an interpreter frame: f_code/f_lineno/f_back."""
-
-    def __init__(self, name, lineno, back=None, filename="synthetic.py"):
-        self.f_code = _FakeCode(name, filename)
-        self.f_lineno = lineno
-        self.f_back = back
-
-
-def _stack(*names):
-    """Build a frame chain; names given root-first, returns the LEAF."""
-    frame = None
-    for i, name in enumerate(names):
-        frame = _FakeFrame(name, 10 + i, back=frame)
-    return frame
-
-
-class TestSamplerDeterminism:
-    def _profiler(self, frames_by_sweep):
-        """A profiler fed a scripted sequence of _current_frames dicts
-        and a scripted clock — fully deterministic."""
-        sweeps = iter(frames_by_sweep)
-        t = [0.0]
-
-        def clock():
-            t[0] += 0.005
-            return t[0]
-
-        return SamplingProfiler(
-            hz=100.0, frames_fn=lambda: next(sweeps), clock=clock
-        )
-
-    def test_collapsed_aggregation_and_counts(self):
-        leaf = _stack("serve", "fan_out", "encode")
-        p = self._profiler([{1: leaf}, {1: leaf}, {1: leaf}])
-        for _ in range(3):
-            p.sample_once()
-        txt = p.collapsed()
-        assert check_collapsed(txt) == 1  # one distinct stack
-        line = txt.strip()
-        assert line.endswith(" 3")
-        # root-first order: serve;fan_out;encode
-        assert line.index("serve") < line.index("fan_out") < line.index("encode")
-        assert "(synthetic.py:" in line
-        assert p.samples == 3 and p.thread_samples == 3
-
-    def test_distinct_stacks_and_thread_names(self):
-        a = _stack("loop", "read")
-        b = _stack("loop", "write")
-        p = self._profiler([{1: a, 2: b}, {1: a, 2: b}])
-        p.sample_once()
-        p.sample_once()
-        txt = p.collapsed()
-        assert check_collapsed(txt) == 2
-        # unnamed tids fall back to a stable synthetic thread name
-        assert "thread-1;" in txt and "thread-2;" in txt
-
-    def test_own_thread_never_sampled(self):
-        own = threading.get_ident()
-        leaf = _stack("me")
-        p = self._profiler([{own: leaf, 99: leaf}])
-        assert p.sample_once() == 1  # only the foreign thread
-        assert "me" in p.collapsed()
-
-    def test_stack_cap_counts_drops(self):
-        p = SamplingProfiler(
-            hz=10, frames_fn=lambda: {}, clock=time.perf_counter, max_stacks=16
-        )
-        for i in range(40):
-            p._agg[("t", (f"f{i}",))] = 1  # simulate 16-cap overflow input
-        # cap enforcement happens on the sample path:
-        sweeps = iter([{7: _stack(f"g{i}")} for i in range(40)])
-        p2 = SamplingProfiler(hz=10, frames_fn=lambda: next(sweeps), max_stacks=16)
-        for _ in range(40):
-            p2.sample_once()
-        assert len(p2._agg) == 16
-        assert p2.dropped_stacks == 24
-
-    def test_trace_events_merge_consecutive_samples(self):
-        """Three identical samples then a divergence at depth 1 become
-        one long span per shared frame plus split spans below it."""
-        a = _stack("root", "walk")
-        b = _stack("root", "encode")
-        p = self._profiler([{5: a}, {5: a}, {5: b}])
-        for _ in range(3):
-            p.sample_once()
-        doc = p.trace_events()
-        assert check_trace_events(doc) > 0
-        names = [e["name"] for e in doc["traceEvents"]]
-        roots = [e for e in doc["traceEvents"] if "root" in e["name"]]
-        assert len(roots) == 1  # merged across all three samples
-        assert any("walk" in n for n in names)
-        assert any("encode" in n for n in names)
-        walk = next(e for e in doc["traceEvents"] if "walk" in e["name"])
-        root = roots[0]
-        assert root["dur"] >= walk["dur"]
-
-    def test_live_thread_sampling_lands_known_function(self):
-        """A real (non-scripted) sweep over a live thread parked in a
-        distinctively-named function finds that function."""
-        ev = threading.Event()
-
-        def profiling_target_fn():
-            ev.wait(TIMEOUT)
-
-        t = threading.Thread(target=profiling_target_fn, daemon=True, name="px")
-        t.start()
-        try:
-            p = SamplingProfiler(hz=100)
-            time.sleep(0.02)  # let the worker reach the wait
-            p.sample_once()
-            txt = p.collapsed()
-            assert "profiling_target_fn" in txt
-            assert "px;" in txt
-        finally:
-            ev.set()
-            t.join(TIMEOUT)
-
-    def test_start_stop_thread_lifecycle(self):
-        p = SamplingProfiler(hz=200)
-        p.start()
-        try:
-            deadline = time.monotonic() + TIMEOUT
-            while p.samples == 0 and time.monotonic() < deadline:
-                time.sleep(0.01)
-            assert p.samples > 0
-        finally:
-            p.stop()
-        assert p._thread is None
-
-
-# -- validators --------------------------------------------------------------
-
-
-class TestCheckCollapsed:
-    def test_accepts_valid(self):
-        good = "main;f (x.py:1);g (x.py:2) 5\nother;h (y.py:3) 1\n"
-        assert check_collapsed(good) == 2
-
-    def test_rejects_bad_count(self):
-        with pytest.raises(ValueError):
-            check_collapsed("main;f 0\n")
-        with pytest.raises(ValueError):
-            check_collapsed("main;f notanumber\n")
-
-    def test_rejects_empty_frame_and_empty_doc(self):
-        with pytest.raises(ValueError):
-            check_collapsed("main;;f 3\n")
-        with pytest.raises(ValueError):
-            check_collapsed("\n\n")
-
-    def test_profile_trace_export_passes_trace_checker(self):
-        p = SamplingProfiler(hz=100, frames_fn=lambda: {3: _stack("a", "b")})
-        p.sample_once()
-        n = check_trace_events(json.dumps(p.trace_events()))
-        assert n >= 2  # one span per open frame depth
-
-
-# -- lock plane --------------------------------------------------------------
 
 
 class TestLockPlane:
@@ -386,64 +206,6 @@ class TestLockPlane:
 # -- topic sketch ------------------------------------------------------------
 
 
-class TestTopicSketch:
-    def test_exact_when_under_capacity(self):
-        sk = TopicSketch(k=16)
-        for i in range(10):
-            for _ in range(i + 1):
-                sk.observe(f"t/{i}")
-        top = sk.top(3)
-        assert top[0] == {"topic": "t/9", "count": 10, "err": 0}
-        assert sk.tracked == 10
-        assert sk.evictions == 0
-        assert sk.total == sum(range(1, 11))
-
-    def test_space_saving_error_bounds(self):
-        """Every tracked count is within `err` of the true count, and a
-        topic whose true count exceeds min_count is guaranteed tracked
-        (the Metwally guarantees the compaction sizing relies on)."""
-        import random
-
-        rng = random.Random(7)
-        sk = TopicSketch(k=32)
-        true: dict = {}
-        # zipf-ish: a few hot topics, a long cold tail
-        for _ in range(5000):
-            if rng.random() < 0.6:
-                t = f"hot/{rng.randrange(8)}"
-            else:
-                t = f"cold/{rng.randrange(800)}"
-            true[t] = true.get(t, 0) + 1
-            sk.observe(t)
-        tracked = {d["topic"]: d for d in sk.top(32)}
-        for topic, d in tracked.items():
-            assert true[topic] <= d["count"], "sketch must never undercount"
-            assert d["count"] - d["err"] <= true[topic]
-        floor = sk.min_count()
-        for topic, n in true.items():
-            if n > floor:
-                assert topic in tracked, (topic, n, floor)
-
-    def test_avg_hits_is_a_lower_bound(self):
-        sk = TopicSketch(k=8)
-        for _ in range(40):
-            sk.observe("hot")
-        for i in range(10):
-            sk.observe(f"cold/{i}")
-        true_avg = 50 / 11
-        assert 0 < sk.avg_hits_per_topic() <= true_avg + 1e-9
-
-    def test_counters_and_top_after_one_observation(self):
-        sk = TopicSketch(k=8)
-        sk.observe("a")
-        assert sk.total == 1 and sk.tracked == 1
-        assert sk.admissions == 1 and sk.evictions == 0
-        assert sk.top(5)[0]["topic"] == "a"
-
-
-# -- amplification accounting vs a known fan-out -----------------------------
-
-
 class TestFanoutAmplification:
     def test_qos1_fanout_encodes_once_per_variant(self):
         """QoS1 publish to N same-variant QoS1 subscribers: the batched
@@ -619,130 +381,6 @@ class TestFanoutAmplification:
 
         run(scenario())
 
-    def test_sketch_observes_sampled_topics(self):
-        async def scenario():
-            h = Harness(Options(inline_client=True, telemetry_sample=1))
-            r, w, _ = await h.connect("s0", version=4)
-            w.write(sub_packet(1, [Subscription(filter="sk/#", qos=0)]))
-            await w.drain()
-            assert (await read_wire_packet(r)).fixed_header.type == SUBACK
-            pr, pw, _ = await h.connect("pub", version=4)
-            for i in range(6):
-                pw.write(pub_packet(f"sk/{i % 2}", b"x"))
-            await pw.drain()
-            for _ in range(6):
-                await read_wire_packet(r)
-            sk = h.server.topic_sketch
-            assert sk is not None
-            assert sk.total == 6
-            tops = {d["topic"] for d in sk.top(4)}
-            assert tops == {"sk/0", "sk/1"}
-            await h.shutdown()
-
-        run(scenario())
-
-
-# -- HTTP matrix -------------------------------------------------------------
-
-
-async def _http(host, port, path, method="GET"):
-    reader, writer = await asyncio.open_connection(host, int(port))
-    writer.write(f"{method} {path} HTTP/1.1\r\nHost: x\r\n\r\n".encode())
-    await writer.drain()
-    data = await asyncio.wait_for(reader.read(262144), TIMEOUT)
-    writer.close()
-    return data
-
-
-class TestProfileHttpMatrix:
-    def test_profile_matrix_and_formats(self):
-        async def scenario():
-            h = Harness(Options(inline_client=True))
-            # make sure the profiler has at least one aggregated stack
-            ev = threading.Event()
-
-            def profile_http_probe_fn():
-                ev.wait(TIMEOUT)
-
-            t = threading.Thread(
-                target=profile_http_probe_fn, daemon=True, name="probe"
-            )
-            t.start()
-            await asyncio.sleep(0.02)
-            h.server.host_profiler.sample_once()
-            st = HTTPStats(
-                LConfig(type="sysinfo", id="s", address="127.0.0.1:0"),
-                h.server.info,
-                telemetry=h.server.telemetry,
-            )
-            await st.init(h.server.log)
-            host, port = st.address().rsplit(":", 1)
-            # GET /profile: collapsed text, no-store
-            data = await _http(host, port, "/profile")
-            head, body = data.split(b"\r\n\r\n", 1)
-            assert head.startswith(b"HTTP/1.1 200")
-            assert b"Cache-Control: no-store" in head
-            assert b"text/plain" in head
-            assert check_collapsed(body.decode()) > 0
-            assert b"profile_http_probe_fn" in body
-            # trace format: Perfetto-loadable
-            data = await _http(host, port, "/profile?format=trace")
-            head, body = data.split(b"\r\n\r\n", 1)
-            assert head.startswith(b"HTTP/1.1 200")
-            assert b"application/json" in head
-            assert check_trace_events(json.loads(body.decode())) > 0
-            # non-GET on the KNOWN path: 405 with Allow
-            post = await _http(host, port, "/profile", "POST")
-            assert post.startswith(b"HTTP/1.1 405") and b"Allow: GET" in post
-            ev.set()
-            t.join(TIMEOUT)
-            await st.close(lambda _: None)
-            await h.shutdown()
-
-        run(scenario())
-
-    def test_profile_404_without_profiler(self):
-        async def scenario():
-            h = Harness(Options(inline_client=True, profile=False))
-            assert h.server.host_profiler is None
-            st = HTTPStats(
-                LConfig(type="sysinfo", id="s", address="127.0.0.1:0"),
-                h.server.info,
-                telemetry=h.server.telemetry,
-            )
-            await st.init(h.server.log)
-            host, port = st.address().rsplit(":", 1)
-            assert (await _http(host, port, "/profile")).startswith(
-                b"HTTP/1.1 404"
-            )
-            # 404 wins over 405 when the surface does not exist at all
-            assert (await _http(host, port, "/profile", "POST")).startswith(
-                b"HTTP/1.1 404"
-            )
-            await st.close(lambda _: None)
-            await h.shutdown()
-
-        run(scenario())
-
-    def test_profile_404_without_telemetry(self):
-        async def scenario():
-            h = Harness(Options(inline_client=True, telemetry=False))
-            st = HTTPStats(
-                LConfig(type="sysinfo", id="s", address="127.0.0.1:0"),
-                h.server.info,
-                telemetry=h.server.telemetry,
-            )
-            await st.init(h.server.log)
-            host, port = st.address().rsplit(":", 1)
-            assert (await _http(host, port, "/profile")).startswith(
-                b"HTTP/1.1 404"
-            )
-            await st.close(lambda _: None)
-            await h.shutdown()
-
-        run(scenario())
-
-
 # -- lock metrics on /metrics ------------------------------------------------
 
 
@@ -778,27 +416,6 @@ class TestLockMetricsExposition:
                 assert int(float(line.rsplit(" ", 1)[1])) > 0
             finally:
                 plane.disarm()
-            await h.shutdown()
-
-        run(scenario())
-
-    def test_trigger_dump_writes_profile_sibling(self, tmp_path):
-        async def scenario():
-            h = Harness(
-                Options(
-                    inline_client=True,
-                    telemetry_dump_dir=str(tmp_path),
-                    telemetry_dump_min_interval_ms=0.0,
-                )
-            )
-            h.server.host_profiler.sample_once()
-            h.server.telemetry.trigger_dump("test_reason")
-            h.server.telemetry.recorder.join_writer()
-            names = sorted(p.name for p in tmp_path.iterdir())
-            assert any(n.startswith("flight_") for n in names), names
-            profs = [n for n in names if n.startswith("profile_")]
-            assert profs, names
-            assert profs[0].endswith(".txt")
             await h.shutdown()
 
         run(scenario())

@@ -8,6 +8,7 @@ import asyncio
 import gc
 import glob
 import os
+import threading
 import time
 
 import pytest
@@ -264,7 +265,8 @@ class TestSlice:
         assert tel.leg_wait["h2d"].sum >= 0 and tel.leg_wait["d2h"].sum >= 0
 
     def test_a_session_that_ends_with_no_traffic_closes_on_poll(self, tmp_path):
-        """The sampler thread's poll, not a batch, notices both edges."""
+        """A poll with no batch (the session follower's) notices both
+        edges."""
         prof = DeviceProfiler()
         assert prof.poll() is False
         start_session(tmp_path)
@@ -504,7 +506,9 @@ class TestLoopCounters:
             await h.server.serve()
             prof = h.server.profiler
             assert prof is not None and prof.matcher_stats is h.server.matcher.stats
-            assert h.server.host_profiler.on_sweep == prof.poll
+            follower = prof._follower
+            assert follower is not None and follower.is_alive()
+            assert follower.name == "mqtt-tpu-session"
             sub_r, sub_w, _ = await h.connect("sub")
             sub_w.write(sub_packet(1, [Subscription(filter="t/#", qos=0)]))
             await sub_w.drain()
@@ -523,7 +527,7 @@ class TestLoopCounters:
             assert prof.ingest_n == prof.fanout_n == 0
             start_session(tmp_path)
             try:
-                # no batch forms: the sampler thread's sweep notices
+                # no batch forms: the session follower notices
                 await asyncio.sleep(0.15)
                 assert prof.armed
                 await publish(6, 18)
@@ -563,3 +567,73 @@ class TestLoopCounters:
         kept = {r.seq for r in sl.batches}
         named = {e["args"]["batch"] for e in roots}
         assert None not in named and kept <= named
+
+
+class TestSessionFollower:
+    """The profiler's own thread follows the session from serve() to
+    close(): with no traffic at all, no batch polls it."""
+
+    OPTS = dict(
+        inline_client=True, device_matcher=True,
+        matcher_opts={"max_levels": 4, "background": False},
+    )
+
+    @staticmethod
+    async def within(cond, seconds=0.5) -> bool:
+        end = time.monotonic() + seconds
+        while not cond():
+            if time.monotonic() > end:
+                return False
+            await asyncio.sleep(0.005)
+        return True
+
+    def test_a_session_with_no_traffic_arms_and_freezes(self, tmp_path):
+        from mqtt_tpu import Options
+        from tests.test_server import Harness
+
+        async def scenario():
+            h = Harness(Options(**self.OPTS))
+            await h.server.serve()
+            prof = h.server.profiler
+            try:
+                start_session(tmp_path)
+                try:
+                    armed = await self.within(lambda: prof.armed)
+                finally:
+                    jax.profiler.stop_trace()
+                frozen = await self.within(lambda: tracing.last_slice() is not None)
+                seen = (h.server.info.messages_received, prof.batches)
+            finally:
+                await h.server.close()
+                await h.shutdown()
+            return armed, frozen, seen
+
+        armed, frozen, seen = run(scenario())
+        assert armed, "the session was not noticed within 0.5 s"
+        assert frozen, "the slice was not frozen within 0.5 s"
+        assert seen == (0, 0)  # no publish, no batch
+        sl = tracing.last_slice()
+        assert sl.batches == [] and sl.a["t_ns"] < sl.b["t_ns"]
+
+    def test_no_follower_outlives_close(self):
+        from mqtt_tpu import Options
+        from tests.test_server import Harness
+
+        def followers() -> set:
+            return {
+                t for t in threading.enumerate()
+                if t.name == "mqtt-tpu-session" and t.is_alive()
+            }
+
+        async def scenario():
+            before = followers()
+            h = Harness(Options(**self.OPTS))
+            await h.server.serve()
+            ours = followers() - before
+            await h.server.close()
+            await h.shutdown()
+            return ours
+
+        ours = run(scenario())
+        assert len(ours) == 1
+        assert not any(t.is_alive() for t in ours)

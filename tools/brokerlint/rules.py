@@ -484,15 +484,74 @@ def _factory_calls(tree: ast.Module) -> Iterator[ast.Call]:
             yield node
 
 
+def _metric_tables(tree: ast.Module) -> dict[str, tuple[list[str], str]]:
+    """NamedTuple classes that declare metrics, by class name: their
+    field names in order and the default of their ``kind`` field (a
+    class with ``metric`` and ``kind`` fields)."""
+    out = {}
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.ClassDef):
+            continue
+        if not any(_terminal_name(b) == "NamedTuple" for b in node.bases):
+            continue
+        fields = []
+        kind_default = ""
+        for stmt in node.body:
+            if isinstance(stmt, ast.AnnAssign) and isinstance(stmt.target, ast.Name):
+                fields.append(stmt.target.id)
+                if (
+                    stmt.target.id == "kind"
+                    and isinstance(stmt.value, ast.Constant)
+                    and isinstance(stmt.value.value, str)
+                ):
+                    kind_default = stmt.value.value
+        if "metric" in fields and "kind" in fields:
+            out[node.name] = (fields, kind_default)
+    return out
+
+
+def _call_field(call: ast.Call, fields: list[str], field: str) -> Optional[ast.AST]:
+    """The argument a call to a NamedTuple class passes for ``field``."""
+    for kw in call.keywords:
+        if kw.arg == field:
+            return kw.value
+    i = fields.index(field)
+    return call.args[i] if i < len(call.args) else None
+
+
 def _code_metrics(ctxs: list[FileCtx]) -> list[tuple[FileCtx, ast.Call, str, str]]:
     """Every metric name the code registers, as ``(ctx, call-node, kind,
-    name)``. Covers the direct literal form and the repo's loop form::
+    name)``. Covers the direct literal form, the repo's loop form::
 
         for name, attr in (("mqtt_tpu_x", "x"), ...):
             r.counter(name, ...)
+
+    and rows of a table whose NamedTuple class has ``metric`` and
+    ``kind`` fields (``Row("x", "mqtt_tpu_x_total", ..., kind="gauge")``).
     """
     out = []
     for ctx in ctxs:
+        tables = _metric_tables(ctx.tree)
+        for node in ast.walk(ctx.tree):
+            if not (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Name)
+                and node.func.id in tables
+            ):
+                continue
+            fields, kind_default = tables[node.func.id]
+            name = _call_field(node, fields, "metric")
+            kind = _call_field(node, fields, "kind")
+            kind_s = kind_default
+            if isinstance(kind, ast.Constant) and isinstance(kind.value, str):
+                kind_s = kind.value
+            if (
+                isinstance(name, ast.Constant)
+                and isinstance(name.value, str)
+                and name.value.startswith(_METRIC_PREFIX)
+                and kind_s in _METRIC_FACTORIES
+            ):
+                out.append((ctx, node, kind_s, name.value))
         for node in _factory_calls(ctx.tree):
             arg = node.args[0]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
