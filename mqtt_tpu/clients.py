@@ -73,9 +73,14 @@ class OutboundQueue:
     producers resolve via ``call_soon_threadsafe`` on the consumer's
     loop. Single-loop brokers pay one uncontended lock acquire per
     enqueue/dequeue and keep identical semantics.
+
+    ``watch`` is ``(SessionsDue, client)`` while the client is in the
+    broker's registry (``SessionsDue.join``), else None: under the queue's
+    lock every item in and out moves the registry's running total, and a
+    queue that fills marks its client for the eviction sweep.
     """
 
-    __slots__ = ("maxsize", "_items", "_lock", "_waiter", "_witness_loop")
+    __slots__ = ("maxsize", "_items", "_lock", "_waiter", "_witness_loop", "watch")
 
     def __init__(self, maxsize: int = 0) -> None:
         self.maxsize = maxsize
@@ -87,6 +92,7 @@ class OutboundQueue:
         # owning-loop identity stamped by the first witnessed get()
         # (mqtt_tpu.utils.loopwitness); None while unobserved/disarmed
         self._witness_loop: Optional[asyncio.AbstractEventLoop] = None
+        self.watch: Optional[tuple] = None
 
     def qsize(self) -> int:
         return len(self._items)
@@ -115,9 +121,17 @@ class OutboundQueue:
                 )
         wake = None
         with self._lock:
+            watch = self.watch
             if 0 < self.maxsize <= len(self._items):
+                if watch is not None:
+                    watch[0].backlog.add(watch[1])
                 raise asyncio.QueueFull()
             self._items.append(item)
+            if watch is not None:
+                due = watch[0]
+                due.count_outbound(1)
+                if len(self._items) == self.maxsize:
+                    due.backlog.add(watch[1])
             if self._waiter is not None:
                 wake, self._waiter = self._waiter, None
         if wake is not None:
@@ -148,6 +162,9 @@ class OutboundQueue:
         while True:
             with self._lock:
                 if self._items:
+                    watch = self.watch
+                    if watch is not None:
+                        watch[0].count_outbound(-1)
                     return self._items.popleft()
                 loop = asyncio.get_running_loop()
                 fut: asyncio.Future = loop.create_future()
@@ -682,6 +699,9 @@ class ClientState:
         # mqtt_tpu_outbound_{bytes,writes}_total counters
         self.out_bytes = 0
         self.out_writes = 0
+        # the broker's SessionsDue while this session is in its registry
+        # (SessionsDue.join / leave), else None
+        self.due: Optional["SessionsDue"] = None
 
     @property
     def outbound_qty(self) -> int:
@@ -883,6 +903,21 @@ class Client:
         else:
             self.net.writer.write(data)
         ops.socket_sends += 1
+        self.note_held()
+
+    def note_held(self) -> None:
+        """Mark this session for the slow-consumer sweep if its transport
+        holds bytes it could not send yet (``SessionsDue.backlog``): after
+        a transport write, where the buffer can grow."""
+        due = self.state.due
+        if due is None:
+            return
+        try:
+            held = self.net.writer.transport.get_write_buffer_size()
+        except Exception:  # no transport, or one that cannot say: the sweep reads 0 too
+            return
+        if held:
+            due.backlog.add(self)
 
     def parse_connect(self, lid: str, pk: Packet) -> None:
         """Absorb CONNECT parameters into client state (clients.go:208-257)."""
@@ -1387,6 +1422,9 @@ class Client:
             self._stop_teardown()
         # brokerlint: ok=R3 session-expiry bookkeeping is wall-clock (persists across restarts)
         self.state.disconnected = int(time.time())
+        due = self.state.due
+        if due is not None:
+            due.stopped.add(self)
 
     def _stop_teardown(self) -> None:
         """The loop-affine half of stop(): cancel the writer task and
@@ -1530,15 +1568,107 @@ class Client:
         self.ops.hooks.on_packet_sent(self, pk, data)
 
 
+class SessionsDue:
+    """The registry's sessions that the housekeeping tick has something to
+    look at, kept where their states change, so that a tick's passes
+    visit those and not every session (``Server._event_loop``):
+
+    - ``inflight``: sessions holding in-flight entries, for their expiry
+      [MQTT-3.3.2-5]. A session's ``Inflight`` adds it as the map gets an
+      entry and drops it as the map empties, under the map's lock.
+    - ``stopped``: sessions whose connection ended (``Client.stop``), for
+      session expiry and the delayed will it fires.
+    - ``backlog``: sessions the slow-consumer sweep has to read: a
+      transport write left bytes buffered (``Client.note_held``) or the
+      outbound queue filled (``OutboundQueue.watch``). The sweep drops a
+      session once its transport holds nothing and its queue is not
+      full: until its next write or fill every sweep would find it so.
+    - ``outbound``: the publishes waiting in the registry's sessions'
+      outbound queues, moved by each queue under its lock: the overload
+      governor's backlog signal, read in place of a sum over sessions.
+
+    A session is watched from its entry into the registry to its removal
+    (``join`` / ``leave``, from ``Clients.add`` / ``delete``). Adding to
+    and discarding from a set are single operations, safe from any
+    loop's thread; a pass walks a copy."""
+
+    __slots__ = ("inflight", "stopped", "backlog", "outbound", "_lock")
+
+    def __init__(self) -> None:
+        self.inflight: set = set()
+        self.stopped: set = set()
+        self.backlog: set = set()
+        self.outbound = 0
+        self._lock = threading.Lock()
+
+    def count_outbound(self, n: int) -> None:
+        with self._lock:
+            self.outbound += n
+
+    def join(self, cl: Client) -> None:
+        """``cl`` entered the registry: watch it, and mark what it holds
+        already (a restored session is stopped before it is registered, a
+        session taken over hands its entries on before)."""
+        st = cl.state
+        if st.due is self:
+            return
+        st.due = self
+        q = st.outbound
+        with q._lock:
+            q.watch = (self, cl)
+            self.count_outbound(len(q._items))
+            full = q.full()
+        st.inflight.watch(self.inflight, cl)
+        if cl.stop_time:
+            self.stopped.add(cl)
+        if full:
+            self.backlog.add(cl)
+        else:
+            cl.note_held()
+
+    def leave(self, cl: Client) -> None:
+        """``cl`` left the registry: no pass looks at it any more."""
+        st = cl.state
+        if st.due is not self:
+            return
+        st.due = None
+        q = st.outbound
+        with q._lock:
+            q.watch = None
+            self.count_outbound(-len(q._items))
+        st.inflight.watch(None, None)
+        self.inflight.discard(cl)
+        self.stopped.discard(cl)
+        self.backlog.discard(cl)
+
+
 class Clients(LockedMap[str, Client]):
     """Clients known by the broker, keyed on client id (clients.go:36-100).
 
     Lock-plane adopted (mqtt_tpu.utils.locked): every fan-out delivery
     does a ``get`` per subscriber, so this is the hottest single lock in
-    the broker."""
+    the broker. The server's registry hands each client that enters or
+    leaves it to its ``SessionsDue`` (``due``; None: nothing watched)."""
 
     def __init__(self) -> None:
         super().__init__(name="clients")
+        self.due: Optional[SessionsDue] = None
+
+    def add(self, key: str, val: Client) -> None:
+        with self._lock:
+            old = self.internal.get(key)
+            self.internal[key] = val
+        due = self.due
+        if due is not None:
+            if old is not None and old is not val:
+                due.leave(old)
+            due.join(val)
+
+    def delete(self, key: str) -> None:
+        with self._lock:
+            old = self.internal.pop(key, None)
+        if old is not None and self.due is not None:
+            self.due.leave(old)
 
     def add_client(self, cl: Client) -> None:
         self.add(cl.id, cl)

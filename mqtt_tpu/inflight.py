@@ -6,7 +6,7 @@ Behavioral parity with reference ``inflight.go:16-156``.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Any, Optional
 
 from .packets import Packet
 from .utils.locked import InstrumentedLock
@@ -29,12 +29,29 @@ class Inflight:
         self.send_quota = 0  # remaining outbound qos quota
         self.maximum_receive_quota = 0
         self.maximum_send_quota = 0
+        # while the session is in the broker's registry: the set of
+        # sessions holding entries (``clients.SessionsDue.inflight``) and
+        # this map's session, which is in the set exactly while the map
+        # holds an entry; the housekeeping tick's expiry pass walks it
+        self._due: Optional[set] = None
+        self._owner: Any = None
+
+    def watch(self, due: Optional[set], owner: Any) -> None:
+        """Keep ``owner`` in ``due`` while the map holds an entry (None:
+        stop)."""
+        with self._lock:
+            self._due, self._owner = due, owner
+            if due is not None and self.internal:
+                due.add(owner)
 
     def set(self, m: Packet) -> bool:
         """Add or update by packet id; True if it was new (inflight.go:33)."""
         with self._lock:
-            existed = m.packet_id in self.internal
-            self.internal[m.packet_id] = m
+            internal = self.internal
+            existed = m.packet_id in internal
+            internal[m.packet_id] = m
+            if len(internal) == 1 and self._due is not None:
+                self._due.add(self._owner)
             return not existed
 
     def set_bulk(self, packets: list[Packet]) -> int:
@@ -47,6 +64,8 @@ class Inflight:
                 if m.packet_id not in self.internal:
                     new += 1
                 self.internal[m.packet_id] = m
+            if self.internal and self._due is not None:
+                self._due.add(self._owner)
             return new
 
     def _free_id(self, start: int, maximum: int) -> int:
@@ -97,6 +116,8 @@ class Inflight:
             state.packet_id = i
             pid = pk.packet_id = i & 0xFFFF  # [MQTT-2.2.1-4]
             internal[pid] = pk  # [MQTT-4.3.2-3] [MQTT-4.3.3-3]
+            if len(internal) == 1 and self._due is not None:
+                self._due.add(self._owner)
             quota = self.send_quota
             if quota > 0:
                 self.send_quota = quota - 1
@@ -136,7 +157,12 @@ class Inflight:
 
     def delete(self, id_: int) -> bool:
         with self._lock:
-            return self.internal.pop(id_, None) is not None
+            internal = self.internal
+            if internal.pop(id_, None) is None:
+                return False
+            if not internal and self._due is not None:
+                self._due.discard(self._owner)
+            return True
 
     def acknowledge(self, ids: list[int]) -> int:
         """A run of acknowledged packet ids under ONE acquisition of the
@@ -160,6 +186,8 @@ class Inflight:
             for id_ in ids:
                 if internal.pop(id_, None) is not None:
                     removed += 1
+            if not internal and self._due is not None:
+                self._due.discard(self._owner)
             if self.send_quota < self.maximum_send_quota:
                 self.send_quota = min(
                     self.send_quota + removed, self.maximum_send_quota

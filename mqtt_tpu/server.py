@@ -26,6 +26,7 @@ from .clients import (
     Client,
     Clients,
     ConnectionClosedError,
+    SessionsDue,
     SliceSocket,
     Will,
 )
@@ -882,6 +883,15 @@ class _Ops:
         self.qos_begins = 0
         self.qos_begins_hooked = 0
         self.qos_begin_ns = 0
+        # the 1 Hz housekeeping tick (Server.housekeep): ticks, sessions
+        # its expiry and eviction passes looked at (SessionsDue's sets;
+        # a loop shard's eviction sweep adds its own), and while a
+        # profiler session is live the passes' time; beside them the
+        # $SYS publish's time, armed only too. Plain adds on the loop.
+        self.housekeep_ticks = 0
+        self.housekeep_visits = 0
+        self.housekeep_ns = 0
+        self.sys_publish_ns = 0
 
 
 class _Counter(NamedTuple):
@@ -1046,6 +1056,32 @@ _OPS_COUNTERS = (
         "/broker/fanout/qos_begin_ns",
         scale=1_000_000_000,
     ),
+    _Counter(
+        "housekeep_ticks", "mqtt_tpu_housekeep_ticks_total",
+        "Housekeeping ticks: the once-a-second expiry and eviction passes",
+        "/broker/housekeep/ticks",
+    ),
+    _Counter(
+        "housekeep_visits", "mqtt_tpu_housekeep_visits_total",
+        "Sessions the housekeeping passes looked at: those holding "
+        "in-flight entries, disconnected ones awaiting expiry, and those "
+        "with a transport or queue backlog",
+        "/broker/housekeep/visits",
+    ),
+    _Counter(
+        "housekeep_ns", "mqtt_tpu_housekeep_seconds_total",
+        "Time of the housekeeping ticks' expiry and eviction passes while "
+        "a profiler session was live",
+        "/broker/housekeep/ns",
+        scale=1_000_000_000,
+    ),
+    _Counter(
+        "sys_publish_ns", "mqtt_tpu_sys_publish_seconds_total",
+        "Time of the housekeeping tick's $SYS publish while a profiler "
+        "session was live",
+        "/broker/housekeep/sys_publish_ns",
+        scale=1_000_000_000,
+    ),
 )
 
 
@@ -1062,6 +1098,10 @@ class Server:
         self.log: logging.Logger = opts.logger or logging.getLogger("mqtt_tpu")
         self.info = Info(version=VERSION, started=int(time.time()))  # brokerlint: ok=R3 $SYS start stamp is wall-clock; uptime uses the monotonic anchor
         self.clients = Clients()
+        # the sessions a housekeeping pass has something to look at
+        # (clients.SessionsDue), kept as the registry and the sessions
+        # change
+        self._due = self.clients.due = SessionsDue()
         self.topics = TopicsIndex()
         self.listeners = Listeners()
         self.hooks = Hooks(self.log)
@@ -1644,11 +1684,7 @@ class Server:
             except asyncio.TimeoutError:
                 pass
             now = int(time.time())  # brokerlint: ok=R3 expiry sweeps compare against absolute wall-clock stamps
-            self.clear_expired_clients(now)
-            self.clear_expired_retained_messages(now)
-            self.send_delayed_lwt(now)
-            self.clear_expired_inflights(now)
-            self.sweep_overload()
+            self.housekeep(now)
             if self.slo is not None:
                 # SLO burn-rate evaluation rides the housekeeping tick
                 # (mqtt_tpu.slo): a handful of histogram-children walks
@@ -1659,8 +1695,45 @@ class Server:
                 except Exception:
                     self.log.exception("SLO evaluation failed")
             if time.monotonic() >= next_sys:
-                self.publish_sys_topics()
+                prof = self.profiler
+                if prof is not None and prof.armed:
+                    t0 = time.perf_counter_ns()
+                    with prof.annotation("mqtt/sys.publish"):
+                        self.publish_sys_topics()
+                    t1 = time.perf_counter_ns()
+                    self._ops.sys_publish_ns += t1 - t0
+                    prof.note_host_span("mqtt/sys.publish", t0, t1)
+                else:
+                    self.publish_sys_topics()
                 next_sys = time.monotonic() + sys_interval
+
+    def housekeep(self, now: int) -> None:
+        """One tick's expiry and eviction passes, each over the sessions
+        that can have something due (``SessionsDue``): counted and, while
+        a profiler session is live, timed and a ``mqtt/housekeep`` span
+        (``DeviceProfiler.note_host_span``)."""
+        ops = self._ops
+        prof = self.profiler
+        if prof is not None and prof.armed:
+            visits = ops.housekeep_visits
+            t0 = time.perf_counter_ns()
+            with prof.annotation("mqtt/housekeep"):
+                self._housekeep_passes(now)
+            t1 = time.perf_counter_ns()
+            ops.housekeep_ns += t1 - t0
+            prof.note_host_span(
+                "mqtt/housekeep", t0, t1, visits=ops.housekeep_visits - visits
+            )
+        else:
+            self._housekeep_passes(now)
+        ops.housekeep_ticks += 1
+
+    def _housekeep_passes(self, now: int) -> None:
+        self.clear_expired_clients(now)
+        self.clear_expired_retained_messages(now)
+        self.send_delayed_lwt(now)
+        self.clear_expired_inflights(now)
+        self.sweep_overload()
 
     # -- telemetry plane (mqtt_tpu.telemetry) ------------------------------
 
@@ -2174,21 +2247,11 @@ class Server:
 
     def _outbound_pressure(self) -> float:
         """Aggregate outbound backlog — publishes parked in every
-        client's bounded outbound queue — normalized against the
-        configured cap (the governor's 'subscribers are not draining'
-        signal)."""
-        clients = self.clients
-        try:
-            # lock-free iteration: the signal is a statistical sample,
-            # and copying the whole registry per evaluation would cost
-            # an O(clients) allocation 4x/second at the target scale
-            total = sum(
-                cl.state.outbound_qty for cl in clients.internal.values()
-            )
-        except RuntimeError:  # a connect/disconnect resized mid-walk
-            total = sum(
-                cl.state.outbound_qty for cl in clients.get_all().values()
-            )
+        registered client's bounded outbound queue, the running total
+        the queues keep (``SessionsDue.outbound``) — normalized against
+        the configured cap (the governor's 'subscribers are not
+        draining' signal)."""
+        total = self._due.outbound
         self._outbound_backlog = total
         return total / self.options.overload_max_outbound_backlog
 
@@ -2229,16 +2292,29 @@ class Server:
     ) -> int:
         """One slow-consumer eviction pass over the clients ``loop``
         owns (every client when no fabric is attached — the single-loop
-        path unchanged). Returns the evictions performed; the shard
-        housekeeping tick feeds it into the per-shard counter."""
+        path unchanged) that may hold a backlog (``SessionsDue.backlog``:
+        a transport write left bytes buffered, or the outbound queue
+        filled). A client whose transport holds nothing and whose queue
+        is not full leaves the set: every sweep until its next such
+        write or fill would find it so, record that and reset its clock,
+        which is what leaving it does. Returns the evictions performed;
+        the shard housekeeping tick feeds it into the per-shard
+        counter."""
         ov = self.overload
         if ov is None:
             return 0
+        due = self._due
+        backlog = due.backlog
         buf_limit = self.options.overload_client_buffer_limit_bytes
         now = time.monotonic()
         evicted = 0
-        for cl in self.clients.get_all().values():
-            if cl.net.inline or cl.closed:
+        held = tuple(backlog)
+        self._ops.housekeep_visits += len(held)
+        for cl in held:
+            if cl.state.due is not due or cl.net.inline or cl.closed:
+                # out of the registry, or never to write again: no sweep
+                # looks at it (a restored session joins afresh)
+                backlog.discard(cl)
                 continue
             if self._fabric is not None:
                 owner = cl.net.loop
@@ -2260,6 +2336,11 @@ class Server:
             cl.state.sweep_buffered = buffered
             if draining or not (buffered > buf_limit or qfull):
                 cl.state.backlog_over_since = None
+                if not buffered and not qfull:
+                    backlog.discard(cl)
+                    if cl.state.outbound.full():  # filled meanwhile, off the loop
+                        backlog.add(cl)
+                    continue
             elif cl.state.backlog_over_since is None:
                 cl.state.backlog_over_since = now
             over_since = cl.state.backlog_over_since
@@ -4696,6 +4777,7 @@ class Server:
                 try:
                     cl.net.writer.write(frame[wrote:])
                     ops.socket_sends += 2  # the flush's and the tail's
+                    cl.note_held()
                 except Exception as e:
                     self.log.debug(
                         "fan-out flush tail failed: error=%s client=%s",
@@ -6307,7 +6389,17 @@ class Server:
     # -- expiry loops (server.go:1696-1758) --------------------------------
 
     def clear_expired_clients(self, dt: int) -> None:
-        for id_, client in self.clients.get_all().items():
+        """Expire the registry's disconnected sessions that are past their
+        expiry interval (``SessionsDue.stopped``: a session enters it when
+        its connection ends and leaves with the registry)."""
+        due = self._due
+        stopped = tuple(due.stopped)
+        self._ops.housekeep_visits += len(stopped)
+        for client in stopped:
+            if client.state.due is not due:
+                due.stopped.discard(client)
+                continue
+            id_ = client.id
             disconnected = client.stop_time
             if disconnected == 0:
                 continue
@@ -6356,7 +6448,16 @@ class Server:
                     self._retained_engine.note_retained(filter_, False)
 
     def clear_expired_inflights(self, now: int) -> None:
-        for client in self.clients.get_all().values():
+        """Drop the expired in-flight entries of the registry's sessions
+        that hold any (``SessionsDue.inflight``: a session's map keeps it
+        there while it holds an entry)."""
+        due = self._due
+        holding = tuple(due.inflight)
+        self._ops.housekeep_visits += len(holding)
+        for client in holding:
+            if client.state.due is not due:
+                due.inflight.discard(client)
+                continue
             deleted = client.clear_expired_inflights(
                 now, self.options.capabilities.maximum_message_expiry_interval
             )

@@ -553,6 +553,7 @@ MAX_SLICE_BATCHES = 16_384
 BEAT_NS = 5_000_000  # the loop heartbeat's interval while armed
 CLOCK_NS = 1_000_000_000  # a mqtt/clock mark at the loop's first turn this long after the last
 TRACES_BATCHES = 256  # a slice's newest batches, as /traces serves them
+MAX_HOST_SPANS = 256  # a slice's housekeeping spans (DeviceProfiler.note_host_span)
 # the session follower's period: arming and freezing a slice lag the
 # session's edges by at most this when no batch forms
 FOLLOW_S = 1 / 29
@@ -654,7 +655,8 @@ def slice_events(sl: TraceSlice, anchor: float, pid: int) -> list:
     (``args.batch`` is the number a sampled publish's root span names),
     the collections that ended inside it (every full one, the young
     ones of over a millisecond), and the loop's longest iteration with
-    its parts (``stall``: ``_LoopFrame``)."""
+    its parts (``stall``: ``_LoopFrame``), and the housekeeping tick's
+    passes and $SYS publishes (``host_spans``)."""
     events = []
 
     def add(name, cat, t0_ns, t1_ns, tid, args):
@@ -677,6 +679,8 @@ def slice_events(sl: TraceSlice, anchor: float, pid: int) -> list:
         t0 = stall["t0_ns"]
         add("loop/stall", "loop", t0, t0 + stall["busy_ns"], 999_998,
             {k: v for k, v in stall.items() if k != "t0_ns"})
+    for name, t0, t1, args in sl.b.get("host_spans", ()):
+        add(name, "loop", t0, t1, 999_997, args)
     return events
 
 
@@ -724,6 +728,7 @@ class _LoopFrame:
         return (
             prof.ingest_busy_ns, prof.ack_busy_ns, prof.fanout_busy_ns,
             prof.slice_flush_ns, prof.send_busy_ns, GC2.pause_ns_total,
+            prof.housekeep_busy_ns, prof.sys_publish_busy_ns,
         )
 
     def select(self, timeout: Optional[float] = None) -> list:
@@ -758,7 +763,7 @@ class _LoopFrame:
         """The iteration that just closed is the slice's longest."""
         prof = self._prof
         prof.stall_busy_ns = busy
-        ingest, ack, fanout, flush, send, gc_ns = (
+        ingest, ack, fanout, flush, send, gc_ns, housekeep, sys_publish = (
             now - was for now, was in zip(self._phases(), self._saved)
         )
         prof.stall = {
@@ -768,6 +773,8 @@ class _LoopFrame:
             # and the collections inside whichever phase made them
             "fanout_ns": fanout, "flush_ns": flush,
             "send_ns": send, "gc_ns": gc_ns,
+            # the housekeeping tick's passes and its $SYS publish
+            "housekeep_ns": housekeep, "sys_publish_ns": sys_publish,
             # the oldest generation collected inside it, -1 where none
             "gc_gen": max(
                 (g for g, end in enumerate(GC2.last_end_ns) if end > began),
@@ -914,6 +921,13 @@ class DeviceProfiler:
         # ``_Ops.socket_sends`` (clients._send, the native flush):
         # inside ingest, fan-out or neither
         self.send_busy_ns = 0
+        # the housekeeping tick's expiry and eviction passes and its $SYS
+        # publish (Server.housekeep, note_host_span), armed only: their
+        # summed time, and each one as ``(name, start_ns, end_ns, args)``
+        # since arming, the first MAX_HOST_SPANS
+        self.housekeep_busy_ns = 0
+        self.sys_publish_busy_ns = 0
+        self.host_spans: list = []
         # the loop's ledger (_LoopFrame adds to it, on the loop's own
         # thread): select() calls that could not block and that could,
         # events they returned, the summed iterations, and the longest
@@ -1065,6 +1079,9 @@ class DeviceProfiler:
             "fanout_n": self.fanout_n,
             "slice_flush_ns": self.slice_flush_ns,
             "send_busy_ns": self.send_busy_ns,
+            "housekeep_busy_ns": self.housekeep_busy_ns,
+            "sys_publish_busy_ns": self.sys_publish_busy_ns,
+            "host_spans": list(self.host_spans),
             "gc_pause_ns": list(GC2.gc_pause_ns),
             "loop_beats": self._beats,
             "loop_stall_max_ns": self.loop_stall_max_ns,
@@ -1146,6 +1163,7 @@ class DeviceProfiler:
         self._beat_ns = 0
         self.stall = None
         self.stall_busy_ns = 0
+        self.host_spans = []
         loop = self.loop
         self._framed = getattr(loop, "_selector", None) is not None
         self._snap_a = self._snapshot()
@@ -1173,7 +1191,8 @@ class DeviceProfiler:
             self._frame = loop._selector = _LoopFrame(
                 selector, self, a["t_ns"],
                 (a["ingest_busy_ns"], a["ack_busy_ns"], a["fanout_busy_ns"],
-                 a["slice_flush_ns"], a["send_busy_ns"], sum(a["gc_pause_ns"])),
+                 a["slice_flush_ns"], a["send_busy_ns"], sum(a["gc_pause_ns"]),
+                 a["housekeep_busy_ns"], a["sys_publish_busy_ns"]),
             )
 
     def _frame_out(self, frame: _LoopFrame) -> None:
@@ -1241,6 +1260,20 @@ class DeviceProfiler:
         self.fanout_wait_ns += start_ns - set_ns
         self.fanout_busy_ns += done_ns - start_ns
         self.fanout_n += 1
+
+    def note_host_span(self, name: str, t0_ns: int, t1_ns: int, **args: Any) -> None:
+        """One of the housekeeping tick's stretches on the loop (armed
+        callers only): ``mqtt/housekeep``, a tick's expiry and eviction
+        passes (``args``: the sessions they looked at), or
+        ``mqtt/sys.publish``, its $SYS publish. Its time goes to the
+        loop frame's phase of that name and the span to the slice
+        (``host_spans``)."""
+        if name == "mqtt/housekeep":
+            self.housekeep_busy_ns += t1_ns - t0_ns
+        else:
+            self.sys_publish_busy_ns += t1_ns - t0_ns
+        if len(self.host_spans) < MAX_HOST_SPANS:
+            self.host_spans.append((name, t0_ns, t1_ns, args))
 
     def note_slice_flush(self, busy_ns: int) -> None:
         """The joined writes at a completion slice's end (the sockets the

@@ -151,6 +151,7 @@ def frame_phases(prof) -> tuple:
     return (
         prof.ingest_busy_ns, prof.ack_busy_ns, prof.fanout_busy_ns,
         prof.slice_flush_ns, prof.send_busy_ns, GC2.pause_ns_total,
+        prof.housekeep_busy_ns, prof.sys_publish_busy_ns,
     )
 
 
@@ -183,6 +184,7 @@ def test_the_stall_is_the_longest_iteration_with_the_phases_inside_it(
     assert prof.stall == {
         "t0_ns": t0, "busy_ns": 50 * MS, "ingest_ns": 30 * MS, "ack_ns": 0,
         "fanout_ns": 0, "flush_ns": 0, "send_ns": 2 * MS, "gc_ns": 5 * MS,
+        "housekeep_ns": 0, "sys_publish_ns": 0,
         "gc_gen": 1,
     }
     rest = prof.stall["busy_ns"] - prof.stall["ingest_ns"] - prof.stall["fanout_ns"]
@@ -300,7 +302,7 @@ def test_a_snapshot_off_the_loop_counts_the_iteration_in_progress(scripted):
     clock, prof, _spans = scripted
     prof._framed = True
     frame = prof._frame = _LoopFrame(
-        ScriptedSelector(clock, [(1 * MS, 0)]), prof, clock.now, (0,) * 6
+        ScriptedSelector(clock, [(1 * MS, 0)]), prof, clock.now, (0,) * 8
     )
     clock.now += 8 * MS
     ledger = prof._ledger()

@@ -175,6 +175,34 @@ EXPORTED = [
         "the hooks",
         "$SYS/broker/fanout/qos_begin_ns",
     ),
+    (
+        "housekeep_ticks",
+        "mqtt_tpu_housekeep_ticks_total",
+        "Housekeeping ticks: the once-a-second expiry and eviction passes",
+        "$SYS/broker/housekeep/ticks",
+    ),
+    (
+        "housekeep_visits",
+        "mqtt_tpu_housekeep_visits_total",
+        "Sessions the housekeeping passes looked at: those holding in-flight "
+        "entries, disconnected ones awaiting expiry, and those with a "
+        "transport or queue backlog",
+        "$SYS/broker/housekeep/visits",
+    ),
+    (
+        "housekeep_ns",
+        "mqtt_tpu_housekeep_seconds_total",
+        "Time of the housekeeping ticks' expiry and eviction passes while a "
+        "profiler session was live",
+        "$SYS/broker/housekeep/ns",
+    ),
+    (
+        "sys_publish_ns",
+        "mqtt_tpu_sys_publish_seconds_total",
+        "Time of the housekeeping tick's $SYS publish while a profiler "
+        "session was live",
+        "$SYS/broker/housekeep/sys_publish_ns",
+    ),
 ]
 
 

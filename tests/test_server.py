@@ -592,8 +592,12 @@ class TestSysTopics:
                 "$SYS/broker/fanout/qos_begins",
                 "$SYS/broker/fanout/qos_begins_hooked",
                 "$SYS/broker/fanout/qos_begin_ns",
+                "$SYS/broker/housekeep/ticks",
+                "$SYS/broker/housekeep/visits",
+                "$SYS/broker/housekeep/ns",
+                "$SYS/broker/housekeep/sys_publish_ns",
             } <= base
-            assert len(base) == 44
+            assert len(base) == 48
             await h.shutdown()
 
         run(scenario())
