@@ -587,7 +587,8 @@ class SliceSocket:
     ``cork``, in submit order. That holds only while the record's own
     appends (and other packets that join the same cork) are all that
     touched the socket, so whatever else puts a frame on the client's
-    way out or takes the socket away clears ``Client._slice``: an early
+    way out or takes the socket away ends the record
+    (``Client._end_slice``, which clears ``Client._slice``): an early
     cork write (``_uncork``), the outbound queue's two ways in
     (``server._enqueue_frame``, ``publish_to_client``), ``stop``. The
     next delivery then reads the socket afresh, as before.
@@ -600,9 +601,20 @@ class SliceSocket:
     reads them from another thread. The counts a profiler's snapshot
     does read off the loop (deliveries, by route; the cork's frames)
     move once a publish and variant, with the publish's ``fanout_n``
-    (``server._flush_variant``)."""
+    (``server._flush_variant``).
 
-    __slots__ = ("cl", "cork", "version", "n", "nbytes", "payload")
+    ``entries``, ``marks``: the socket's run, its QoS>0 deliveries whose
+    bookkeeping waits (``take_run``): each one's inflight entry, and
+    ``(publish, frame offset in the cork, packet id offset in the frame,
+    frame length)``. The run is settled (``server._settle_run``: one
+    ``Inflight.begin_run``, the ids written into the cork) before
+    anything else reads the session's inflight map, cursor or quota
+    (``Client.settled_inflight``) and before the cork's bytes can leave
+    (``Client._cut_cork``, ``_end_slice``, the slice's end)."""
+
+    __slots__ = (
+        "cl", "cork", "version", "n", "nbytes", "payload", "entries", "marks"
+    )
 
     def __init__(self, cl: "Client") -> None:
         self.cl = cl
@@ -611,6 +623,8 @@ class SliceSocket:
         self.n = 0
         self.nbytes = 0
         self.payload = 0
+        self.entries: list = []
+        self.marks: list = []
 
     def take(self, frame: bytes, payload: int) -> None:
         """One delivery: ``frame`` behind what the cork holds
@@ -621,6 +635,24 @@ class SliceSocket:
         cork += frame
         self.n += 1
         self.nbytes += len(frame)
+        self.payload += payload
+        if len(cork) >= CORK_MAX_BYTES:
+            self.cl._cut_cork()
+
+    def take_run(
+        self, frame: bytes, id_off: int, payload: int, entry, pk
+    ) -> None:
+        """One QoS>0 delivery of ``pk`` whose bookkeeping joins the run:
+        ``frame`` behind what the cork holds with its placeholder packet
+        id (at ``id_off``), ``entry`` the inflight entry it will have. As
+        ``take`` otherwise."""
+        cork = self.cork
+        n = len(frame)
+        self.entries.append(entry)
+        self.marks.append((pk, len(cork), id_off, n))
+        cork += frame
+        self.n += 1
+        self.nbytes += n
         self.payload += payload
         if len(cork) >= CORK_MAX_BYTES:
             self.cl._cut_cork()
@@ -870,10 +902,57 @@ class Client:
 
     def _cut_cork(self) -> None:
         """The cork is past its byte bound: write it out early, and go
-        on corking."""
+        on corking. A slice's run is settled first: the frames of the
+        deliveries it refuses or parks leave the cork, which may then be
+        under its bound, as it would be had they never joined it. A cork
+        whose record another thread dropped (``stop``) is not cut: the
+        slice that made the record settles its run at its end, before
+        the cork can be written."""
+        rec = self._slice  # read before ``closed``: a stop flips that first
+        if rec is None:
+            if self.closed:
+                return
+        elif rec.entries:
+            self.ops.qos_run(rec)
+            if len(self._cork) < CORK_MAX_BYTES:
+                return
         self.ops.cork_early_writes += 1
         self._uncork()
         self._cork = bytearray()
+
+    def settled_inflight(self) -> Inflight:
+        """The session's inflight map, for whatever reads its entries,
+        cursor or quota, or takes a packet id, on the loop that owns the
+        socket: the run of the slice's record of this socket
+        (``SliceSocket.entries``) is settled first, so that ids are
+        taken in delivery order. The record goes on."""
+        rec = self._slice
+        if rec is not None and rec.entries:
+            self.ops.qos_run(rec)
+        return self.state.inflight
+
+    def _end_slice(self) -> None:
+        """End the slice's record of this socket, if it holds one, its
+        run settled first. From a thread other than the loop that owns
+        the socket (a ``stop``) the record is only dropped: that loop is
+        inside the slice that made it, which settles it at its end,
+        before the cork can be written."""
+        rec = self._slice
+        if rec is not None:
+            if rec.entries and self._on_owner_loop():
+                self.ops.qos_run(rec)
+            self._slice = None
+
+    def _on_owner_loop(self) -> bool:
+        """Whether this thread may touch what the socket's loop owns: it
+        runs that loop, or that loop is not running."""
+        loop = self.net.loop
+        if loop is None or not loop.is_running():
+            return True
+        try:
+            return asyncio.get_running_loop() is loop
+        except RuntimeError:
+            return False
 
     def _uncork(self) -> None:
         """Write what this socket's cork holds, as one transport write,
@@ -882,9 +961,10 @@ class Client:
         cork written (``cork_writes``), beside the packets that joined
         one (``cork_frames``) and the writes its byte bound forced
         (``cork_early_writes``). The slice's record of the socket ends
-        with its cork: the transport may hold bytes now."""
+        with its cork, its run settled first: the transport may hold
+        bytes now."""
+        self._end_slice()
         cork, self._cork = self._cork, None
-        self._slice = None
         if cork and self.net.writer is not None:
             self.ops.cork_writes += 1
             self._send(bytes(cork))
@@ -1402,22 +1482,18 @@ class Client:
         if not self.state.open:
             return
         self.state.open = False
-        self._slice = None  # a slice in hand reads the socket afresh
+        # a slice in hand reads the socket afresh; the entries of its
+        # run are stored, for resend in a session that persists
+        self._end_slice()
         if err is not None:
             self.state.stop_cause = err
-        loop = self.net.loop
         marshaled = False
-        if loop is not None and loop.is_running():
+        if not self._on_owner_loop():
             try:
-                running = asyncio.get_running_loop()
+                self.net.loop.call_soon_threadsafe(self._stop_teardown)
+                marshaled = True
             except RuntimeError:
-                running = None
-            if loop is not running:
-                try:
-                    loop.call_soon_threadsafe(self._stop_teardown)
-                    marshaled = True
-                except RuntimeError:
-                    marshaled = False  # owner loop died first
+                marshaled = False  # owner loop died first
         if not marshaled:
             self._stop_teardown()
         # brokerlint: ok=R3 session-expiry bookkeeping is wall-clock (persists across restarts)

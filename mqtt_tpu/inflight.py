@@ -74,6 +74,9 @@ class Inflight:
         flight, wrapping to 1 past ``maximum``; -1 once the walk comes
         round to ``start``."""
         internal = self.internal
+        i = start + 1
+        if i <= maximum and internal.get(i & 0xFFFF) is None:
+            return i  # the walk's first step, as a session mostly finds it
         i = start
         overflowed = False
         while True:
@@ -107,23 +110,36 @@ class Inflight:
         caller marks it for resend (``expiry = -1``) once its
         ``on_qos_publish`` hooks have seen it."""
         with self._lock:
-            internal = self.internal
-            if len(internal) >= cap:
-                return BEGIN_FULL
-            i = self._free_id(state.packet_id, maximum)
-            if i < 0:
-                return BEGIN_EXHAUSTED
-            state.packet_id = i
-            pid = pk.packet_id = i & 0xFFFF  # [MQTT-2.2.1-4]
-            internal[pid] = pk  # [MQTT-4.3.2-3] [MQTT-4.3.3-3]
-            if len(internal) == 1 and self._due is not None:
-                self._due.add(self._owner)
-            quota = self.send_quota
-            if quota > 0:
-                self.send_quota = quota - 1
-            elif quota == 0 and self.maximum_send_quota > 0:
-                return BEGIN_PARKED
-            return pid
+            return self._begin_locked(state, pk, cap, maximum)
+
+    def begin_run(self, state, entries: list, cap: int, maximum: int) -> list:
+        """:meth:`begin` for each of ``entries`` in order, under ONE
+        acquisition of the lock: one socket's QoS>0 deliveries of a
+        completion slice (``server._settle_run``). Returns one answer an
+        entry, as :meth:`begin` answers it."""
+        with self._lock:
+            begin = self._begin_locked
+            return [begin(state, pk, cap, maximum) for pk in entries]
+
+    def _begin_locked(self, state, pk: Packet, cap: int, maximum: int) -> int:
+        """:meth:`begin`'s bookkeeping, the lock held by the caller."""
+        internal = self.internal
+        if len(internal) >= cap:
+            return BEGIN_FULL
+        i = self._free_id(state.packet_id, maximum)
+        if i < 0:
+            return BEGIN_EXHAUSTED
+        state.packet_id = i
+        pid = pk.packet_id = i & 0xFFFF  # [MQTT-2.2.1-4]
+        internal[pid] = pk  # [MQTT-4.3.2-3] [MQTT-4.3.3-3]
+        if len(internal) == 1 and self._due is not None:
+            self._due.add(self._owner)
+        quota = self.send_quota
+        if quota > 0:
+            self.send_quota = quota - 1
+        elif quota == 0 and self.maximum_send_quota > 0:
+            return BEGIN_PARKED
+        return pid
 
     def get(self, id_: int) -> Optional[Packet]:
         with self._lock:

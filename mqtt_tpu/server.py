@@ -883,6 +883,12 @@ class _Ops:
         self.qos_begins = 0
         self.qos_begins_hooked = 0
         self.qos_begin_ns = 0
+        # the server's settlement of a slice's run of one socket's QoS>0
+        # deliveries (Server._settle_run; None until the server wires
+        # it), the runs it settled and the entries they held
+        self.qos_run: Optional[Callable[..., None]] = None
+        self.qos_runs = 0
+        self.qos_run_entries = 0
         # the 1 Hz housekeeping tick (Server.housekeep): ticks, sessions
         # its expiry and eviction passes looked at (SessionsDue's sets;
         # a loop shard's eviction sweep adds its own), and while a
@@ -1057,6 +1063,18 @@ _OPS_COUNTERS = (
         scale=1_000_000_000,
     ),
     _Counter(
+        "qos_runs", "mqtt_tpu_qos_runs_total",
+        "Runs of one socket's QoS>0 deliveries in a completion slice "
+        "whose inflight bookkeeping was settled at once",
+        "/broker/fanout/qos_runs",
+    ),
+    _Counter(
+        "qos_run_entries", "mqtt_tpu_qos_run_entries_total",
+        "QoS>0 deliveries whose bookkeeping a run settled (the rest "
+        "were begun one at a time)",
+        "/broker/fanout/qos_run_entries",
+    ),
+    _Counter(
         "housekeep_ticks", "mqtt_tpu_housekeep_ticks_total",
         "Housekeeping ticks: the once-a-second expiry and eviction passes",
         "/broker/housekeep/ticks",
@@ -1114,6 +1132,7 @@ class Server:
         self._ops.fast_publish_eligible = self.fast_publish_eligible
         self._ops.ingest_run = self.ingest_run
         self._ops.ack_run = self.ack_run
+        self._ops.qos_run = self._settle_run
         # "no hook provides any of these events", by gate name, as of a
         # hooks generation: (generation, verdict) (_no_hook_provides)
         self._hook_gates: dict = {}
@@ -2890,16 +2909,17 @@ class Server:
 
     def _drain_quota_starved(self, cl: Client) -> None:
         # post-process: drain one quota-starved inflight if quota freed up
-        if len(cl.state.inflight) > 0 and cl.state.inflight.send_quota > 0:
-            nxt = cl.state.inflight.next_immediate()
+        inflight = cl.settled_inflight()  # a slice's run parks first
+        if len(inflight) > 0 and inflight.send_quota > 0:
+            nxt = inflight.next_immediate()
             if nxt is not None:
                 try:
                     cl.write_packet(nxt)
                 except Exception:  # brokerlint: ok=R4 client mid-teardown; the inflight store still reconciles below
                     pass
-                if cl.state.inflight.delete(nxt.packet_id):
+                if inflight.delete(nxt.packet_id):
                     self.info.inflight -= 1
-                cl.state.inflight.decrease_send_quota()
+                inflight.decrease_send_quota()
 
     def process_connect(self, cl: Client, pk: Packet) -> None:
         """A second CONNECT is a protocol violation [MQTT-3.1.0-2]
@@ -3612,7 +3632,10 @@ class Server:
         loop. A socket targeted once is written at its own publish.
         What a corked socket is, is read once too and kept for the slice
         (``clients.SliceSocket``): its deliveries are appends, and the
-        counters they move are added when the slice ends.
+        counters they move are added when the slice ends. Its QoS>0
+        deliveries are begun as one run, settled (``_settle_run``) when
+        the slice ends, before its corks are written, unless something
+        inside the slice needed the session's inflight state first.
 
         A result that holds ``$share`` groups has each group's member
         picked here, before the slice reads its targets, when nothing can
@@ -3719,6 +3742,25 @@ class Server:
                         if wake is not None:
                             wake()
         finally:
+            if records:
+                # the records' runs, each socket's QoS>0 bookkeeping in
+                # one settlement: fan-out's time, before the joined
+                # writes are timed
+                if prof is not None:
+                    t_run = time.perf_counter_ns()
+                runs = 0
+                for rec in records:
+                    if rec.entries:
+                        runs += 1
+                        try:
+                            self._settle_run(rec)
+                        except Exception as e:
+                            self.log.debug(
+                                "slice settlement failed: error=%s client=%s",
+                                e, rec.cl.id,
+                            )
+                if prof is not None and runs:
+                    prof.note_qos_runs(time.perf_counter_ns() - t_run)
             if corked:
                 if prof is not None:
                     span = prof.annotation(
@@ -3993,7 +4035,7 @@ class Server:
         ``count_delivery`` keeps $SYS housekeeping fan-out out of the
         amplification accounting (the caller knows the topic; the
         pre-encoded frame does not)."""
-        tcl._slice = None  # frames wait in the queue now: not ready
+        tcl._end_slice()  # frames wait in the queue now: not ready
         try:
             tcl.state.outbound.put_nowait(data)
             tcl.state.outbound_full_since = None
@@ -4561,12 +4603,18 @@ class Server:
         record of (``Client._slice``, ``clients.SliceSocket``: corked by
         the slice and found ready then) is not read again: past the ACL
         call and the QoS bookkeeping its delivery is one append to that
-        cork. Whatever else touches the socket inside the slice ends the
-        record, a hook of this very delivery too, and the socket is read
-        as any other. The three ways out are counted, a delivery
-        each where it was accepted (``_Ops.deliveries_flush``, ``_cork``,
-        ``_queue``), and the deliveries a full queue refused
-        (``deliveries_dropped_full``).
+        cork. Where no hook provides ``on_qos_publish``, a QoS>0
+        delivery to such a socket appends its frame with the variant's
+        placeholder id and joins the record's run: the socket's QoS>0
+        deliveries of the slice are begun together, under one hold of
+        the session's inflight lock, and their ids written into the
+        cork (``_settle_run``), before anything else reads the session's
+        inflight state and before the cork is written. Whatever else
+        touches the socket inside the slice ends the record, a hook of
+        this very delivery too, and the socket is read as any other. The
+        three ways out are counted, a delivery each where it was accepted
+        (``_Ops.deliveries_flush``, ``_cork``, ``_queue``), and the
+        deliveries a full queue refused (``deliveries_dropped_full``).
 
         Under the shard fabric the group is split BY OWNING SHARD
         first: each remote shard receives its whole sub-group as one
@@ -4647,9 +4695,20 @@ class Server:
                         proto.fixed_header.qos = eff
                         proto.fixed_header.retain = retain
                     out = proto.clone()
-                    st = cl.state
-                    pid = st.inflight.begin(st, out, cap, max_id)
                     n_begins += 1
+                    if rec is not None and not hooked:
+                        # the socket's run: the frame joins the cork
+                        # with its placeholder id, and the lock, the id
+                        # and the store wait for the run's settlement
+                        # (_settle_run), once a socket a slice
+                        if armed:
+                            begin_ns += time.perf_counter_ns() - t_begin
+                        rec.take_run(data, id_off, payload_len, out, dpk)
+                        n_rec += 1
+                        continue
+                    # a pending run of the socket takes its ids first
+                    st = cl.state
+                    pid = cl.settled_inflight().begin(st, out, cap, max_id)
                     if pid >= BEGIN_PARKED:  # a new entry
                         info.inflight += 1
                         if hooked:
@@ -4799,6 +4858,92 @@ class Server:
             # legacy path counts after publish_to_client succeeds)
             ops.deliveries_flush += 1
             self._note_tenant_out(cl, dpk)
+
+    def _settle_run(self, rec: SliceSocket) -> None:
+        """Settle the run of a slice's record of one socket
+        (``SliceSocket.entries``, ``.marks``: its QoS>0 deliveries, each
+        one's frame in the cork with a placeholder id): what
+        ``_flush_variant`` does a delivery with ``Inflight.begin``, for
+        all of them under one acquisition of the session's inflight lock
+        (``Inflight.begin_run``). Each accepted entry's id is written into
+        its frame's two bytes in the cork; a parked entry is marked for
+        resend; the frames of parked and refused deliveries are cut out
+        of the cork (``_cut_frames``); each refusal is then told
+        (``_qos_refused``), in delivery order. Entries, ids, cursor,
+        quota and bytes come out as the per-delivery bookkeeping leaves
+        them. Where the bookkeeping fails, every frame of the run is cut
+        out: none leaves with its placeholder id."""
+        entries, rec.entries = rec.entries, []
+        marks, rec.marks = rec.marks, []
+        prof = self.profiler
+        armed = prof is not None and prof.armed
+        if armed:
+            t0 = time.perf_counter_ns()
+        cl = rec.cl
+        st = cl.state
+        inflight = st.inflight
+        caps = self.options.capabilities
+        try:
+            answers = inflight.begin_run(
+                st, entries, caps.maximum_inflight, caps.maximum_packet_id
+            )
+        except Exception as e:
+            self.log.debug(
+                "failed settling a run: error=%s client=%s", e, cl.id
+            )
+            self._cut_frames(rec, marks)
+            # what the failed call stored stays, for resend
+            self.info.inflight += sum(
+                inflight.get(out.packet_id) is out for out in entries
+            )
+            return
+        cork = rec.cork
+        dropped: list = []
+        refused: list = []
+        for pid, entry, mark in zip(answers, entries, marks):
+            if pid > 0:
+                at = mark[1] + mark[2]
+                cork[at] = pid >> 8
+                cork[at + 1] = pid & 0xFF
+                continue
+            dropped.append(mark)
+            if pid == BEGIN_PARKED:
+                entry.expiry = -1  # resent once quota frees
+            else:
+                refused.append((mark[0], pid))
+        if dropped:
+            self._cut_frames(rec, dropped)
+        self.info.inflight += len(answers) - len(refused)
+        ops = self._ops
+        ops.qos_runs += 1
+        ops.qos_run_entries += len(entries)
+        for pk, code in refused:
+            try:
+                self._qos_refused(cl, pk, code)
+            except Exception as e:
+                self.log.debug(
+                    "failed publishing packet: error=%s client=%s", e, cl.id
+                )
+        if armed:
+            ops.qos_begin_ns += time.perf_counter_ns() - t0
+
+    def _cut_frames(self, rec: SliceSocket, marks: list) -> None:
+        """Cut the frames ``marks`` names (``SliceSocket.marks``, in cork
+        order) out of the record's cork, back to front, and take back the
+        counts their appends moved: on the loop that moved them, inside
+        the same slice."""
+        cork = rec.cork
+        ops = self._ops
+        tele = self.telemetry
+        for pk, at, _id_off, n in reversed(marks):
+            del cork[at:at + n]
+            rec.n -= 1
+            rec.nbytes -= n
+            rec.payload -= len(pk.payload)
+            ops.cork_frames -= 1
+            ops.deliveries_cork -= 1
+            if tele is not None and not pk.topic_name.startswith("$SYS"):
+                tele.fanout_deliveries.inc(-1)
 
     @staticmethod
     def _patch_id(data: bytes, id_off: int, pid: int) -> bytes:
@@ -5288,7 +5433,8 @@ class Server:
             # encode-once fan-out takes them (_flush_variant)
             caps = self.options.capabilities
             st = cl.state
-            pid = st.inflight.begin(
+            # a slice's run of the socket takes its ids first
+            pid = cl.settled_inflight().begin(
                 st, out, caps.maximum_inflight, caps.maximum_packet_id
             )
             if pid < BEGIN_PARKED:
@@ -5303,7 +5449,7 @@ class Server:
         if cl.net.writer is None or cl.closed:
             raise CODE_DISCONNECT()
 
-        cl._slice = None  # frames wait in the queue now: not ready
+        cl._end_slice()  # frames wait in the queue now: not ready
         try:
             cl.state.outbound.put_nowait(out)
             cl.state.outbound_full_since = None

@@ -1283,6 +1283,13 @@ class DeviceProfiler:
         self.fanout_busy_ns += busy_ns
         self.slice_flush_ns += busy_ns
 
+    def note_qos_runs(self, busy_ns: int) -> None:
+        """The settlement of a completion slice's runs at its end (each
+        socket's QoS>0 bookkeeping, ``server._settle_run``): fan-out time
+        of the slice's publishes, and no publish of its own, outside the
+        joined writes' ``slice_flush_ns``."""
+        self.fanout_busy_ns += busy_ns
+
     def ensure_device(self, did: int) -> _DevWindow:
         """The window replica for one device id, creating it (and its
         ``device``-labeled metric children) on first sight. Idempotent;

@@ -3,7 +3,8 @@ on entries, the packet-id walk, the store, the send quota and its park)
 under ONE acquisition of the session's inflight lock, against the three
 calls it replaced (``len``, ``Client.next_packet_id``'s walk of ``get``
 calls, ``set`` + ``decrease_send_quota``), written out below as the
-oracle."""
+oracle; and ``Inflight.begin_run``, a run of entries under one
+acquisition, against ``begin`` called once an entry."""
 
 import random
 
@@ -173,5 +174,103 @@ def test_one_acquisition_a_begin():
         before = stats.acquisitions
         three_calls(old, entry(), 100, 0xFFFF)
         assert stats.acquisitions - before == 4  # len, get, get, set
+    finally:
+        DEFAULT_PLANE.disarm()
+
+
+def twin(a):
+    """A second session holding what ``a`` holds."""
+    b = ClientState(0, 16)
+    for pid in a.inflight.internal:
+        b.inflight.set(entry(pid))
+    b.packet_id = a.packet_id
+    b.inflight.reset_send_quota(a.inflight.maximum_send_quota)
+    b.inflight.send_quota = a.inflight.send_quota
+    return b
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_begin_run_answers_as_begin_did_an_entry(seed):
+    """Random sessions and runs of one to twelve entries at a small id
+    space: the run's answers, ids, map, cursor and quota are those of
+    ``begin`` called once an entry, in order."""
+    rng = random.Random(1000 + seed)
+    seen = set()
+    for _ in range(60):
+        maximum = rng.randint(1, 8)
+        cap = rng.randint(1, 10)
+        a = session(rng, maximum)
+        b = twin(a)
+        run = [entry() for _ in range(rng.randint(1, 12))]
+        singles = [entry() for _ in run]
+        got = a.inflight.begin_run(a, run, cap, maximum)
+        want = [b.inflight.begin(b, pk, cap, maximum) for pk in singles]
+        assert got == want
+        assert snapshot(a) == snapshot(b)
+        for code, pk, single in zip(got, run, singles):
+            if code >= BEGIN_PARKED:
+                assert a.inflight.internal[pk.packet_id] is pk
+                assert pk.packet_id == single.packet_id
+            else:
+                assert pk.packet_id == 0
+            seen.add(code if code < 0 else "id")
+    assert seen == {BEGIN_FULL, BEGIN_EXHAUSTED, BEGIN_PARKED, "id"}
+
+
+@pytest.mark.parametrize(
+    "held, cursor, want",
+    [
+        ((), 0, [1, 2, 3]),
+        ((2,), 0, [1, 3, 4]),  # the walk passes a held id over
+        ((), 4, [5, 1, 2]),  # and wraps past the maximum
+        ((1, 2, 3, 4), 0, [5, BEGIN_EXHAUSTED, BEGIN_EXHAUSTED]),
+    ],
+    ids=["fresh", "held", "wrap", "exhausted"],
+)
+def test_begin_run_walks_ids_in_order(held, cursor, want):
+    st = ClientState(0, 16)
+    for pid in held:
+        st.inflight.set(entry(pid))
+    st.packet_id = cursor
+    assert st.inflight.begin_run(st, [entry() for _ in want], 100, 5) == want
+
+
+def test_an_empty_run_moves_nothing():
+    st = ClientState(0, 16)
+    st.packet_id = 7
+    due, owner = set(), object()
+    st.inflight.watch(due, owner)
+    assert st.inflight.begin_run(st, [], 100, 0xFFFF) == []
+    assert st.packet_id == 7 and not due
+
+
+def test_a_run_puts_its_session_in_the_due_set():
+    """The session is in the set of sessions holding entries once the run
+    stores one, as after a ``begin``; a run that stores none leaves it
+    out."""
+    st = ClientState(0, 16)
+    due, owner = set(), object()
+    st.inflight.watch(due, owner)
+    assert st.inflight.begin_run(st, [entry()], 0, 0xFFFF) == [BEGIN_FULL]
+    assert not due
+    assert st.inflight.begin_run(st, [entry(), entry()], 100, 0xFFFF) == [1, 2]
+    assert due == {owner}
+
+
+def test_one_acquisition_a_run():
+    """Under an enabled lock plane a run of forty takes the session's
+    inflight lock once; forty begins take it forty times."""
+    DEFAULT_PLANE.arm()
+    try:
+        stats = DEFAULT_PLANE.stats("inflight")
+        st = ClientState(0, 16)
+        st.inflight.set(entry(1))
+        before = stats.acquisitions
+        assert st.inflight.begin_run(st, [entry() for _ in range(40)], 100, 0xFFFF) == list(range(2, 42))
+        assert stats.acquisitions - before == 1
+        before = stats.acquisitions
+        for _ in range(40):
+            st.inflight.begin(st, entry(), 100, 0xFFFF)
+        assert stats.acquisitions - before == 40
     finally:
         DEFAULT_PLANE.disarm()

@@ -176,6 +176,20 @@ EXPORTED = [
         "$SYS/broker/fanout/qos_begin_ns",
     ),
     (
+        "qos_runs",
+        "mqtt_tpu_qos_runs_total",
+        "Runs of one socket's QoS>0 deliveries in a completion slice whose "
+        "inflight bookkeeping was settled at once",
+        "$SYS/broker/fanout/qos_runs",
+    ),
+    (
+        "qos_run_entries",
+        "mqtt_tpu_qos_run_entries_total",
+        "QoS>0 deliveries whose bookkeeping a run settled (the rest were "
+        "begun one at a time)",
+        "$SYS/broker/fanout/qos_run_entries",
+    ),
+    (
         "housekeep_ticks",
         "mqtt_tpu_housekeep_ticks_total",
         "Housekeeping ticks: the once-a-second expiry and eviction passes",

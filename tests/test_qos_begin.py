@@ -8,15 +8,21 @@ QoS, retain flag and packet id written, and the cap, the id walk, the
 store, the send quota and its park as three calls on the map. Entries
 equal field for field, frames byte for byte, through every way out (a
 slice's cork, the native flush, the outbound queue) and through
-``publish_to_client``. CPU backend: entries, bytes and counts."""
+``publish_to_client``. A slice's QoS>0 deliveries to a socket it keeps a
+record of are begun as one run (``Server._settle_run``,
+``Inflight.begin_run``): held to the same slices begun a delivery at a
+time, write for write, through refusals, parks, an early cork write and
+every way a record ends. CPU backend: entries, bytes and counts."""
 
 import asyncio
 import logging
+import threading
 
 import pytest
 
 from mqtt_tpu import Options
 from mqtt_tpu.hooks import ON_PACKET_ID_EXHAUSTED, ON_QOS_PUBLISH, Hook
+from mqtt_tpu.inflight import Inflight
 from mqtt_tpu.packets import (
     PUBLISH,
     FixedHeader,
@@ -484,3 +490,253 @@ class TestTheHookDispatch:
             await rig.slice.h.shutdown()
 
         run(scenario())
+
+
+# -- a slice's run: one socket's QoS>0 deliveries begun together --------
+
+RUN_CASES = [
+    "slices", "cap", "exhausted", "quota", "early_cut",
+    "enqueue_frame", "publish_to_client", "stop", "stop_from_another_loop",
+    "hooked",
+]
+# what the run moves that the per-delivery bookkeeping does not: its own
+# counts, the hooked begins the oracle's forced gate counts, the time
+RUN_ONLY = {"qos_runs", "qos_run_entries", "qos_begins_hooked", "qos_begin_ns"}
+
+
+def outcome(monkeypatch, case, per_delivery):
+    """Two completion slices of QoS1 publishes to three subscribers whose
+    sockets the slices keep records of, set up as ``case`` says; with
+    ``per_delivery`` the ``on_qos_publish`` gate is forced shut, so each
+    delivery is begun on its own (``Inflight.begin``) with no hook to
+    call: the oracle. What the sessions, the sockets, the hooks and the
+    counts came to."""
+    import mqtt_tpu.clients as clients_mod
+
+    async def scenario():
+        rig = Rig(monkeypatch)
+        srv = rig.server
+        hook = Recorder(
+            (ON_QOS_PUBLISH, ON_PACKET_ID_EXHAUSTED) if case == "hooked"
+            else (ON_PACKET_ID_EXHAUSTED,)
+        )
+        srv.add_hook(hook)
+        if case == "quota":
+            await rig.join(3, version=5, props=Properties(receive_maximum=2))
+        else:
+            await rig.join(3)
+        s0, s1, s2 = rig.subs
+        caps = srv.options.capabilities
+        held = publish("ingest", 1, b"h", "x")
+        if case == "cap":
+            caps.maximum_inflight = 3
+            s1.state.inflight.set(legacy_entry(held, 1, False, 2))
+        elif case == "exhausted":
+            caps.maximum_packet_id = 4
+            for pid in (1, 3):
+                s0.state.inflight.set(legacy_entry(held, 1, False, pid))
+        elif case == "early_cut":
+            # three frames fill the cork; s1's third and later are refused
+            # at the cap, so that its frames would cross the bound early
+            n = len(srv._encode_variant(held, 4 | 1 << 3, None)[0])
+            monkeypatch.setattr(clients_mod, "CORK_MAX_BYTES", 3 * n)
+            caps.maximum_inflight = 5
+            for pid in (7, 8, 9):
+                s1.state.inflight.set(legacy_entry(held, 1, False, pid))
+        extra = publish("ingest", 1, b"extra", rig.pub.id)
+        # the deliveries a stop left in s2's run (None: no stop yet)
+        left = []
+
+        async def stop_there():
+            s2.stop()
+
+        def after(payload):
+            if payload != b"p1":
+                return
+            if case == "enqueue_frame":
+                srv._enqueue_frame(s0, PING, lambda: None)
+            elif case == "publish_to_client":
+                srv.publish_to_client(s1, Subscription(filter="t/#", qos=2), extra)
+            elif case.startswith("stop"):
+                rec = s2._slice
+                if case == "stop":
+                    s2.stop()
+                else:
+                    # the stop a takeover makes from another loop (a loop
+                    # shard's), while this loop is inside the slice
+                    t = threading.Thread(target=asyncio.run, args=(stop_there(),))
+                    t.start()
+                    t.join()
+                left.append(len(rec.entries))
+
+        rig.slice.after_publish = after
+        if per_delivery:
+            gate = srv._no_hook_provides
+            monkeypatch.setattr(
+                srv, "_no_hook_provides",
+                lambda name, events: name != "qos_publish" and gate(name, events),
+            )
+        pks = [publish("ingest", 1, b"p%d" % i, rig.pub.id) for i in range(6)]
+        before = srv._slice_counters()
+        info0 = vars(srv.info).copy()
+        del rig.slice.log[:]
+        rig.slice_of(pks[:4])
+        rig.slice_of(pks[4:])
+        after_counts = srv._slice_counters()
+        moved = {
+            k: after_counts[k] - before[k]
+            for k in after_counts
+            if isinstance(after_counts[k], int) and k not in RUN_ONLY
+        }
+        info = {
+            k: v - info0[k] for k, v in vars(srv.info).items()
+            if isinstance(v, int) and k in info0
+        }
+        sessions = {
+            cl.id: (
+                dict(cl.state.inflight.internal), cl.state.packet_id,
+                cl.state.inflight.send_quota, cl.state.out_bytes,
+                cl.state.out_writes,
+            )
+            for cl in rig.subs
+        }
+        runs = (
+            after_counts["qos_runs"] - before["qos_runs"],
+            after_counts["qos_run_entries"] - before["qos_run_entries"],
+            after_counts["qos_begins_hooked"] - before["qos_begins_hooked"],
+            left,
+        )
+        writes = list(rig.slice.log)
+        raw = await drained(srv, rig.readers)
+        await rig.slice.h.shutdown()
+        return {
+            "sessions": sessions,
+            "counts": moved,
+            "info": info,
+            "writes": writes,
+            "frames": {cid: frames_of(b) for cid, b in raw.items()},
+            # a session's hook calls in order (a run tells its refusals
+            # when it is settled: sessions interleave otherwise)
+            "hook": {
+                cl.id: [c[1:] for c in hook.calls if c[0] == cl.id]
+                for cl in rig.subs
+            },
+        }, runs
+
+    return run(scenario())
+
+
+@pytest.mark.parametrize("case", RUN_CASES)
+def test_a_run_leaves_what_the_per_delivery_bookkeeping_leaves(
+    monkeypatch, case
+):
+    """Entries field for field, ids, cursor, quota, ``info.inflight``,
+    every transport write and the frames byte for byte, the hooks' calls
+    in order and every count: a slice's runs against the same slices
+    begun a delivery at a time."""
+    got, runs = outcome(monkeypatch, case, per_delivery=False)
+    want, oracle_runs = outcome(monkeypatch, case, per_delivery=True)
+    assert got == want
+    assert oracle_runs[:2] == (0, 0)
+    begun = got["counts"]["qos_begins"]
+    sessions = got["sessions"]
+    if case == "hooked":
+        # a provider of on_qos_publish: no run, each entry dispatched
+        assert runs[:3] == (0, 0, begun)
+        assert [c[2] for c in got["hook"]["s0"]] == [1, 2, 3, 4, 5, 6]
+        return
+    assert runs[2] == 0 and runs[0] >= 3 and runs[1] > 0
+    assert got["info"]["inflight"] == sum(
+        len(s[0]) for s in sessions.values()
+    ) - sum(len(held) for held in want_held(case).values())
+    for entries, *_ in sessions.values():
+        assert all(e.packet_id == pid for pid, e in entries.items())
+    if case in ("slices", "quota", "cap", "exhausted"):
+        # every delivery joined a run
+        assert runs[1] == begun == 18
+    if case == "slices":
+        assert runs[:3] == (6, 18, 0)
+        for entries, cursor, *_ in sessions.values():
+            assert sorted(entries) == list(range(1, 7)) and cursor == 6
+    elif case == "cap":
+        assert got["info"]["inflight_dropped"] == 3 + 4 + 3  # s1 holds one
+    elif case == "exhausted":
+        # s0 holds ids 1 and 3 of 4: two deliveries take 2 and 4
+        assert [
+            bytes(c[0].payload) for c in got["hook"]["s0"]
+        ] == [b"p2", b"p3", b"p4", b"p5"]
+        assert all(c[1] == "exhausted" for c in got["hook"]["s0"])
+        assert len(got["hook"]["s1"]) == len(got["hook"]["s2"]) == 2
+    elif case == "quota":
+        for entries, _cursor, quota, *_ in sessions.values():
+            assert quota == 0
+            assert [e.expiry for e in entries.values()] == [0, 0] + [-1] * 4
+        assert all(len(f) == 2 for f in got["frames"].values())
+    elif case == "early_cut":
+        assert got["counts"]["cork_early_writes"] == 2  # s0's and s2's
+        # their records end at the cut: p3 is begun on its own
+        assert runs[1] == begun - 2
+        assert len(got["frames"]["s1"]) == 2
+    elif case == "publish_to_client":
+        # the run's ids were taken before the per-target one: in order
+        entries = sessions["s1"][0]
+        assert [bytes(entries[pid].payload) for pid in sorted(entries)] == [
+            b"p0", b"p1", b"extra", b"p2", b"p3", b"p4", b"p5"
+        ]
+    elif case.startswith("stop"):
+        # the stopped session keeps the entries of what it was sent
+        assert sorted(sessions["s2"][0]) == [1, 2]
+        assert len(got["frames"]["s2"]) == 2
+        # the owner's stop settles the run; another loop's only drops
+        # the record, and the slice settles the run at its end
+        assert runs[3] == [0 if case == "stop" else 2]
+    elif case == "enqueue_frame":
+        assert got["counts"]["deliveries_queue"] == 4  # s0's p2..p5
+
+
+def want_held(case):
+    """The entries a case's sessions held before its slices."""
+    return {
+        "cap": {"s1": [2]},
+        "exhausted": {"s0": [1, 3]},
+        "early_cut": {"s1": [7, 8, 9]},
+    }.get(case, {})
+
+
+@pytest.mark.parametrize("stored", [0, 1])
+def test_a_run_that_fails_to_settle_sends_none_of_its_frames(
+    monkeypatch, stored
+):
+    """A run whose bookkeeping raises (``Inflight.begin_run``, here after
+    storing ``stored`` of its entries): none of its frames reaches a
+    socket, so none leaves with the variant's placeholder id, and the
+    counts their appends moved are taken back; what the failed call
+    stored stays in the session, counted in ``info.inflight``."""
+
+    def failing(self, state, entries, cap, maximum):
+        with self._lock:
+            for pk in entries[:stored]:
+                self._begin_locked(state, pk, cap, maximum)
+        raise RuntimeError("injected")
+
+    async def scenario():
+        rig = Rig(monkeypatch)
+        srv = rig.server
+        await rig.join(2)
+        monkeypatch.setattr(Inflight, "begin_run", failing)
+        before = srv._slice_counters()
+        inflight0 = srv.info.inflight
+        rig.slice_of(
+            [publish("ingest", 1, b"p%d" % i, rig.pub.id) for i in range(3)]
+        )
+        after = srv._slice_counters()
+        raw = await drained(srv, rig.readers)
+        await rig.slice.h.shutdown()
+        assert raw == {"s0": b"", "s1": b""}
+        assert [len(cl.state.inflight) for cl in rig.subs] == [stored] * 2
+        assert srv.info.inflight - inflight0 == 2 * stored
+        for k in ("cork_frames", "deliveries_cork", "qos_runs"):
+            assert after[k] == before[k], k
+        assert after["qos_begins"] - before["qos_begins"] == 6
+
+    run(scenario())

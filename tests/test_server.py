@@ -565,7 +565,7 @@ class TestSysTopics:
             # the 20 of the reference's tree, the trie's three counts
             # and the two each of the ingest run and the ack run, the
             # direct feeder's reads, the eight of the way out, the
-            # five of shared-group selection and the three of the QoS>0
+            # five of shared-group selection and the five of the QoS>0
             # bookkeeping
             assert {
                 "$SYS/broker/topics/particles",
@@ -592,12 +592,14 @@ class TestSysTopics:
                 "$SYS/broker/fanout/qos_begins",
                 "$SYS/broker/fanout/qos_begins_hooked",
                 "$SYS/broker/fanout/qos_begin_ns",
+                "$SYS/broker/fanout/qos_runs",
+                "$SYS/broker/fanout/qos_run_entries",
                 "$SYS/broker/housekeep/ticks",
                 "$SYS/broker/housekeep/visits",
                 "$SYS/broker/housekeep/ns",
                 "$SYS/broker/housekeep/sys_publish_ns",
             } <= base
-            assert len(base) == 48
+            assert len(base) == 50
             await h.shutdown()
 
         run(scenario())
